@@ -114,12 +114,20 @@ def _detect_local_devices() -> int:
     kind, count = sniff_accelerator()
     if kind == "tpu":
         return count
-    try:
-        import jax
-
-        return jax.local_device_count()
-    except Exception:  # noqa: BLE001 - no jax / no devices in agent is fine
-        return 1
+    # nothing visible in /dev or sysfs (a sealed VM may show neither,
+    # and the CPU test mesh never does): ask a short-lived child, which
+    # has released whatever it touched by the time it is reaped
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.local_device_count())"],
+        capture_output=True, text=True, timeout=120,
+    )
+    if out.returncode != 0:
+        raise RuntimeError(
+            "cannot count local devices: the probe child failed: "
+            + out.stderr.strip()[-500:]
+        )
+    return int(out.stdout.split()[-1])
 
 
 class ElasticAgent:

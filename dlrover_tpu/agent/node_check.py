@@ -41,13 +41,6 @@ def _probe_payload() -> float:
     import jax
     import jax.numpy as jnp
 
-    platform = os.environ.get(EnvKey.PLATFORM)
-    if platform:  # hermetic tests force the CPU backend
-        try:
-            jax.config.update("jax_platforms", platform)
-        except RuntimeError:
-            pass
-
     num_nodes = int(os.environ.get(EnvKey.NODE_NUM, "1"))
     coordinator = os.environ.get(EnvKey.COORDINATOR, "")
     if num_nodes > 1 and coordinator:

@@ -89,7 +89,7 @@ def publish_device_memory() -> int:
     """Per-device HBM used/limit gauges + total used MB.
 
     Reads ``jax.local_devices()[i].memory_stats()`` — None on backends
-    without it (CPU, some tunnels), so every field access is None-safe
+    without it (CPU), so every field access is None-safe
     and a statless backend publishes nothing and returns 0. Must only be
     called from the process that owns the chips (the trainer)."""
     try:
@@ -113,7 +113,7 @@ def publish_device_memory() -> int:
 
 def local_hbm_used_mb() -> int:
     """HBM bytes in use across this process's local devices (0 if the
-    runtime doesn't expose memory_stats — e.g. CPU or tunneled backends).
+    runtime doesn't expose memory_stats — e.g. CPU).
     Also refreshes the per-device ``dlrover_tpu_device_memory_bytes``
     gauges as a side effect."""
     return publish_device_memory()

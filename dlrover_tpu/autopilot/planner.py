@@ -6,7 +6,7 @@ repo already owns: every candidate point is AOT-lowered on the host
 (``parallel/dry_run.py`` — per-device peak memory and FLOPs without
 touching a chip), filtered by the device-memory envelope
 (``parallel/auto.py device_hbm_bytes``, overridable via
-``DLROVER_TPU_DEVICE_HBM_BYTES`` for CPU/tunneled backends), and ranked
+``DLROVER_TPU_DEVICE_HBM_BYTES`` for CPU backends), and ranked
 by the schedule-aware roofline (``parallel/cost_model.py``). The MPMD
 schedule axis (2412.14374) enters as an extra point per eligible stage
 count, costed with the per-stage heterogeneous estimates behind

@@ -272,14 +272,14 @@ def run_scenario(scenario: Scenario, work_dir: str, *,
             )
             env = dict(os.environ)
             env.update(env_extra or {})
-            env.setdefault(EnvKey.PLATFORM, "cpu")
+            env.setdefault("JAX_PLATFORMS", "cpu")
             env.setdefault(EnvKey.DEVICE_COUNT_OVERRIDE, "1")
             # hermetic compile cache, shared across this scenario's legs
-            # (the satellite shared-dir contract) but never across
-            # scenarios/test runs — a stale /tmp hit would silently turn
-            # a cold-compile assertion warm
-            env.setdefault(EnvKey.COMPILE_CACHE_SHARED_DIR,
-                           os.path.join(work_dir, "compile_cache"))
+            # but never across scenarios/test runs — a stale hit would
+            # silently turn a cold-compile assertion warm
+            if "JAX_COMPILATION_CACHE_DIR" not in (env_extra or {}):
+                env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(
+                    work_dir, "compile_cache")
             # IPC dirs hold AF_UNIX sockets, whose path limit (~108
             # chars) a nested work_dir easily exceeds: keep them short
             # and top-level, removed in the finally below

@@ -272,9 +272,9 @@ def take_restore_prefetch(ckpt_dir: str, node_id: int
 
 
 class CheckpointEngine:
-    # async snapshots supersede older pending ones, which is safe only
-    # when one node's snapshot is the whole checkpoint; sharded engines
-    # need cross-node step agreement and keep the sync path
+    # async snapshots skip steps while the writer is busy, which is safe
+    # only when one node's snapshot is the whole checkpoint; sharded
+    # engines need cross-node step agreement and keep the sync path
     supports_async_snapshot = True
 
     def __init__(
@@ -440,7 +440,8 @@ class CheckpointEngine:
             # the training-path cost the Young-Daly tuner prices (C)
             _snapshot_seconds.observe(snap_s)
             logger.info(
-                "step %d snapshotted to shm in %.3fs", step, snap_s,
+                "step %d snapshotted to shm in %.3fs%s", step, snap_s,
+                " (async writer)" if _async_seq is not None else "",
             )
             return True
         finally:
@@ -489,14 +490,23 @@ class CheckpointEngine:
         "Array has been deleted"), then a worker thread blocks and writes
         the arena while the main thread keeps dispatching steps.
 
-        Costs one transient state copy in HBM; callers with states near
-        the HBM limit (the 1B ckpt bench) use the sync path. Supersede
-        semantics: only the newest pending snapshot is written.
+        Costs ONE transient state copy in HBM, never two: a request
+        that arrives while the writer still holds the previous copy is
+        skipped (the next cadence tick takes a fresh one), so a cadence
+        faster than the device-to-host copy cannot stack copies — a
+        gpt2-medium AdamW state is 4.5 GB, and two copies beside the
+        state and the step's temporaries exceed a 16 GB chip. Callers
+        with states near the HBM limit (the 1B ckpt bench) use the sync
+        path.
         """
         if not self._async_eligible():
             self.save_to_memory(step, state)
             return
         import jax
+
+        with self._pending_lock:
+            if self._pending is not None or self._async_writing:
+                return
 
         if self._device_copy is None:
             import jax.numpy as jnp
@@ -534,6 +544,10 @@ class CheckpointEngine:
             except Exception:  # noqa: BLE001 - snapshots are best-effort
                 logger.exception("async snapshot at step %d failed", step)
             finally:
+                # let the device copy go BEFORE reporting idle: this
+                # thread's locals would otherwise hold it through the
+                # wait above, beside the next request's copy
+                pending = snap = None
                 with self._pending_lock:
                     self._async_writing = False
 

@@ -7,12 +7,14 @@ that translation would be a bug: libtpu grants EXCLUSIVE chip access to
 the first process that initializes it, so a launcher or agent that calls
 ``jax.local_device_count()`` steals the chips from the trainer child it
 is about to spawn. Instead we look at what the kernel already exposes:
-the TPU driver's ``/dev/accel*`` nodes (v2-v4 PCI hosts), falling back
-to a sysfs PCI scan for Google (vendor 0x1ae0) *processing accelerator*
-(class 0x1200xx) functions — the class check matters because gVNIC NICs
-share Google's vendor id, and on v5+ hosts the chips are VFIO-bound so
-``/dev`` alone cannot distinguish them from any other passthrough
-device.
+the TPU driver's ``/dev/accel*`` nodes (v2-v4 PCI hosts); then, on
+v5+ hosts where the chips are VFIO-bound, the ``/dev/vfio/<group>``
+nodes whose IOMMU group holds a Google (vendor 0x1ae0) function — the
+groups this process can actually open, which on a one-chip VM of a
+four-chip host is one although sysfs shows four functions; last a
+sysfs PCI scan for Google *processing accelerator* (class 0x1200xx)
+functions or known TPU device ids — the check matters because gVNIC
+NICs share Google's vendor id.
 
 The returned count uses JAX *device* semantics, not chip semantics:
 v2/v3 chips carry two TensorCores each (two JAX devices per chip,
@@ -35,6 +37,9 @@ _PCI_CLASS_PROCESSING_ACCEL = "0x1200"  # PCI class 0x12, subclass 0x00
 # PCI device id -> JAX devices (TensorCores) per chip. v2/v3 expose two
 # cores per chip; v4+ (megacore) and the v5/v6 families expose one.
 _CORES_PER_CHIP = {"0x0027": 2, "0x0037": 2}
+# TPU functions that report an unassigned PCI class: v5e (0x0063) shows
+# class 0xff0000 on the v5litepod hosts this was brought up on
+_TPU_PCI_DEVICE_IDS = {"0x0063"}
 
 
 def _read(path: str) -> str:
@@ -54,6 +59,7 @@ def sniff_accelerator(
     dev_root: str = "/dev",
     sys_pci_root: str = "/sys/bus/pci/devices",
     sys_accel_root: str = "/sys/class/accel",
+    sys_iommu_root: str = "/sys/kernel/iommu_groups",
 ) -> tuple[str, int]:
     """Return ``(kind, local_device_count)`` with ``kind`` one of
     ``"tpu"`` / ``"cpu"``; never touches the accelerator.
@@ -90,13 +96,23 @@ def sniff_accelerator(
                 )
             total += _chip_devices(pci_dir)
         return "tpu", total
+    # VFIO-bound chips: one /dev/vfio/<group> node per chip this
+    # process may open (the bare /dev/vfio/vfio container is not one)
     total = 0
+    for node in glob.glob(os.path.join(dev_root, "vfio", "[0-9]*")):
+        group = os.path.join(sys_iommu_root, os.path.basename(node),
+                             "devices", "*")
+        for dev in glob.glob(group):
+            if _read(os.path.join(dev, "vendor")) == _GOOGLE_PCI_VENDOR:
+                total += _chip_devices(dev)
+    if total:
+        return "tpu", total
     for dev in glob.glob(os.path.join(sys_pci_root, "*")):
         if _read(os.path.join(dev, "vendor")) != _GOOGLE_PCI_VENDOR:
             continue
         if _read(os.path.join(dev, "class")).startswith(
             _PCI_CLASS_PROCESSING_ACCEL
-        ):
+        ) or _read(os.path.join(dev, "device")) in _TPU_PCI_DEVICE_IDS:
             total += _chip_devices(dev)
     if total:
         return "tpu", total

@@ -118,14 +118,6 @@ class EnvKey:
     CKPT_META_DIR = "DLROVER_TPU_CKPT_META_DIR"
     MOCK_ERR_RANK = "DLROVER_TPU_MOCK_ERR_RANK"
     DEVICE_COUNT_OVERRIDE = "DLROVER_TPU_DEVICE_COUNT"
-    COMPILE_CACHE_DIR = "DLROVER_TPU_COMPILE_CACHE"
-    # escape hatch: pin the ONE compile-cache directory every
-    # incarnation, parked standby, and serving replica on this node
-    # shares (XLA persistent cache + serialized AOT executables). The
-    # default derives from the job name for the same sharing property;
-    # this exists for operators who must place the cache explicitly
-    # (job-shared NFS, a ramdisk, a pre-warmed image path).
-    COMPILE_CACHE_SHARED_DIR = "DLROVER_TPU_COMPILE_CACHE_DIR"
     # coordination-service join timeout (seconds) for
     # jax.distributed.initialize — the launcher scales it with the node
     # count (reference analog: auto_configure_params' comm timeouts,
@@ -176,9 +168,6 @@ class EnvKey:
     # (telemetry/snapshot_delta.py): every Kth push is a full snapshot,
     # the ones between suppress unchanged families; 0/1 = always full
     SNAPSHOT_FULL_EVERY = "DLROVER_TPU_SNAPSHOT_FULL_EVERY"
-    # platform/backend selection (run.py --platform mirror; "cpu"
-    # forces JAX_PLATFORMS=cpu in children)
-    PLATFORM = "DLROVER_TPU_PLATFORM"
     # directory for cross-process handshake files (standby promotion
     # payloads, paral-config mirror, chaos scenario legs); default
     # tempdir — co-hosted jobs override to avoid collisions
@@ -214,7 +203,7 @@ class EnvKey:
     CKPT_PERSIST_WORKERS = "DLROVER_TPU_CKPT_PERSIST_WORKERS"
     CKPT_PERSIST_CHUNK_MB = "DLROVER_TPU_CKPT_PERSIST_CHUNK_MB"
     # strategy autopilot (DESIGN.md §24): the stated per-device memory
-    # envelope for backends whose runtime reports none (CPU/tunneled —
+    # envelope for backends whose runtime reports none (CPU —
     # the planner's feasibility filter), and the per-job bound on
     # closed-loop retunes the master-side controller may apply
     DEVICE_HBM_BYTES = "DLROVER_TPU_DEVICE_HBM_BYTES"

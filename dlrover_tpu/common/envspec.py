@@ -80,10 +80,6 @@ SPECS: tuple[EnvVar, ...] = (
     EnvVar("DLROVER_TPU_RESTART_COUNT", "0",
            "incarnation counter the agent bumps per respawn", "§6",
            restart_required=True),
-    EnvVar("DLROVER_TPU_PLATFORM", None,
-           "platform/backend selection (cpu|tpu|k8s|ray contexts); "
-           "'cpu' forces JAX_PLATFORMS=cpu in children", "§1",
-           restart_required=True),
     EnvVar("DLROVER_TPU_ACCELERATOR", None,
            "accelerator kind hint set by the launcher", "§2",
            restart_required=True),
@@ -151,12 +147,6 @@ SPECS: tuple[EnvVar, ...] = (
            "preemption notice poll URL (GCE maintenance-event "
            "convention)", "§16"),
     # -------------------------------------------------------- compile cache
-    EnvVar("DLROVER_TPU_COMPILE_CACHE", None,
-           "XLA persistent compilation cache dir (location only)", "§17",
-           restart_required=True),
-    EnvVar("DLROVER_TPU_COMPILE_CACHE_DIR", None,
-           "shared artifact dir for serialized AOT executables + XLA "
-           "cache (default keyed by job name)", "§17"),
     EnvVar("DLROVER_TPU_AOT_CACHE", "1",
            "'0' disables the serialized-AOT-executable cache", "§17"),
     EnvVar("DLROVER_TPU_FALLBACK_AOT", None,
@@ -215,7 +205,7 @@ SPECS: tuple[EnvVar, ...] = (
     # ------------------------------------------------------------ autopilot
     EnvVar("DLROVER_TPU_DEVICE_HBM_BYTES", None,
            "stated per-device memory envelope in bytes for backends "
-           "whose runtime reports none (CPU/tunneled); the planner's "
+           "whose runtime reports none (CPU); the planner's "
            "AOT feasibility filter uses it", "§24"),
     EnvVar("DLROVER_TPU_AUTOPILOT_MAX_RETUNES", "2",
            "per-job bound on closed-loop autopilot retunes; 0 keeps "

@@ -47,12 +47,13 @@ def _load_lib():
         if _lib is not None:
             return _lib
         # run make unconditionally: it's a no-op when the .so is current,
-        # and an edited kv_variable.cc must never load stale. Tolerate a
-        # missing toolchain when a prebuilt .so exists.
+        # and an edited kv_variable.cc must never load stale. The .so is
+        # git-ignored and built from the tracked sources at first use;
+        # a failed build is an error, never a stale library.
         proc = subprocess.run(
             ["make", "-C", _NATIVE_DIR], capture_output=True, text=True
         )
-        if proc.returncode != 0 and not os.path.exists(_LIB_PATH):
+        if proc.returncode != 0:
             raise RuntimeError(
                 f"native build failed:\n{proc.stderr[-4000:]}"
             )

@@ -48,7 +48,6 @@ from dlrover_tpu import chaos
 # decode_msg is re-exported: tests and tools treat this module as the
 # wire-protocol surface for the embedding tier
 from dlrover_tpu.common.array_wire import decode_msg, encode_msg  # noqa: F401
-from dlrover_tpu.common.constants import EnvKey
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.common.msg_server import (
     ArrayMsgServer,
@@ -967,8 +966,7 @@ class EmbeddingServerScaler:
             cmd += ["--ckpt-dir", self.ckpt_dir]
         proc = subprocess.Popen(
             cmd, stdout=subprocess.PIPE, text=True,
-            env={**os.environ, "JAX_PLATFORMS": "cpu",
-                 EnvKey.PLATFORM: "cpu"},
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
         )
         # bounded readiness wait: a wedged child must not park scale()
         # (and with it the auto-scaler tick + stop_all) on readline

@@ -173,7 +173,7 @@ class CompileCacheService:
     def export_state(self, spill_dir: str | None) -> list[dict]:
         """Entry metadata for the master snapshot, blobs spilled to
         ``spill_dir`` (same ``<key with / -> _>.aot`` naming as the
-        node-local ``DLROVER_TPU_COMPILE_CACHE_DIR`` layer, so the dir
+        node-local compile-cache layer (``compile_cache.cache_root``), so the dir
         is inspectable with the same tooling). ``spill_dir=None``
         exports metadata only — a restarted master then serves misses
         for the blobs, which is a degradation, not corruption.

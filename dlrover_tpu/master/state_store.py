@@ -26,7 +26,7 @@ is the full recoverable control-plane state:
   blended costs;
 - ``compile_cache``: entry metadata in the snapshot, blobs spilled to
   ``<state_dir>/compile_cache`` with the same ``<key>.aot`` naming as
-  the node-local ``DLROVER_TPU_COMPILE_CACHE_DIR`` layer — a restarted
+  the node-local compile-cache layer — a restarted
   master answers ``CompileCacheGet`` warm.
 
 Components that were in the snapshot are restored; everything else

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec
 
 Params = Any
 
@@ -62,7 +63,7 @@ class TransformerConfig:
     # activation checkpointing (atorch checkpoint_optimization.py).
     # 1 = remat every layer (classic); requires n_layers % k == 0.
     remat_interval: int = 1
-    # "dense" | "flash" | "flash_own" | "splash" | "ring" | "ulysses"
+    # "dense" | "flash" | "splash" | "ring" | "ulysses"
     attention: str = "dense"
     # splash only: sliding-window size (0 = full causal); the sparse
     # kernel skips fully-masked blocks, so long seqs pay O(S * window)
@@ -739,7 +740,7 @@ def make_loss_fn(cfg: TransformerConfig, strategy, mesh) -> Callable:
     "ring" (long_context preset) and "ulysses" run sequence-parallel
     attention over the mesh's "sequence" axis (ops/ring_attention.py /
     ops/ulysses.py), degrading to dense when the mesh has no sequence
-    axis; "flash"/"flash_own"/"splash" pick per-device kernels.
+    axis; "flash"/"splash" pick per-device Pallas kernels.
     """
     from dlrover_tpu.parallel.partition import constrain as _constrain
 
@@ -759,22 +760,44 @@ def make_loss_fn(cfg: TransformerConfig, strategy, mesh) -> Callable:
     elif cfg.attention == "flash":
         from dlrover_tpu.ops.flash_attention import flash_attention
 
-        attn = flash_attention
-    elif cfg.attention == "flash_own":
-        # this repo's full fwd+bwd Pallas kernel pair (no library
-        # fallback); interpret mode makes it runnable on the CPU mesh
-        from dlrover_tpu.ops.flash_attention import flash_attention_own
-
-        def attn(q, k, v, causal=True):
-            return flash_attention_own(q, k, v, causal)
+        attn = _per_device(flash_attention, mesh)
     elif cfg.attention == "splash":
         from dlrover_tpu.ops.splash_attention import make_splash_attention
 
-        attn = make_splash_attention(
+        attn = _per_device(make_splash_attention(
             cfg.attention_window,
             native_gqa=bool(extra.get("native_gqa", False)),
-        )
+        ), mesh)
     return partial(loss_fn, cfg=cfg, attention_fn=attn, constrain=pin)
+
+
+def _per_device(attn: AttentionFn, mesh) -> AttentionFn:
+    """Run a Pallas attention kernel per device under ``shard_map``.
+
+    XLA cannot partition a Mosaic kernel: in a program that spans
+    several devices the TPU compiler refuses it ("Mosaic kernels cannot
+    be automatically partitioned"). Attention is independent per batch
+    row and per head, so each device runs the kernel on its own block —
+    batch over the data axes, heads over the tensor axis — and no
+    collective is added. A one-device mesh needs no wrapping.
+    """
+    if mesh.size == 1:
+        return attn
+    from dlrover_tpu.ops.collectives import shard_map_nocheck
+    from dlrover_tpu.parallel.mesh import batch_axes
+
+    batch = batch_axes(mesh)
+    heads = "tensor" if "tensor" in mesh.axis_names else None
+    spec = PartitionSpec(batch or None, None, heads, None)
+
+    def sharded(q, k, v, causal: bool = True):
+        return shard_map_nocheck(
+            partial(attn, causal=causal), mesh=mesh,
+            in_specs=(spec, spec, spec), out_specs=spec,
+        )(q, k, v)
+
+    sharded.supports_gqa = getattr(attn, "supports_gqa", False)
+    return sharded
 
 
 def _blockwise_ce(

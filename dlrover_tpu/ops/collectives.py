@@ -31,24 +31,11 @@ from jax import lax
 
 
 def shard_map_nocheck(f: Callable, *, mesh, in_specs, out_specs) -> Callable:
-    """``shard_map`` with replication/varying-axis checking disabled,
-    across jax versions: resolves the top-level vs experimental export
-    and the ``check_vma`` vs ``check_rep`` kwarg rename in one place.
-    """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax exposes it under experimental
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    """``shard_map`` with replication/varying-axis checking disabled."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 def _quantize(x: jax.Array) -> tuple[jax.Array, jax.Array]:

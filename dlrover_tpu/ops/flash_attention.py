@@ -1,373 +1,44 @@
-"""Flash attention for TPU: Pallas tiled online-softmax kernels.
+"""Flash attention for TPU: jax's Pallas flash kernel in this repo's layout.
 
 Reference analog: the reference glues flash-attn CUDA kernels into its
 models (atorch/atorch/modules/transformer/layers.py FA wrappers; tfplus
 ships its own fmha C++ op, tfplus/flash_attn/kernels/
-flash_attention_fwd_kernel.cc:28). The TPU-native equivalents are Pallas
-kernels: this module provides
-
-- ``flash_attention(q, k, v, causal=...)``: drop-in for
-  models.transformer.dense_attention ([B, S, H, D] layout). On TPU it
-  dispatches to jax's production Pallas flash kernel (fwd + bwd,
-  jax.experimental.pallas.ops.tpu.flash_attention); elsewhere it falls
-  back to the dense einsum path.
-- ``flash_fwd_pallas``: this repo's own forward kernel — a compact tiled
-  online-softmax implementation (one (batch*head, q-block) grid cell
-  streams K/V blocks through VMEM, carrying running max / sum / output) —
-  runnable in interpret mode on CPU for tests and usable directly for
-  inference-style no-grad calls.
-- ``flash_attention_own``: the differentiable form of the own kernel —
-  custom VJP whose backward is two more Pallas kernels (FlashAttention-2
-  split: a dQ kernel streaming K/V per q-block and a dK/dV kernel
-  streaming Q per k-block, both recomputing probabilities from the saved
-  per-row logsumexp instead of materializing [S, S]).
+flash_attention_fwd_kernel.cc:28). The TPU-native equivalent is a Pallas
+kernel: ``flash_attention(q, k, v, causal=...)`` is a drop-in for
+models.transformer.dense_attention ([B, S, H, D] layout) that always
+dispatches to jax's tiled fwd + bwd kernel
+(jax.experimental.pallas.ops.tpu.flash_attention). There is no silent
+fallback: off-TPU the Pallas lowering raises. Tests on the CPU mesh that
+need a model configured with a kernel attention enter
+``reference_kernels()`` and get the dense einsum instead.
 """
 
 from __future__ import annotations
 
-import functools
+import contextlib
 import math
 
 import jax
-import jax.numpy as jnp
 
-NEG_INF = -1e30
-
-
-# --------------------------------------------------------------- own kernel
+_reference = False
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
-                causal: bool, scale: float):
-    """One (batch*head, q-block) cell: stream K/V blocks, online softmax.
-
-    Refs are blocked to [block_q, D] (q, o) and [S, D] (k, v); the K/V
-    sequence is tiled in ``block_k`` chunks inside the kernel so VMEM
-    holds one chunk at a time.
-    """
-    from jax.experimental import pallas as pl
-
-    block_q, d = q_ref.shape
-    s = k_ref.shape[0]
-    q_idx = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32) * scale
-
-    def body(start, carry):
-        o, m, l = carry
-        k = k_ref[pl.dslice(start * block_k, block_k), :].astype(
-            jnp.float32
-        )
-        v = v_ref[pl.dslice(start * block_k, block_k), :].astype(
-            jnp.float32
-        )
-        logits = q @ k.T  # [block_q, block_k]
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = start * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-        m_new = jnp.maximum(m, logits.max(axis=-1))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(logits - m_new[:, None])
-        l = l * corr + p.sum(axis=-1)
-        o = o * corr[:, None] + p @ v
-        return o, m_new, l
-
-    o0 = jnp.zeros((block_q, d), jnp.float32)
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-
-    num_k = s // block_k
-    if causal:
-        # blocks strictly past the q block's diagonal contribute nothing
-        last = (q_idx + 1) * block_q
-        num_k_live = jax.lax.div(last + block_k - 1, block_k)
-        o, m, l = jax.lax.fori_loop(0, num_k_live, body, (o0, m0, l0))
-    else:
-        o, m, l = jax.lax.fori_loop(0, num_k, body, (o0, m0, l0))
-    o_ref[:] = (o / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    # per-row logsumexp of the scaled logits: the backward recomputes
-    # probabilities from it (p = exp(scale*qk - lse)) instead of saving P
-    lse_ref[:] = m + jnp.log(jnp.maximum(l, 1e-30))
+@contextlib.contextmanager
+def reference_kernels():
+    """Inside, ``flash_attention`` and ``splash_attention`` trace the
+    dense einsum reference in place of their TPU kernels. For tests on
+    the CPU mesh only: nothing in the program enters it, so a kernel
+    config on a device without the kernel is an error, not dense."""
+    global _reference
+    prev, _reference = _reference, True
+    try:
+        yield
+    finally:
+        _reference = prev
 
 
-def _resolve_blocks(S: int, block_q: int, block_k: int) -> tuple[int, int]:
-    block_q = min(block_q, S)
-    block_k = min(block_k, S)
-    if S % block_q or S % block_k:
-        raise ValueError(f"seq {S} not divisible by blocks "
-                         f"({block_q}, {block_k})")
-    return block_q, block_k
-
-
-def _to_bhsd(x: jax.Array) -> jax.Array:
-    B, S, H, D = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
-
-
-def _fwd_call(qt, kt, vt, *, causal: bool, block_q: int, block_k: int,
-              interpret: bool) -> tuple[jax.Array, jax.Array]:
-    """[B*H, S, D] inputs -> (o [B*H, S, D], lse [B*H, S])."""
-    from jax.experimental import pallas as pl
-
-    BH, S, D = qt.shape
-    scale = 1.0 / math.sqrt(D)
-    kernel = functools.partial(
-        _fwd_kernel, block_k=block_k, causal=causal, scale=scale
-    )
-    return pl.pallas_call(
-        kernel,
-        grid=(BH, S // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, S, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, S, D), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q), lambda b, i: (b, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), qt.dtype),
-            jax.ShapeDtypeStruct((BH, S), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qt, kt, vt)
-
-
-def flash_fwd_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                     causal: bool = True, block_q: int = 128,
-                     block_k: int = 128,
-                     interpret: bool | None = None) -> jax.Array:
-    """This repo's Pallas forward kernel. [B, S, H, D] -> [B, S, H, D].
-
-    ``interpret`` defaults to True off-TPU so the same kernel is testable
-    on the CPU mesh.
-    """
-    B, S, H, D = q.shape
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    block_q, block_k = _resolve_blocks(S, block_q, block_k)
-    out, _ = _fwd_call(
-        _to_bhsd(q), _to_bhsd(k), _to_bhsd(v),
-        causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret,
-    )
-    return out.reshape(B, H, S, D).transpose(0, 2, 1, 3)
-
-
-# ------------------------------------------------------------ own backward
-
-
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
-               block_k: int, causal: bool, scale: float):
-    """dQ for one (batch*head, q-block): stream K/V, recompute P row-wise.
-
-    ds = P * (dO @ V^T - delta); dQ = scale * ds @ K — FlashAttention-2's
-    backward with the probabilities rebuilt from the saved logsumexp.
-    """
-    from jax.experimental import pallas as pl
-
-    block_q, d = q_ref.shape
-    s = k_ref.shape[0]
-    q_idx = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32)
-    do = do_ref[:].astype(jnp.float32)
-    lse = lse_ref[:]
-    delta = delta_ref[:]
-
-    def body(start, dq):
-        k = k_ref[pl.dslice(start * block_k, block_k), :].astype(
-            jnp.float32
-        )
-        v = v_ref[pl.dslice(start * block_k, block_k), :].astype(
-            jnp.float32
-        )
-        logits = (q @ k.T) * scale
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = start * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-        p = jnp.exp(logits - lse[:, None])
-        dp = do @ v.T
-        ds = p * (dp - delta[:, None])
-        return dq + ds @ k
-
-    num_k = s // block_k
-    if causal:
-        last = (q_idx + 1) * block_q
-        num_k = jax.lax.div(last + block_k - 1, block_k)
-    dq = jax.lax.fori_loop(
-        0, num_k, body, jnp.zeros((block_q, d), jnp.float32)
-    )
-    dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, *, block_q: int, causal: bool,
-                scale: float):
-    """dK/dV for one (batch*head, k-block): stream Q/dO blocks.
-
-    dV = P^T @ dO; dK = scale * ds^T @ Q. Causal skips Q blocks entirely
-    above the diagonal (their rows can't attend into this k-block).
-    """
-    from jax.experimental import pallas as pl
-
-    block_k, d = k_ref.shape
-    s = q_ref.shape[0]
-    k_idx = pl.program_id(1)
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
-
-    def body(qi, carry):
-        dk, dv = carry
-        q = q_ref[pl.dslice(qi * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[pl.dslice(qi * block_q, block_q), :].astype(
-            jnp.float32
-        )
-        lse = lse_ref[pl.dslice(qi * block_q, block_q)]
-        delta = delta_ref[pl.dslice(qi * block_q, block_q)]
-        logits = (q @ k.T) * scale  # [block_q, block_k]
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-        p = jnp.exp(logits - lse[:, None])
-        dv = dv + p.T @ do
-        dp = do @ v.T
-        ds = p * (dp - delta[:, None])
-        dk = dk + ds.T @ q
-        return dk, dv
-
-    num_q = s // block_q
-    zero = (jnp.zeros((block_k, d), jnp.float32),
-            jnp.zeros((block_k, d), jnp.float32))
-    if causal:
-        # first q block whose rows reach this k-block's first column
-        first = jax.lax.div(k_idx * block_k, block_q)
-        dk, dv = jax.lax.fori_loop(first, num_q, body, zero)
-    else:
-        dk, dv = jax.lax.fori_loop(0, num_q, body, zero)
-    dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
-
-
-def _bwd_call(qt, kt, vt, ot, do_t, lse, *, causal: bool, block_q: int,
-              block_k: int, interpret: bool):
-    from jax.experimental import pallas as pl
-
-    BH, S, D = qt.shape
-    scale = 1.0 / math.sqrt(D)
-    # delta_i = rowsum(dO_i * O_i): the softmax-jacobian diagonal term,
-    # cheap enough to leave to XLA fusion outside the kernels
-    delta = (do_t.astype(jnp.float32) * ot.astype(jnp.float32)).sum(-1)
-
-    full = lambda b, i: (b, 0, 0)  # noqa: E731
-    rows = lambda b, i: (b, i, 0)  # noqa: E731
-    vec = lambda b, i: (b, i)      # noqa: E731
-    vec_full = lambda b, i: (b, 0)  # noqa: E731
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale),
-        grid=(BH, S // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), rows),
-            pl.BlockSpec((None, S, D), full),
-            pl.BlockSpec((None, S, D), full),
-            pl.BlockSpec((None, block_q, D), rows),
-            pl.BlockSpec((None, block_q), vec),
-            pl.BlockSpec((None, block_q), vec),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, D), rows),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), qt.dtype),
-        interpret=interpret,
-    )(qt, kt, vt, do_t, lse, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=block_q, causal=causal,
-                          scale=scale),
-        grid=(BH, S // block_k),
-        in_specs=[
-            pl.BlockSpec((None, S, D), full),
-            pl.BlockSpec((None, block_k, D), rows),
-            pl.BlockSpec((None, block_k, D), rows),
-            pl.BlockSpec((None, S, D), full),
-            pl.BlockSpec((None, S), vec_full),
-            pl.BlockSpec((None, S), vec_full),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, D), rows),
-            pl.BlockSpec((None, block_k, D), rows),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), kt.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), vt.dtype),
-        ],
-        interpret=interpret,
-    )(qt, kt, vt, do_t, lse, delta)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention_own(q: jax.Array, k: jax.Array, v: jax.Array,
-                        causal: bool = True, block_q: int = 128,
-                        block_k: int = 128,
-                        interpret: bool | None = None) -> jax.Array:
-    """Differentiable own-kernel flash attention, [B, S, H, D] layout.
-
-    Forward and backward are all this repo's Pallas kernels (no library
-    fallback): fwd saves (O, lse); bwd runs the dQ and dK/dV kernels.
-    Interpret mode makes the full fwd+bwd pair testable on CPU.
-    """
-    out, _ = _flash_own_fwd(q, k, v, causal, block_q, block_k, interpret)
-    return out
-
-
-def _flash_own_fwd(q, k, v, causal, block_q, block_k, interpret):
-    B, S, H, D = q.shape
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    bq, bk = _resolve_blocks(S, block_q, block_k)
-    qt, kt, vt = _to_bhsd(q), _to_bhsd(k), _to_bhsd(v)
-    ot, lse = _fwd_call(qt, kt, vt, causal=causal, block_q=bq,
-                        block_k=bk, interpret=interpret)
-    out = ot.reshape(B, H, S, D).transpose(0, 2, 1, 3)
-    return out, (qt, kt, vt, ot, lse, (B, S, H, D))
-
-
-def _flash_own_bwd(causal, block_q, block_k, interpret, res, g):
-    qt, kt, vt, ot, lse, (B, S, H, D) = res
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
-    bq, bk = _resolve_blocks(S, block_q, block_k)
-    do_t = _to_bhsd(g)
-    dq, dk, dv = _bwd_call(
-        qt, kt, vt, ot, do_t, lse, causal=causal, block_q=bq,
-        block_k=bk, interpret=interpret,
-    )
-
-    def back(x):
-        return x.reshape(B, H, S, D).transpose(0, 2, 1, 3)
-
-    return back(dq), back(dk), back(dv)
-
-
-flash_attention_own.defvjp(_flash_own_fwd, _flash_own_bwd)
-
-
-# ----------------------------------------------------- production dispatch
+def reference_enabled() -> bool:
+    return _reference
 
 
 def _block_for(seq: int) -> int:
@@ -377,13 +48,10 @@ def _block_for(seq: int) -> int:
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True) -> jax.Array:
-    """Training-path flash attention, dense_attention-compatible.
-
-    On TPU: jax's production Pallas kernel (tiled fwd AND bwd — the bwd
-    is what keeps long-seq training memory flat). Elsewhere: the dense
-    einsum reference (CPU Pallas interpret mode has no bwd kernel).
-    """
-    if jax.devices()[0].platform != "tpu":
+    """Training-path flash attention, dense_attention-compatible:
+    jax's production Pallas kernel (tiled fwd AND bwd — the bwd is what
+    keeps long-seq training memory flat)."""
+    if _reference:
         from dlrover_tpu.models.transformer import dense_attention
 
         return dense_attention(q, k, v, causal=causal)

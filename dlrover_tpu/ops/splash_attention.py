@@ -11,7 +11,9 @@ from O(S^2/2) to O(S*W).
 Exposed through the same AttentionFn interface the transformer uses
 (``[B, S, H, D]``, ``causal`` kwarg), selected via
 ``TransformerConfig.attention = "splash"`` with an optional
-``attention_window``; falls back to (windowed) dense einsum off-TPU.
+``attention_window``. Always the kernel: off-TPU the Pallas lowering
+raises, and CPU tests that need the (windowed) dense einsum enter
+``ops.flash_attention.reference_kernels()``.
 """
 
 from __future__ import annotations
@@ -21,6 +23,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from dlrover_tpu.ops.flash_attention import reference_enabled
 
 
 def _dense_window(q, k, v, *, causal: bool, window: int) -> jax.Array:
@@ -62,7 +66,7 @@ def splash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     instead of the repeat-to-H path, cutting KV memory traffic by H/G.
     """
     n_rep = q.shape[2] // k.shape[2]
-    if jax.devices()[0].platform != "tpu":
+    if reference_enabled():
         if n_rep > 1:
             k = jnp.repeat(k, n_rep, axis=2)
             v = jnp.repeat(v, n_rep, axis=2)

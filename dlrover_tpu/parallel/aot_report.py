@@ -38,17 +38,7 @@ def main(argv=None) -> int:
     p.add_argument("--attention", default="")
     args = p.parse_args(argv)
 
-    import os
-
     import jax
-
-    from dlrover_tpu.common.constants import EnvKey
-
-    # an eagerly-registered TPU plugin beats the JAX_PLATFORMS env var;
-    # the live config does not (same trick as trainer/bootstrap.py)
-    platform = os.environ.get(EnvKey.PLATFORM)
-    if platform:
-        jax.config.update("jax_platforms", platform)
     import numpy as np
     import optax
 

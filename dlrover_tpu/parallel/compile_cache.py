@@ -4,7 +4,7 @@ topology × model-shape × strategy fingerprint (DESIGN.md §17).
 The residual per-failure cost after the warm-recovery path (PR 5) is
 XLA recompilation: respawn/rendezvous/restore are ~0, but every
 incarnation re-traces and re-compiles the same program — ~7s on CPU,
-20-30s per real XLA:TPU compile (BENCH_r04 ``compile_s``). ElasWave
+tens of seconds per real XLA:TPU compile. ElasWave
 (PAPERS.md 2510.00606) closes this gap by making a membership change a
 resharding event instead of a restart; the enabling piece is that the
 program for the post-change topology must already exist.
@@ -19,8 +19,8 @@ This module is the trainer half of that cache:
   CRC-checked envelope (a torn cache file must read as a miss, never a
   misloaded program).
 - ``CompileCacheClient``: two layers — a node-local directory (shared
-  by every incarnation and the parked standby on the host, the
-  ``DLROVER_TPU_COMPILE_CACHE_DIR`` satellite) in front of the
+  by every incarnation and the parked standby on the host; placed by
+  ``JAX_COMPILATION_CACHE_DIR``, see ``cache_root``) in front of the
   master-served store (``master/kv_store.py::CompileCacheService``)
   that survives node relaunches and feeds freshly joined hosts.
 - ``load_or_compile``: the one call sites use — returns the loaded
@@ -177,20 +177,43 @@ def verify_key(base_key: str, *, depth: int) -> str:
 
 def executable_stats(compiled) -> dict:
     """Cheap post-compile facts worth caching beside the executable —
-    today the program's FLOPs (XLA cost analysis), the number the live
-    MFU gauge needs. Computed ONCE at compile time and stored in the
-    envelope, so a warm cache load never re-lowers just to count."""
+    the program's FLOPs (XLA cost analysis), the number the live MFU
+    gauge needs, and how many Pallas kernels it holds
+    (``tpu_custom_call`` in its text: the evidence that a kernel
+    attention config got its kernel). Computed ONCE at compile time and
+    stored in the envelope, so a warm cache load never re-lowers or
+    re-prints the program just to count."""
     from dlrover_tpu.utils.profiler import executable_flops
 
+    stats = {}
     flops = executable_flops(compiled)
-    return {"flops": flops} if flops > 0 else {}
+    if flops > 0:
+        stats["flops"] = flops
+    kernels = compiled.as_text().count("tpu_custom_call")
+    if kernels:
+        stats["pallas_calls"] = kernels
+    return stats
+
+
+def _execution_device_ids(compiled) -> list[int]:
+    """Ids of the devices ``compiled`` runs on, in assignment order —
+    read off its argument/result shardings."""
+    import jax
+
+    shardings = jax.tree_util.tree_leaves(
+        (compiled.input_shardings, compiled.output_shardings)
+    )
+    if not shardings:
+        return []
+    return [d.id for d in shardings[0]._device_assignment]
 
 
 def serialize_executable_blob(compiled, inputs: dict,
                               stats: dict | None = None) -> bytes:
     """Envelope a compiled (AOT) executable: magic + crc32 + pickle of
-    the serialize_executable triple, the fingerprint inputs, and
-    post-compile ``stats`` (``executable_stats``; None = compute)."""
+    the serialize_executable triple, the devices it was compiled for,
+    the fingerprint inputs, and post-compile ``stats``
+    (``executable_stats``; None = compute)."""
     from jax.experimental.serialize_executable import serialize
 
     payload, in_tree, out_tree = serialize(compiled)
@@ -198,6 +221,7 @@ def serialize_executable_blob(compiled, inputs: dict,
         "exe": payload,
         "in_tree": in_tree,
         "out_tree": out_tree,
+        "devices": _execution_device_ids(compiled),
         "inputs": inputs,
         "stats": executable_stats(compiled) if stats is None else stats,
         "created": time.time(),
@@ -233,8 +257,13 @@ def blob_stats(blob: bytes) -> dict:
 
 
 def load_executable_blob(blob: bytes, expect_inputs: dict | None = None):
-    """Deserialize an envelope back into a callable executable; returns
-    None (a miss) on any damage or fingerprint-input mismatch."""
+    """Deserialize an envelope back into a callable executable, loaded
+    onto the devices it was compiled for (left to itself,
+    ``deserialize_and_load`` takes every device of the backend, and a
+    one-device program then cannot be called in a process that sees
+    more). Returns None (a miss) on any damage, fingerprint-input
+    mismatch, or a device this process does not have."""
+    import jax
     from jax.experimental.serialize_executable import deserialize_and_load
 
     try:
@@ -247,8 +276,11 @@ def load_executable_blob(blob: bytes, expect_inputs: dict | None = None):
             # program inputs — must read as a miss, never a wrong load
             logger.warning("compile-cache fingerprint mismatch; ignoring")
             return None
+        by_id = {d.id: d for d in jax.devices()}
+        devices = [by_id[i] for i in record.get("devices") or ()]
         return deserialize_and_load(
-            record["exe"], record["in_tree"], record["out_tree"]
+            record["exe"], record["in_tree"], record["out_tree"],
+            execution_devices=devices or None,
         )
     except Exception as e:  # noqa: BLE001 - any damage is a miss
         logger.warning("compile-cache artifact unusable: %s", e)
@@ -258,16 +290,25 @@ def load_executable_blob(blob: bytes, expect_inputs: dict | None = None):
 # ----------------------------------------------------------------- client
 
 
+def cache_root() -> str:
+    """The ONE compile-cache directory (XLA's persistent cache at its
+    top, the serialized AOT executables under ``aot/``), shared by every
+    incarnation, the parked standby and the serving replicas on this
+    host. ``JAX_COMPILATION_CACHE_DIR`` places it from outside; unset,
+    it is a fixed git-ignored directory in the checkout — the path is
+    part of XLA's cache key, so it never moves with a job name, a pid
+    or a temp dir. Keys carry the model, strategy and topology, so jobs
+    that share it cannot cross-hit."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))),
+        ".compile_cache",
+    )
+
+
 def default_local_dir() -> str:
-    """Node-local artifact dir, shared by every incarnation and the
-    parked standby of one job on the host. ``DLROVER_TPU_COMPILE_CACHE_DIR``
-    overrides (the shared-dir escape hatch); the default is keyed by
-    job name so co-hosted jobs cannot cross-hit."""
-    explicit = os.environ.get(EnvKey.COMPILE_CACHE_SHARED_DIR)
-    if explicit:
-        return os.path.join(explicit, "aot")
-    job = os.environ.get(EnvKey.JOB_NAME, "local") or "local"
-    return os.path.join("/tmp", f"dlrover_tpu_aot_{job}")
+    """Where the AOT artifact layer lives: ``<cache_root>/aot``."""
+    return os.path.join(cache_root(), "aot")
 
 
 class CompileCacheClient:
@@ -409,6 +450,9 @@ class AotStep:
     # compile time and cached in the envelope, so warm loads feed the
     # live MFU gauge without re-lowering; 0.0 when unknown
     flops: float = 0.0
+    # Pallas kernels in the program (``executable_stats``), cached the
+    # same way
+    pallas_calls: int = 0
 
 
 def load_or_compile(
@@ -426,9 +470,11 @@ def load_or_compile(
     start = time.monotonic()
     if not aot_cache_enabled():
         compiled = compile_fn()
+        stats = executable_stats(compiled)
         return AotStep(fn=compiled, cache_hit=False, source="disabled",
                        seconds=time.monotonic() - start, key=key,
-                       flops=executable_stats(compiled).get("flops", 0.0))
+                       flops=stats.get("flops", 0.0),
+                       pallas_calls=stats.get("pallas_calls", 0))
     cache = cache or CompileCacheClient()
     got = cache.get(key)
     if got is not None:
@@ -443,7 +489,8 @@ def load_or_compile(
                         got[1], key, dur)
             return AotStep(fn=loaded, cache_hit=True, source=got[1],
                            seconds=dur, key=key,
-                           flops=float(stats.get("flops", 0.0) or 0.0))
+                           flops=float(stats.get("flops", 0.0) or 0.0),
+                           pallas_calls=int(stats.get("pallas_calls", 0)))
     compiled = compile_fn()
     stats = executable_stats(compiled)
     try:
@@ -459,7 +506,8 @@ def load_or_compile(
                 key, dur)
     return AotStep(fn=compiled, cache_hit=False, source="compiled",
                    seconds=dur, key=key,
-                   flops=float(stats.get("flops", 0.0) or 0.0))
+                   flops=float(stats.get("flops", 0.0) or 0.0),
+                   pallas_calls=int(stats.get("pallas_calls", 0)))
 
 
 # --------------------------------------------------- fallback pre-compiler

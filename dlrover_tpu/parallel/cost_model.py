@@ -29,9 +29,11 @@ from dlrover_tpu.common.log import get_logger
 
 logger = get_logger(__name__)
 
-# v5e-class defaults (per chip). Absolute accuracy is not the goal —
-# candidates are ranked against each other under the SAME constants.
-_V5E = dict(peak_flops=1.97e14, hbm_bps=8.1e11, ici_bps=9.0e10,
+# Constants for explicit specs and offline ranking (per chip, v5e
+# class). Absolute accuracy is not the goal — candidates are ranked
+# against each other under the SAME constants. ``for_device`` takes the
+# compute and memory peaks of a live TPU from the peaks table instead.
+_V5E = dict(peak_flops=1.97e14, hbm_bps=8.19e11, ici_bps=9.0e10,
             dcn_bps=6.25e9, mxu_efficiency=0.5)
 
 
@@ -45,19 +47,18 @@ class HardwareSpec:
 
     @classmethod
     def for_device(cls, device=None) -> "HardwareSpec":
-        """Best-effort spec for the live backend; exact constants only
-        matter for absolute estimates, never for ranking."""
-        try:
-            import jax
+        """Spec for the live backend: a TPU's compute and memory peaks
+        come from the peaks table (an unknown ``device_kind`` raises);
+        the link and efficiency terms are this model's own constants."""
+        import jax
 
-            device = device or jax.devices()[0]
-        except Exception:  # noqa: BLE001
-            return cls()
+        device = device or jax.devices()[0]
         if device.platform == "tpu":
-            from dlrover_tpu.utils.profiler import PEAK_FLOPS
+            from dlrover_tpu.utils.profiler import device_peaks
 
-            peak = PEAK_FLOPS.get(device.device_kind)
-            return cls(**({**_V5E, "peak_flops": peak} if peak else _V5E))
+            peaks = device_peaks(device)
+            return cls(**{**_V5E, "peak_flops": peaks.bf16_flops,
+                          "hbm_bps": peaks.hbm_bps})
         # CPU / virtual test meshes: small constants so comm terms are
         # visible relative to compute in unit tests
         return cls(peak_flops=2e11, hbm_bps=5e10, ici_bps=2e10,

@@ -314,14 +314,13 @@ def measured_search(
 
 
 def _time_steps(compiled, batch, steps: int) -> float:
-    """Median-of-run wall time per global step (loss device_get is the
-    sync point — block_until_ready does not block on remote platforms)."""
+    """Mean wall time per global step over one run of ``steps``."""
     state = compiled.init(jax.random.PRNGKey(0))
     step_batch = jax.device_put(batch, compiled.batch_sharding)
     state, m = compiled.step(state, step_batch)  # compile + warmup
-    float(jax.device_get(m["loss"]))
+    jax.block_until_ready(m["loss"])
     t0 = time.monotonic()
     for _ in range(steps):
         state, m = compiled.step(state, step_batch)
-    float(jax.device_get(m["loss"]))
+    jax.block_until_ready(m["loss"])
     return (time.monotonic() - t0) / steps

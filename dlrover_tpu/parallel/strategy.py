@@ -113,9 +113,12 @@ class Strategy:
 
 # Rule fragments shared by the presets. Logical names are the vocabulary the
 # bundled models use (models/transformer.py); user models may extend freely.
+# No ["vocab", "fsdp"] rule: it only ever decided the embedding table
+# (every other weight's embed dim claims the axis first), and published
+# vocabularies do not divide by a chip count (GPT-2's 50257 = 29 x 1733),
+# so the table shards over its embed dim like every other weight.
 _FSDP_RULES = [
     ["embed", "fsdp"],          # shard the big embed dim of every weight
-    ["vocab", "fsdp"],
     ["batch", ["data", "fsdp"]],
 ]
 _TP_RULES = [

@@ -23,24 +23,54 @@ from dlrover_tpu.common.log import get_logger
 
 logger = get_logger(__name__)
 
-# bf16 peak FLOP/s per chip by device kind (public spec sheets).
-PEAK_FLOPS = {
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5": 459e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    bf16_flops: float   # FLOP/s
+    int8_ops: float     # OP/s
+    hbm_bytes: int
+    hbm_bps: float      # bytes/s
+
+
+# The one peaks table, keyed by ``device_kind`` as JAX reports it.
+# Source: Google Cloud documentation, "TPU v5e" system architecture —
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM2e at 819 GB/s. A device
+# that is not here is an error (``device_peaks``), never a default: add
+# its published row.
+_V5E_PEAKS = ChipPeaks(bf16_flops=197e12, int8_ops=393e12,
+                       hbm_bytes=16 * 10**9, hbm_bps=819e9)
+PEAKS = {
+    "TPU v5 lite": _V5E_PEAKS,
+    "TPU v5e": _V5E_PEAKS,
 }
 
 
-def device_peak_flops(device=None) -> float | None:
-    """bf16 peak FLOP/s of one chip, or None when unknown (CPU)."""
+def device_peaks(device=None) -> ChipPeaks:
+    """Published peaks of ``device`` (default: device 0); raises for a
+    ``device_kind`` the table does not hold."""
     import jax
 
     device = device or jax.devices()[0]
-    return PEAK_FLOPS.get(getattr(device, "device_kind", ""))
+    kind = getattr(device, "device_kind", "")
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"device kind {kind!r} (platform {device.platform}) is not "
+            f"in the peaks table (utils/profiler.py PEAKS: "
+            f"{sorted(PEAKS)}); add its published peaks"
+        ) from None
+
+
+def device_peak_flops(device=None) -> float | None:
+    """bf16 peak FLOP/s of one chip. None on the CPU (the test
+    substrate has no peak, so MFU gauges stay off there); any other
+    device must be in the table."""
+    import jax
+
+    device = device or jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    return device_peaks(device).bf16_flops
 
 
 def executable_flops(compiled) -> float:

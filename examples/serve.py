@@ -29,7 +29,8 @@ def parse_args(argv=None):
     p = argparse.ArgumentParser("serve")
     p.add_argument("--model", default="tiny")
     p.add_argument("--ckpt-dir", default="",
-                   help="flash-checkpoint dir to restore params from; "
+                   help="flash-checkpoint dir to restore params from "
+                        "(a dir without a checkpoint is an error); "
                         "empty = random init (smoke testing)")
     p.add_argument("--prompt", action="append", default=[],
                    help="space-separated token ids; repeatable. "
@@ -60,6 +61,7 @@ def main(argv=None) -> int:
     from dlrover_tpu.trainer import bootstrap
 
     bootstrap.setup_compilation_cache()
+    print(bootstrap.describe_devices(), file=sys.stderr)
     cfg = tfm.CONFIGS[args.model]
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
 
@@ -80,13 +82,14 @@ def main(argv=None) -> int:
         loaded = engine.load(template)
         engine.close()
         if loaded is None:
-            print("no checkpoint found; serving random init",
-                  file=sys.stderr)
-        else:
-            step, state = loaded
-            params = state.params
-            print(f"restored step {step} from {args.ckpt_dir}",
-                  file=sys.stderr)
+            raise SystemExit(f"no checkpoint found in {args.ckpt_dir}")
+        step, state = loaded
+        params = state.params
+        # serve only the parameters: the template and the optimizer
+        # moments (three more copies of the model) leave the device here
+        del template, state, loaded
+        print(f"restored step {step} from {args.ckpt_dir}",
+              file=sys.stderr)
 
     eng = InferenceEngine(
         params, cfg, slots=args.slots, max_len=args.max_len or 0,

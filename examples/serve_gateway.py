@@ -34,7 +34,8 @@ def parse_args(argv=None):
     p = argparse.ArgumentParser("serve_gateway")
     p.add_argument("--model", default="tiny")
     p.add_argument("--ckpt-dir", default="",
-                   help="flash-checkpoint dir to restore params from; "
+                   help="flash-checkpoint dir to restore params from "
+                        "(a dir without a checkpoint is an error); "
                         "empty = random init (smoke testing)")
     p.add_argument("--host", default="0.0.0.0")
     p.add_argument("--port", type=int, default=8000)
@@ -95,9 +96,7 @@ def _load_params(args, cfg):
     loaded = engine.load(template)
     engine.close()
     if loaded is None:
-        print("no checkpoint found; serving random init",
-              file=sys.stderr)
-        return params
+        raise SystemExit(f"no checkpoint found in {args.ckpt_dir}")
     step, state = loaded
     print(f"restored step {step} from {args.ckpt_dir}", file=sys.stderr)
     return state.params
@@ -119,6 +118,7 @@ def main(argv=None) -> int:
     from dlrover_tpu.trainer import bootstrap
 
     bootstrap.setup_compilation_cache()
+    print(bootstrap.describe_devices(), file=sys.stderr)
     cfg = tfm.CONFIGS[args.model]
     params = _load_params(args, cfg)
 

@@ -188,8 +188,7 @@ def _spawn_sharded_table(args, ckpt_dir: str):
                 cmd += ["--spill-dir", args.spill_dir]
             proc = subprocess.Popen(
                 cmd, stdout=subprocess.PIPE, text=True,
-                env={**os.environ, "JAX_PLATFORMS": "cpu",
-                     "DLROVER_TPU_PLATFORM": "cpu"},
+                env={**os.environ, "JAX_PLATFORMS": "cpu"},
             )
             procs.append(proc)
             line = proc.stdout.readline().strip()

@@ -84,7 +84,7 @@ def _mk_plan(strategy, schedule="spmd", pred=0.01, source="model",
 
 
 def test_device_hbm_bytes_env_override(monkeypatch):
-    """ISSUE-13 satellite: CPU/tunneled backends state the REAL
+    """ISSUE-13 satellite: CPU backends state the REAL
     envelope through DLROVER_TPU_DEVICE_HBM_BYTES instead of the
     conservative default (0 on CPU = fit check silently skipped)."""
     from dlrover_tpu.parallel.auto import device_hbm_bytes

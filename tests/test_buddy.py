@@ -232,7 +232,7 @@ def test_sigkilled_node_restores_from_buddy(tmp_path, monkeypatch):
     from dlrover_tpu.cluster.scaler import LocalProcessScaler
     from dlrover_tpu.master.job_master import JobMaster
 
-    monkeypatch.setenv("DLROVER_TPU_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.setenv("DLROVER_TPU_DEVICE_COUNT", "4")
     monkeypatch.setenv("DLROVER_TPU_IPC_DIR", str(tmp_path / "ipc"))
     monkeypatch.setenv("PYTHONPATH", REPO)

@@ -387,7 +387,7 @@ def test_gateway_keeps_serving_while_degraded(monkeypatch):
 
 def _scenario_env(tmp_path) -> dict:
     return {
-        "DLROVER_TPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "DLROVER_TPU_DEVICE_COUNT": "1",
         # warm recovery is a recovery path: the acceptance scenario must
         # stay deterministic WITH standby promotion in the loop (pinned

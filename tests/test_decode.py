@@ -94,12 +94,14 @@ class TestSlidingWindowDecode:
         tokens = jax.random.randint(
             jax.random.PRNGKey(1), (2, 12), 0, cfg.vocab_size
         )
+        from dlrover_tpu.ops.flash_attention import reference_kernels
         from dlrover_tpu.ops.splash_attention import make_splash_attention
 
-        ref = tfm.forward(
-            params, tokens, cfg,
-            attention_fn=make_splash_attention(cfg.attention_window),
-        )
+        with reference_kernels():  # CPU: the windowed dense reference
+            ref = tfm.forward(
+                params, tokens, cfg,
+                attention_fn=make_splash_attention(cfg.attention_window),
+            )
         cache = init_cache(cfg, 2, 16)
         out_p, cache = forward_cached(params, tokens[:, :4], cache, cfg)
         outs = [out_p]

@@ -27,7 +27,7 @@ def _env(tmp_path) -> dict:
     env = dict(os.environ)
     env.update(
         {
-            "DLROVER_TPU_PLATFORM": "cpu",  # children force the CPU backend
+            "JAX_PLATFORMS": "cpu",  # children force the CPU backend
             "DLROVER_TPU_DEVICE_COUNT": "1",
             "DLROVER_TPU_IPC_DIR": str(tmp_path / "ipc"),
             "PYTHONPATH": REPO,

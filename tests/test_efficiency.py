@@ -665,7 +665,7 @@ def test_profile_request_against_running_standalone_job(tmp_path):
     bundles = str(tmp_path / "bundles")
     env = dict(os.environ)
     env.update({
-        "DLROVER_TPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "DLROVER_TPU_DEVICE_COUNT": "1",
         "DLROVER_TPU_IPC_DIR": str(tmp_path / "ipc"),
         "DLROVER_TPU_JOURNAL_DIR": str(tmp_path / "journal"),
@@ -676,6 +676,7 @@ def test_profile_request_against_running_standalone_job(tmp_path):
     cmd = [
         sys.executable, "-m", "dlrover_tpu.run", "--standalone",
         "--monitor-interval", "0.3", "--heartbeat-interval", "0.5",
+        "--job-name", f"profile-e2e-{os.getpid()}",
         example, "--",
         "--model", "tiny", "--global-batch", "8", "--seq", "128",
         "--max-steps", "2000", "--step-delay", "0.05",
@@ -736,10 +737,13 @@ def test_profile_request_against_running_standalone_job(tmp_path):
             proc.wait(timeout=20)
         except subprocess.TimeoutExpired:
             proc.kill()
-        subprocess.run(["pkill", "-9", "-f", example],
+        # only THIS test's trainer (its ckpt dir) and master (its job
+        # name): the suite runs files on parallel workers, and a broad
+        # pattern kills a sibling test's job
+        subprocess.run(["pkill", "-9", "-f", str(tmp_path)],
                        capture_output=True)
         subprocess.run(
-            ["pkill", "-9", "-f", "dlrover_tpu.master.job_master"],
+            ["pkill", "-9", "-f", f"job-name profile-e2e-{os.getpid()}"],
             capture_output=True,
         )
 

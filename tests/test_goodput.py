@@ -145,7 +145,7 @@ def test_e2e_goodput_log_across_crash(tmp_path):
     spans both incarnations and the aggregator sees the rollback."""
     env = dict(os.environ)
     env.update({
-        "DLROVER_TPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "DLROVER_TPU_DEVICE_COUNT": "1",
         "DLROVER_TPU_IPC_DIR": str(tmp_path / "ipc"),
         "PYTHONPATH": REPO,

@@ -79,7 +79,7 @@ def test_wedged_trainer_restarted_by_agent(tmp_path):
     result_file = str(tmp_path / "result.json")
     env = dict(os.environ)
     env.update({
-        "DLROVER_TPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "DLROVER_TPU_DEVICE_COUNT": "1",
         "DLROVER_TPU_IPC_DIR": str(tmp_path / "ipc"),
         "PYTHONPATH": REPO,

@@ -536,7 +536,7 @@ class TestRecsysExample:
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         result = tmp_path / "result.json"
         env = dict(os.environ)
-        env["DLROVER_TPU_PLATFORM"] = "cpu"
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = repo
         proc = subprocess.run(
             [sys.executable, os.path.join(repo, "examples/train_recsys.py"),

@@ -55,7 +55,7 @@ def _mpmd(optimizer=None, microbatches=4, accum=1, cfg=CFG):
 def aot_dir(tmp_path, monkeypatch):
     """Hermetic per-test compile-cache dir (the runtime's programs all
     ride load_or_compile)."""
-    monkeypatch.setenv("DLROVER_TPU_COMPILE_CACHE_DIR",
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
                        str(tmp_path / "aot"))
     monkeypatch.setenv("DLROVER_TPU_JOURNAL_DIR", str(tmp_path / "jr"))
     return tmp_path

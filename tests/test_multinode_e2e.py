@@ -36,7 +36,7 @@ def _env(tmp_path) -> dict:
     env = dict(os.environ)
     env.update(
         {
-            "DLROVER_TPU_PLATFORM": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "DLROVER_TPU_DEVICE_COUNT": "4",
             "DLROVER_TPU_IPC_DIR": str(tmp_path / "ipc"),
             # cross-process event journal: master mints the trace id,
@@ -139,7 +139,9 @@ def _run_two_nodes(tmp_path, train_args, kill_after_ckpt=False,
                 os.killpg(master.pid, signal.SIGKILL)
             except ProcessLookupError:
                 pass
-        subprocess.run(["pkill", "-9", "-f", EXAMPLE],
+        # only this test's trainers (their ckpt dir): a sibling xdist
+        # worker runs the same example
+        subprocess.run(["pkill", "-9", "-f", str(tmp_path)],
                        capture_output=True)
 
 
@@ -182,7 +184,7 @@ def _drain(proc: subprocess.Popen, timeout: float = 30) -> str:
     return out
 
 
-def _kill_all(launchers, master) -> None:
+def _kill_all(launchers, master, tmp_path) -> None:
     for p in (launchers.values() if isinstance(launchers, dict)
               else launchers):
         if p.poll() is None:
@@ -195,7 +197,8 @@ def _kill_all(launchers, master) -> None:
             os.killpg(master.pid, signal.SIGKILL)
         except ProcessLookupError:
             pass
-    subprocess.run(["pkill", "-9", "-f", EXAMPLE], capture_output=True)
+    subprocess.run(["pkill", "-9", "-f", str(tmp_path)],
+                   capture_output=True)
 
 
 @pytest.mark.timeout(500)
@@ -251,7 +254,7 @@ def test_three_nodes_shrink_to_two_on_node_loss(tmp_path):
         assert result["num_nodes"] == 2       # the world actually shrank
         assert result["resumed_from"] > 0     # resharded restore
     finally:
-        _kill_all(launchers, master)
+        _kill_all(launchers, master, tmp_path)
 
 
 @pytest.mark.timeout(500)
@@ -292,7 +295,7 @@ def test_two_nodes_grow_to_three_on_join(tmp_path):
         assert result["num_nodes"] == 3       # the world actually grew
         assert result["resumed_from"] > 0     # restored mid-run
     finally:
-        _kill_all(launchers, master)
+        _kill_all(launchers, master, tmp_path)
 
 
 @pytest.mark.timeout(500)

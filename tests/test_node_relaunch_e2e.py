@@ -29,7 +29,7 @@ EXAMPLE = os.path.join(REPO, "examples", "train_transformer.py")
 def test_hardware_fault_relaunches_node_and_completes(
     tmp_path, monkeypatch
 ):
-    monkeypatch.setenv("DLROVER_TPU_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.setenv("DLROVER_TPU_DEVICE_COUNT", "1")
     monkeypatch.setenv("DLROVER_TPU_IPC_DIR", str(tmp_path / "ipc"))
     monkeypatch.setenv("PYTHONPATH", REPO)

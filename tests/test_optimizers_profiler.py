@@ -335,3 +335,49 @@ class TestAdam4bit:
             np.asarray(params_a["x"]), np.asarray(params_b["x"]),
             atol=0.15,
         )
+
+
+class TestPeaksTable:
+    """One table keyed by device_kind; an unknown device is an error."""
+
+    def test_v5e_row_holds_the_published_peaks(self):
+        from dlrover_tpu.utils.profiler import PEAKS
+
+        row = PEAKS["TPU v5 lite"]
+        assert (row.bf16_flops, row.int8_ops) == (197e12, 393e12)
+        assert (row.hbm_bytes, row.hbm_bps) == (16 * 10**9, 819e9)
+        assert PEAKS["TPU v5e"] is row
+
+    def test_unknown_device_kind_raises(self):
+        from types import SimpleNamespace
+
+        from dlrover_tpu.parallel.auto import device_hbm_bytes
+        from dlrover_tpu.parallel.cost_model import HardwareSpec
+        from dlrover_tpu.utils.profiler import (
+            device_peak_flops,
+            device_peaks,
+        )
+
+        unknown = SimpleNamespace(platform="tpu", device_kind="TPU v9",
+                                  memory_stats=lambda: None)
+        for fn in (device_peaks, device_peak_flops,
+                   HardwareSpec.for_device, device_hbm_bytes):
+            with pytest.raises(ValueError, match="peaks table"):
+                fn(unknown)
+
+    def test_known_tpu_and_cpu(self):
+        from types import SimpleNamespace
+
+        from dlrover_tpu.parallel.auto import device_hbm_bytes
+        from dlrover_tpu.parallel.cost_model import HardwareSpec
+        from dlrover_tpu.utils.profiler import device_peak_flops
+
+        v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite",
+                              memory_stats=lambda: None)
+        assert device_peak_flops(v5e) == 197e12
+        assert device_hbm_bytes(v5e) == 16 * 10**9
+        hw = HardwareSpec.for_device(v5e)
+        assert (hw.peak_flops, hw.hbm_bps) == (197e12, 819e9)
+        # the CPU test substrate has no peak: gauges off, no HBM check
+        assert device_peak_flops() is None
+        assert device_hbm_bytes() == 0

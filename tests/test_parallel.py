@@ -133,7 +133,30 @@ class TestStrategies:
         # adamw state: (ScaleByAdamState(count, mu, nu), ...) — mu follows
         mu = state.opt_state[0].mu
         assert mu["layers"]["wq"].sharding.spec == P(None, "fsdp")
-        assert mu["embed"].sharding.spec == P("fsdp")
+        # the table shards over its embed dim, not the vocabulary: a
+        # published vocab (50257) does not divide by a chip count
+        assert mu["embed"].sharding.spec == P(None, "fsdp")
+
+    def test_fsdp_shards_a_gpt2_vocabulary_over_four_devices(self):
+        """50257 = 29 x 1733 divides by no mesh size: with a vocab rule
+        the FSDP init program was refused on four devices."""
+        import dataclasses
+
+        cfg = dataclasses.replace(
+            T.CONFIGS["tiny"], vocab_size=50257, variant="gpt2")
+        strat = S.fsdp(4)
+        mesh = strat.build_mesh(jax.devices()[:4])
+        ct = compile_train(
+            strategy=strat, mesh=mesh,
+            loss_fn=T.make_loss_fn(cfg, strat, mesh),
+            init_params_fn=lambda rng: T.init_params(cfg, rng),
+            logical_params=T.logical_axes(cfg),
+            optimizer=optax.adamw(1e-3),
+        )
+        state = jax.eval_shape(ct.init, jax.random.PRNGKey(0))
+        assert state.params["embed"].shape == (50257, cfg.d_model)
+        assert ct.state_shardings.params["embed"].spec == P(None, "fsdp")
+        assert ct.state_shardings.params["lm_head"].spec == P("fsdp")
 
     def test_train_two_steps_loss_decreases(self):
         strat = S.fsdp(8)

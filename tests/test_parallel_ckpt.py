@@ -569,7 +569,7 @@ class TestGatewayAotColdStart:
         )
         from dlrover_tpu.serving.engine import InferenceEngine
 
-        monkeypatch.setenv("DLROVER_TPU_COMPILE_CACHE_DIR",
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
                            str(tmp_path / "cache"))
         monkeypatch.setenv("DLROVER_TPU_JOURNAL_DIR",
                            str(tmp_path / "journal"))

@@ -45,7 +45,7 @@ def _steps_logged(log: str) -> int:
 @pytest.mark.slow
 @pytest.mark.timeout(300)
 def test_preemption_notice_buddy_restore_no_storage(tmp_path, monkeypatch):
-    monkeypatch.setenv("DLROVER_TPU_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.setenv("DLROVER_TPU_DEVICE_COUNT", "2")
     # children inherit the env: 2 virtual devices per node, dp=4
     monkeypatch.setenv("XLA_FLAGS",

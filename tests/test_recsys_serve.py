@@ -64,7 +64,6 @@ def test_train_sharded_table_e2e(tmp_path):
         "PYTHONPATH": REPO,
         "DLROVER_TPU_IPC_DIR": str(tmp_path / "ipc"),
         "JAX_PLATFORMS": "cpu",
-        "DLROVER_TPU_PLATFORM": "cpu",
     })
     train = subprocess.run(
         [sys.executable, os.path.join(REPO, "examples",

@@ -178,6 +178,39 @@ class TestEnvelope:
         assert cc.load_executable_blob(b"junk") is None
 
 
+    @pytest.mark.parametrize("device_ids", [[0], [5], [0, 1, 2, 3]])
+    def test_loads_onto_the_devices_it_was_compiled_for(self, device_ids):
+        """A program for fewer devices than the process sees (a
+        one-chip serving replica on a four-chip host; here the 8-device
+        CPU mesh) must load onto ITS devices: left to its default,
+        deserialize_and_load takes all 8 and the first call fails with
+        "Expected args ... to have 8 shards"."""
+        import numpy as np
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        mesh = Mesh(np.array([jax.devices()[i] for i in device_ids]),
+                    ("d",))
+        sharding = NamedSharding(mesh, PartitionSpec("d"))
+        x = jax.device_put(np.arange(8 * 4, dtype=np.float32).reshape(8, 4),
+                           sharding)
+        compiled = jax.jit(lambda a: (a * 2 + 1, a.sum())).lower(
+            jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+        ).compile()
+        client = cc.CompileCacheClient()  # the per-run dir of conftest
+        key = f"t/one_device_{'_'.join(map(str, device_ids))}"
+        client.put(key, cc.serialize_executable_blob(compiled, {"k": 1}))
+        blob, layer = client.get(key)
+        assert layer == "local"
+        loaded = cc.load_executable_blob(blob, expect_inputs={"k": 1})
+        assert loaded is not None
+        doubled, total = loaded(x)
+        np.testing.assert_array_equal(np.asarray(doubled),
+                                      np.asarray(x) * 2 + 1)
+        assert float(total) == float(np.asarray(x).sum())
+        assert {d.id for d in doubled.sharding.device_set} \
+            == set(device_ids)
+
+
 class TestLoadOrCompile:
     def test_miss_compiles_then_hit_loads(self, tmp_path):
         jitted, abstract, fresh_args = _tiny_aot()
@@ -473,7 +506,7 @@ def test_kill_recovery_trail_shows_reshard_and_warm_compile(tmp_path):
     work = str(tmp_path / "run")
     res = run_scenario(
         scenario, work,
-        env_extra={"DLROVER_TPU_PLATFORM": "cpu",
+        env_extra={"JAX_PLATFORMS": "cpu",
                    "DLROVER_TPU_DEVICE_COUNT": "1",
                    "DLROVER_TPU_STANDBY": "1"},
         deadline_s=160,

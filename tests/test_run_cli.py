@@ -129,6 +129,100 @@ class TestSniffAccelerator:
         _pci_dev(pci, "0000:00:03.0", "0x1ae0", "0x020000")  # gVNIC only
         assert sniff_accelerator(str(tmp_path), str(pci)) == ("cpu", 1)
 
+    def test_vfio_bound_chip_counts_groups_this_process_can_open(
+            self, tmp_path):
+        """The v5e machine of PR 22: no /dev/accel*, four Google
+        functions of an unassigned PCI class in sysfs, ONE /dev/vfio
+        group node — the VM holds one chip, and one is the answer."""
+        pci = tmp_path / "pci"
+        iommu = tmp_path / "iommu"
+        for i, addr in enumerate(("08", "09", "0a", "0b")):
+            _pci_dev(pci, f"0000:00:{addr}.0", "0x1ae0", "0xff0000")
+            (pci / f"0000:00:{addr}.0" / "device").write_text("0x0063\n")
+            grp = iommu / str(i) / "devices" / f"0000:00:{addr}.0"
+            grp.mkdir(parents=True)
+            (grp / "vendor").write_text("0x1ae0\n")
+            (grp / "device").write_text("0x0063\n")
+        (tmp_path / "vfio").mkdir()
+        (tmp_path / "vfio" / "vfio").touch()  # the container, not a chip
+        (tmp_path / "vfio" / "3").touch()
+        assert sniff_accelerator(
+            str(tmp_path), str(pci), str(tmp_path / "cls"), str(iommu)
+        ) == ("tpu", 1)
+
+    def test_unassigned_class_v5e_functions_found_by_device_id(
+            self, tmp_path):
+        pci = tmp_path / "pci"
+        for addr in ("08", "09"):
+            _pci_dev(pci, f"0000:00:{addr}.0", "0x1ae0", "0xff0000")
+            (pci / f"0000:00:{addr}.0" / "device").write_text("0x0063\n")
+        assert sniff_accelerator(
+            str(tmp_path), str(pci), str(tmp_path / "cls"),
+            str(tmp_path / "iommu")) == ("tpu", 2)
+
+
+class TestControlPlaneStaysOffJax:
+    """A chip belongs to one process: the launcher and the agent must
+    leave it to the trainer they spawn."""
+
+    def test_detect_local_devices_never_imports_jax_in_the_agent(
+            self, monkeypatch, tmp_path):
+        """Empty /dev and sysfs (a sealed VM may show neither): the
+        count comes from a short-lived child, not from this process."""
+        import subprocess
+        import sys
+
+        from dlrover_tpu.agent import elastic_agent
+
+        monkeypatch.delenv(EnvKey.DEVICE_COUNT_OVERRIDE, raising=False)
+        monkeypatch.setattr(elastic_agent, "sniff_accelerator",
+                            lambda: ("cpu", 1))
+        seen = []
+        real_run = subprocess.run
+
+        def spy(cmd, **kw):
+            seen.append(cmd)
+            return real_run(cmd, **kw)
+
+        monkeypatch.setattr(elastic_agent.subprocess, "run", spy)
+        jax_mod = sys.modules.pop("jax")  # the test process has it
+        try:
+            count = elastic_agent._detect_local_devices()
+            assert "jax" not in sys.modules
+        finally:
+            sys.modules["jax"] = jax_mod
+        assert count == 8  # the child inherits the 8-device CPU mesh
+        assert len(seen) == 1 and "import jax" in seen[0][-1]
+
+    def test_detect_local_devices_prefers_override_and_sniff(
+            self, monkeypatch):
+        from dlrover_tpu.agent import elastic_agent
+
+        monkeypatch.setattr(elastic_agent.subprocess, "run",
+                            lambda *a, **k: pytest.fail("child spawned"))
+        monkeypatch.setattr(elastic_agent, "sniff_accelerator",
+                            lambda: ("tpu", 4))
+        monkeypatch.delenv(EnvKey.DEVICE_COUNT_OVERRIDE, raising=False)
+        assert elastic_agent._detect_local_devices() == 4
+        monkeypatch.setenv(EnvKey.DEVICE_COUNT_OVERRIDE, "2")
+        assert elastic_agent._detect_local_devices() == 2
+
+    @pytest.mark.parametrize("module", [
+        "dlrover_tpu.run", "dlrover_tpu.agent.elastic_agent",
+        "dlrover_tpu.master.job_master", "dlrover_tpu.agent.standby",
+    ])
+    def test_importing_the_control_plane_does_not_import_jax(self, module):
+        import subprocess
+        import sys
+
+        out = subprocess.run(
+            [sys.executable, "-c",
+             f"import sys, {module}; "
+             "sys.exit(1 if 'jax' in sys.modules else 0)"],
+            capture_output=True, text=True, timeout=120,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+
 
 class TestAutoConfigure:
     def test_nnodes_promoted_from_env(self, clean_env, tmp_path):

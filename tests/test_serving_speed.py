@@ -46,16 +46,20 @@ from dlrover_tpu.serving.observatory import (
 
 CFG = tfm.CONFIGS["tiny"]
 
-# short cyclic prompts: the order-k n-gram shadow finds its repeats in
-# the prompt itself, so greedy rows start drafting within a few tokens
-_CYCLIC = [
+# short cyclic prompts: each pattern twice, so the order-k n-gram shadow
+# finds its repeats in the prompt itself and greedy rows start drafting
+# within a few tokens — whatever this machine's XLA:CPU makes the
+# random-init model continue with (PR 22: with the patterns given once,
+# drafting hung on the continuation happening to repeat, and on this
+# host it does not)
+_CYCLIC = [2 * p for p in (
     [454, 126, 12, 214, 262, 346],
     [229, 389, 164, 351],
     [485, 180, 384, 142, 241, 56],
     [4, 47, 391, 116],
     [21, 485, 24],
     [443, 88, 403],
-]
+)]
 
 # one full KV page (page_size == prefill_len == 8 throughout) shared
 # verbatim across requests, so the sharing index has something to dedup

@@ -31,7 +31,7 @@ pytestmark = pytest.mark.skipif(
 def test_soak_many_kills(tmp_path):
     env = dict(os.environ)
     env.update({
-        "DLROVER_TPU_PLATFORM": "cpu",
+        "JAX_PLATFORMS": "cpu",
         "DLROVER_TPU_DEVICE_COUNT": "4",
         "DLROVER_TPU_IPC_DIR": str(tmp_path / "ipc"),
         "PYTHONPATH": REPO,

@@ -1,8 +1,10 @@
 """Splash attention (ops/splash_attention.py).
 
-On the CPU test mesh the TPU kernel is unavailable, so these pin the
-dense fallback's mask semantics (which the on-TPU kernel is validated
-against by the same module's _dense_window) and the strategy wiring.
+The TPU kernel cannot run on the CPU test mesh (it is compiled for a
+described chip in tests/test_tpu_compile.py), so this file chooses the
+dense reference explicitly — every test here runs inside
+``reference_kernels()`` — and pins its mask semantics and the strategy
+wiring.
 """
 
 import dataclasses
@@ -11,12 +13,20 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dlrover_tpu.models import transformer as T
+from dlrover_tpu.ops.flash_attention import reference_kernels
 from dlrover_tpu.ops.splash_attention import (
     _dense_window,
     splash_attention,
 )
+
+
+@pytest.fixture(autouse=True)
+def _dense_reference():
+    with reference_kernels():
+        yield
 
 
 def _qkv(key, b=2, s=64, h=4, d=16):
@@ -92,7 +102,7 @@ class TestGqa:
         )}
         strat = S.dp()
         strat.extra["native_gqa"] = True
-        mesh = strat.build_mesh()
+        mesh = strat.build_mesh(jax.devices()[:2])  # batch 2: 1 row each
         a = float(jax.jit(T.make_loss_fn(cfg_d, S.dp(), mesh))(
             params, batch
         ))
@@ -138,7 +148,7 @@ class TestStrategyWiring:
         from dlrover_tpu.parallel import strategy as S
 
         strat = S.dp()
-        mesh = strat.build_mesh()
+        mesh = strat.build_mesh(jax.devices()[:4])  # batch 4: 1 row each
         loss = T.make_loss_fn(cfg, strat, mesh)
         params = T.init_params(cfg, jax.random.PRNGKey(0))
         batch = {"tokens": jax.random.randint(

@@ -1,0 +1,174 @@
+"""The main path's kernels and step programs, compiled for a described
+``v5e:2x2`` at real widths — no chip, no run.
+
+Interpret mode and the CPU backend cannot show what the TPU compiler
+refuses (block shapes off the (8, 128) tiling, too much VMEM, a program
+that does not fit 16 GB, a kernel that cannot be partitioned). These
+compiles can, at about two seconds a kernel and some more for a step
+program. Nothing here executes; a pass is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at
+import: only one process at a time may load libtpu, and every xdist
+worker imports every test file), and every compile runs in the test's
+own process. Keep these tests in this one file.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+import optax
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+from dlrover_tpu.models import transformer as tfm
+from dlrover_tpu.parallel import strategy as strat_lib
+from dlrover_tpu.trainer.train_step import compile_train
+
+HBM_BYTES = 16 * 10**9  # v5e, utils/profiler.py PEAKS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _abstract_train_args(compiled_train, batch: int, seq: int):
+    state = jax.eval_shape(compiled_train.init, jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda leaf, sh: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                              sharding=sh),
+        state, compiled_train.state_shardings,
+        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct),
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (1, batch, seq + 1), np.int32,
+        sharding=compiled_train.batch_sharding)
+    return state, {"tokens": tokens}
+
+
+def _compile_train_step(cfg, strategy, devices, batch, seq):
+    mesh = strategy.build_mesh(devices)
+    ct = compile_train(
+        strategy=strategy, mesh=mesh,
+        loss_fn=tfm.make_loss_fn(cfg, strategy, mesh),
+        init_params_fn=lambda rng: tfm.init_params(cfg, rng),
+        logical_params=tfm.logical_axes(cfg),
+        optimizer=optax.adamw(1e-4),
+    )
+    state, batch_abs = _abstract_train_args(ct, batch, seq)
+    return ct, state, ct.step.lower(state, batch_abs).compile()
+
+
+@pytest.mark.parametrize("kernel", ["flash", "splash", "splash_window"])
+def test_attention_kernels_compile_fwd_bwd(one_chip, kernel):
+    """gpt2-medium attention geometry (B2 S1024 H16 D64, bf16), forward
+    and backward, as the train step calls them."""
+    from dlrover_tpu.ops.flash_attention import flash_attention
+    from dlrover_tpu.ops.splash_attention import splash_attention
+
+    fn = {
+        "flash": flash_attention,
+        "splash": splash_attention,
+        "splash_window": lambda q, k, v, causal: splash_attention(
+            q, k, v, causal=causal, window=256),
+    }[kernel]
+
+    def loss(q, k, v):
+        return fn(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((2, 1024, 16, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2  # fwd + bwd
+
+
+def test_gpt2_medium_train_step_fits_one_chip(topo):
+    """The whole step ``chip_smoke.py`` trains (full width and depth,
+    batch 8 x 1024, AdamW in f32) holds a Pallas kernel and leaves room
+    for the async snapshot's copy of the state."""
+    cfg = dataclasses.replace(
+        tfm.CONFIGS["gpt2-medium"], attention="splash", remat_scan=True,
+        remat_policy="nothing", ce_chunks=16)
+    _, state, compiled = _compile_train_step(
+        cfg, strat_lib.dp(), topo.devices[:1], batch=8, seq=1024)
+    assert "tpu_custom_call" in compiled.as_text()
+    state_bytes = sum(
+        int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+        for leaf in jax.tree_util.tree_leaves(state))
+    assert _device_bytes(compiled) + state_bytes < HBM_BYTES
+
+
+def test_fsdp_step_shards_over_four_chips(topo):
+    """gpt2-xl widths (d 1600, 25 heads) with the depth cut to 2 layers,
+    FSDP on the 2x2 mesh: every chip holds a quarter of the state and the
+    step gathers parameters over the interconnect."""
+    cfg = dataclasses.replace(
+        tfm.CONFIGS["gpt2-xl"], n_layers=2, attention="splash",
+        remat_scan=True, remat_policy="nothing", ce_chunks=16)
+    ct, state, compiled = _compile_train_step(
+        cfg, strat_lib.fsdp(), topo.devices, batch=8, seq=1024)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+    total = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                for leaf in jax.tree_util.tree_leaves(state))
+    per_device = compiled.memory_analysis().argument_size_in_bytes
+    assert per_device < 0.3 * total  # ~1/4 + the replicated small leaves
+    assert _device_bytes(compiled) < HBM_BYTES
+    assert isinstance(ct.state_shardings.params["embed"], NamedSharding)
+
+
+def test_serving_programs_compile(one_chip):
+    """Prefill chunk and decode step of ``InferenceEngine`` at
+    gpt2-medium widths (depth cut to 1), the step with the canonical
+    numerics the engine compiles it under. The step's full-vocabulary
+    sampling makes it the slow compile of this file (~25 s); the decode
+    block and the verify program share its body and are compiled at
+    full depth by the chip smoke."""
+    from dlrover_tpu.models.decode import init_cache
+    from dlrover_tpu.serving import engine as serving
+
+    cfg = dataclasses.replace(tfm.CONFIGS["gpt2-medium"], n_layers=1)
+    eng = serving.InferenceEngine(
+        tfm.init_params(cfg, jax.random.PRNGKey(0)), cfg, slots=8)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                np.shape(a), jnp.asarray(a).dtype, sharding=one_chip),
+            tree)
+
+    eng._step_block.lower(
+        *on_chip(eng._step_sample_args()), n_steps=1
+    ).compile(compiler_options=serving._CANONICAL_NUMERICS)
+    chunk = jax.ShapeDtypeStruct((1, eng.prefill_len), jnp.int32,
+                                 sharding=one_chip)
+    row = on_chip(init_cache(cfg, 1, eng.max_len))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    eng._prefill_chunk.lower(
+        on_chip(eng.params), chunk, row["k"], row["v"], row["pos"], scalar
+    ).compile()
