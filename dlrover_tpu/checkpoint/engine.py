@@ -32,7 +32,14 @@ from dlrover_tpu.common.constants import CheckpointStorageType, EnvKey
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.common.multi_process import SharedQueue, client_socket_ready
 from dlrover_tpu.common.storage import CheckpointStorage, PosixDiskStorage
-from dlrover_tpu.telemetry.journal import get_journal, spawn_ctx
+from dlrover_tpu.telemetry.journal import (
+    adopt_remote_ctx,
+    current_trace_id,
+    format_ctx,
+    get_journal,
+    hot_span,
+    spawn_ctx,
+)
 from dlrover_tpu.telemetry.metrics import registry
 from dlrover_tpu.checkpoint.shm_handler import (
     SharedMemoryHandler,
@@ -307,7 +314,8 @@ class CheckpointEngine:
         self.replicated = replicated
         # async-snapshot pipeline state (save_to_memory_async)
         self._pending_lock = threading.Lock()
-        self._pending: tuple[int, int, Any] | None = None  # (seq, step, snap)
+        # (seq, step, device copy, the request's journal span id)
+        self._pending: tuple[int, int, Any, str] | None = None
         self._async_seq = 0
         # sequence floor: a sync save lifts it so an older async snapshot
         # popped-but-unwritten can never overwrite the newer sync write
@@ -499,32 +507,35 @@ class CheckpointEngine:
         with states near the HBM limit (the 1B ckpt bench) use the sync
         path.
         """
-        if not self._async_eligible():
-            self.save_to_memory(step, state)
-            return
-        import jax
-
-        with self._pending_lock:
-            if self._pending is not None or self._async_writing:
+        with hot_span("snapshot_request", step=step) as request:
+            if not self._async_eligible():
+                self.save_to_memory(step, state)
                 return
+            import jax
 
-        if self._device_copy is None:
-            import jax.numpy as jnp
+            with self._pending_lock:
+                if self._pending is not None or self._async_writing:
+                    request.set(skipped=True)
+                    return
 
-            self._device_copy = jax.jit(
-                lambda t: jax.tree.map(jnp.copy, t)
-            )
-        snap = self._device_copy(state)
-        with self._pending_lock:
-            self._async_seq += 1
-            self._pending = (self._async_seq, step, snap)
-        if self._snap_thread is None:
-            self._snap_thread = threading.Thread(
-                target=self._snapshot_worker, name="snapshot-writer",
-                daemon=True,
-            )
-            self._snap_thread.start()
-        self._snap_wake.set()
+            if self._device_copy is None:
+                import jax.numpy as jnp
+
+                self._device_copy = jax.jit(
+                    lambda t: jax.tree.map(jnp.copy, t)
+                )
+            snap = self._device_copy(state)
+            with self._pending_lock:
+                self._async_seq += 1
+                self._pending = (self._async_seq, step, snap, request.id)
+            request.set(seq=self._async_seq, skipped=False)
+            if self._snap_thread is None:
+                self._snap_thread = threading.Thread(
+                    target=self._snapshot_worker, name="snapshot-writer",
+                    daemon=True,
+                )
+                self._snap_thread.start()
+            self._snap_wake.set()
 
     def _snapshot_worker(self) -> None:
         while not self._snap_stop.is_set():
@@ -538,9 +549,13 @@ class CheckpointEngine:
                     self._async_writing = True
             if pending is None:
                 continue
-            seq, step, snap = pending
+            seq, step, snap, request_id = pending
             try:
-                self.save_to_memory(step, snap, _async_seq=seq)
+                # the writer's spans are children of the request that
+                # handed the copy over, across the thread boundary
+                with adopt_remote_ctx(
+                        format_ctx(current_trace_id(), request_id)):
+                    self.save_to_memory(step, snap, _async_seq=seq)
             except Exception:  # noqa: BLE001 - snapshots are best-effort
                 logger.exception("async snapshot at step %d failed", step)
             finally:
@@ -647,9 +662,15 @@ class CheckpointEngine:
         if loaded is None:
             return None
         step, arrays = loaded
-        restored = step, restore_pytree(template, arrays, put=put)
+        state = restore_pytree(template, arrays, put=put)
+        if put is not None:
+            # a device `put` returns before its transfer has finished:
+            # the restore is over when the state is on the chip
+            import jax
+
+            jax.block_until_ready(state)
         _record_restore("engine", start, step)
-        return restored
+        return step, state
 
     def load_raw(self) -> tuple[int, dict] | None:
         """(step, {leaf_path: array}) without a shape template — for

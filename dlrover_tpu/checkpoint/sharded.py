@@ -393,6 +393,10 @@ class ShardedCheckpointEngine(CheckpointEngine):
             # executable can see it, or donation corrupts it in place
             # on the CPU backend (DESIGN.md §17.4)
             loaded = (loaded[0], launder(loaded[1]))
+            # over when the state is on the chip, not when it is queued
+            import jax
+
+            jax.block_until_ready(loaded[1])
             _record_restore("sharded", start, loaded[0])
         return loaded
 

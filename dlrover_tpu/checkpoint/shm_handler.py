@@ -31,6 +31,7 @@ from dlrover_tpu.common.multi_process import (
     SharedLock,
     SharedMemoryArena,
 )
+from dlrover_tpu.telemetry.journal import hot_span
 
 logger = get_logger(__name__)
 
@@ -116,26 +117,11 @@ class SharedMemoryHandler:
         total. Falls back to per-leaf (with overlapped async D2H) for
         host leaves or states too big to duplicate on device.
         """
-        import jax
-
         named = _leaf_paths(tree)
         metas, total = compute_layout(named)
-        fetched = self._fetch_packed(named)
-        if fetched is None:
-            # kick off all D2H copies before the first blocking read
-            for _, leaf in named:
-                if isinstance(leaf, jax.Array) and hasattr(
-                    leaf, "copy_to_host_async"
-                ):
-                    try:
-                        leaf.copy_to_host_async()
-                    except RuntimeError:
-                        pass
-            fetched = {
-                name: np.asarray(jax.device_get(leaf))
-                for name, leaf in named
-            }
-        with self._local_lock:
+        fetched = self._fetch(named, step, total)
+        with hot_span("snapshot_arena_write", step=step, bytes=total), \
+                self._local_lock:
             arena = self._ensure_arena(total)
             buf = arena.buf
             for name, _ in named:
@@ -187,24 +173,9 @@ class SharedMemoryHandler:
         no allocations beyond loop temporaries — minimizing the window
         for the classic fork-while-malloc-locked deadlock.
         """
-        import jax
-
         named = _leaf_paths(tree)
         metas, total = compute_layout(named)
-        fetched = self._fetch_packed(named)
-        if fetched is None:
-            for _, leaf in named:
-                if isinstance(leaf, jax.Array) and hasattr(
-                    leaf, "copy_to_host_async"
-                ):
-                    try:
-                        leaf.copy_to_host_async()
-                    except RuntimeError:
-                        pass
-            fetched = {
-                name: np.asarray(jax.device_get(leaf))
-                for name, leaf in named
-            }
+        fetched = self._fetch(named, step, total)
         with self._local_lock:
             arena = self._ensure_arena(total)
         buf = arena.buf
@@ -272,6 +243,30 @@ class SharedMemoryHandler:
         threading.Thread(target=_watch, name="cow-snapshot-watch",
                          daemon=True).start()
         return info
+
+    def _fetch(self, named: list[tuple[str, Any]], step: int,
+               total: int) -> dict[str, np.ndarray]:
+        """Every leaf on the host: packed where that is possible, else
+        leaf by leaf with the device-to-host copies overlapped."""
+        import jax
+
+        with hot_span("snapshot_fetch", step=step, bytes=total):
+            fetched = self._fetch_packed(named)
+            if fetched is None:
+                # kick off all D2H copies before the first blocking read
+                for _, leaf in named:
+                    if isinstance(leaf, jax.Array) and hasattr(
+                        leaf, "copy_to_host_async"
+                    ):
+                        try:
+                            leaf.copy_to_host_async()
+                        except RuntimeError:
+                            pass
+                fetched = {
+                    name: np.asarray(jax.device_get(leaf))
+                    for name, leaf in named
+                }
+        return fetched
 
     def _fetch_packed(self, named: list[tuple[str, Any]]
                       ) -> dict[str, np.ndarray] | None:

@@ -179,7 +179,7 @@ class EnvKey:
     FALLBACK_AOT = "DLROVER_TPU_FALLBACK_AOT"
     # efficiency observatory (DESIGN.md §18): per-step phase split
     # ("0" restores fire-and-forget dispatch) and the journal cadence
-    # of metrics_sample/step_phase points
+    # of metrics_sample points
     STEP_PHASES = "DLROVER_TPU_STEP_PHASES"
     EFFICIENCY_JOURNAL_EVERY = "DLROVER_TPU_EFFICIENCY_JOURNAL_EVERY"
     # buddy-replication of shm snapshots (checkpoint/buddy.py): "0"

@@ -196,7 +196,7 @@ SPECS: tuple[EnvVar, ...] = (
            "'0' restores fire-and-forget dispatch (no per-step phase "
            "split)", "§18", restart_required=True),
     EnvVar("DLROVER_TPU_EFFICIENCY_JOURNAL_EVERY", "25",
-           "steps between metrics_sample/step_phase journal points "
+           "steps between metrics_sample journal points "
            "(0 disables)", "§18"),
     # ---------------------------------------------------------------- chaos
     EnvVar("DLROVER_TPU_CHAOS", None,

@@ -55,9 +55,10 @@ def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0):
     scale = 1.0 / math.sqrt(D)
     G = k_cache.shape[2]  # kv heads
     qg = q.reshape(B, S_new, G, n_rep, D)
-    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache).astype(
-        jnp.float32
-    ) * scale
+    with jax.named_scope("kv_read"):
+        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache).astype(
+            jnp.float32
+        ) * scale
     max_len = k_cache.shape[1]
     k_pos = jnp.arange(max_len)
     if jnp.ndim(pos) == 0:
@@ -76,7 +77,8 @@ def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0):
         mask = mask[:, None, None]
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-    o = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache)
+    with jax.named_scope("kv_read"):
+        o = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache)
     return o.reshape(B, S_new, H, D)
 
 
@@ -99,20 +101,26 @@ def forward_cached(
     scalar_pos = jnp.ndim(pos) == 0  # static at trace time
     n_rep = c.n_heads // c.n_kv_heads
 
+    def cast(weight):
+        # the stored (float32) weights, converted on every call: a
+        # scope of its own, so that the trace can price it
+        with jax.named_scope("weight_cast"):
+            return weight.astype(dt)
+
     if scalar_pos:
         positions = pos + jnp.broadcast_to(jnp.arange(S_new), (B, S_new))
     else:
         positions = pos[:, None] + jnp.arange(S_new)[None]
-    x = params["embed"].astype(dt)[tokens]
+    x = cast(params["embed"])[tokens]
     if c.variant == "gpt2":
         if scalar_pos:
             pe = lax.dynamic_slice_in_dim(
-                params["pos_embed"].astype(dt), pos, S_new, axis=0
+                cast(params["pos_embed"]), pos, S_new, axis=0
             )[None]
         else:
             # gather (not slice): per-row positions; clamp keeps the
             # lookup in-table for padded/inactive rows
-            pe = params["pos_embed"].astype(dt)[
+            pe = cast(params["pos_embed"])[
                 jnp.clip(positions, 0, c.max_seq_len - 1)
             ]
         x = x + pe
@@ -137,81 +145,89 @@ def forward_cached(
     def layer(carry, inputs):
         x = carry
         w, k_cache_l, v_cache_l = inputs
-        h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
-        q = jnp.einsum("bse,ehd->bshd", h, w["wq"].astype(dt))
-        if c.mup_base_width:
-            # same order as training: scale before rope (they commute,
-            # but keep the copies textually aligned)
-            q = q / math.sqrt(c.head_dim)
-        k = jnp.einsum("bse,ehd->bshd", h, w["wk"].astype(dt))
-        v = jnp.einsum("bse,ehd->bshd", h, w["wv"].astype(dt))
-        if c.variant == "llama":
-            q = _rope(q, positions, c.rope_theta)
-            k = _rope(k, positions, c.rope_theta)
-        if scalar_pos:
-            # one contiguous slice update for the whole batch (keeps the
-            # generate()/PPO hot path off the scatter lowering the
-            # vmapped form implies)
-            k_cache_l = lax.dynamic_update_slice_in_dim(
-                k_cache_l, k.astype(dt), pos, axis=1
+        with jax.named_scope("attn"):
+            h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
+            q = jnp.einsum("bse,ehd->bshd", h, cast(w["wq"]))
+            if c.mup_base_width:
+                # same order as training: scale before rope (they
+                # commute, but keep the copies textually aligned)
+                q = q / math.sqrt(c.head_dim)
+            k = jnp.einsum("bse,ehd->bshd", h, cast(w["wk"]))
+            v = jnp.einsum("bse,ehd->bshd", h, cast(w["wv"]))
+            if c.variant == "llama":
+                q = _rope(q, positions, c.rope_theta)
+                k = _rope(k, positions, c.rope_theta)
+            with jax.named_scope("kv_write"):
+                if scalar_pos:
+                    # one contiguous slice update for the whole batch
+                    # (keeps the generate()/PPO hot path off the scatter
+                    # lowering the vmapped form implies)
+                    k_cache_l = lax.dynamic_update_slice_in_dim(
+                        k_cache_l, k.astype(dt), pos, axis=1
+                    )
+                    v_cache_l = lax.dynamic_update_slice_in_dim(
+                        v_cache_l, v.astype(dt), pos, axis=1
+                    )
+                else:
+                    # per-row write offsets: vmap a single-row update
+                    row_update = jax.vmap(
+                        lambda row, new, p:
+                        lax.dynamic_update_slice_in_dim(
+                            row, new, p, axis=0
+                        )
+                    )
+                    k_cache_l = row_update(k_cache_l, k.astype(dt), pos)
+                    v_cache_l = row_update(v_cache_l, v.astype(dt), pos)
+            # the window only binds when training actually used it (the
+            # splash kind) — other attention kinds ignore
+            # attention_window in training, so decode must too or the
+            # masks diverge
+            o = _layer_attend(
+                q, k_cache_l, v_cache_l, pos, n_rep, dt,
+                window=(c.attention_window if c.attention == "splash"
+                        else 0),
             )
-            v_cache_l = lax.dynamic_update_slice_in_dim(
-                v_cache_l, v.astype(dt), pos, axis=1
-            )
-        else:
-            # per-row write offsets: vmap a single-row dynamic update
-            row_update = jax.vmap(
-                lambda row, new, p: lax.dynamic_update_slice_in_dim(
-                    row, new, p, axis=0
+            o = jnp.einsum("bshd,hde->bse", o, cast(w["wo"]))
+            x = x + o
+        with jax.named_scope("mlp"):
+            h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant)
+            if c.moe_experts:
+                ff, _ = moe_ffn(
+                    {"w_router": w["w_router"], "w_in": w["w_in"],
+                     "w_out": w["w_out"]},
+                    h, moe_cfg,
                 )
-            )
-            k_cache_l = row_update(k_cache_l, k.astype(dt), pos)
-            v_cache_l = row_update(v_cache_l, v.astype(dt), pos)
-        # the window only binds when training actually used it (the
-        # splash kind) — other attention kinds ignore attention_window
-        # in training, so decode must too or the masks diverge
-        o = _layer_attend(
-            q, k_cache_l, v_cache_l, pos, n_rep, dt,
-            window=c.attention_window if c.attention == "splash" else 0,
-        )
-        o = jnp.einsum("bshd,hde->bse", o, w["wo"].astype(dt))
-        x = x + o
-        h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant)
-        if c.moe_experts:
-            ff, _ = moe_ffn(
-                {"w_router": w["w_router"], "w_in": w["w_in"],
-                 "w_out": w["w_out"]},
-                h, moe_cfg,
-            )
-        elif c.variant == "llama":
-            gate = jax.nn.silu(
-                jnp.einsum("bse,ef->bsf", h, w["w_gate"].astype(dt))
-            )
-            up = jnp.einsum("bse,ef->bsf", h, w["w_up"].astype(dt))
-            ff = jnp.einsum("bsf,fe->bse", gate * up,
-                            w["w_down"].astype(dt))
-        else:
-            hidden = jax.nn.gelu(
-                jnp.einsum("bse,ef->bsf", h, w["w_gate"].astype(dt))
-                + w["b_ff"].astype(dt)
-            )
-            ff = (jnp.einsum("bsf,fe->bse", hidden,
-                             w["w_down"].astype(dt))
-                  + w["b_out"].astype(dt))
-        x = x + ff
+            elif c.variant == "llama":
+                gate = jax.nn.silu(
+                    jnp.einsum("bse,ef->bsf", h, cast(w["w_gate"]))
+                )
+                up = jnp.einsum("bse,ef->bsf", h, cast(w["w_up"]))
+                ff = jnp.einsum("bsf,fe->bse", gate * up,
+                                cast(w["w_down"]))
+            else:
+                hidden = jax.nn.gelu(
+                    jnp.einsum("bse,ef->bsf", h, cast(w["w_gate"]))
+                    + cast(w["b_ff"])
+                )
+                ff = (jnp.einsum("bsf,fe->bse", hidden,
+                                 cast(w["w_down"]))
+                      + cast(w["b_out"]))
+            x = x + ff
         return x, (k_cache_l, v_cache_l)
 
     x, (k_new, v_new) = lax.scan(
         layer, x, (params["layers"], cache["k"], cache["v"])
     )
-    x = _norm(x, params["ln_f"], params.get("ln_f_b"), c.variant)
-    logits = jnp.einsum("bse,ev->bsv", x, params["lm_head"].astype(dt))
-    if c.mup_base_width:
-        logits = logits * (c.mup_base_width / c.d_model)
+    with jax.named_scope("lm_head"):
+        x = _norm(x, params["ln_f"], params.get("ln_f_b"), c.variant)
+        logits = jnp.einsum("bse,ev->bsv", x, cast(params["lm_head"]))
+        if c.mup_base_width:
+            logits = logits * (c.mup_base_width / c.d_model)
     new_cache = {"k": k_new, "v": v_new, "pos": pos + S_new}
     return logits.astype(jnp.float32), new_cache
 
 
+@jax.named_scope("sample")
 def sample_logits(
     logits: jax.Array, key: jax.Array,
     temperature: float | jax.Array = 1.0,

@@ -133,6 +133,25 @@ class TransformerConfig:
         final_norm = c.d_model * (1 if c.variant == "llama" else 2)
         return embed + pos + c.n_layers * per_layer + final_norm + lm_head
 
+    def train_flops_per_token(self, seq: int) -> float:
+        """Model FLOPs of one token's forward and backward pass in a
+        sequence of ``seq``, the way model-FLOPs utilization counts
+        them: the matrix multiplications the mathematics needs (2 per
+        multiply-add; a token passes ``moe_top_k`` experts), the causal
+        half of attention when the model is causal, the backward twice
+        the forward, recomputed operations NOT counted."""
+        c = self
+        attn = c.d_model * c.head_dim * (c.n_heads * 2 + c.n_kv_heads * 2)
+        if c.moe_experts:
+            ffn = (c.d_model * c.moe_experts
+                   + c.moe_top_k * 2 * c.d_model * c.d_ff)
+        else:
+            ffn = (3 if c.variant == "llama" else 2) * c.d_model * c.d_ff
+        matmul = c.n_layers * (attn + ffn) + c.d_model * c.vocab_size
+        keys = (seq + 1) / 2 if c.causal else seq
+        scores = c.n_layers * 2 * 2 * c.n_heads * c.head_dim * keys
+        return 3.0 * (2.0 * matmul + scores)
+
 
 # Per-layer remat policies for remat_scan (distinct from the step-level
 # Strategy.remat table in parallel/strategy.py): "full" is an alias of
@@ -446,47 +465,49 @@ def make_layer_fn(
         """
         aux = jnp.zeros((), jnp.float32)
         positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
-        h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
-        q = proj(h, w["wq"].astype(dt), "bse,ehd->bshd")
-        if c.mup_base_width:
-            q = q * mup_q_scale
-        k = proj(h, w["wk"].astype(dt), "bse,ehd->bshd")
-        v = proj(h, w["wv"].astype(dt), "bse,ehd->bshd")
-        if c.variant == "llama":
-            q = _rope(q, positions, c.rope_theta)
-            k = _rope(k, positions, c.rope_theta)
-        if n_rep > 1 and not getattr(attn, "supports_gqa", False):
-            # GQA-native impls (splash) read the shared KV directly —
-            # repeating here would multiply KV memory traffic by n_rep
-            k = jnp.repeat(k, n_rep, axis=2)
-            v = jnp.repeat(v, n_rep, axis=2)
-        o = attn(q, k, v, causal=c.causal)
-        o = proj(o, w["wo"].astype(dt), "bshd,hde->bse", n_contract=2)
-        o = checkpoint_name(o, "attn_out")  # inert without a names policy
-        x = pin(x + o, ("batch", "sequence", "embed"))
+        with jax.named_scope("attn"):
+            h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
+            q = proj(h, w["wq"].astype(dt), "bse,ehd->bshd")
+            if c.mup_base_width:
+                q = q * mup_q_scale
+            k = proj(h, w["wk"].astype(dt), "bse,ehd->bshd")
+            v = proj(h, w["wv"].astype(dt), "bse,ehd->bshd")
+            if c.variant == "llama":
+                q = _rope(q, positions, c.rope_theta)
+                k = _rope(k, positions, c.rope_theta)
+            if n_rep > 1 and not getattr(attn, "supports_gqa", False):
+                # GQA-native impls (splash) read the shared KV directly —
+                # repeating here would multiply KV memory traffic by n_rep
+                k = jnp.repeat(k, n_rep, axis=2)
+                v = jnp.repeat(v, n_rep, axis=2)
+            o = attn(q, k, v, causal=c.causal)
+            o = proj(o, w["wo"].astype(dt), "bshd,hde->bse", n_contract=2)
+            o = checkpoint_name(o, "attn_out")  # inert without a names policy
+            x = pin(x + o, ("batch", "sequence", "embed"))
 
-        h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant)
-        if c.moe_experts:
-            ff, aux_l = moe_ffn(
-                {"w_router": w["w_router"], "w_in": w["w_in"],
-                 "w_out": w["w_out"]},
-                h, moe_cfg, constrain=pin, token_mask=mask,
-            )
-            aux = aux_l
-        elif c.variant == "llama":
-            gate = jax.nn.silu(proj(h, w["w_gate"].astype(dt),
-                                    "bse,ef->bsf"))
-            up = proj(h, w["w_up"].astype(dt), "bse,ef->bsf")
-            ff = proj(gate * up, w["w_down"].astype(dt), "bsf,fe->bse")
-        else:
-            hidden = jax.nn.gelu(
-                proj(h, w["w_gate"].astype(dt), "bse,ef->bsf")
-                + w["b_ff"].astype(dt)
-            )
-            hidden = checkpoint_name(hidden, "ffn_hidden")
-            ff = (proj(hidden, w["w_down"].astype(dt), "bsf,fe->bse")
-                  + w["b_out"].astype(dt))
-        x = pin(x + ff, ("batch", "sequence", "embed"))
+        with jax.named_scope("mlp"):
+            h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant)
+            if c.moe_experts:
+                ff, aux_l = moe_ffn(
+                    {"w_router": w["w_router"], "w_in": w["w_in"],
+                     "w_out": w["w_out"]},
+                    h, moe_cfg, constrain=pin, token_mask=mask,
+                )
+                aux = aux_l
+            elif c.variant == "llama":
+                gate = jax.nn.silu(proj(h, w["w_gate"].astype(dt),
+                                        "bse,ef->bsf"))
+                up = proj(h, w["w_up"].astype(dt), "bse,ef->bsf")
+                ff = proj(gate * up, w["w_down"].astype(dt), "bsf,fe->bse")
+            else:
+                hidden = jax.nn.gelu(
+                    proj(h, w["w_gate"].astype(dt), "bse,ef->bsf")
+                    + w["b_ff"].astype(dt)
+                )
+                hidden = checkpoint_name(hidden, "ffn_hidden")
+                ff = (proj(hidden, w["w_down"].astype(dt), "bsf,fe->bse")
+                      + w["b_out"].astype(dt))
+            x = pin(x + ff, ("batch", "sequence", "embed"))
         return x, aux
 
     return layer
@@ -509,11 +530,12 @@ def embed_tokens(
     # partitioner otherwise leaves the gather's layout ambiguous and
     # falls back to involuntary full rematerialization of the embedding
     # (seen in the r02 4D dryrun tail)
-    x = pin(params["embed"].astype(dt)[tokens],
-            ("batch", "sequence", "embed"))
-    if c.variant == "gpt2":
-        x = x + params["pos_embed"].astype(dt)[:tokens.shape[1]][None]
-        x = pin(x, ("batch", "sequence", "embed"))
+    with jax.named_scope("embed"):
+        x = pin(params["embed"].astype(dt)[tokens],
+                ("batch", "sequence", "embed"))
+        if c.variant == "gpt2":
+            x = x + params["pos_embed"].astype(dt)[:tokens.shape[1]][None]
+            x = pin(x, ("batch", "sequence", "embed"))
     return x
 
 
@@ -879,25 +901,27 @@ def loss_fn(
             attention_fn=attention_fn, constrain=constrain,
             mask=mask_in, return_hidden=True, prefix_len=prefix_len,
         )
-        ce = _blockwise_ce(
-            hidden, params, targets,
-            loss_mask[:, 1:] if loss_mask is not None else None, cfg,
-        )
+        with jax.named_scope("ce_loss"):
+            ce = _blockwise_ce(
+                hidden, params, targets,
+                loss_mask[:, 1:] if loss_mask is not None else None, cfg,
+            )
     else:
         logits, aux = forward_with_aux(
             params, tokens[:, :-1], cfg,
             attention_fn=attention_fn, constrain=constrain,
             mask=mask_in, prefix_len=prefix_len,
         )
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(
-            logp, targets[..., None], axis=-1
-        )[..., 0]
-        if loss_mask is not None:
-            m = loss_mask[:, 1:].astype(nll.dtype)
-            ce = (nll * m).sum() / jnp.maximum(m.sum(), 1.0)
-        else:
-            ce = nll.mean()
+        with jax.named_scope("ce_loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(
+                logp, targets[..., None], axis=-1
+            )[..., 0]
+            if loss_mask is not None:
+                m = loss_mask[:, 1:].astype(nll.dtype)
+                ce = (nll * m).sum() / jnp.maximum(m.sum(), 1.0)
+            else:
+                ce = nll.mean()
     if cfg.moe_experts:
         ce = ce + cfg.moe_aux_weight * aux
     return ce

@@ -110,7 +110,7 @@ from dlrover_tpu.serving.observatory import (
     PrefixDigestStore,
     ServingObservatory,
 )
-from dlrover_tpu.telemetry.journal import get_journal
+from dlrover_tpu.telemetry.journal import get_journal, hot_span
 from dlrover_tpu.telemetry.metrics import registry
 
 logger = get_logger(__name__)
@@ -134,6 +134,12 @@ _decode_stall_seconds = registry().histogram(
     "steps while slots were actively decoding",
     buckets=(0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
              2.5, 5.0),
+)
+_decoding_slots = registry().gauge(
+    "dlrover_tpu_engine_decoding_slots",
+    "slots that took part in the engine's newest decode call (0 when "
+    "the newest step made none), per engine",
+    label_names=("engine",),
 )
 _kv_parked_total = registry().counter(
     "dlrover_tpu_engine_kv_parked_total",
@@ -281,6 +287,9 @@ class _PrefillRun:
     chunks: int = 0
     work_s: float = 0.0
     done: bool = False
+    # whose prompt this is, for the spans (-1: the prefill pool's)
+    request: int = -1
+    sctx: str = ""
 
 
 @dataclasses.dataclass
@@ -673,6 +682,7 @@ class InferenceEngine:
         # 1 leftover). Other block sizes keep the jit ladder.
         self._aot_step = None
         self.aot_info = None
+        self._decoding_gauge = _decoding_slots.labels(self.engine_id)
         _LIVE_ENGINES.add(self)
 
     # ------------------------------------------------------- AOT cold start
@@ -951,29 +961,33 @@ class InferenceEngine:
         t0 = time.monotonic()
         lo = run.next_lo
         chunk = run.prompt[lo: lo + P]
-        toks = np.zeros((1, P), np.int32)
-        toks[0, : len(chunk)] = chunk
-        run.row_k, run.row_v, run.pos, run.last = self._prefill_chunk(
-            self.params, jnp.asarray(toks), run.row_k, run.row_v,
-            run.pos, jnp.asarray(len(chunk), jnp.int32),
-        )
-        final_top = len(run.prompt) // P * P
-        if self.prefix_cache_entries and len(chunk) == P:
-            # snapshot the FINAL aligned boundary always; intermediate
-            # boundaries only when extending an already-cached prefix
-            # (start > 0, the shared-system-prompt chain). A cold
-            # non-sharing prompt then adds ONE entry instead of top/P,
-            # so a wave of long unrelated prompts can no longer churn
-            # the LRU and evict the shared prefixes that actually hit.
-            if lo + P == final_top or run.start > 0:
-                self._prefix_store(
-                    tuple(run.prompt[: lo + P]),
-                    (run.row_k, run.row_v, run.pos, run.last),
-                )
-        run.next_lo = lo + P
-        run.chunks += 1
-        run.done = run.next_lo >= len(run.prompt)
-        jax.block_until_ready(run.last)
+        with hot_span("prefill_chunk", remote_parent=run.sctx,
+                      request=run.request, tokens=len(chunk),
+                      chunk=run.chunks, context=lo):
+            toks = np.zeros((1, P), np.int32)
+            toks[0, : len(chunk)] = chunk
+            run.row_k, run.row_v, run.pos, run.last = self._prefill_chunk(
+                self.params, jnp.asarray(toks), run.row_k, run.row_v,
+                run.pos, jnp.asarray(len(chunk), jnp.int32),
+            )
+            final_top = len(run.prompt) // P * P
+            if self.prefix_cache_entries and len(chunk) == P:
+                # snapshot the FINAL aligned boundary always;
+                # intermediate boundaries only when extending an
+                # already-cached prefix (start > 0, the
+                # shared-system-prompt chain). A cold non-sharing prompt
+                # then adds ONE entry instead of top/P, so a wave of
+                # long unrelated prompts can no longer churn the LRU and
+                # evict the shared prefixes that actually hit.
+                if lo + P == final_top or run.start > 0:
+                    self._prefix_store(
+                        tuple(run.prompt[: lo + P]),
+                        (run.row_k, run.row_v, run.pos, run.last),
+                    )
+            run.next_lo = lo + P
+            run.chunks += 1
+            run.done = run.next_lo >= len(run.prompt)
+            jax.block_until_ready(run.last)
         run.work_s += time.monotonic() - t0
         return run.done
 
@@ -1265,6 +1279,7 @@ class InferenceEngine:
         else:
             run = self.prefill_begin(req.prompt)
             kind = "hit" if run.start else "cold"
+        run.request, run.sctx = req.id, req.sctx
         self._pending = _PendingAdmit(req=req, run=run, pages=pages,
                                       kind=kind,
                                       shared=set(range(shared_n)))
@@ -1362,7 +1377,10 @@ class InferenceEngine:
         if pa.run.done:
             slot = self._take_slot()
             if slot is not None:
-                self._install_admit(slot, pa)
+                with hot_span("kv_install", remote_parent=pa.req.sctx,
+                              request=pa.req.id, slot=slot,
+                              tokens=len(pa.req.prompt)):
+                    self._install_admit(slot, pa)
                 self._pending = None
                 worked = True
         return worked
@@ -1501,6 +1519,16 @@ class InferenceEngine:
         """Admit (at most one chunk of) waiting work, decode one token
         (or one compiled block) for every active slot, retire finished
         ones. Returns number of active slots."""
+        with hot_span("engine_step", queued=len(self._queue)) as span:
+            decoding, n_steps, active = self._step()
+            span.set(decoding_slots=decoding, n_steps=n_steps)
+        # slots in this step's decode call: what `slot_occupancy`
+        # (requests a replica holds) cannot see
+        self._decoding_gauge.set(decoding)
+        return active
+
+    def _step(self) -> tuple[int, int, int]:
+        """(slots in the decode call, its steps, slots active after)."""
         had_active = any(r is not None for r in self._active)
         t0 = time.monotonic()
         admitted = self._admit_tick()
@@ -1522,7 +1550,8 @@ class InferenceEngine:
             [r is not None for r in self._active], bool
         )
         if not active_mask.any():
-            return 0
+            return 0, 0, 0
+        decoding = int(active_mask.sum())
         temp, top_k, top_p, eos_ids = self._sampling_tensors()
         args = (
             self.params, self._cache["k"], self._cache["v"],
@@ -1533,35 +1562,46 @@ class InferenceEngine:
         plan = self._spec_plan() if self._spec else None
         if plan is not None:
             depth, guesses = plan
+            n_steps = depth
             fn = self._aot_verify.get(depth, self._verify_block)
-            toks_dev, k, v, pos, last, acc_dev = fn(
-                *args, jnp.asarray(guesses))
-            toks_sn, acc = (np.asarray(a) for a in
-                            jax.device_get((toks_dev, acc_dev)))
+            with hot_span("decode_block", slots=decoding, n_steps=depth):
+                toks_dev, k, v, pos, last, acc_dev = fn(
+                    *args, jnp.asarray(guesses))
+                toks_sn, acc = (np.asarray(a) for a in
+                                jax.device_get((toks_dev, acc_dev)))
             toks = toks_sn.T                     # [depth, slots]
             counts = acc.astype(np.int64)        # inactive rows: 0
             self._sampled += counts
             self.spec_steps_total += 1
             _spec_verify_steps_total.inc()
-            extra = int(counts.sum()) - int(active_mask.sum())
+            extra = int(counts.sum()) - decoding
             if extra > 0:
                 self.spec_extra_tokens_total += extra
                 _spec_extra_tokens_total.inc(extra)
             self._spec_score(guesses, toks_sn, depth)
         else:
-            block = self._block_size()
-            if block == 1 and self._aot_step is not None:
-                toks_dev, k, v, pos, last = self._aot_step(*args)
-            else:
-                toks_dev, k, v, pos, last = self._step_block(
-                    *args, n_steps=block,
-                )
+            n_steps = block = self._block_size()
+            with hot_span("decode_block", slots=decoding, n_steps=block):
+                if block == 1 and self._aot_step is not None:
+                    toks_dev, k, v, pos, last = self._aot_step(*args)
+                else:
+                    toks_dev, k, v, pos, last = self._step_block(
+                        *args, n_steps=block,
+                    )
+                toks = np.asarray(jax.device_get(toks_dev))
             self._sampled[active_mask] += block
-            toks = np.asarray(jax.device_get(toks_dev))
             counts = np.where(active_mask, block, 0)
         self._cache["k"], self._cache["v"] = k, v
         self._cache["pos"] = pos
         self._last = last
+        with hot_span("engine_emit", tokens=int(counts.sum())):
+            self._emit(toks, counts)
+        return decoding, n_steps, sum(r is not None for r in self._active)
+
+    def _emit(self, toks, counts) -> None:
+        """The host's share of a step: every new token to its request
+        (digest store, observatory, the streaming callback), finished
+        requests retired."""
         for s, req in enumerate(self._active):
             if req is None:
                 continue
@@ -1590,7 +1630,6 @@ class InferenceEngine:
                     break
         if self._obs is not None:
             self._obs.on_step()
-        return sum(r is not None for r in self._active)
 
     def _retire(self, slot: int, reason: str) -> None:
         req = self._active[slot]

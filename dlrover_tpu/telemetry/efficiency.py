@@ -5,14 +5,18 @@ The lost-time report answers "why did the job lose time to failures";
 this module answers "where does a *healthy* step go". Three pieces, all
 riding the existing telemetry substrate:
 
-- **Live MFU** — the trainer knows the compiled program's exact FLOPs
-  once per incarnation (``utils/profiler.executable_flops``, cached in
-  the AOT envelope so a warm compile-cache load never re-lowers —
-  ``parallel/compile_cache.py``); dividing by the rolling mean step
-  time × per-device peak FLOPs gives model-FLOPs utilization as a
-  continuously updated ``dlrover_tpu_mfu{model,strategy}`` gauge. The
-  gauge rides the trainer's existing metrics-snapshot pushes, so the
-  master's one-scrape exposition shows job-wide MFU per node.
+- **Live MFU** — the trainer is told the MODEL's FLOPs per step (what
+  the mathematics needs: no recompute, the causal half of attention —
+  ``TransformerConfig.train_flops_per_token``); dividing by the rolling
+  mean step time × per-device peak FLOPs gives model-FLOPs utilization
+  as a continuously updated ``dlrover_tpu_mfu{model,strategy}`` gauge.
+  The compiled program's own FLOPs (``utils/profiler.executable_flops``,
+  cached in the AOT envelope so a warm compile-cache load never
+  re-lowers — ``parallel/compile_cache.py``) count recomputed work too
+  and feed a second gauge, ``dlrover_tpu_hfu``: the two differ by the
+  recompute the strategy bought. Both ride the trainer's existing
+  metrics-snapshot pushes, so the master's one-scrape exposition shows
+  them job-wide per node.
 - **Step-phase attribution** — every step is split into
   ``data_wait | h2d | dispatch | block | ckpt`` phases
   (``dlrover_tpu_step_phase_seconds{phase}`` histograms). ``block`` is
@@ -33,10 +37,10 @@ riding the existing telemetry substrate:
 
 Journaling: every ``journal_every`` steps the monitor emits one
 ``metrics_sample`` point (rolling mfu / step time / host-blocked
-fraction / per-phase means — the counter-track source for
-``telemetry/timeline.py``) plus one ``step_phase`` point per phase with
-that step's actual phase duration, so the Perfetto view shows phase
-lanes beside the MFU counter without journaling every step.
+fraction — the MFU counter track of ``telemetry/timeline.py``). Every
+step's phases ride the trainer's ``train_step`` point
+(``trainer/elastic_trainer.py``) and nowhere else: ``telemetry/report.py``
+averages them and the timeline stacks them.
 
 Like all telemetry, nothing here may take down the instrumented path:
 capture and journaling failures are swallowed and counted.
@@ -78,15 +82,23 @@ _PHASE_BUCKETS = (
 
 _mfu_gauge = registry().gauge(
     "dlrover_tpu_mfu",
-    "live model-FLOPs utilization: compiled-program FLOPs / (rolling "
-    "mean step seconds x per-device peak FLOPs x devices); unset when "
-    "the device has no known peak (CPU) or FLOPs are unknown",
+    "live model-FLOPs utilization: the model's FLOPs per step (no "
+    "recompute, causal half of attention) / (rolling mean step seconds "
+    "x per-device peak FLOPs x devices); unset when the device has no "
+    "known peak (CPU) or the trainer was not told the model's FLOPs",
     label_names=("model", "strategy"),
 )
 _flops_gauge = registry().gauge(
     "dlrover_tpu_mfu_flops_per_step",
-    "compiled-program FLOPs per train step feeding the live MFU gauge "
-    "(XLA cost analysis, cached in the AOT compile-cache envelope)",
+    "model FLOPs per train step feeding the live MFU gauge",
+    label_names=("model", "strategy"),
+)
+_hfu_gauge = registry().gauge(
+    "dlrover_tpu_hfu",
+    "live hardware-FLOPs utilization: the compiled program's FLOPs per "
+    "step (XLA cost analysis, recomputed work included; cached in the "
+    "AOT compile-cache envelope) over the same denominator as "
+    "dlrover_tpu_mfu",
     label_names=("model", "strategy"),
 )
 _phase_seconds = registry().histogram(
@@ -121,7 +133,7 @@ def live_mfu(model: str, strategy: str) -> float | None:
 
 
 def journal_sample_every(default: int = 25) -> int:
-    """Cadence (in steps) of metrics_sample/step_phase journal points;
+    """Cadence (in steps) of metrics_sample journal points;
     ``DLROVER_TPU_EFFICIENCY_JOURNAL_EVERY`` overrides, 0 disables."""
     raw = (os.environ.get(EnvKey.EFFICIENCY_JOURNAL_EVERY) or "").strip()
     if not raw:
@@ -189,8 +201,10 @@ class EfficiencyMonitor:
         self.peak_flops = peak_flops
         self.num_devices = max(1, num_devices)
         self._flops = 0.0
+        self._executable_flops = 0.0
         self._mfu_child = _mfu_gauge.labels(self.model, self.strategy)
         self._flops_child = _flops_gauge.labels(self.model, self.strategy)
+        self._hfu_child = _hfu_gauge.labels(self.model, self.strategy)
         if flops_per_step:
             self.set_flops(flops_per_step)
         self._phase_children = {p: _phase_seconds.labels(p) for p in PHASES}
@@ -212,8 +226,8 @@ class EfficiencyMonitor:
     # ----------------------------------------------------------- accounting
 
     def set_flops(self, flops_per_step: float) -> None:
-        """Install the compiled program's FLOPs (once per incarnation;
-        warm AOT loads read it from the cache envelope)."""
+        """Install the MODEL's FLOPs per step: what the MFU gauge
+        divides (no recompute, causal half of attention)."""
         self._flops = float(flops_per_step or 0.0)
         if self._flops > 0:
             self._flops_child.set(self._flops)
@@ -221,6 +235,16 @@ class EfficiencyMonitor:
     @property
     def flops_per_step(self) -> float:
         return self._flops
+
+    def set_executable_flops(self, flops_per_step: float) -> None:
+        """Install the compiled program's FLOPs (once per incarnation;
+        warm AOT loads read it from the cache envelope): what the HFU
+        gauge divides."""
+        self._executable_flops = float(flops_per_step or 0.0)
+
+    @property
+    def executable_flops(self) -> float:
+        return self._executable_flops
 
     def observe_phase(self, phase: str, seconds: float) -> None:
         child = self._phase_children.get(phase)
@@ -230,14 +254,21 @@ class EfficiencyMonitor:
         child.observe(seconds)
         self._acc[phase] += seconds
 
-    def mfu(self) -> float | None:
-        """Rolling-window MFU, or None when peak/FLOPs are unknown."""
-        if not (self._flops > 0 and self.peak_flops and self._steps):
+    def _utilization(self, flops: float) -> float | None:
+        if not (flops > 0 and self.peak_flops and self._steps):
             return None
         mean = statistics.fmean(self._steps)
         if mean <= 0:
             return None
-        return self._flops / mean / (self.peak_flops * self.num_devices)
+        return flops / mean / (self.peak_flops * self.num_devices)
+
+    def mfu(self) -> float | None:
+        """Rolling-window MFU, or None when peak/FLOPs are unknown."""
+        return self._utilization(self._flops)
+
+    def hfu(self) -> float | None:
+        """The same over the compiled program's FLOPs."""
+        return self._utilization(self._executable_flops)
 
     def step_seconds(self) -> float | None:
         """Rolling-window MEDIAN step cadence — the measured step time
@@ -260,9 +291,10 @@ class EfficiencyMonitor:
             return 0.0
         return sum(self._blocked) / len(self._blocked)
 
-    def end_step(self, step: int, step_seconds: float) -> None:
+    def end_step(self, step: int, step_seconds: float) -> dict:
         """Close out one step: fold the phase accumulator, refresh the
-        MFU gauge, journal a sample on cadence, advance any capture."""
+        MFU gauge, journal a sample on cadence, advance any capture.
+        Returns the step's phases in seconds."""
         self._steps.append(max(0.0, float(step_seconds)))
         host = sum(self._acc[p] for p in HOST_PHASES)
         self._blocked.append(host > self._acc["block"])
@@ -272,20 +304,20 @@ class EfficiencyMonitor:
         mfu = self.mfu()
         if mfu is not None:
             self._mfu_child.set(round(mfu, 4))
+        hfu = self.hfu()
+        if hfu is not None:
+            self._hfu_child.set(round(hfu, 4))
         if self._journal_every and step % self._journal_every == 0:
             self._journal_sample(step, mfu)
         self._drive_capture(step)
+        return self._last_phases
 
     def _journal_sample(self, step: int, mfu: float | None) -> None:
-        journal = get_journal()
-        for phase, dur in self._last_phases.items():
-            journal.emit("step_phase", dur=dur, phase=phase, step=step)
-        journal.emit(
+        get_journal().emit(
             "metrics_sample", step=step,
             mfu=round(mfu, 4) if mfu is not None else None,
             step_s=round(statistics.fmean(self._steps), 6),
             host_blocked_frac=round(self.host_blocked_frac(), 4),
-            phases={p: round(v, 6) for p, v in self._last_phases.items()},
         )
 
     # ------------------------------------------------------ profiler capture

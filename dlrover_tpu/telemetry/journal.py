@@ -39,8 +39,22 @@ every name is documented in DESIGN.md): ``rdzv_round`` / ``job_start`` /
 ``hang_verdict`` / ``debug_bundle`` / ``standby_promote`` /
 ``profile_request`` (agent), ``compile`` / ``train_step`` /
 ``ckpt_restore`` / ``restore_prefetch`` / ``metrics_sample`` /
-``step_phase`` / ``profile_capture`` (trainer), ``gateway_*`` (serving
-gateway).
+``profile_capture`` (trainer), ``gateway_*`` (serving gateway).
+
+Hot-path spans (``hot_span`` / ``annotate`` below; DESIGN.md §32): the
+trainer loop's phases ``data_wait`` / ``h2d`` / ``dispatch`` / ``block``
+/ ``ckpt`` and the caller's ``on_step`` callback (profiler annotations
+in and around the ``train_step`` step annotation; the per-step
+``train_step`` journal point carries the phases as ``*_s`` fields), the
+snapshot path ``snapshot_request`` -> ``snapshot_fetch`` /
+``snapshot_arena_write`` (the writer thread's children of the request),
+and the serving engine's ``engine_step`` -> ``prefill_chunk`` /
+``kv_install`` / ``decode_block`` / ``engine_emit``.
+``hot_span`` writes each to the journal AND, while a ``jax.profiler``
+capture is live, into the profiler's host plane under the same name and
+fields — the xplane file then holds the program's spans in nanoseconds
+of the device trace's own clock, so a device idle gap can be put down
+to what the host was doing (``benchmark/span_reduce.py``).
 
 Rotation: when ``DLROVER_TPU_JOURNAL_MAX_MB`` is set, a file that
 reaches the cap is atomically renamed to ``.1`` (replacing the previous
@@ -55,6 +69,7 @@ import hashlib
 import itertools
 import json
 import os
+import sys
 import time
 import uuid
 from contextlib import contextmanager
@@ -413,3 +428,89 @@ def get_journal():
             journal = NullJournal()
     _cached = (journal_dir, pid, journal)
     return journal
+
+
+# ------------------------------------------------------- hot-path spans
+#
+# The one place a journal span is paired with a profiler annotation.
+
+
+class _NoAnnotation:
+    """Stands in where no profiler capture can be live."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set_metadata(self, **fields) -> None:
+        pass
+
+
+_NO_ANNOTATION = _NoAnnotation()
+
+
+def annotate(name: str, step_num: int | None = None, **fields):
+    """A ``jax.profiler.TraceAnnotation`` of this name with ``fields``
+    as its arguments: the profiler half of :func:`hot_span`, for
+    intervals too frequent to journal (the trainer's step phases).
+    With ``step_num`` it is a ``StepTraceAnnotation``, which the
+    profiler's own tools read as one training step. A no-op object
+    unless a capture is live in this process. JAX is never imported
+    here: a process that has not imported it (master, agent) cannot be
+    under capture."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.profiler.TraceAnnotation.is_enabled():
+        return _NO_ANNOTATION
+    if step_num is not None:
+        return jax.profiler.StepTraceAnnotation(name, step_num=step_num,
+                                                **fields)
+    return jax.profiler.TraceAnnotation(name, **fields)
+
+
+class HotSpan:
+    """Handle a ``hot_span`` block yields: ``id`` is the journal span id
+    ("" under ``NullJournal``), ``set`` adds fields known only once the
+    work is under way (they land on the journal's end event and on the
+    profiler event)."""
+
+    __slots__ = ("id", "_annotation", "_late")
+
+    def __init__(self, span_id: str, annotation) -> None:
+        self.id = span_id
+        self._annotation = annotation
+        self._late: dict = {}
+
+    def set(self, **fields) -> None:
+        self._late.update(fields)
+        self._annotation.set_metadata(**fields)
+
+
+@contextmanager
+def hot_span(name: str, remote_parent: str | None = None,
+             **fields) -> Iterator[HotSpan]:
+    """A journal span and a profiler annotation of the same name and
+    fields over one block. With no capture live the annotation is a
+    no-op; with no journal directory the journal half is
+    ``NullJournal``: then nothing is written anywhere. ``remote_parent``
+    is a serving request's span context string: where it names a span,
+    the journal span is a child of the REQUEST, whatever encloses it
+    here (a request's chunk runs inside some engine step, but belongs
+    to the request's tree; in the profiler's plane nesting is by time
+    anyway)."""
+    journal = get_journal()
+    start = time.time()
+    with annotate(name, **fields) as annotation:
+        span_id = journal.begin(
+            name, parent=parse_ctx(remote_parent)[1] or None, **fields)
+        span = HotSpan(span_id, annotation)
+        token = _SPAN_STACK.set(_SPAN_STACK.get() + (span_id,)) \
+            if span_id else None
+        try:
+            yield span
+        finally:
+            if token is not None:
+                _SPAN_STACK.reset(token)
+            journal.end(span_id, name, start=start, **span._late)
+

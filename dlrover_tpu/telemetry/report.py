@@ -22,7 +22,7 @@ averaged away.
 
 Beside the lost-time table the report renders a **steady-state
 efficiency** table (DESIGN.md §18) from the trainer's journaled
-``metrics_sample``/``step_phase`` points: per-incarnation MFU,
+``metrics_sample`` and ``train_step`` points: per-incarnation MFU,
 mean step time, %-of-samples host-blocked, and the phase breakdown —
 "where does a healthy step go" next to "where did the failures' time
 go" — and a **master saturation** table (DESIGN.md §22) from the
@@ -222,7 +222,7 @@ class LostTimeReport:
     #  "redone_s": ...}
     incarnations: list[dict] = dataclasses.field(default_factory=list)
     # steady-state efficiency rows per incarnation, from the trainer's
-    # journaled metrics_sample/step_phase points
+    # journaled metrics_sample and train_step points
     # (telemetry/efficiency.py): {"incarnation", "samples", "mfu_mean",
     # "mfu_min", "mfu_max", "step_s_mean", "host_blocked_pct",
     # "phase_s": {phase: mean seconds}, "phase_pct": {phase: share}}
@@ -412,8 +412,9 @@ def _bin_incarnation(bounds: list[tuple[int, float]], t: float) -> int:
 
 def _efficiency_rows(spans: list[Span]) -> list[dict]:
     """Steady-state efficiency per incarnation from the trainer's
-    journaled ``metrics_sample``/``step_phase`` points
-    (telemetry/efficiency.py): MFU summary, mean step time, per-phase
+    journaled ``metrics_sample`` points (telemetry/efficiency.py) and
+    the phases every ``train_step`` point carries as ``<phase>_s``
+    fields (trainer/elastic_trainer.py): MFU summary, mean step time, per-phase
     seconds and share of step, and the %-of-samples host-blocked — the
     table that answers "where does a healthy step go" beside the
     lost-time table's "where did the failures' time go"."""
@@ -437,13 +438,12 @@ def _efficiency_rows(spans: list[Span]) -> list[dict]:
             frac = span.fields.get("host_blocked_frac")
             if isinstance(frac, (int, float)):
                 b["blocked"].append(float(frac))
-        elif span.name == "step_phase":
+        elif span.name == "train_step":
             b = bucket(_bin_incarnation(bounds, span.end))
-            phase = span.fields.get("phase")
-            if isinstance(phase, str) and phase:
-                b["phases"].setdefault(phase, []).append(
-                    max(0.0, span.end - span.start)
-                )
+            for key, value in span.fields.items():
+                if key.endswith("_s") and isinstance(value, (int, float)):
+                    b["phases"].setdefault(key[:-2], []).append(
+                        max(0.0, float(value)))
 
     def mean(xs: list[float]) -> float | None:
         return sum(xs) / len(xs) if xs else None

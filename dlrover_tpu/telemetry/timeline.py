@@ -17,10 +17,10 @@ and ui.perfetto.dev both accept):
 - spans a crashed process never closed (begin without end) carry
   ``args.open=true`` — the visual signature of "died in here";
 - journaled efficiency samples (``metrics_sample`` points,
-  telemetry/efficiency.py) render as ``ph="C"`` counter tracks: an
-  ``mfu`` lane and a stacked ``step_phase_seconds`` lane per process,
-  so utilization dips line up visually with the span lanes that
-  caused them.
+  telemetry/efficiency.py) render as a ``ph="C"`` ``mfu`` counter lane,
+  and the phases every ``train_step`` point carries as ``<phase>_s``
+  as a stacked ``step_phase_seconds`` lane per process, so utilization
+  dips line up visually with the span lanes that caused them.
 
 Timestamps are microseconds relative to the earliest event, which keeps
 the numbers small and makes the goodput report's lost-time categories
@@ -44,9 +44,12 @@ INSTANT_NAMES = frozenset({
 
 # journaled metric samples render as Perfetto COUNTER tracks (ph="C"),
 # not spans: metrics_sample (telemetry/efficiency.py) becomes an MFU
-# lane and a stacked step-phase lane; kv_pool (serving/observatory.py,
-# §29) becomes page-pool, share-headroom and draft-acceptance lanes
+# lane; kv_pool (serving/observatory.py, §29) becomes page-pool,
+# share-headroom and draft-acceptance lanes
 COUNTER_NAMES = frozenset({"metrics_sample", "kv_pool"})
+# the per-step point keeps its span lane AND feeds the stacked
+# step-phase counter lane from its `<phase>_s` fields
+PHASED_POINT = "train_step"
 
 
 def _lane_key(span: Span) -> tuple[str, str]:
@@ -64,6 +67,9 @@ def build_trace(paths: list[str], trace: str | None = None) -> dict:
         spans = [s for s in spans if s.trace == trace]
     counters = [s for s in spans if s.name in COUNTER_NAMES]
     spans = [s for s in spans if s.name not in COUNTER_NAMES]
+    counters += [s for s in spans if s.name == PHASED_POINT and any(
+        k.endswith("_s") for k in s.fields)]
+    counters.sort(key=lambda s: s.end)
 
     procs = sorted({s.proc or "unknown" for s in spans}
                    | {s.proc or "unknown" for s in counters})
@@ -194,15 +200,16 @@ def build_trace(paths: list[str], trace: str | None = None) -> dict:
                 "ph": "C", "name": "mfu", "cat": "efficiency",
                 "ts": ts, "pid": pid, "args": {"mfu": float(mfu)},
             })
-        phases = sample.fields.get("phases")
-        if isinstance(phases, dict) and phases:
+        phases = {
+            k[:-2]: float(v) for k, v in sorted(sample.fields.items())
+            if sample.name == PHASED_POINT and k.endswith("_s")
+            and isinstance(v, (int, float))
+        }
+        if phases:
             out.append({
                 "ph": "C", "name": "step_phase_seconds",
                 "cat": "efficiency", "ts": ts, "pid": pid,
-                "args": {
-                    str(p): float(v) for p, v in sorted(phases.items())
-                    if isinstance(v, (int, float))
-                },
+                "args": phases,
             })
 
     traces = sorted(

@@ -23,7 +23,7 @@ from dlrover_tpu.common.constants import EnvKey
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.parallel.mesh import data_parallel_size
 from dlrover_tpu.telemetry.efficiency import EfficiencyMonitor
-from dlrover_tpu.telemetry.journal import get_journal, spawn_ctx
+from dlrover_tpu.telemetry.journal import annotate, get_journal, spawn_ctx
 from dlrover_tpu.telemetry.metrics import registry
 from dlrover_tpu.trainer.train_step import CompiledTrain, TrainState
 
@@ -31,8 +31,9 @@ logger = get_logger(__name__)
 
 _step_seconds = registry().histogram(
     "dlrover_tpu_train_step_seconds",
-    "train_step wall time (dispatch-to-dispatch; first call of an "
-    "incarnation carries the XLA compile)",
+    "train_step wall time (staging, dispatch and the wait for the "
+    "previous step; first call of an incarnation carries the XLA "
+    "compile)",
 )
 _steps_total = registry().counter(
     "dlrover_tpu_train_steps_total",
@@ -90,6 +91,7 @@ class ElasticTrainer:
         report_step_interval: int = 1,
         master_client=None,
         model_name: str = "",
+        model_flops_per_step: float = 0.0,
     ):
         self.compiled = compiled
         dp = data_parallel_size(compiled.mesh)
@@ -132,22 +134,26 @@ class ElasticTrainer:
             self._client = MasterClient.singleton()
         # efficiency observatory (telemetry/efficiency.py): live MFU +
         # step-phase attribution + on-demand profiler capture. The block
-        # phase syncs on the step's replicated metrics each step, which
-        # trades the one-step host/device overlap for clean host-vs-
-        # device attribution; DLROVER_TPU_STEP_PHASES=0 keeps the
-        # fire-and-forget dispatch (phases then report dispatch-time
-        # only).
+        # phase is a LAGGED wait: once step N is dispatched the host
+        # waits for step N-1's replicated metrics, so one step is always
+        # in flight and the attribution costs no host/device overlap.
+        # block is then ~0 when the host is the bottleneck and ~a step
+        # when the device is. DLROVER_TPU_STEP_PHASES=0 never waits
+        # (phases then report dispatch time only).
         self._phase_block = envspec.get_bool(EnvKey.STEP_PHASES)
+        self._prev_metrics: Any = None  # the one extra reference held
         from dlrover_tpu.utils.profiler import device_peak_flops
 
         self.efficiency = EfficiencyMonitor(
             model=model_name,
             strategy=getattr(compiled.strategy, "name", "") or "",
-            flops_per_step=getattr(compiled, "flops_per_step", 0.0),
+            flops_per_step=model_flops_per_step,
             peak_flops=device_peak_flops(),
             num_devices=jax.device_count(),
             on_bundle=self._report_profile_bundle,
         )
+        self.efficiency.set_executable_flops(
+            getattr(compiled, "flops_per_step", 0.0))
         self._last_step_end = 0.0
         # autopilot retune hook (autopilot/apply.py, DESIGN.md §24):
         # called once per step with (step, state); returning
@@ -163,7 +169,7 @@ class ElasticTrainer:
         """Install a retuned step program mid-run (same batch geometry
         — the applier's ``can_apply`` guards that). The next dispatch
         is treated as a first dispatch so its compile/load cost lands
-        in the recompile cost class, and the MFU gauge re-bases on the
+        in the recompile cost class, and the HFU gauge re-bases on the
         new program's FLOPs; the rolling step window resets so the
         post-retune median (the value the autopilot history records
         against the new plan) never spans pre-retune steps."""
@@ -171,7 +177,7 @@ class ElasticTrainer:
         self._first_dispatch = True
         flops = getattr(compiled, "flops_per_step", 0.0) or 0.0
         if flops > 0:
-            self.efficiency.set_flops(flops)
+            self.efficiency.set_executable_flops(flops)
         self.efficiency.reset_window()
         logger.info(
             "swapped compiled step program (strategy %s)",
@@ -190,22 +196,31 @@ class ElasticTrainer:
 
     def train_step(self, state: TrainState, batch: dict
                    ) -> tuple[TrainState, dict]:
+        # host-side counter: reading state.step would block async dispatch
+        step = self._host_step + 1
+        with annotate("train_step", step_num=step):
+            return self._train_step(state, batch, step)
+
+    def _train_step(self, state: TrainState, batch: dict, step: int
+                    ) -> tuple[TrainState, dict]:
         step_start = time.monotonic()
-        if self.num_processes > 1:
-            sharding = self.compiled.batch_sharding
-            batch = jax.tree.map(
-                lambda x: jax.make_array_from_process_local_data(
-                    sharding, np.ascontiguousarray(x),
-                    (x.shape[0], x.shape[1] * self.num_processes)
-                    + x.shape[2:],
-                ),
-                batch,
-            )
-        else:
-            batch = jax.device_put(batch, self.compiled.batch_sharding)
+        with annotate("h2d"):
+            if self.num_processes > 1:
+                sharding = self.compiled.batch_sharding
+                batch = jax.tree.map(
+                    lambda x: jax.make_array_from_process_local_data(
+                        sharding, np.ascontiguousarray(x),
+                        (x.shape[0], x.shape[1] * self.num_processes)
+                        + x.shape[2:],
+                    ),
+                    batch,
+                )
+            else:
+                batch = jax.device_put(batch, self.compiled.batch_sharding)
         t_dispatch = time.monotonic()
         self.efficiency.observe_phase("h2d", t_dispatch - step_start)
-        state, metrics = self.compiled.step(state, batch)
+        with annotate("dispatch"):
+            state, metrics = self.compiled.step(state, batch)
         t_block = time.monotonic()
         # up to dispatch-return: on a first call this carries the trace
         # + XLA compile (or the AOT executable's ~0 re-dispatch), never
@@ -213,19 +228,29 @@ class ElasticTrainer:
         dispatch_wall = t_block - step_start
         self.efficiency.observe_phase("dispatch", t_block - t_dispatch)
         if self._phase_block:
-            # block_until_ready on the replicated metrics scalars is the
-            # host-vs-device separator: everything still in flight after
-            # dispatch returns is device compute, attributed as "block"
-            jax.block_until_ready(metrics)
+            # the host-vs-device separator, one step late: this step is
+            # already queued behind the previous one, so waiting for the
+            # previous step's replicated metrics leaves the device busy
+            # while it tells how long the host had to wait for it
+            waited_for, self._prev_metrics = self._prev_metrics, metrics
+            if waited_for is not None:
+                with annotate("block"):
+                    jax.block_until_ready(waited_for)
+                del waited_for
             self.efficiency.observe_phase(
                 "block", time.monotonic() - t_block
             )
-        # host-side counter: reading state.step would block async dispatch
-        self._host_step += 1
-        step = self._host_step
+        self._host_step = step
         step_wall = time.monotonic() - step_start
         _step_seconds.observe(step_wall)
         _steps_total.inc()
+        # step cadence (previous end -> this end) feeds the rolling MFU:
+        # it includes data_wait/callbacks/ckpt, i.e. real throughput
+        now = time.monotonic()
+        cadence = (now - self._last_step_end if self._last_step_end
+                   else step_wall)
+        self._last_step_end = now
+        phases = self.efficiency.end_step(step, cadence)
         if self._first_dispatch:
             # the incarnation's first call traces + compiles (or loads
             # the persistent compile cache) before dispatching — the
@@ -250,14 +275,12 @@ class ElasticTrainer:
             )
             self._maybe_install_flops(state, batch)
         else:
-            get_journal().emit("train_step", dur=step_wall, step=step)
-        # step cadence (previous end -> this end) feeds the rolling MFU:
-        # it includes data_wait/callbacks/ckpt, i.e. real throughput
-        now = time.monotonic()
-        cadence = (now - self._last_step_end if self._last_step_end
-                   else step_wall)
-        self._last_step_end = now
-        self.efficiency.end_step(step, cadence)
+            # one line a step: how long the step took the loop (its
+            # cadence) and where the host spent it
+            get_journal().emit(
+                "train_step", dur=cadence, step=step,
+                **{f"{p}_s": round(v, 6) for p, v in phases.items()},
+            )
         self._progress.report(step)
         if self._client is not None and step % self._report_interval == 0:
             try:
@@ -294,13 +317,13 @@ class ElasticTrainer:
         return state, metrics
 
     def _maybe_install_flops(self, state: TrainState, batch: dict) -> None:
-        """Plain-jit fallback for the live MFU gauge: when the AOT path
+        """Plain-jit fallback for the live HFU gauge: when the AOT path
         didn't supply FLOPs and the device has a known peak (real TPU —
         never on the CPU test backend), count the compiled program once
         via the already-populated compile cache. Uses the NEW state's
         avals (the donated input's buffers are gone, its avals are not
         what ``.lower`` needs anyway)."""
-        if self.efficiency.flops_per_step > 0 \
+        if self.efficiency.executable_flops > 0 \
                 or not self.efficiency.peak_flops \
                 or not hasattr(self.compiled.step, "lower"):
             return
@@ -309,7 +332,7 @@ class ElasticTrainer:
 
             flops = compiled_flops(self.compiled.step, state, batch)
             if flops > 0:
-                self.efficiency.set_flops(flops)
+                self.efficiency.set_executable_flops(flops)
         except Exception:  # noqa: BLE001 - MFU is telemetry, not training
             logger.exception("post-compile FLOPs count failed")
 
@@ -359,7 +382,8 @@ class ElasticTrainer:
             while True:
                 t0 = time.monotonic()
                 try:
-                    batch = next(it)
+                    with annotate("data_wait"):
+                        batch = next(it)
                 except StopIteration:
                     break
                 self.efficiency.observe_phase(
@@ -371,11 +395,13 @@ class ElasticTrainer:
                     # metrics stay on device: fetching here would
                     # serialize host and device every step; callbacks
                     # device_get at their own cadence
-                    on_step(step, metrics)
+                    with annotate("on_step", step=step):
+                        on_step(step, metrics)
                 if (checkpointer is not None and checkpoint_interval
                         and step % checkpoint_interval == 0):
                     t0 = time.monotonic()
-                    checkpointer(step, state)
+                    with annotate("ckpt", step=step):
+                        checkpointer(step, state)
                     self.efficiency.observe_phase(
                         "ckpt", time.monotonic() - t0
                     )
@@ -387,6 +413,7 @@ class ElasticTrainer:
                 if max_steps is not None and step >= max_steps:
                     break
         finally:
+            self._prev_metrics = None
             # a capture armed mid-loop must not leak an open trace
             self.efficiency.close()
         logger.info(

@@ -298,10 +298,11 @@ def compile_train(
         loss, grads = compute(state.params, batch)
         if grad_constraint is not None:
             grads = grad_constraint(grads)
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             step=state.step + 1, params=params, opt_state=opt_state
         )

@@ -261,7 +261,11 @@ class Trainer:
         plain ``loss_fn`` when it doesn't depend on the layout;
       - ``init_params_fn(rng)`` + ``logical_params`` (axis names) so the
         strategy layer can place every tensor;
-      - ``optimizer`` (optax), optionally ``lr_schedule(step)`` for logging.
+      - ``optimizer`` (optax), optionally ``lr_schedule(step)`` for logging;
+      - ``model_flops_per_step``: what the mathematics needs per global
+        step (``TransformerConfig.train_flops_per_token`` x tokens), for
+        ``dlrover_tpu_mfu``; left out, only ``dlrover_tpu_hfu`` (the
+        executable's count) is published.
 
     Data surface: ``train_dataset`` is a Sequence (len/getitem -> epoch +
     shuffle semantics) or any re-iterable; ``collate_fn(list) -> dict of
@@ -287,6 +291,7 @@ class Trainer:
         lr_schedule: Callable[[int], float] | None = None,
         engine: CheckpointEngine | None = None,
         example_batch: Any | None = None,
+        model_flops_per_step: float = 0.0,
     ):
         self.args = args
         self.train_dataset = train_dataset
@@ -356,6 +361,7 @@ class Trainer:
             self.compiled,
             global_batch_size=args.global_batch_size,
             micro_batch_size=args.micro_batch_size,
+            model_flops_per_step=model_flops_per_step,
         )
 
         os.makedirs(args.output_dir, exist_ok=True)

@@ -418,6 +418,10 @@ def main(argv=None) -> int:
         global_batch_size=args.global_batch,
         micro_batch_size=micro,
         model_name=args.model,
+        # what the MFU gauge divides: the model's own FLOPs, whatever
+        # the strategy recomputes
+        model_flops_per_step=(
+            args.global_batch * seq * cfg.train_flops_per_token(seq)),
     )
 
     # ---- autopilot closed loop (DESIGN.md §24): arm the master-side

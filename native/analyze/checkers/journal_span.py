@@ -10,8 +10,10 @@ half of the original ``native/check_metric_names.py`` lint and adds
 the open/close pairing the regex could never see:
 
 - ``.emit("name")`` / ``.begin("name")`` / ``.span("name")`` first
-  arguments must be string literals matching ``[a-z_]+`` and appear
-  verbatim in DESIGN.md;
+  arguments, and those of the hot-path helpers ``hot_span("name")`` /
+  ``annotate("name")`` (``telemetry/journal.py``: a journal span paired
+  with a profiler annotation), must be string literals matching
+  ``[a-z_][a-z0-9_]*`` and appear verbatim in DESIGN.md;
 - a ``sid = X.begin("name")`` must be paired, within the same function
   or (via a ``self.attr``) the same class, with an ``X.end(sid, ...)``
   — the ``span()`` context manager pairs itself and is always fine;
@@ -38,9 +40,13 @@ from native.analyze.core import (
     register,
 )
 
-SPAN_NAME_RE = re.compile(r"^[a-z_]+$")
+# (a digit inside a name is fine: the step phase `h2d`)
+SPAN_NAME_RE = re.compile(r"^[a-z_][a-z0-9_]*$")
 EXCLUDE_SUFFIXES = ("telemetry/journal.py",)
 SPAN_METHODS = ("emit", "begin", "span")
+# the hot-path helpers that pair a journal span with a profiler
+# annotation (telemetry/journal.py): plain functions, same contract
+SPAN_FUNCTIONS = ("hot_span", "annotate")
 
 
 def _first_arg(call: ast.Call) -> ast.AST | None:
@@ -55,7 +61,7 @@ def _first_arg(call: ast.Call) -> ast.AST | None:
 @register
 class JournalSpanChecker(Checker):
     rule = "journal-span"
-    description = ("journal span names are literal [a-z_]+ documented "
+    description = ("journal span names are literal [a-z_][a-z0-9_]* documented "
                    "in DESIGN.md; every .begin() is paired with .end() "
                    "in the same function or class")
     hint = ('use `with journal.span("name"):` (self-pairing), or keep '
@@ -78,9 +84,15 @@ class JournalSpanChecker(Checker):
                      project: Project) -> list[Finding]:
         findings: list[Finding] = []
         for node in ast.walk(module.tree):
-            if not (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in SPAN_METHODS):
+            if not isinstance(node, ast.Call):
+                continue
+            if isinstance(node.func, ast.Attribute) \
+                    and node.func.attr in SPAN_METHODS:
+                how = f".{node.func.attr}()"
+            elif isinstance(node.func, ast.Name) \
+                    and node.func.id in SPAN_FUNCTIONS:
+                how = f"{node.func.id}()"
+            else:
                 continue
             arg = _first_arg(node)
             if arg is None:
@@ -91,7 +103,7 @@ class JournalSpanChecker(Checker):
                 # DESIGN.md contract
                 findings.append(self.finding(
                     module, node,
-                    f"journal .{node.func.attr}() with a non-literal "
+                    f"journal {how} with a non-literal "
                     "span name — names must be grep-able literals",
                 ))
                 continue

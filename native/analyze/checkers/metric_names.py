@@ -29,9 +29,12 @@ REG_RE = re.compile(
     r"\.\s*(counter|gauge|histogram)\(\s*(?:\n\s*)?"
     r"(?:(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)|(?P<nonlit>[A-Za-z_f][^,)]*))"
 )
-SPAN_NAME_RE = re.compile(r"^[a-z_]+$")
+# (a digit inside a name is fine: the step phase `h2d`)
+SPAN_NAME_RE = re.compile(r"^[a-z_][a-z0-9_]*$")
+# journal methods, and the hot-path helpers that pair a journal span
+# with a profiler annotation (telemetry/journal.py hot_span / annotate)
 SPAN_RE = re.compile(
-    r"\.\s*(emit|begin|span)\(\s*(?:\n\s*)?"
+    r"(?:\.\s*(?:emit|begin|span)|\b(?:hot_span|annotate))\(\s*(?:\n\s*)?"
     r"(?:(?P<q>['\"])(?P<name>[^'\"]+)(?P=q)|(?P<nonlit>[A-Za-z_f][^,)]*))"
 )
 # the journal implementation itself forwards caller-supplied names

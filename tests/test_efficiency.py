@@ -162,7 +162,8 @@ class TestEfficiencyMonitor:
 def test_phase_histograms_account_for_step_time(journal_dir):
     """Run a real (tiny) compiled train loop: the five phase histograms
     must account for ~the whole step wall, and the journal must carry
-    the metrics_sample/step_phase points the report and timeline
+    the metrics_sample points and the per-step train_step points (with
+    every phase as a ``<phase>_s`` field) the report and timeline
     consume."""
     import optax
 
@@ -229,9 +230,16 @@ def test_phase_histograms_account_for_step_time(journal_dir):
 
     events = load_events(os.path.join(journal_dir, "events.jsonl"))
     names = {e["name"] for e in events}
-    assert "metrics_sample" in names and "step_phase" in names
+    assert "metrics_sample" in names and "step_phase" not in names
+    steps = [e for e in events if e["name"] == "train_step"]
+    assert [e["step"] for e in steps] == [2, 3, 4, 5, 6]
+    assert all({f"{p}_s" for p in eff.PHASES} <= set(e) for e in steps)
+    # dur is the loop's cadence: it holds the phases, data_wait included
+    assert all(e["dur"] >= e["h2d_s"] + e["dispatch_s"] + e["block_s"]
+               + e["data_wait_s"] - 1e-4 for e in steps)
     samples = [e for e in events if e["name"] == "metrics_sample"]
-    assert all(set(s["phases"]) == set(eff.PHASES) for s in samples)
+    # the phases are journaled once, on the train_step points
+    assert samples and all("phases" not in s for s in samples)
     # CPU backend has no known peak: mfu must be null, never wrong
     assert all(s["mfu"] is None for s in samples)
 
@@ -569,10 +577,7 @@ def _write_journal_line(f, **ev):
 def _sample_event(t, step, mfu, proc="node0", **extra):
     return dict(t=t, trace="tr", span=f"ms{step}", name="metrics_sample",
                 ev="p", proc=proc, pid=1, step=step, mfu=mfu,
-                step_s=0.1, host_blocked_frac=0.25,
-                phases={"data_wait": 0.01, "h2d": 0.002,
-                        "dispatch": 0.003, "block": 0.08, "ckpt": 0.0},
-                **extra)
+                step_s=0.1, host_blocked_frac=0.25, **extra)
 
 
 class TestReportEfficiency:
@@ -582,11 +587,10 @@ class TestReportEfficiency:
             for i, step in enumerate((5, 10, 15)):
                 _write_journal_line(f, **_sample_event(
                     t0 + i, step, 0.5 + 0.1 * i))
-                for phase, dur in (("data_wait", 0.01), ("block", 0.08)):
-                    _write_journal_line(
-                        f, t=t0 + i, trace="tr", span=f"sp{step}{phase}",
-                        name="step_phase", ev="p", proc="node0", pid=1,
-                        dur=dur, phase=phase, step=step)
+                _write_journal_line(
+                    f, t=t0 + i, trace="tr", span=f"ts{step}",
+                    name="train_step", ev="p", proc="node0", pid=1,
+                    dur=0.1, step=step, data_wait_s=0.01, block_s=0.08)
             # incarnation 1 after a restart
             _write_journal_line(
                 f, t=t0 + 10, trace="tr", span="nr1", name="node_restart",
@@ -622,16 +626,23 @@ class TestReportEfficiency:
         assert "steady-state efficiency" in out
 
     def test_timeline_counter_tracks_across_rotation(self, tmp_path):
-        """metrics_sample points split across a journal rotation render
-        as ph='C' counter events (mfu + stacked phase lanes)."""
+        """metrics_sample and train_step points split across a journal
+        rotation render as ph='C' counter events: the mfu lane from the
+        samples, the stacked phase lane from each step's own phases."""
         live = str(tmp_path / "events.jsonl")
+        step = dict(trace="tr", name="train_step", ev="p", proc="node0",
+                    pid=1, dur=0.1, data_wait_s=0.01, h2d_s=0.002,
+                    dispatch_s=0.003, block_s=0.08, ckpt_s=0.0)
         with open(live + ".1", "w") as f:
             _write_journal_line(f, **_sample_event(1000.0, 5, 0.5))
+            _write_journal_line(f, t=1000.5, span="ts1", step=5, **step)
+            # a point from before the phases rode on it: a lane, no counter
             _write_journal_line(
-                f, t=1000.5, trace="tr", span="ts1", name="train_step",
-                ev="p", proc="node0", pid=1, dur=0.1, step=5)
+                f, t=1000.6, trace="tr", span="ts0", name="train_step",
+                ev="p", proc="node0", pid=1, dur=0.1, step=6)
         with open(live, "w") as f:
             _write_journal_line(f, **_sample_event(1001.0, 10, 0.6))
+            _write_journal_line(f, t=1001.5, span="ts2", step=10, **step)
         trace = build_trace([live])
         counters = [e for e in trace["traceEvents"] if e["ph"] == "C"]
         mfu = [e for e in counters if e["name"] == "mfu"]
@@ -639,11 +650,16 @@ class TestReportEfficiency:
         phases = [e for e in counters
                   if e["name"] == "step_phase_seconds"]
         assert len(phases) == 2
-        assert phases[0]["args"]["block"] == pytest.approx(0.08)
-        # metrics_sample is a counter source, not a span lane
+        assert phases[0]["args"] == {"data_wait": 0.01, "h2d": 0.002,
+                                     "dispatch": 0.003, "block": 0.08,
+                                     "ckpt": 0.0}
+        # metrics_sample is a counter source, not a span lane; train_step
+        # keeps its lane beside the counter it feeds
         assert not any(e.get("name") == "metrics_sample"
                        for e in trace["traceEvents"] if e["ph"] != "C")
-        assert trace["otherData"]["n_counter_samples"] == 2
+        assert sum(e.get("name") == "train_step" and e["ph"] == "X"
+                   for e in trace["traceEvents"]) == 3
+        assert trace["otherData"]["n_counter_samples"] == 4
 
 
 # -------------------------------------------------- live standalone e2e

@@ -40,7 +40,8 @@ def _dataset(n=64, seed=0):
     return [{"x": xs[i], "y": ys[i]} for i in range(n)]
 
 
-def _trainer(tmp_path, train_n=64, callbacks=None, **arg_overrides):
+def _trainer(tmp_path, train_n=64, callbacks=None, model_flops_per_step=0.0,
+             **arg_overrides):
     args = TrainingArguments(
         output_dir=str(tmp_path / "out"),
         global_batch_size=16,
@@ -58,7 +59,20 @@ def _trainer(tmp_path, train_n=64, callbacks=None, **arg_overrides):
         eval_dataset=_dataset(32, seed=1),
         callbacks=callbacks,
         lr_schedule=lambda step: 1e-2,
+        model_flops_per_step=model_flops_per_step,
     )
+
+
+@pytest.mark.parametrize("flops", [0.0, 3.5e9])
+def test_trainer_tells_the_mfu_gauge_its_models_flops(tmp_ipc_dir, tmp_path,
+                                                      flops):
+    """Only a trainer told its model's FLOPs publishes ``dlrover_tpu_mfu``;
+    the executable's count goes to ``dlrover_tpu_hfu`` either way."""
+    t = _trainer(tmp_path, model_flops_per_step=flops)
+    try:
+        assert t.elastic.efficiency.flops_per_step == flops
+    finally:
+        t.close()
 
 
 @pytest.mark.timeout(120)
