@@ -7,6 +7,16 @@ equivalent is a cache-carrying decode step under jit — static shapes
 step program, O(S) per generated token instead of the O(S^2) recompute of
 calling the full forward per step.
 
+The cache is one stack per K and V, ``[L, B, max_len, H_kv, D]``, and
+it is never copied whole (DESIGN.md §23.1): the layer loop CARRIES the
+stack, layer ``l`` writes its ``S_new`` new rows into it in place (the
+update is the new rows alone) and attends over ``K[l]`` read out of the
+carry. Nothing of a layer's shape is scanned in or out: a scanned
+input is sliced out whole and a scanned output copied back whole, per
+layer. A caller that wants the update in place donates the
+stack to the jitted program that calls ``forward_cached`` and keeps no
+other reference to it (``serving/engine.py`` does).
+
 Correctness is pinned to the training forward by an equivalence test
 (tests/test_decode.py): prefill+cached-decode logits must match
 ``forward`` on the same tokens bit-for-tolerance.
@@ -82,6 +92,25 @@ def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0):
     return o.reshape(B, S_new, H, D)
 
 
+def _write_rows(stack, new, layer, pos):
+    """Write ``new`` [B, S_new, H_kv, D] into ``stack`` [L, B, max_len,
+    H_kv, D] at ``[layer, b, pos[b] : pos[b] + S_new]``, in place where
+    the stack is a loop's carry: the update is the new rows alone,
+    never a layer. Rows in lockstep (scalar ``pos``) take one
+    ``dynamic_update_slice``; rows at positions of their own take one
+    each, unrolled: as ONE scatter the TPU compiler runs a loop over
+    the rows that costs 2.8 us a row (a block of 8 decode steps at 16
+    slots on a v5e: 97.2 ms against 81.6; PERF.md §6, PR 26). A start past
+    ``max_len - S_new`` is clamped so that the rows fit."""
+    if jnp.ndim(pos) == 0:
+        return lax.dynamic_update_slice(
+            stack, new[None], (layer, 0, pos, 0, 0))
+    for b in range(new.shape[0]):
+        stack = lax.dynamic_update_slice(
+            stack, new[None, b:b + 1], (layer, b, pos[b], 0, 0))
+    return stack
+
+
 def forward_cached(
     params: Params, tokens: jax.Array, cache: dict,
     cfg: TransformerConfig,
@@ -142,9 +171,11 @@ def forward_cached(
     # cache update and absolute-position math are what differ). The
     # equivalence tests in tests/test_decode.py pin the two together —
     # extend them when touching either copy.
+    window = c.attention_window if c.attention == "splash" else 0
+
     def layer(carry, inputs):
-        x = carry
-        w, k_cache_l, v_cache_l = inputs
+        x, k_stack, v_stack = carry
+        w, l = inputs
         with jax.named_scope("attn"):
             h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
             q = jnp.einsum("bse,ehd->bshd", h, cast(w["wq"]))
@@ -158,34 +189,16 @@ def forward_cached(
                 q = _rope(q, positions, c.rope_theta)
                 k = _rope(k, positions, c.rope_theta)
             with jax.named_scope("kv_write"):
-                if scalar_pos:
-                    # one contiguous slice update for the whole batch
-                    # (keeps the generate()/PPO hot path off the scatter
-                    # lowering the vmapped form implies)
-                    k_cache_l = lax.dynamic_update_slice_in_dim(
-                        k_cache_l, k.astype(dt), pos, axis=1
-                    )
-                    v_cache_l = lax.dynamic_update_slice_in_dim(
-                        v_cache_l, v.astype(dt), pos, axis=1
-                    )
-                else:
-                    # per-row write offsets: vmap a single-row update
-                    row_update = jax.vmap(
-                        lambda row, new, p:
-                        lax.dynamic_update_slice_in_dim(
-                            row, new, p, axis=0
-                        )
-                    )
-                    k_cache_l = row_update(k_cache_l, k.astype(dt), pos)
-                    v_cache_l = row_update(v_cache_l, v.astype(dt), pos)
+                k_stack = _write_rows(k_stack, k.astype(dt), l, pos)
+                v_stack = _write_rows(v_stack, v.astype(dt), l, pos)
             # the window only binds when training actually used it (the
             # splash kind) — other attention kinds ignore
             # attention_window in training, so decode must too or the
             # masks diverge
             o = _layer_attend(
-                q, k_cache_l, v_cache_l, pos, n_rep, dt,
-                window=(c.attention_window if c.attention == "splash"
-                        else 0),
+                q, lax.dynamic_index_in_dim(k_stack, l, keepdims=False),
+                lax.dynamic_index_in_dim(v_stack, l, keepdims=False),
+                pos, n_rep, dt, window=window,
             )
             o = jnp.einsum("bshd,hde->bse", o, cast(w["wo"]))
             x = x + o
@@ -213,10 +226,14 @@ def forward_cached(
                                  cast(w["w_down"]))
                       + cast(w["b_out"]))
             x = x + ff
-        return x, (k_cache_l, v_cache_l)
+        return (x, k_stack, v_stack), None
 
-    x, (k_new, v_new) = lax.scan(
-        layer, x, (params["layers"], cache["k"], cache["v"])
+    # the stack rides the CARRY: a scanned input or output of the
+    # per-layer shape would be sliced out and copied back whole, per
+    # layer, for the sake of S_new new rows
+    (x, k_new, v_new), _ = lax.scan(
+        layer, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(c.n_layers, dtype=jnp.int32)),
     )
     with jax.named_scope("lm_head"):
         x = _norm(x, params["ln_f"], params.get("ln_f_b"), c.variant)

@@ -492,6 +492,11 @@ class InferenceEngine:
         self._cache = init_cache(cfg, slots, self.max_len)
         self._cache["pos"] = jnp.zeros((slots,), jnp.int32)
         self._last = jnp.zeros((slots, cfg.vocab_size), jnp.float32)
+        # The stack is never copied whole (DESIGN.md §23.1): every
+        # program that returns it DONATES it and updates it in place.
+        # So nobody keeps a reference to `_cache["k"]` / `["v"]` (or
+        # `["pos"]`, `_last`) across a call that returns them; the
+        # engine rebinds all four from the call's outputs.
         # per-slot sampling randomness: a seed per REQUEST + a count of
         # tokens sampled so far — the per-draw key is derived from both,
         # so a request's stream never depends on batch composition
@@ -526,7 +531,7 @@ class InferenceEngine:
             last_all = last_all.at[slot].set(last_row)
             return cache_k, cache_v, pos, last_all
 
-        self._install = jax.jit(_install)
+        self._install = jax.jit(_install, donate_argnums=(0, 1, 2, 3))
 
         if self._paging:
             L = cfg.n_layers
@@ -563,7 +568,8 @@ class InferenceEngine:
                 last_all = last_all.at[slot].set(last_row)
                 return cache_k, cache_v, pos_all, last_all
 
-            self._resume_install = jax.jit(_resume_install)
+            self._resume_install = jax.jit(
+                _resume_install, donate_argnums=(0, 1, 2, 3))
 
         def _row_keys(seeds, counts):
             # per-row key = f(request seed, index of this draw): pure
@@ -611,6 +617,7 @@ class InferenceEngine:
 
         self._step_block = jax.jit(
             _step_block, static_argnames=("n_steps",),
+            donate_argnums=(1, 2, 3, 4),
             compiler_options=_CANONICAL_NUMERICS,
         )
 
@@ -669,7 +676,8 @@ class InferenceEngine:
                     acc)
 
         self._verify_block = jax.jit(
-            _verify_block, compiler_options=_CANONICAL_NUMERICS,
+            _verify_block, donate_argnums=(1, 2, 3, 4),
+            compiler_options=_CANONICAL_NUMERICS,
         )
         # per-depth AOT verify programs (warm_aot_verify); missing
         # depths fall back to the jit shape ladder above
@@ -686,6 +694,22 @@ class InferenceEngine:
         _LIVE_ENGINES.add(self)
 
     # ------------------------------------------------------- AOT cold start
+
+    def _aot_strategy(self, kind: str, **facts) -> dict:
+        """The strategy facts of a serving program's compile-cache
+        digest. The digest keys on facts, not on the program's text, so
+        what makes two builds' executables NOT interchangeable has to
+        be a fact: one compiled WITHOUT canonical numerics (§31
+        spec-on/off identity), and one that does not donate the stack
+        (§23.1: this build's, loaded by a build that keeps the stack
+        it passed in, would delete buffers its caller still reads;
+        that build's, loaded here, would hold two stacks). Older
+        entries must miss here, and these must miss there."""
+        return {"kind": kind, "slots": self.slots,
+                "max_len": self.max_len,
+                "prefill_len": self.prefill_len, **facts,
+                "numerics": "canonical",
+                "kv_stack": "carried-donated"}
 
     def _step_sample_args(self) -> tuple:
         """The exact runtime argument tuple of a decode step (zero
@@ -724,16 +748,7 @@ class InferenceEngine:
                 total_devices=jax.local_device_count(),
                 mesh_axes={},
                 model=self.cfg,
-                strategy={"kind": "serving_step", "slots": self.slots,
-                          "max_len": self.max_len,
-                          "prefill_len": self.prefill_len,
-                          "n_steps": 1,
-                          # part of the digest on purpose: an executable
-                          # compiled WITHOUT canonical numerics is not
-                          # interchangeable with one compiled with them
-                          # (§31 spec-on/off identity), so pre-§31 cache
-                          # entries must miss here
-                          "numerics": "canonical"},
+                strategy=self._aot_strategy("serving_step", n_steps=1),
                 args_signature=abstract_signature(sample),
             )
             aot = load_or_compile(
@@ -786,11 +801,7 @@ class InferenceEngine:
                     total_devices=jax.local_device_count(),
                     mesh_axes={},
                     model=self.cfg,
-                    strategy={"kind": "serving_verify",
-                              "slots": self.slots,
-                              "max_len": self.max_len,
-                              "prefill_len": self.prefill_len,
-                              "numerics": "canonical"},
+                    strategy=self._aot_strategy("serving_verify"),
                     args_signature=abstract_signature(sample),
                 )
                 key = verify_key(key, depth=depth)

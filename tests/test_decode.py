@@ -12,7 +12,12 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models import transformer as tfm
-from dlrover_tpu.models.decode import forward_cached, generate, init_cache
+from dlrover_tpu.models.decode import (
+    _write_rows,
+    forward_cached,
+    generate,
+    init_cache,
+)
 
 
 def _f32(cfg):
@@ -73,6 +78,181 @@ class TestCachedForwardEquivalence:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(ref), atol=3e-4, rtol=3e-4
         )
+
+
+def _variant(name):
+    """f32 two-layer configs of both layer bodies: gpt2 (learned
+    positions) and llama (rope, GQA with n_rep 2)."""
+    extra = {"gpt2": {"variant": "gpt2"}, "llama": {"n_kv_heads": 2}}[name]
+    return _f32(dataclasses.replace(
+        tfm.CONFIGS["tiny"], n_layers=2, max_seq_len=64, **extra))
+
+
+def _stacked_rows(cfg, params, seqs, lens, max_len):
+    """A [B]-row cache whose row b holds ``seqs[b][:lens[b]]``, each
+    row prefilled alone (scalar pos) and the rows stacked: rows at
+    different positions in one cache."""
+    rows = []
+    for seq, n in zip(seqs, lens):
+        _, row = forward_cached(
+            params, seq[None, :n], init_cache(cfg, 1, max_len), cfg)
+        rows.append(row)
+    return {
+        "k": jnp.concatenate([r["k"] for r in rows], axis=1),
+        "v": jnp.concatenate([r["v"] for r in rows], axis=1),
+        "pos": jnp.asarray(lens, jnp.int32),
+    }
+
+
+class TestStackIsCarriedAndWrittenInPlace:
+    """ISSUE 26: the layer loop carries the stacked cache and writes
+    only the new rows into it; nothing of a layer's shape is scanned
+    in or out (each such operand was a whole-layer copy per layer)."""
+
+    @pytest.mark.parametrize("s_new", [1, 4])
+    @pytest.mark.parametrize("pos_kind", ["scalar", "vector"])
+    @pytest.mark.parametrize("name", ["gpt2", "llama"])
+    def test_layer_loop_scans_no_cache_shaped_operand(
+            self, name, pos_kind, s_new):
+        cfg = _variant(name)
+        B, max_len = 3, 16
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        cache = init_cache(cfg, B, max_len)
+        if pos_kind == "vector":
+            cache["pos"] = jnp.asarray([0, 3, 7], jnp.int32)
+        tokens = jnp.zeros((B, s_new), jnp.int32)
+        jaxpr = jax.make_jaxpr(
+            lambda p, t, c: forward_cached(p, t, c, cfg)
+        )(params, tokens, cache).jaxpr
+        stack = cache["k"].shape       # [L, B, max_len, Hkv, D]
+        layer = stack[1:]
+        loops = [e for e in jaxpr.eqns if e.primitive.name == "scan"
+                 and e.params["length"] == cfg.n_layers]
+        assert len(loops) == 1
+        [loop] = loops
+        n_consts = loop.params["num_consts"]
+        n_carry = loop.params["num_carry"]
+        consts = loop.invars[:n_consts]
+        carry = loop.invars[n_consts:n_consts + n_carry]
+        xs = loop.invars[n_consts + n_carry:]
+        ys = loop.outvars[n_carry:]
+        # a scanned operand [L, *layer] is a per-layer [*layer] inside
+        for v in list(xs) + list(ys):
+            assert tuple(v.aval.shape[1:]) != layer, v.aval
+        assert not [v for v in consts if tuple(v.aval.shape) == stack]
+        assert len([v for v in carry
+                    if tuple(v.aval.shape) == stack]) == 2   # K and V
+        # and inside the loop whatever yields a stack is an in-place
+        # write of new rows alone: one [1, B, S_new, Hkv, D] update for
+        # rows in lockstep, one [1, 1, S_new, Hkv, D] a row otherwise
+        body = loop.params["jaxpr"].jaxpr
+        writes = [e for e in body.eqns
+                  if any(tuple(getattr(v.aval, "shape", ())) == stack
+                         for v in e.outvars)]
+        rows = B if pos_kind == "scalar" else 1
+        assert len(writes) == 2 * B // rows
+        for e in writes:
+            assert e.primitive.name == "dynamic_update_slice"
+            assert tuple(e.invars[1].aval.shape) == (
+                1, rows, s_new) + stack[3:]
+
+    @pytest.mark.parametrize("start", [
+        [0, 5, 12], [12, 12, 12], [13, 16, 40], [0, 1, 15]])
+    def test_write_rows_clamps_a_start_so_that_the_rows_fit(self, start):
+        """Each row's new keys land at ``min(start, max_len - S_new)``
+        of its own cache row in the one layer written, and nowhere
+        else: the edge the old per-row update had."""
+        L, B, max_len, H, D, s_new = 2, 3, 16, 2, 4, 4
+        stack = np.asarray(jax.random.normal(
+            jax.random.PRNGKey(0), (L, B, max_len, H, D)))
+        new = np.asarray(jax.random.normal(
+            jax.random.PRNGKey(1), (B, s_new, H, D)))
+        got = jax.jit(_write_rows)(
+            stack, new, 1, jnp.asarray(start, jnp.int32))
+        want = stack.copy()
+        for b in range(B):
+            at = min(start[b], max_len - s_new)
+            want[1, b, at:at + s_new] = new[b]
+        np.testing.assert_array_equal(np.asarray(got), want)
+        if len(set(start)) == 1:      # lockstep: the scalar form agrees
+            np.testing.assert_array_equal(np.asarray(jax.jit(_write_rows)(
+                stack, new, 1, jnp.asarray(start[0], jnp.int32))), want)
+
+    @pytest.mark.parametrize("pos_kind", ["scalar", "vector"])
+    @pytest.mark.parametrize("name", ["gpt2", "llama"])
+    def test_rows_that_end_at_the_end_of_the_cache_row(
+            self, name, pos_kind):
+        """pos = max_len - S_new: the last rows of the cache row are
+        written and attended, unclamped."""
+        cfg = _variant(name)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        tokens = jax.random.randint(
+            jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
+        ref = tfm.forward(params, tokens, cfg)
+        _, cache = forward_cached(
+            params, tokens[:, :12], init_cache(cfg, 2, 16), cfg)
+        if pos_kind == "vector":
+            cache["pos"] = jnp.full((2,), 12, jnp.int32)
+        out, cache = forward_cached(params, tokens[:, 12:], cache, cfg)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref[:, 12:]), atol=3e-4,
+            rtol=3e-4)
+        assert np.asarray(cache["pos"]).tolist() in (16, [16, 16])
+
+    @pytest.mark.parametrize("s_new", [1, 4])
+    @pytest.mark.parametrize("name", ["gpt2", "llama"])
+    def test_rows_at_different_positions_in_one_call(self, name, s_new):
+        cfg = _variant(name)
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        seqs = jax.random.randint(
+            jax.random.PRNGKey(2), (3, 20), 0, cfg.vocab_size)
+        lens = [5, 9, 16 - s_new]
+        ref = tfm.forward(params, seqs, cfg)
+        cache = _stacked_rows(cfg, params, seqs, lens, 16)
+        new = jnp.stack([seqs[b, n:n + s_new]
+                         for b, n in enumerate(lens)])
+        out, cache = forward_cached(params, new, cache, cfg)
+        for b, n in enumerate(lens):
+            np.testing.assert_allclose(
+                np.asarray(out[b]), np.asarray(ref[b, n:n + s_new]),
+                atol=3e-4, rtol=3e-4)
+        assert np.asarray(cache["pos"]).tolist() == [
+            n + s_new for n in lens]
+
+    @pytest.mark.parametrize("idle_pos", [0, 7, 16])
+    def test_an_inactive_row_is_harmless(self, idle_pos):
+        """The engine steps every slot, active or not: an idle row
+        (whatever its frozen position, the end of its row included)
+        writes only into its own row and leaves its batchmates' logits
+        and cache rows as a call without it gives them."""
+        cfg = _variant("gpt2")
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        seqs = jax.random.randint(
+            jax.random.PRNGKey(3), (2, 12), 0, cfg.vocab_size)
+        lens = [4, 9]
+        ref = tfm.forward(params, seqs, cfg)
+        two = _stacked_rows(cfg, params, seqs, lens, 16)
+        junk = jax.random.normal(
+            jax.random.PRNGKey(4), (cfg.n_layers, 1) + two["k"].shape[2:])
+        three = {
+            "k": jnp.concatenate([two["k"], junk], axis=1),
+            "v": jnp.concatenate([two["v"], junk], axis=1),
+            "pos": jnp.asarray(lens + [idle_pos], jnp.int32),
+        }
+        new = jnp.stack([seqs[b, n:n + 1] for b, n in enumerate(lens)])
+        out2, c2 = forward_cached(params, new, two, cfg)
+        out3, c3 = forward_cached(
+            params, jnp.concatenate([new, jnp.zeros((1, 1), new.dtype)]),
+            three, cfg)
+        for b, n in enumerate(lens):
+            np.testing.assert_allclose(
+                np.asarray(out3[b]), np.asarray(ref[b, n:n + 1]),
+                atol=3e-4, rtol=3e-4)
+        np.testing.assert_array_equal(
+            np.asarray(out3[:2]), np.asarray(out2))
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(c3[name][:, :2]), np.asarray(c2[name]))
 
 
 class TestSlidingWindowDecode:

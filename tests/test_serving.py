@@ -9,6 +9,8 @@ lengths join and leave the batch mid-flight.
 
 from __future__ import annotations
 
+import re
+
 import numpy as np
 import pytest
 
@@ -603,3 +605,111 @@ class TestPrefixCache:
         out_a = {r.id: r.tokens for r in eng.run()}[rid_a]
         out_b = {r.id: r.tokens for r in fresh.run()}[rid_b]
         assert out_a == out_b
+
+
+# ------------------------------------ the stack is donated (ISSUE 26)
+
+
+def _donated(lowered) -> list[str]:
+    """Tensor types of the arguments a lowered program aliases to an
+    output (or offers as a donor), read off its text: no chip needed."""
+    [sig] = [line for line in lowered.as_text().splitlines()
+             if "func.func public @main" in line]
+    return [
+        m.group(1)
+        for m in re.finditer(
+            r"%arg\d+: tensor<([^>]*)>(?: \{([^%]*?)\})?(?=, %arg|\) ->)",
+            sig)
+        if m.group(2) and ("tf.aliasing_output" in m.group(2)
+                           or "jax.buffer_donor" in m.group(2))
+    ]
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("program", [
+    "_step_block", "_verify_block", "_install", "_resume_install"])
+def test_programs_that_return_the_stack_donate_it(params, program):
+    """ISSUE 26: every program that takes the stacked cache and returns
+    it aliases k, v (and pos, last) input to output, so no call holds
+    two stacks or rewrites one to change a row."""
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
+                          prefill_len=8, kv_pages=16)
+    step = eng._step_sample_args()
+    stack = "x".join(map(str, eng._cache["k"].shape)) + "xbf16"
+    row = jnp.zeros((CFG.n_layers, 1) + eng._cache["k"].shape[2:],
+                    eng._cache["k"].dtype)
+    slot = jnp.asarray(0, jnp.int32)
+    table = jnp.zeros((eng.pages_per_slot,), jnp.int32)
+    lowered = {
+        "_step_block": lambda: eng._step_block.lower(*step, n_steps=4),
+        "_verify_block": lambda: eng._verify_block.lower(
+            *step, jnp.full((eng.slots, 4), -1, jnp.int32)),
+        "_install": lambda: eng._install.lower(
+            eng._cache["k"], eng._cache["v"], eng._cache["pos"],
+            eng._last, row, row, eng._last[0], slot, slot),
+        "_resume_install": lambda: eng._resume_install.lower(
+            eng._cache["k"], eng._cache["v"], eng._cache["pos"],
+            eng._last, eng._kpool, eng._vpool, table, slot, slot,
+            eng._last[0]),
+    }[program]()
+    donated = _donated(lowered)
+    assert donated.count(stack) == 2, donated             # k and v
+    assert f"{eng.slots}xi32" in donated                  # pos
+    assert f"{eng.slots}x{CFG.vocab_size}xf32" in donated  # last
+    # what a prefill holds (working rows, a row's logits) and the
+    # weights are never given away
+    assert len(donated) == 4, donated
+
+
+@pytest.mark.timeout(300)
+def test_prefill_chunk_donates_nothing(params):
+    """The working rows of a prefill are held by the prefix cache and
+    by a bundle in the making, so the chunk program keeps its inputs."""
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
+                          prefill_len=8, prefix_cache_entries=2)
+    run = eng.prefill_begin([1, 2, 3])
+    lowered = eng._prefill_chunk.lower(
+        eng.params, jnp.zeros((1, 8), jnp.int32), run.row_k, run.row_v,
+        run.pos, jnp.asarray(3, jnp.int32))
+    assert _donated(lowered) == []
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("kind", ["serving_step", "serving_verify"])
+def test_aot_digest_names_the_stack_contract(params, monkeypatch, kind):
+    """The elastic compile cache keys on facts, not on the program's
+    text: the parent's build (which keeps the stack it passes in) and
+    this one (which donates it) must not load each other's serving
+    executables, so the cache contract is a fact of the digest, and it
+    is the digest the engine publishes under."""
+    from dlrover_tpu.parallel.compile_cache import (
+        abstract_signature,
+        compile_fingerprint,
+        verify_key,
+    )
+
+    monkeypatch.setenv("DLROVER_TPU_SERVING_OBSERVATORY", "1")
+    monkeypatch.setenv("DLROVER_TPU_SPEC_DEPTH", "2")
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
+                          prefill_len=8)
+    if kind == "serving_step":
+        aot, facts, extra = eng.warm_aot_step(), {"n_steps": 1}, ()
+    else:
+        [aot], facts = eng.warm_aot_verify(depths=[2]), {}
+        extra = (jnp.full((eng.slots, 2), -1, jnp.int32),)
+
+    def key(strategy):
+        base, _ = compile_fingerprint(
+            num_nodes=1, total_devices=jax.local_device_count(),
+            mesh_axes={}, model=CFG, strategy=strategy,
+            args_signature=abstract_signature(
+                eng._step_sample_args() + extra))
+        return base if kind == "serving_step" else verify_key(
+            base, depth=2)
+
+    ours = eng._aot_strategy(kind, **facts)
+    parents = {k: v for k, v in ours.items() if k != "kv_stack"}
+    assert set(parents) == {"kind", "slots", "max_len", "prefill_len",
+                            "numerics", *facts}
+    assert aot.key == key(ours)
+    assert key(parents) != key(ours)

@@ -357,6 +357,78 @@ def test_forced_cow_break_repoints_without_stream_change(
     assert out[rid] == want
 
 
+@pytest.mark.timeout(900)
+def test_every_path_crossed_after_donated_steps(params, monkeypatch):
+    """ISSUE 26: every program that returns the stacked cache donates
+    it, so the engine must never read a stack (or pos, or last) it
+    handed to an earlier call. One engine crosses every path after
+    donated steps — admission + install, prefix-cache hits, COW shares,
+    parks and resumes through the pages, a forced COW break,
+    speculative verifies, a weight push through the ``params`` setter —
+    and emits, request for request, what a one-slot engine with no
+    pages, no prefix cache and no speculation emits for that request
+    alone; each watched call has deleted the buffers it was given."""
+    def alone(weights, reqs):
+        _serving_env(monkeypatch, spec=0, cow=False)
+        eng = InferenceEngine(weights, CFG, slots=1, max_len=64,
+                              prefill_len=8)
+        return [_drain(eng, [r])[0] for r in reqs]
+
+    wave = _spec_reqs(max_new=24) + _shared_prefix_reqs()
+    forced = (list(_SYS8), SamplingParams(
+        temperature=0.0, max_new_tokens=17, seed=5))
+    pushed = tfm.init_params(CFG, jax.random.PRNGKey(7))
+    want = alone(params, wave + [forced])
+    want_pushed = alone(pushed, wave)
+
+    _serving_env(monkeypatch, spec=4, cow=True)
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
+                          prefill_len=8, kv_pages=40,
+                          prefix_cache_entries=4)
+    gave_away = {}
+
+    def watch(name, first):
+        real = getattr(eng, name)
+
+        def call(*args, **kw):
+            out = real(*args, **kw)
+            stale = args[first:first + 4]       # k, v, pos, last
+            assert all(a.is_deleted() for a in stale), name
+            gave_away[name] = gave_away.get(name, 0) + 1
+            return out
+
+        setattr(eng, name, call)
+
+    for name, first in (("_install", 0), ("_resume_install", 0),
+                        ("_step_block", 1), ("_verify_block", 1)):
+        watch(name, first)
+
+    assert _drain(eng, wave) == want[:-1]
+    assert eng.kv_parked_total > 0 and eng.spec_steps_total > 0
+    assert eng.prefix_cache_hits > 0 and eng.cow_pages_shared_total > 0
+
+    # a forced copy-on-write break (see the test above), then a resume
+    rid = eng.submit(*forced)
+    while len(eng._emitted[0]) < 2:
+        eng.step()
+    pid = eng._slot_pages[0][1]
+    eng._share_index[b"forced"] = pid
+    eng._page_digest[pid] = b"forced"
+    eng._park_slot(0)
+    assert eng.cow_breaks_total == 1
+    assert {r.id: r.tokens for r in eng.run()}[rid] == want[-1]
+
+    # a weight push between waves: the stack outlives the weights
+    eng.params = pushed
+    assert not eng._prefix_cache
+    assert _drain(eng, wave) == want_pushed
+    assert set(gave_away) == {"_install", "_resume_install",
+                              "_step_block", "_verify_block"}
+    ledger = eng.kv_page_ledger()
+    assert ledger["ok"] and ledger["leased"] == 0
+    assert check_kv_ledgers() == []
+
+
 # ------------------------------------- depth policy + digest satellite
 
 

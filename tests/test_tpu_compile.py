@@ -162,9 +162,13 @@ def test_serving_programs_compile(one_chip):
                 np.shape(a), jnp.asarray(a).dtype, sharding=one_chip),
             tree)
 
-    eng._step_block.lower(
+    step = eng._step_block.lower(
         *on_chip(eng._step_sample_args()), n_steps=1
     ).compile(compiler_options=serving._CANONICAL_NUMERICS)
+    # ISSUE 26: the chip's compiler takes the donation: K and V alias
+    # input to output (with pos and last), one stack resident, not two
+    stack = int(np.prod(eng._cache["k"].shape)) * 2
+    assert step.memory_analysis().alias_size_in_bytes >= 2 * stack
     chunk = jax.ShapeDtypeStruct((1, eng.prefill_len), jnp.int32,
                                  sharding=one_chip)
     row = on_chip(init_cache(cfg, 1, eng.max_len))
