@@ -7,15 +7,18 @@ equivalent is a cache-carrying decode step under jit — static shapes
 step program, O(S) per generated token instead of the O(S^2) recompute of
 calling the full forward per step.
 
-The cache is one stack per K and V, ``[L, B, max_len, H_kv, D]``, and
-it is never copied whole (DESIGN.md §23.1): the layer loop CARRIES the
-stack, layer ``l`` writes its ``S_new`` new rows into it in place (the
-update is the new rows alone) and attends over ``K[l]`` read out of the
-carry. Nothing of a layer's shape is scanned in or out: a scanned
-input is sliced out whole and a scanned output copied back whole, per
-layer. A caller that wants the update in place donates the
-stack to the jitted program that calls ``forward_cached`` and keeps no
-other reference to it (``serving/engine.py`` does).
+The cache is the model's TREE (DESIGN.md §23.5): a position and
+stacks laid out ``[L, B, max_len, ...]``: one per K and V, ``[L, B,
+max_len, H_kv, D]``, for per-head attention; one latent stack for
+``attn_kind='latent'`` (models/latent.py). A stack is never copied
+whole (§23.1): the layer loop CARRIES it, layer ``l`` writes its
+``S_new`` new rows into it in place (the update is the new rows alone)
+and attends over ``stack[l]`` read out of the carry. Nothing of a
+layer's shape is scanned in or out: a scanned input is sliced out
+whole and a scanned output copied back whole, per layer. A caller that
+wants the update in place donates the stack to the jitted program that
+calls ``forward_cached`` and keeps no other reference to it
+(``serving/engine.py`` does).
 
 Correctness is pinned to the training forward by an equivalence test
 (tests/test_decode.py): prefill+cached-decode logits must match
@@ -41,13 +44,44 @@ Params = Any
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
+    """The model's cache TREE (DESIGN.md §23.5): ``pos``, stacks laid
+    out ``[L, B, max_len, ...]`` under names of the model's choosing
+    (``k`` and ``v`` per head here; one ``latent`` stack for
+    ``attn_kind='latent'``), and optionally ``counters``. Callers carry
+    it whole and name none of its stacks: :func:`cache_stacks`."""
     c = cfg
+    if c.new_kinds:
+        from dlrover_tpu.models import latent
+
+        return latent.init_cache(c, batch, max_len)
     shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
     return {
         "k": jnp.zeros(shape, jnp.dtype(c.dtype)),
         "v": jnp.zeros(shape, jnp.dtype(c.dtype)),
         "pos": jnp.zeros((), jnp.int32),
     }
+
+
+def cache_stacks(cache: dict) -> dict:
+    """The stacks of a cache tree, ``[L, B, max_len, ...]`` each: all
+    but the position and the counters."""
+    return {k: v for k, v in cache.items() if k not in ("pos", "counters")}
+
+
+def cache_counter_fields(cache: dict) -> dict:
+    """What a span says of a model's counters: their scalars, under the
+    names the model gave them (none for a model that counts nothing)."""
+    return {name: value for name, value in cache.get("counters", {}).items()
+            if jnp.ndim(value) == 0}
+
+
+def zero_counters(cache: dict) -> dict:
+    """``cache`` with its counters at zero: a program that reports them
+    a call at a time starts from here."""
+    if "counters" not in cache:
+        return cache
+    return {**cache, "counters": jax.tree.map(jnp.zeros_like,
+                                              cache["counters"])}
 
 
 def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0):
@@ -93,8 +127,9 @@ def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0):
 
 
 def _write_rows(stack, new, layer, pos):
-    """Write ``new`` [B, S_new, H_kv, D] into ``stack`` [L, B, max_len,
-    H_kv, D] at ``[layer, b, pos[b] : pos[b] + S_new]``, in place where
+    """Write ``new`` [B, S_new, ...] into ``stack`` [L, B, max_len, ...]
+    (per-head rows ``[H_kv, D]`` or one latent row: any trailing dims)
+    at ``[layer, b, pos[b] : pos[b] + S_new]``, in place where
     the stack is a loop's carry: the update is the new rows alone,
     never a layer. Rows in lockstep (scalar ``pos``) take one
     ``dynamic_update_slice``; rows at positions of their own take one
@@ -102,12 +137,13 @@ def _write_rows(stack, new, layer, pos):
     the rows that costs 2.8 us a row (a block of 8 decode steps at 16
     slots on a v5e: 97.2 ms against 81.6; PERF.md §6, PR 26). A start past
     ``max_len - S_new`` is clamped so that the rows fit."""
+    rest = (0,) * (stack.ndim - 3)
     if jnp.ndim(pos) == 0:
         return lax.dynamic_update_slice(
-            stack, new[None], (layer, 0, pos, 0, 0))
+            stack, new[None], (layer, 0, pos, *rest))
     for b in range(new.shape[0]):
         stack = lax.dynamic_update_slice(
-            stack, new[None, b:b + 1], (layer, b, pos[b], 0, 0))
+            stack, new[None, b:b + 1], (layer, b, pos[b], *rest))
     return stack
 
 
@@ -124,6 +160,11 @@ def forward_cached(
     per-row positions — the continuous-batching serving engine).
     """
     c = cfg
+    if c.new_kinds:
+        # one definition of those kinds' block, cached or not
+        from dlrover_tpu.models import latent
+
+        return latent.forward(params, tokens, c, cache)
     dt = jnp.dtype(c.dtype)
     B, S_new = tokens.shape
     pos = cache["pos"]
@@ -157,11 +198,14 @@ def forward_cached(
     if c.moe_experts:
         from dlrover_tpu.ops.moe import MoeConfig, moe_ffn
 
-        # Same router/experts as training. Capacity is per forward_cached
-        # call (B*S_new tokens), not per training sequence: a decode step
-        # routes B tokens against a fresh capacity pool, so drop patterns
-        # can differ from the training forward when experts overflow —
-        # exact train/decode equivalence holds in the no-drop regime.
+        # Same router/experts as training (the softmax-routed,
+        # capacity-limited `moe_experts` kind; the sigmoid-routed kind
+        # has no capacity and left for models/latent.py above).
+        # Capacity is per forward_cached call (B*S_new tokens), not per
+        # training sequence: a decode step routes B tokens against a
+        # fresh capacity pool, so drop patterns can differ from the
+        # training forward when experts overflow — exact train/decode
+        # equivalence holds in the no-drop regime.
         moe_cfg = MoeConfig(
             n_experts=c.moe_experts, top_k=c.moe_top_k,
             capacity_factor=c.moe_capacity_factor,

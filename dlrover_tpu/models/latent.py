@@ -1,0 +1,366 @@
+"""The block kinds that ``variant`` cannot name: latent attention, sandwich
+norms, a sigmoid-routed expert layer beside a shared expert, a stack
+whose first layers are dense (the DeepSeek-V3 / openPangu-Ultra-MoE
+family). The forward pass only: scoring and serving.
+
+ONE definition of the block, :func:`forward`: without a cache it is the
+uncached forward (``transformer.forward_with_aux`` hands over to it),
+with one it is ``decode.forward_cached`` (prefill chunk, decode step,
+verify block). The two differ in one thing, which rows the queries
+attend over: the call's own latent rows, or the cache's after the call's
+rows were written into it.
+
+RMSNorm ``N`` (eps ``cfg.norm_eps``); ``h`` is a layer's normed input:
+
+  sandwich   x = x + N_post_attn(Attn(N_in(x)));
+             x = x + N_post_mlp(FFN(N_pre_mlp(x)))
+  latent     c_q = N_q(h W_qa); per head [q_nope | q_rope] = c_q W_qb,
+             q_rope = RoPE(q_rope); [c_kv | k_r] = h W_kva,
+             c_kv = N_kv(c_kv), k_rope = RoPE(k_r) (one for all heads);
+             [k_nope_h | v_h] = c_kv W_kvb;
+             score_h = (q_nope_h . k_nope_h + q_rope_h . k_rope)
+                       / sqrt(nope + rope); causal softmax in float32;
+             out = concat_h(sum p v_h) W_o
+  experts    ops/moe.py: sigmoid_topk_route, held_expert_ffn, swiglu
+
+**The cache is ``c_kv`` and ``k_rope``**: one stack ``latent [L, B,
+max_len, kv_lora_rank + qk_rope_head_dim]`` (576 numbers a token a layer
+at the published sizes), carried through both scans, written in place
+(``decode._write_rows``). A call of at most :data:`ABSORB_UPTO` new
+tokens a row reads it as it lies: ``W_kvb`` is absorbed into the
+query (``q~_h = q_nope_h W_kvb,k,h^T``, ``score_h = [q~_h | q_rope_h] .
+[c_kv | k_rope]``) and into the output (``o_h = (sum p c_kv) W_kvb,v,h``):
+the same function. A wider call (a prefill chunk) expands keys and
+values, a group of heads at a time.
+
+Parameters: ``embed [V, E]``, ``ln_f [E]``, ``lm_head [E, V]``,
+``dense_layers`` (the first ``first_k_dense``) and ``layers`` (the
+rest), each stacked along a leading layer dim; see :func:`param_shapes`.
+The routed experts' stacks ``we_*`` hold ``experts_held`` experts from
+``expert_first`` on; the router keeps its full width.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from dlrover_tpu.models.decode import _write_rows
+from dlrover_tpu.ops import moe
+
+Params = Any
+EXPERT_STACKS = ("we_gate", "we_up", "we_down")
+# heads whose keys and values the expanded path holds at once
+HEAD_GROUP = 16
+# a cached call of at most this many new tokens a row (a decode step, a
+# verify block) reads the latent rows as they lie, W_kvb absorbed into
+# the query and the output; a wider one (a prefill chunk) expands keys
+# and values. Chosen from the call's width, which the code sees
+ABSORB_UPTO = 64
+KINDS = ("latent", "sandwich", "sigmoid_experts")
+
+
+def routed_config(cfg) -> moe.RoutedConfig:
+    return moe.RoutedConfig(
+        n_experts=cfg.n_routed_experts, top_k=cfg.moe_top_k,
+        scaling=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
+        first=cfg.expert_first, held=cfg.experts_held)
+
+
+def segments(cfg) -> list[tuple[str, bool, int]]:
+    """The stack as ``(params key, expert layer?, layers)`` runs."""
+    kinds = (cfg.attn_kind, cfg.norm_kind, cfg.ffn_kind)
+    if kinds != KINDS:
+        # 'pre' norms or a plain 'swiglu' under latent attention: no
+        # configuration asks for them yet
+        raise NotImplementedError(
+            f"attn_kind / norm_kind / ffn_kind {kinds}: models/latent.py "
+            f"runs {KINDS} together, and `variant` names the rest")
+    dense = min(cfg.first_k_dense, cfg.n_layers)
+    runs = [("dense_layers", False, dense),
+            ("layers", True, cfg.n_layers - dense)]
+    return [r for r in runs if r[2] > 0]
+
+
+def param_shapes(cfg) -> dict:
+    """The parameter tree as shapes (a tuple a leaf)."""
+    c = cfg
+    e, h = c.d_model, c.n_heads
+    attn = {
+        "ln_in": (e,), "w_qa": (e, c.q_lora_rank), "ln_q": (c.q_lora_rank,),
+        "w_qb": (c.q_lora_rank, h, c.qk_nope_head_dim + c.qk_rope_head_dim),
+        "w_kva": (e, c.kv_lora_rank + c.qk_rope_head_dim),
+        "ln_kv": (c.kv_lora_rank,),
+        "w_kvb": (c.kv_lora_rank, h, c.qk_nope_head_dim + c.v_head_dim),
+        "w_o": (h, c.v_head_dim, e), "ln_post_attn": (e,),
+        "ln_pre_mlp": (e,), "ln_post_mlp": (e,),
+    }
+    held, fe = routed_config(c).n_held, c.moe_d_ff
+    fs = c.n_shared_experts * c.moe_d_ff
+    ffn = {
+        False: {"w_gate": (e, c.d_ff), "w_up": (e, c.d_ff),
+                "w_down": (c.d_ff, e)},
+        True: {"w_router": (e, c.n_routed_experts),
+               "we_gate": (held, e, fe), "we_up": (held, e, fe),
+               "we_down": (held, fe, e),
+               "ws_gate": (e, fs), "ws_up": (e, fs), "ws_down": (fs, e)},
+    }
+    tree = {"embed": (c.vocab_size, e), "ln_f": (e,),
+            "lm_head": (e, c.vocab_size)}
+    for key, experts, n in segments(c):
+        tree[key] = {name: (n, *shape)
+                     for name, shape in {**attn, **ffn[experts]}.items()}
+    return tree
+
+
+def init_params(cfg, key: jax.Array) -> Params:
+    """Seeded weights in ``cfg.param_dtype``: matrices normal /
+    sqrt(fan_in) (the contracted dim), norm scales one."""
+    dt = jnp.dtype(cfg.param_dtype)
+    shapes = param_shapes(cfg)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
+    leaves = []
+    for i, (path, shape) in enumerate(flat):
+        name = path[-1].key
+        if name.startswith("ln"):
+            leaves.append(jnp.ones(shape, dt))
+            continue
+        stacked = len(path) > 1
+        core = shape[1:] if stacked else shape
+        if name in EXPERT_STACKS:
+            fan_in = core[1]
+        elif name == "w_o":
+            fan_in = core[0] * core[1]
+        else:
+            fan_in = core[0]
+        leaves.append((jax.random.normal(jax.random.fold_in(key, i), shape,
+                                         jnp.float32)
+                       / math.sqrt(fan_in)).astype(dt))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def init_cache(cfg, batch: int, max_len: int) -> dict:
+    """The cache tree: the latent stack, the position, and the expert
+    layer's counters, which every cached call adds to: ``loads [expert
+    layers, held]`` (assignments each held expert took) and the scalars
+    a span reports by these names (``decode.cache_counter_fields``):
+    ``expert_tokens`` (the sum of ``loads``), ``expert_load_max`` (its
+    largest cell), ``expert_load_max_over_mean`` (that cell against the
+    mean cell; 1.0 is even) and ``experts_hit`` (held experts that took
+    at least one assignment, summed over layers and calls)."""
+    c = cfg
+    cache = {
+        "latent": jnp.zeros(
+            (c.n_layers, batch, max_len,
+             c.kv_lora_rank + c.qk_rope_head_dim), jnp.dtype(c.dtype)),
+        "pos": jnp.zeros((), jnp.int32),
+    }
+    n_expert = sum(n for _, experts, n in segments(c) if experts)
+    if n_expert:
+        # a buffer of its own for each: the tree is donated leaf by leaf
+        cache["counters"] = {
+            "loads": jnp.zeros((n_expert, routed_config(c).n_held),
+                               jnp.int32),
+            **{name: jnp.zeros((), jnp.int32) for name in (
+                "expert_tokens", "expert_load_max", "experts_hit")},
+            "expert_load_max_over_mean": jnp.zeros((), jnp.float32)}
+    return cache
+
+
+def _count(counters: dict, loads: jax.Array) -> dict:
+    """``counters`` after a call whose expert layers took ``loads``."""
+    total = counters["loads"] + loads
+    n, top = total.sum(), total.max()
+    return {
+        "loads": total, "expert_tokens": n, "expert_load_max": top,
+        "experts_hit": counters["experts_hit"]
+        + (loads > 0).sum().astype(jnp.int32),
+        "expert_load_max_over_mean": jnp.where(
+            n > 0, top * total.size / jnp.maximum(n, 1), 0.0
+        ).astype(jnp.float32),
+    }
+
+
+def _rms(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * inv).astype(x.dtype) * scale.astype(x.dtype)
+
+
+def _rope(x, positions, theta):
+    """Rotary embedding of ``x [B, S, H, D]`` at ``positions [B, S]``,
+    pairing components ``(2i, 2i + 1)``; angles in float32."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angles = positions[:, :, None, None].astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., 0::2], x32[..., 1::2]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _masked_softmax(scores, q_pos, dt):
+    """``scores [B, H, S, K]`` float32; query ``s`` of row ``b`` sits at
+    ``q_pos[b, s]`` and sees keys at positions up to its own."""
+    k_pos = jnp.arange(scores.shape[-1])
+    mask = q_pos[:, None, :, None] >= k_pos[None, None, None, :]
+    return jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1).astype(dt)
+
+
+def attend(q_nope, q_rope, rows, w_kvb, q_pos, cfg, absorbed: bool):
+    """``q_nope [B, S, H, nope]``, ``q_rope [B, S, H, rope]`` over the
+    latent ``rows [B, K, rank + rope]`` -> ``[B, S, H, v]``."""
+    c = cfg
+    dt = q_nope.dtype
+    rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
+    scale = 1.0 / math.sqrt(nope + c.qk_rope_head_dim)
+    if absorbed:
+        q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w_kvb[..., :nope])
+        scores = jnp.einsum(
+            "bshc,bkc->bhsk", jnp.concatenate([q_lat, q_rope], -1), rows
+        ).astype(jnp.float32) * scale
+        probs = _masked_softmax(scores, q_pos, dt)
+        o_lat = jnp.einsum("bhsk,bkr->bshr", probs, rows[..., :rank])
+        return jnp.einsum("bshr,rhv->bshv", o_lat, w_kvb[..., nope:])
+
+    c_kv, k_rope = rows[..., :rank], rows[..., rank:]
+    B, S, H, _ = q_nope.shape
+    hg = min(H, HEAD_GROUP)
+    if H % hg:
+        raise ValueError(f"{H} heads do not split into groups of {hg}")
+
+    def group(_, inputs):
+        qn, qr, w = inputs                     # [B,S,hg,*], [rank,hg,*]
+        kv = jnp.einsum("bkr,rhn->bkhn", c_kv, w)
+        scores = (jnp.einsum("bshn,bkhn->bhsk", qn, kv[..., :nope])
+                  + jnp.einsum("bshn,bkn->bhsk", qr, k_rope)
+                  ).astype(jnp.float32) * scale
+        probs = _masked_softmax(scores, q_pos, dt)
+        return None, jnp.einsum("bhsk,bkhv->bshv", probs, kv[..., nope:])
+
+    def split(a, axis):                        # heads -> [groups, ..., hg]
+        shape = a.shape[:axis] + (H // hg, hg) + a.shape[axis + 1:]
+        return jnp.moveaxis(a.reshape(shape), axis, 0)
+
+    _, out = lax.scan(group, None,
+                      (split(q_nope, 2), split(q_rope, 2), split(w_kvb, 1)))
+    return jnp.moveaxis(out, 0, 2).reshape(B, S, H, -1)
+
+
+def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
+            return_hidden: bool = False):
+    """``tokens [B, S]`` -> ``(float32 logits [B, S, V], cache)``.
+
+    ``cache`` None: the uncached forward, every row from position 0.
+    Else the call's tokens start at ``cache['pos']`` (a scalar: rows in
+    lockstep; ``[B]``: rows at positions of their own), their latent
+    rows are written into the stack and the queries attend over it.
+    """
+    c = cfg
+    dt = jnp.dtype(c.dtype)
+    eps = c.norm_eps
+    B, S = tokens.shape
+    rcfg = routed_config(c)
+    pos = cache["pos"] if cache is not None else jnp.zeros((), jnp.int32)
+    steps = jnp.arange(S, dtype=jnp.int32)
+    positions = (pos[:, None] + steps[None] if jnp.ndim(pos)
+                 else jnp.broadcast_to(pos + steps, (B, S)))
+    absorbed = cache is not None and S <= ABSORB_UPTO
+    rank = c.kv_lora_rank
+
+    def block(x, stack, w, experts, layer, global_layer):
+        """One layer on ``x [B, S, E]``; ``stack`` is the cache's latent
+        stack or None. Returns (x, stack, loads of this layer or None)."""
+        h = _rms(x, w["ln_in"], eps)
+        with jax.named_scope("mla_q"):
+            c_q = _rms(jnp.einsum("bse,er->bsr", h, w["w_qa"].astype(dt)),
+                       w["ln_q"], eps)
+            q = jnp.einsum("bsr,rhd->bshd", c_q, w["w_qb"].astype(dt))
+            q_nope = q[..., :c.qk_nope_head_dim]
+            q_rope = _rope(q[..., c.qk_nope_head_dim:], positions,
+                           c.rope_theta)
+        with jax.named_scope("latent_write"):
+            kva = jnp.einsum("bse,er->bsr", h, w["w_kva"].astype(dt))
+            rows = jnp.concatenate([
+                _rms(kva[..., :rank], w["ln_kv"], eps),
+                _rope(kva[..., None, rank:], positions,
+                      c.rope_theta)[:, :, 0]], -1)
+            if stack is not None:
+                stack = _write_rows(stack, rows, global_layer, pos)
+                rows = lax.dynamic_index_in_dim(stack, global_layer,
+                                                keepdims=False)
+        with jax.named_scope("mla_attend"):
+            o = attend(q_nope, q_rope, rows, w["w_kvb"].astype(dt),
+                       positions, c, absorbed)
+            o = jnp.einsum("bshv,hve->bse", o, w["w_o"].astype(dt))
+        x = x + _rms(o, w["ln_post_attn"], eps)
+
+        h = _rms(x, w["ln_pre_mlp"], eps)
+        loads = None
+        if experts is None:
+            with jax.named_scope("mlp"):
+                ff = moe.swiglu(h, w["w_gate"].astype(dt),
+                                w["w_up"].astype(dt), w["w_down"].astype(dt))
+        else:
+            ht = h.reshape(B * S, -1)
+            with jax.named_scope("moe_router"):
+                idx, gate = moe.sigmoid_topk_route(ht, w["w_router"], rcfg)
+            with jax.named_scope("moe_experts"):
+                routed, loads = moe.held_expert_ffn(
+                    ht, idx, gate, experts, layer, rcfg)
+            with jax.named_scope("moe_shared"):
+                shared = moe.swiglu(
+                    h, w["ws_gate"].astype(dt), w["ws_up"].astype(dt),
+                    w["ws_down"].astype(dt))
+            ff = shared + routed.reshape(B, S, -1).astype(dt)
+        x = x + _rms(ff, w["ln_post_mlp"], eps)
+        return x, stack, loads
+
+    x = params["embed"].astype(dt)[tokens]
+    stack = cache["latent"] if cache is not None else None
+    counters = (cache or {}).get("counters")
+    first = 0
+    for key, is_expert, n in segments(c):
+        seg = params[key]
+        # the routed experts' stacks are closed over and indexed in
+        # place by the tile loop; everything else is scanned in
+        experts = ({k: seg[k].astype(dt) for k in EXPERT_STACKS}
+                   if is_expert else None)
+        scanned = {k: v for k, v in seg.items() if k not in EXPERT_STACKS}
+
+        def layer(carry, inputs, experts=experts, first=first):
+            x, stack = carry
+            w, i = inputs
+            x, stack, mine = block(x, stack, w, experts, i, first + i)
+            return (x, stack), mine
+
+        (x, stack), loads = lax.scan(
+            layer, (x, stack), (scanned, jnp.arange(n, dtype=jnp.int32)))
+        if is_expert and counters is not None:
+            counters = _count(counters, loads)
+        first += n
+    with jax.named_scope("lm_head"):
+        x = _rms(x, params["ln_f"], eps)
+        out = x if return_hidden else jnp.einsum(
+            "bse,ev->bsv", x, params["lm_head"].astype(dt)
+        ).astype(jnp.float32)
+    if cache is None:
+        return out, None
+    new_cache = {"latent": stack, "pos": pos + S}
+    if counters is not None:
+        new_cache["counters"] = counters
+    return out, new_cache
+
+
+def forward_uncached(params: Params, tokens: jax.Array, cfg,
+                     return_hidden: bool = False):
+    """``forward_with_aux``'s answer for these kinds: no balancing loss
+    (the router is served, not trained), so the aux term is zero."""
+    out, _ = forward(params, tokens, cfg, None, return_hidden)
+    return out, jnp.zeros((), jnp.float32)

@@ -107,14 +107,62 @@ class TransformerConfig:
     # fused cross-entropy (atorch modules/transformer/cross_entropy.py)
     # done the XLA way. 0 = single full-logits pass.
     ce_chunks: int = 0
+    # --- what a block is made of, as KINDS. The defaults are what
+    # `variant` / `moe_experts` say. models/latent.py runs the one other
+    # combination there is (the forward pass only: scoring and serving)
+    # and refuses the rest by name: attn_kind "latent" (queries and
+    # keys/values through low-rank latents, the cache ONE stack of
+    # kv_lora_rank + qk_rope_head_dim numbers a token a layer) with
+    # norm_kind "sandwich" (a norm after attention and after the
+    # feed-forward too) and ffn_kind "sigmoid_experts": the first
+    # `first_k_dense` layers one SwiGLU of `d_ff`, the rest a
+    # sigmoid-routed expert layer (ops/moe.py RoutedConfig: top
+    # `moe_top_k` of `n_routed_experts`, SwiGLU experts of `moe_d_ff`)
+    # beside `n_shared_experts` shared ones.
+    attn_kind: str = "heads"
+    norm_kind: str = "pre"
+    ffn_kind: str = ""
+    norm_eps: float = 1e-5          # the new kinds' RMSNorm
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    first_k_dense: int = 0
+    n_routed_experts: int = 0
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+    # the share held here of a deployment that divides each layer over
+    # several chips: `experts_held` of the routed experts from
+    # `expert_first` on (0: all); `n_layers` and `vocab_size` are then
+    # the layers and vocabulary rows HELD, whatever the publication has
+    experts_held: int = 0
+    expert_first: int = 0
+    # the dtype the weights rest in ("bfloat16": as published, no
+    # per-call conversion)
+    param_dtype: str = "float32"
 
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
     @property
+    def new_kinds(self) -> bool:
+        """True where models/latent.py runs the block."""
+        return (self.attn_kind, self.norm_kind, self.ffn_kind) != (
+            "heads", "pre", "")
+
+    @property
     def param_count(self) -> int:
         c = self
+        if c.new_kinds:
+            # the parameters HELD (the share), counted from the shapes
+            from dlrover_tpu.models.latent import param_shapes
+
+            return sum(math.prod(s) for s in jax.tree.leaves(
+                param_shapes(c), is_leaf=lambda s: isinstance(s, tuple)))
         embed = c.vocab_size * c.d_model
         attn = c.d_model * c.head_dim * (c.n_heads * 2 + c.n_kv_heads * 2)
         if c.moe_experts:
@@ -141,6 +189,11 @@ class TransformerConfig:
         half of attention when the model is causal, the backward twice
         the forward, recomputed operations NOT counted."""
         c = self
+        if c.new_kinds:
+            raise NotImplementedError(
+                "train_flops_per_token: the latent / sandwich / "
+                "sigmoid_experts kinds are served, not trained "
+                "(benchmark/counts/mla_moe.py counts their forward)")
         attn = c.d_model * c.head_dim * (c.n_heads * 2 + c.n_kv_heads * 2)
         if c.moe_experts:
             ffn = (c.d_model * c.moe_experts
@@ -218,6 +271,30 @@ CONFIGS = {
     "llama3-8b": TransformerConfig(
         vocab_size=128256, d_model=4096, n_layers=32, n_heads=32, n_kv_heads=8,
         d_ff=14336, max_seq_len=8192, variant="llama", rope_theta=500000.0),
+    # the kinds of the entry below at a size for CPU tests
+    "tiny-latent-moe": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        d_ff=160, max_seq_len=256, rope_theta=10000.0,
+        attn_kind="latent", norm_kind="sandwich",
+        ffn_kind="sigmoid_experts", q_lora_rank=32, kv_lora_rank=24,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        first_k_dense=1, n_routed_experts=16, moe_top_k=4, moe_d_ff=32,
+        n_shared_experts=1, routed_scaling_factor=2.5, dtype="float32"),
+    # openPangu-Ultra-MoE-718B as published (config.json, model_type
+    # pangu_ultra_moe); a deployment sets the share it holds with
+    # dataclasses.replace (n_layers, first_k_dense, experts_held,
+    # expert_first, vocab_size). Its multi-token-prediction module is
+    # not modelled.
+    "openpangu-ultra-moe-718b": TransformerConfig(
+        vocab_size=153600, d_model=7680, n_layers=61, n_heads=128,
+        n_kv_heads=128, d_ff=18432, max_seq_len=131072,
+        rope_theta=25600000.0, attn_kind="latent", norm_kind="sandwich",
+        ffn_kind="sigmoid_experts", norm_eps=1e-5, q_lora_rank=1536,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, first_k_dense=3, n_routed_experts=256,
+        moe_top_k=8, moe_d_ff=2048, n_shared_experts=1,
+        routed_scaling_factor=2.5, norm_topk_prob=True,
+        param_dtype="bfloat16"),
 }
 
 
@@ -227,6 +304,10 @@ CONFIGS = {
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     """Initialize an fp32 parameter pytree (layer-stacked)."""
     c = cfg
+    if c.new_kinds:
+        from dlrover_tpu.models.latent import init_params as init_latent
+
+        return init_latent(c, key)
     k_embed, k_layers, k_out, k_pos = jax.random.split(key, 4)
     hd = c.head_dim
 
@@ -296,6 +377,11 @@ def logical_axes(cfg: TransformerConfig) -> Params:
     model dim — FSDP shards it), heads/kv_heads (TP), mlp (TP).
     """
     c = cfg
+    if c.new_kinds:
+        raise NotImplementedError(
+            "logical_axes: the latent / sandwich / sigmoid_experts kinds "
+            "run on one device (models/latent.py); no rule table names "
+            "their weights yet")
     layers = {
         "wq": ("layers", "embed", "heads", None),
         "wk": ("layers", "embed", "kv_heads", None),
@@ -604,6 +690,17 @@ def forward_with_aux(
     stack with every strategy unchanged.
     """
     c = cfg
+    if c.new_kinds:
+        from dlrover_tpu.models.latent import forward_uncached
+
+        if (attention_fn is not None or c.prefix_lm or c.remat_scan
+                or c.pipeline_stages > 1 or inputs_embeds is not None
+                or mask is not None):
+            raise NotImplementedError(
+                "the latent / sandwich / sigmoid_experts kinds take "
+                "tokens and nothing else (models/latent.py)")
+        return forward_uncached(params, tokens, c,
+                                return_hidden=return_hidden)
     dt = jnp.dtype(c.dtype)
     pin = constrain or (lambda x, a: x)
     if c.prefix_lm:

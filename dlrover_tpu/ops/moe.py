@@ -13,6 +13,25 @@ Gating follows the Switch/GShard recipe: softmax router, top-k experts
 per token, per-expert capacity ``ceil(T/E * capacity_factor)`` with
 overflow tokens dropped (their residual path passes through), and the
 load-balancing auxiliary loss ``E * sum_e f_e * p_e``.
+
+Which routing each family uses:
+
+- ``moe_ffn`` (the ``tiny-moe`` preset, training and serving): softmax
+  router, ReLU experts of two matrices, ``[T, E, C]`` one-hot dispatch,
+  tokens past an expert's capacity dropped.
+- ``sigmoid_topk_route`` + ``held_expert_ffn`` (the DeepSeek-V3 /
+  openPangu-Ultra-MoE family, ``models/latent.py``, serving): sigmoid
+  scores over ALL routed experts in float32, the ``top_k`` largest,
+  gates normalised over the chosen and scaled; SwiGLU experts; NO
+  capacity, so no token is dropped. The layer is told which experts it
+  holds (``first``, ``held``: one chip's share of an expert-parallel
+  deployment), routes over all of them and adds only its own experts'
+  part; what the absent experts would add is left out, and no code
+  stands in for the exchange. Assignments are sorted by expert and run
+  as a grouped product over row tiles: the work follows the
+  assignments that landed here, not ``T x held``, and an expert that
+  got no token reads no weight. The loop's trip count is data: the
+  layer is for the forward pass (scoring, serving), not for ``grad``.
 """
 
 from __future__ import annotations
@@ -156,3 +175,112 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoeConfig,
     y_e = jnp.einsum("ecf,efm->ecm", h, params["w_out"].astype(x.dtype))
     y = jnp.einsum("tec,ecm->tm", combine, y_e)
     return y.reshape(B, S, M), aux.astype(jnp.float32)
+
+
+# ------------------------------------------------- held experts, no drops
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedConfig:
+    """A sigmoid-routed expert layer and the share of it held here."""
+    n_experts: int            # the router's width: every routed expert
+    top_k: int
+    scaling: float = 1.0      # routed_scaling_factor
+    norm_topk: bool = True    # gates normalised over the chosen
+    first: int = 0            # index of the first expert held here
+    held: int = 0             # how many are held (0: all)
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.n_experts
+
+
+def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
+    """``Down(silu(Gate h) * Up h)`` on ``h [..., M]``."""
+    gate = jax.nn.silu(jnp.einsum("...m,mf->...f", h, w_gate))
+    return jnp.einsum("...f,fm->...m",
+                      gate * jnp.einsum("...m,mf->...f", h, w_up), w_down)
+
+
+def sigmoid_topk_route(h: jax.Array, w_router: jax.Array,
+                       cfg: RoutedConfig) -> tuple[jax.Array, jax.Array]:
+    """``h [T, M]`` -> (expert ids ``[T, k]`` int32, gates ``[T, k]``
+    float32) over ALL ``n_experts``. Scores are float32 products at
+    ``HIGHEST``: the k-th and (k+1)-th score of a token can lie a
+    rounding apart, and a flipped choice is a different function."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "tm,me->te", h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, idx = jax.lax.top_k(scores, cfg.top_k)
+    if cfg.norm_topk:
+        top = top / (top.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), top * cfg.scaling
+
+
+def _tile_rows(tokens: int) -> int:
+    """Rows of one grouped-product tile: the token count rounded up to
+    the bfloat16 sublane tile, at most 128."""
+    return min(128, -(-tokens // 16) * 16)
+
+
+def held_expert_ffn(x: jax.Array, idx: jax.Array, gate: jax.Array,
+                    experts: dict, layer, cfg: RoutedConfig
+                    ) -> tuple[jax.Array, jax.Array]:
+    """The held experts' part of the routed sum.
+
+    ``x [T, M]``; ``idx``, ``gate`` ``[T, k]`` from the router;
+    ``experts`` holds ``we_gate``, ``we_up`` ``[L, held, M, F]`` and
+    ``we_down`` ``[L, held, F, M]`` for ALL layers and ``layer`` picks
+    one (a traced index: the tile loop reads ``experts[layer, e]`` in
+    place, where a per-layer slice handed to the loop would be copied
+    whole). Returns ``(y [T, M] float32, loads [held] int32)``: the
+    gate-weighted sum over the assignments that landed on held experts,
+    and how many each held expert took.
+
+    Assignments are sorted by held expert (the rest sort last and are
+    never touched); expert ``e``'s rows are walked in tiles of
+    ``_tile_rows(T)``: the tile's tokens are picked by a one-hot product
+    (no gather or scatter: the TPU runs those a row at a time), pass the
+    expert's SwiGLU, and are added back through the transposed one-hot.
+    """
+    T, M = x.shape
+    k, held = idx.shape[1], cfg.n_held
+    n = T * k
+    tm = _tile_rows(T)
+    local = idx - cfg.first
+    on = (local >= 0) & (local < held)
+    key = jnp.where(on, local, held).reshape(n)
+    order = jnp.argsort(key, stable=True)
+    pad = jnp.zeros((tm,), jnp.int32)
+    tok_s = jnp.concatenate([(order // k).astype(jnp.int32), pad])
+    gate_s = jnp.concatenate([gate.reshape(n)[order], pad.astype(gate.dtype)])
+    loads = (key[:, None] == jnp.arange(held)[None]).sum(0).astype(jnp.int32)
+    ends = jnp.cumsum(loads)
+    starts = ends - loads
+    tiles = -(-loads // tm)                        # tiles of expert e
+    tile_ends = jnp.cumsum(tiles)
+    tokens = jnp.arange(T, dtype=jnp.int32)
+
+    def tile(t, y):
+        e = jnp.searchsorted(tile_ends, t, side="right").astype(jnp.int32)
+        off = starts[e] + (t - (tile_ends[e] - tiles[e])) * tm
+        rows = off + jnp.arange(tm, dtype=jnp.int32)
+        valid = rows < ends[e]
+        tok = jax.lax.dynamic_slice(tok_s, (off,), (tm,))
+        g = jnp.where(valid, jax.lax.dynamic_slice(gate_s, (off,), (tm,)), 0)
+        pick = ((tok[:, None] == tokens[None]) & valid[:, None]).astype(x.dtype)
+        xt = jnp.einsum("rt,tm->rm", pick, x)
+
+        def w(name):
+            stack = experts[name]
+            return jax.lax.dynamic_slice(
+                stack, (layer, e, 0, 0), (1, 1) + stack.shape[2:])[0, 0]
+
+        out = swiglu(xt, w("we_gate"), w("we_up"), w("we_down"))
+        out = (out.astype(jnp.float32) * g[:, None]).astype(x.dtype)
+        return y + jnp.einsum("rt,rm->tm", pick, out,
+                              preferred_element_type=jnp.float32)
+
+    y = jax.lax.fori_loop(0, tile_ends[-1], tile,
+                          jnp.zeros((T, M), jnp.float32))
+    return y, loads

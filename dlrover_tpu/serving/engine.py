@@ -6,8 +6,11 @@ continuous batching: requests join and leave a fixed slot batch between
 decode iterations, so the accelerator always steps a full batch instead
 of waiting for the longest sequence. TPU-natively that becomes THREE
 compiled programs total (prefill, slot-install, decode-step) over a
-per-row-position KV cache (models/decode.py forward_cached with vector
-``pos``):
+per-row-position cache (models/decode.py forward_cached with vector
+``pos``). The cache is the model's own TREE of ``[L, B, max_len, ...]``
+stacks (DESIGN.md §23.5: ``k`` and ``v`` per head, or one latent
+stack); these programs carry, write and donate it whole and name none
+of its stacks:
 
 - **prefill**: [1, prefill_len] forward chunks filling a working cache
   row — long prompts loop the SAME compiled chunk (cache position
@@ -101,9 +104,12 @@ from dlrover_tpu.common import envspec
 from dlrover_tpu.common.constants import EnvKey
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.models.decode import (
+    cache_counter_fields,
+    cache_stacks,
     forward_cached,
     init_cache,
     sample_logits,
+    zero_counters,
 )
 from dlrover_tpu.models.transformer import TransformerConfig
 from dlrover_tpu.serving.observatory import (
@@ -135,6 +141,26 @@ _decode_stall_seconds = registry().histogram(
     buckets=(0.001, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0,
              2.5, 5.0),
 )
+
+
+# gauges for a scrape of what a model's cache counters say of the newest
+# decode call, keyed by the counter's name (the model names its
+# counters, DESIGN.md §23.5; metric names are literals, which the
+# metric-name lint holds the package to, so a counter that wants a
+# gauge is listed here; a model that counts nothing sets none)
+_COUNTER_GAUGES = {
+    "expert_tokens": registry().gauge(
+        "dlrover_tpu_engine_expert_tokens",
+        "token-expert assignments that landed on held experts in the "
+        "engine's newest decode call, summed over its layers and steps "
+        "(every row of the slot batch routes, idle slots too)",
+        label_names=("engine",)),
+    "expert_load_max_over_mean": registry().gauge(
+        "dlrover_tpu_engine_expert_load_max_over_mean",
+        "the busiest held expert's assignments in the newest decode "
+        "call over the mean of all held experts (1.0: even)",
+        label_names=("engine",)),
+}
 _decoding_slots = registry().gauge(
     "dlrover_tpu_engine_decoding_slots",
     "slots that took part in the engine's newest decode call (0 when "
@@ -253,8 +279,9 @@ class Result:
 class KVBundle:
     """Prefilled KV handed from a prefill engine to a decode engine.
 
-    Page-granular and host-resident: ``k``/``v`` are
-    ``[L, n_pages, page_size, kv_heads, head_dim]`` numpy arrays
+    Page-granular and host-resident: ``stacks`` maps each stack of the
+    model's cache tree (``k`` and ``v``, or one ``latent``) to a
+    ``[L, n_pages, page_size, ...]`` numpy array
     covering only the pages the prompt actually filled, so the handoff
     ships ``ceil(prompt/page)`` pages, never a full max_len row. Plain
     numpy means the same bundle travels in-process (jnp.asarray at
@@ -262,8 +289,7 @@ class KVBundle:
     array_wire / shm ckpt framing.
     """
 
-    k: Any
-    v: Any
+    stacks: dict
     pos: int                   # true prompt length
     last: Any                  # [vocab] float32 logits of the last token
     page_size: int
@@ -278,9 +304,7 @@ class _PrefillRun:
     """One in-flight chunked prefill (admission or prefill-pool)."""
 
     prompt: list[int]
-    row_k: Any
-    row_v: Any
-    pos: Any
+    row: Any                   # the working row: a [L, 1, max_len, ...] cache tree
     last: Any
     next_lo: int               # next chunk start offset
     start: int                 # where prefill resumed (prefix-cache hit)
@@ -392,6 +416,12 @@ class InferenceEngine:
         self.kv_pages = int(kv_pages)
         self.pages_per_slot = self.max_len // self.page_size
         self._paging = self.kv_pages > 0
+        if self._paging and cfg.attn_kind != "heads":
+            raise NotImplementedError(
+                f"kv_pages > 0 with attn_kind {cfg.attn_kind!r}: the paged "
+                "store, park/resume and copy-on-write hold [kv_heads, "
+                "head_dim] pages; a latent cache has none (run it with "
+                "kv_pages=0)")
         if self._paging:
             c = cfg
             pool_shape = (c.n_layers, self.kv_pages + 1, self.page_size,
@@ -489,14 +519,25 @@ class InferenceEngine:
         self.spec_drafts_scored = 0
         self.spec_collapsed_total = 0
 
+        # The cache is the MODEL's tree (DESIGN.md §23.5): `pos`, stacks
+        # laid out [L, B, max_len, ...] under names this engine never
+        # reads (`cache_stacks`), and counters the model may keep. The
+        # stacks are never copied whole (§23.1): every program that
+        # returns the tree DONATES it and updates it in place. So
+        # nobody keeps a reference to `_cache` (or `_last`) across a
+        # call that returns them; the engine rebinds both from the
+        # call's outputs.
         self._cache = init_cache(cfg, slots, self.max_len)
         self._cache["pos"] = jnp.zeros((slots,), jnp.int32)
         self._last = jnp.zeros((slots, cfg.vocab_size), jnp.float32)
-        # The stack is never copied whole (DESIGN.md §23.1): every
-        # program that returns it DONATES it and updates it in place.
-        # So nobody keeps a reference to `_cache["k"]` / `["v"]` (or
-        # `["pos"]`, `_last`) across a call that returns them; the
-        # engine rebinds all four from the call's outputs.
+        # what a token costs to keep, over all layers and stacks
+        self.cache_bytes_per_token = sum(
+            int(np.prod(a.shape[3:])) * a.shape[0] * a.dtype.itemsize
+            for a in cache_stacks(self._cache).values())
+        # what a working row carries beside stacks and position
+        self._row_extras = {
+            k: v for k, v in init_cache(cfg, 1, 1).items()
+            if k == "counters"}
         # per-slot sampling randomness: a seed per REQUEST + a count of
         # tokens sampled so far — the per-draw key is derived from both,
         # so a request's stream never depends on batch composition
@@ -505,33 +546,30 @@ class InferenceEngine:
         self._seed_gen = np.random.default_rng(0)
 
         # --- compiled programs ---------------------------------------
-        def _prefill_chunk(params, tokens, k, v, pos, true_len):
-            # one prefill_len chunk into a [1, max_len] working cache;
-            # long prompts loop this program (cache pos carries across
-            # chunks, so only the FINAL chunk may be pad-tailed — a
-            # mid-sequence pad would sit under later queries' causal
-            # mask). Returns the last REAL token's logits of the chunk.
-            cache = {"k": k, "v": v, "pos": pos}
-            logits, cache = forward_cached(params, tokens, cache, cfg)
-            last = logits[0, true_len - 1]
-            return cache["k"], cache["v"], cache["pos"], last
+        def _prefill_chunk(params, tokens, row, true_len):
+            # one prefill_len chunk into a [1, max_len] working row;
+            # long prompts loop this program (the row's pos carries
+            # across chunks, so only the FINAL chunk may be pad-tailed —
+            # a mid-sequence pad would sit under later queries' causal
+            # mask). Returns the last REAL token's logits of the chunk
+            # and what the model counted in it.
+            logits, row = forward_cached(params, tokens,
+                                         zero_counters(row), cfg)
+            return row, logits[0, true_len - 1], cache_counter_fields(row)
 
         self._prefill_chunk = jax.jit(_prefill_chunk)
 
-        def _install(cache_k, cache_v, pos, last_all, row_k, row_v,
-                     last_row, slot, true_len):
-            # write the prefilled row into slot `slot` of the big cache
-            cache_k = lax.dynamic_update_index_in_dim(
-                cache_k, row_k[:, 0], slot, axis=1
-            )
-            cache_v = lax.dynamic_update_index_in_dim(
-                cache_v, row_v[:, 0], slot, axis=1
-            )
-            pos = pos.at[slot].set(true_len)
-            last_all = last_all.at[slot].set(last_row)
-            return cache_k, cache_v, pos, last_all
+        def _install(cache, last_all, row, last_row, slot, true_len):
+            # write the prefilled row into slot `slot` of every stack
+            stacks = {
+                name: lax.dynamic_update_index_in_dim(
+                    stack, row[name][:, 0], slot, axis=1)
+                for name, stack in cache_stacks(cache).items()}
+            cache = {**cache, **stacks,
+                     "pos": cache["pos"].at[slot].set(true_len)}
+            return cache, last_all.at[slot].set(last_row)
 
-        self._install = jax.jit(_install, donate_argnums=(0, 1, 2, 3))
+        self._install = jax.jit(_install, donate_argnums=(0, 1))
 
         if self._paging:
             L = cfg.n_layers
@@ -580,7 +618,7 @@ class InferenceEngine:
                 )
             )(seeds, counts)
 
-        def _step_block(params, k, v, pos, last, seeds, counts,
+        def _step_block(params, cache, last, seeds, counts,
                         temperature, top_k, top_p, active, eos_ids,
                         n_steps):
             # per-row sampling params as VECTORS: one compiled program
@@ -590,38 +628,37 @@ class InferenceEngine:
             # after the block, so the batchmates never drop to
             # token-at-a-time decode.
             def body(carry, i):
-                k, v, pos, last, done = carry
+                cache, last, done = carry
                 nxt = sample_logits(
                     last, _row_keys(seeds, counts + i), temperature,
                     top_k, top_p,
                 )
                 nxt = jnp.where(done, jnp.maximum(eos_ids, 0), nxt)
                 hit = (eos_ids >= 0) & (nxt == eos_ids)
-                cache = {"k": k, "v": v, "pos": pos}
-                logits, cache = forward_cached(
+                logits, new = forward_cached(
                     params, nxt[:, None], cache, cfg
                 )
                 # inactive/finished rows must not advance (their pos
                 # would creep past max_len and clamp the next install's
                 # attention)
                 run = active & ~done
-                new_pos = jnp.where(run, cache["pos"], pos)
-                return (cache["k"], cache["v"], new_pos,
-                        logits[:, 0], done | hit), nxt
+                new["pos"] = jnp.where(run, new["pos"], cache["pos"])
+                return (new, logits[:, 0], done | hit), nxt
 
             done0 = jnp.zeros(active.shape, bool)
-            (k, v, pos, last, _), toks = lax.scan(
-                body, (k, v, pos, last, done0), jnp.arange(n_steps)
+            (cache, last, _), toks = lax.scan(
+                body, (zero_counters(cache), last, done0),
+                jnp.arange(n_steps)
             )
-            return toks, k, v, pos, last
+            return toks, cache, last, cache_counter_fields(cache)
 
         self._step_block = jax.jit(
             _step_block, static_argnames=("n_steps",),
-            donate_argnums=(1, 2, 3, 4),
+            donate_argnums=(1, 2),
             compiler_options=_CANONICAL_NUMERICS,
         )
 
-        def _verify_block(params, k, v, pos, last, seeds, counts,
+        def _verify_block(params, cache, last, seeds, counts,
                           temperature, top_k, top_p, active, eos_ids,
                           guesses):
             # speculative verify (§31): ONE wide forward checks a
@@ -648,8 +685,9 @@ class InferenceEngine:
             fed = jnp.concatenate(
                 [x0[:, None], jnp.maximum(guesses[:, 1:], 0)], axis=1
             )
-            cache = {"k": k, "v": v, "pos": pos}
-            logits, cache = forward_cached(params, fed, cache, cfg)
+            pos = cache["pos"]
+            logits, cache = forward_cached(params, fed,
+                                           zero_counters(cache), cfg)
             toks = [x0]
             for i in range(1, n):
                 toks.append(sample_logits(
@@ -671,12 +709,12 @@ class InferenceEngine:
             sel = jnp.maximum(acc - 1, 0)
             new_last = jax.vmap(lambda row, i: row[i])(logits, sel)
             new_last = jnp.where(active[:, None], new_last, last)
-            new_pos = jnp.where(active, pos + acc, pos)
-            return (toks, cache["k"], cache["v"], new_pos, new_last,
-                    acc)
+            cache["pos"] = jnp.where(active, pos + acc, pos)
+            return (toks, cache, new_last, acc,
+                    cache_counter_fields(cache))
 
         self._verify_block = jax.jit(
-            _verify_block, donate_argnums=(1, 2, 3, 4),
+            _verify_block, donate_argnums=(1, 2),
             compiler_options=_CANONICAL_NUMERICS,
         )
         # per-depth AOT verify programs (warm_aot_verify); missing
@@ -700,16 +738,17 @@ class InferenceEngine:
         digest. The digest keys on facts, not on the program's text, so
         what makes two builds' executables NOT interchangeable has to
         be a fact: one compiled WITHOUT canonical numerics (§31
-        spec-on/off identity), and one that does not donate the stack
-        (§23.1: this build's, loaded by a build that keeps the stack
-        it passed in, would delete buffers its caller still reads;
-        that build's, loaded here, would hold two stacks). Older
-        entries must miss here, and these must miss there."""
+        spec-on/off identity), and one that does not take and donate
+        the cache as the model's tree (§23.1, §23.5: this build's,
+        loaded by a build that keeps the stack it passed in, would
+        delete buffers its caller still reads; that build's, loaded
+        here, would hold two stacks). Older entries must miss here,
+        and these must miss there."""
         return {"kind": kind, "slots": self.slots,
                 "max_len": self.max_len,
                 "prefill_len": self.prefill_len, **facts,
                 "numerics": "canonical",
-                "kv_stack": "carried-donated"}
+                "kv_stack": "tree-carried-donated"}
 
     def _step_sample_args(self) -> tuple:
         """The exact runtime argument tuple of a decode step (zero
@@ -717,8 +756,7 @@ class InferenceEngine:
         performs — lowering against these pins the true avals."""
         temp, top_k, top_p, eos_ids = self._sampling_tensors()
         active = np.zeros((self.slots,), bool)
-        return (self.params, self._cache["k"], self._cache["v"],
-                self._cache["pos"], self._last,
+        return (self.params, self._cache, self._last,
                 jnp.asarray(self._seeds), jnp.asarray(self._sampled),
                 temp, top_k, top_p, jnp.asarray(active), eos_ids)
 
@@ -726,10 +764,13 @@ class InferenceEngine:
         """Compile-or-load the n_steps=1 decode-step program through the
         elastic compile cache; returns the ``AotStep`` evidence (None
         when jax/caching is unavailable). Safe to skip: the jit path
-        stays fully functional. The engine's params/cache are laundered
-        first — a deserialized ``Compiled`` skips pjit's input
-        re-staging, and host-built trees must own proper per-device
-        buffers before it ever sees them (DESIGN.md §17.4)."""
+        stays fully functional. What the program DONATES (the cache
+        tree and ``last``) is laundered first — a deserialized
+        ``Compiled`` skips pjit's input re-staging and updates donated
+        buffers in place, so host-built trees must own proper
+        per-device buffers before it ever sees them (DESIGN.md §17.4).
+        The weights are only read: a copy of them would hold the model
+        twice, which a model sized to the chip does not survive."""
         from dlrover_tpu.parallel.compile_cache import (
             abstract_signature,
             compile_fingerprint,
@@ -738,7 +779,6 @@ class InferenceEngine:
         )
 
         try:
-            self._params = launder(self._params)
             self._cache = launder(self._cache)
             self._last = launder(self._last)
             self._samp_cache = None
@@ -789,7 +829,6 @@ class InferenceEngine:
                 d *= 2
         out = []
         try:
-            self._params = launder(self._params)
             self._cache = launder(self._cache)
             self._last = launder(self._last)
             self._samp_cache = None
@@ -899,7 +938,7 @@ class InferenceEngine:
 
     def _prefix_lookup(self, prompt: list[int]):
         """Longest chunk-aligned cached prefix of ``prompt``; returns
-        ``(start, (row_k, row_v, pos, last))`` or ``None``. jax arrays
+        ``(start, (row, last))`` or ``None``. jax arrays
         are immutable, so handing out the stored row is alias-safe.
 
         Probe depth is capped by the set of key lengths actually stored
@@ -941,8 +980,7 @@ class InferenceEngine:
         """Start a chunked prefill into a fresh working row (resuming
         from the longest cached aligned prefix). Drives both admission
         and the disaggregated prefill pool."""
-        work = init_cache(self.cfg, 1, self.max_len)
-        row_k, row_v, pos = work["k"], work["v"], work["pos"]
+        row = None
         last = None
         start = 0
         if self.prefix_cache_entries:
@@ -950,14 +988,16 @@ class InferenceEngine:
             _prefix_cache_queries_total.inc()
             hit = self._prefix_lookup(prompt)
             if hit is not None:
-                start, (row_k, row_v, pos, last) = hit
+                start, (row, last) = hit
                 self.prefix_cache_hits += 1
                 _prefix_cache_hits_total.inc()
             _prefix_cache_entries.labels(self.engine_id).set(
                 len(self._prefix_cache)
             )
+        if row is None:
+            row = init_cache(self.cfg, 1, self.max_len)
         return _PrefillRun(
-            prompt=list(prompt), row_k=row_k, row_v=row_v, pos=pos,
+            prompt=list(prompt), row=row,
             last=last, next_lo=start, start=start,
             done=start >= len(prompt),
         )
@@ -974,12 +1014,12 @@ class InferenceEngine:
         chunk = run.prompt[lo: lo + P]
         with hot_span("prefill_chunk", remote_parent=run.sctx,
                       request=run.request, tokens=len(chunk),
-                      chunk=run.chunks, context=lo):
+                      chunk=run.chunks, context=lo) as span:
             toks = np.zeros((1, P), np.int32)
             toks[0, : len(chunk)] = chunk
-            run.row_k, run.row_v, run.pos, run.last = self._prefill_chunk(
-                self.params, jnp.asarray(toks), run.row_k, run.row_v,
-                run.pos, jnp.asarray(len(chunk), jnp.int32),
+            run.row, run.last, counted = self._prefill_chunk(
+                self.params, jnp.asarray(toks), run.row,
+                jnp.asarray(len(chunk), jnp.int32),
             )
             final_top = len(run.prompt) // P * P
             if self.prefix_cache_entries and len(chunk) == P:
@@ -993,12 +1033,14 @@ class InferenceEngine:
                 if lo + P == final_top or run.start > 0:
                     self._prefix_store(
                         tuple(run.prompt[: lo + P]),
-                        (run.row_k, run.row_v, run.pos, run.last),
+                        (run.row, run.last),
                     )
             run.next_lo = lo + P
             run.chunks += 1
             run.done = run.next_lo >= len(run.prompt)
             jax.block_until_ready(run.last)
+            # the chunk is done: its counters cost no wait of their own
+            span.set(**_counted(jax.device_get(counted)))
         run.work_s += time.monotonic() - t0
         return run.done
 
@@ -1012,14 +1054,15 @@ class InferenceEngine:
         n_pages = -(-n_tok // P)
         # device_get can return views of device buffers on CPU — copy,
         # so the bundle owns its bytes wherever it travels
-        rk = np.ascontiguousarray(
-            np.asarray(jax.device_get(run.row_k))[:, 0, : n_pages * P])
-        rv = np.ascontiguousarray(
-            np.asarray(jax.device_get(run.row_v))[:, 0, : n_pages * P])
-        shape = (rk.shape[0], n_pages, P) + rk.shape[2:]
+        stacks = {}
+        for name, stack in cache_stacks(run.row).items():
+            rows = np.ascontiguousarray(
+                np.asarray(jax.device_get(stack))[:, 0, : n_pages * P])
+            stacks[name] = rows.reshape(
+                (rows.shape[0], n_pages, P) + rows.shape[2:])
         top = n_tok // self.prefill_len * self.prefill_len
         return KVBundle(
-            k=rk.reshape(shape), v=rv.reshape(shape), pos=n_tok,
+            stacks=stacks, pos=n_tok,
             last=np.asarray(jax.device_get(run.last)),
             page_size=P, prefix_key=tuple(run.prompt[:top]),
         )
@@ -1034,23 +1077,21 @@ class InferenceEngine:
                 f"bundle page_size {b.page_size} != engine page_size "
                 f"{self.page_size}"
             )
-        covered = b.k.shape[1] * b.page_size
-        L = b.k.shape[0]
-
         def pad(pages):
             # one fresh buffer per tensor: CPU device_put may ADOPT an
-            # aligned writable host buffer (DESIGN.md §17.4), so k and
-            # v must never share one staging array
+            # aligned writable host buffer (DESIGN.md §17.4), so two
+            # stacks must never share one staging array
+            L, covered = pages.shape[0], pages.shape[1] * b.page_size
             row = np.zeros((L, 1, self.max_len) + pages.shape[3:],
                            dtype=pages.dtype)
             row[:, 0, :covered] = pages.reshape(
                 (L, covered) + pages.shape[3:])
             return jnp.asarray(row)
 
-        row_k, row_v = pad(b.k), pad(b.v)
+        row = {name: pad(pages) for name, pages in b.stacks.items()}
+        row.update(self._row_extras, pos=jnp.asarray(b.pos, jnp.int32))
         return _PrefillRun(
-            prompt=list(req.prompt), row_k=row_k, row_v=row_v,
-            pos=jnp.asarray(b.pos, jnp.int32),
+            prompt=list(req.prompt), row=row,
             last=jnp.asarray(b.last), next_lo=len(req.prompt),
             start=0, done=True,
         )
@@ -1298,10 +1339,8 @@ class InferenceEngine:
 
     def _install_admit(self, slot: int, pa: _PendingAdmit) -> None:
         req, run = pa.req, pa.run
-        (self._cache["k"], self._cache["v"], self._cache["pos"],
-         self._last) = self._install(
-            self._cache["k"], self._cache["v"], self._cache["pos"],
-            self._last, run.row_k, run.row_v, run.last,
+        self._cache, self._last = self._install(
+            self._cache, self._last, run.row, run.last,
             jnp.asarray(slot, jnp.int32),
             jnp.asarray(len(req.prompt), jnp.int32),
         )
@@ -1332,9 +1371,11 @@ class InferenceEngine:
             _kv_handoffs_total.inc()
             journal.emit(
                 "kv_handoff", request=req.id,
-                pages=int(req.bundle.k.shape[1]),
+                pages=int(next(iter(
+                    req.bundle.stacks.values())).shape[1]),
                 tokens=len(req.prompt),
-                bytes=int(req.bundle.k.nbytes + req.bundle.v.nbytes),
+                bytes=int(sum(a.nbytes
+                              for a in req.bundle.stacks.values())),
                 remote_parent=req.sctx,
             )
 
@@ -1565,8 +1606,7 @@ class InferenceEngine:
         decoding = int(active_mask.sum())
         temp, top_k, top_p, eos_ids = self._sampling_tensors()
         args = (
-            self.params, self._cache["k"], self._cache["v"],
-            self._cache["pos"], self._last,
+            self.params, self._cache, self._last,
             jnp.asarray(self._seeds), jnp.asarray(self._sampled),
             temp, top_k, top_p, jnp.asarray(active_mask), eos_ids,
         )
@@ -1575,11 +1615,14 @@ class InferenceEngine:
             depth, guesses = plan
             n_steps = depth
             fn = self._aot_verify.get(depth, self._verify_block)
-            with hot_span("decode_block", slots=decoding, n_steps=depth):
-                toks_dev, k, v, pos, last, acc_dev = fn(
+            with hot_span("decode_block", slots=decoding,
+                          n_steps=depth) as span:
+                toks_dev, cache, last, acc_dev, counted = fn(
                     *args, jnp.asarray(guesses))
-                toks_sn, acc = (np.asarray(a) for a in
-                                jax.device_get((toks_dev, acc_dev)))
+                toks_sn, acc, counted = jax.device_get(
+                    (toks_dev, acc_dev, counted))
+                toks_sn, acc = np.asarray(toks_sn), np.asarray(acc)
+                span.set(**self._note_counted(counted))
             toks = toks_sn.T                     # [depth, slots]
             counts = acc.astype(np.int64)        # inactive rows: 0
             self._sampled += counts
@@ -1592,22 +1635,32 @@ class InferenceEngine:
             self._spec_score(guesses, toks_sn, depth)
         else:
             n_steps = block = self._block_size()
-            with hot_span("decode_block", slots=decoding, n_steps=block):
+            with hot_span("decode_block", slots=decoding,
+                          n_steps=block) as span:
                 if block == 1 and self._aot_step is not None:
-                    toks_dev, k, v, pos, last = self._aot_step(*args)
+                    toks_dev, cache, last, counted = self._aot_step(*args)
                 else:
-                    toks_dev, k, v, pos, last = self._step_block(
+                    toks_dev, cache, last, counted = self._step_block(
                         *args, n_steps=block,
                     )
-                toks = np.asarray(jax.device_get(toks_dev))
+                # the model's counters ride the tokens' device_get
+                toks, counted = jax.device_get((toks_dev, counted))
+                toks = np.asarray(toks)
+                span.set(**self._note_counted(counted))
             self._sampled[active_mask] += block
             counts = np.where(active_mask, block, 0)
-        self._cache["k"], self._cache["v"] = k, v
-        self._cache["pos"] = pos
-        self._last = last
+        self._cache, self._last = cache, last
         with hot_span("engine_emit", tokens=int(counts.sum())):
             self._emit(toks, counts)
         return decoding, n_steps, sum(r is not None for r in self._active)
+
+    def _note_counted(self, counted: dict) -> dict:
+        """A decode call's counters as span fields, and onto their
+        gauges (none for a model that counts nothing)."""
+        fields = _counted(counted)
+        for name in fields.keys() & _COUNTER_GAUGES.keys():
+            _COUNTER_GAUGES[name].labels(self.engine_id).set(fields[name])
+        return fields
 
     def _emit(self, toks, counts) -> None:
         """The host's share of a step: every new token to its request
@@ -1746,6 +1799,13 @@ class InferenceEngine:
             )
         out, self._results = self._results, []
         return out
+
+
+def _counted(counted: dict) -> dict:
+    """Fetched counter scalars as plain numbers for a span's fields."""
+    return {name: (float(v) if np.issubdtype(np.asarray(v).dtype,
+                                             np.floating) else int(v))
+            for name, v in counted.items()}
 
 
 def check_kv_ledgers() -> list[str]:

@@ -502,6 +502,11 @@ class ServingObservatory:
             "spec_scored": spec_scored,
             "spec_collapsed": int(
                 getattr(eng, "spec_collapsed_total", 0)),
+            # what one cached token costs over all layers and stacks:
+            # the model's cache tree decides (per-head keys and values,
+            # or one latent row a layer)
+            "cache_bytes_per_token": int(
+                getattr(eng, "cache_bytes_per_token", 0)),
         }
         eid = eng.engine_id
         _pages_free.labels(eid).set(free)

@@ -206,7 +206,7 @@ def test_prefill_engine_chunks_and_bundles(params):
     [res] = eng.poll_results()
     assert res.id == rid and res.chunks == 3
     assert res.bundle.pos == 19
-    assert res.bundle.k.shape[1] == 3        # ceil(19/8) pages shipped
+    assert res.bundle.stacks["k"].shape[1] == 3        # ceil(19/8) pages shipped
     with pytest.raises(ValueError):
         eng.submit([])
 

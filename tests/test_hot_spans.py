@@ -452,7 +452,7 @@ def test_decoding_slots_is_the_active_masks_count(journal_dir):
 
         def spy(*args, **kw):
             nonlocal mask
-            mask = np.asarray(args[10])
+            mask = np.asarray(args[8])  # (params, cache, last, seeds, ...)
             return real(*args, **kw)
 
         real = eng._step_block

@@ -636,8 +636,7 @@ def test_programs_that_return_the_stack_donate_it(params, program):
                           prefill_len=8, kv_pages=16)
     step = eng._step_sample_args()
     stack = "x".join(map(str, eng._cache["k"].shape)) + "xbf16"
-    row = jnp.zeros((CFG.n_layers, 1) + eng._cache["k"].shape[2:],
-                    eng._cache["k"].dtype)
+    row = eng.prefill_begin([1, 2, 3]).row
     slot = jnp.asarray(0, jnp.int32)
     table = jnp.zeros((eng.pages_per_slot,), jnp.int32)
     lowered = {
@@ -645,8 +644,7 @@ def test_programs_that_return_the_stack_donate_it(params, program):
         "_verify_block": lambda: eng._verify_block.lower(
             *step, jnp.full((eng.slots, 4), -1, jnp.int32)),
         "_install": lambda: eng._install.lower(
-            eng._cache["k"], eng._cache["v"], eng._cache["pos"],
-            eng._last, row, row, eng._last[0], slot, slot),
+            eng._cache, eng._last, row, eng._last[0], slot, slot),
         "_resume_install": lambda: eng._resume_install.lower(
             eng._cache["k"], eng._cache["v"], eng._cache["pos"],
             eng._last, eng._kpool, eng._vpool, table, slot, slot,
@@ -669,8 +667,8 @@ def test_prefill_chunk_donates_nothing(params):
                           prefill_len=8, prefix_cache_entries=2)
     run = eng.prefill_begin([1, 2, 3])
     lowered = eng._prefill_chunk.lower(
-        eng.params, jnp.zeros((1, 8), jnp.int32), run.row_k, run.row_v,
-        run.pos, jnp.asarray(3, jnp.int32))
+        eng.params, jnp.zeros((1, 8), jnp.int32), run.row,
+        jnp.asarray(3, jnp.int32))
     assert _donated(lowered) == []
 
 

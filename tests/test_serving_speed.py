@@ -387,21 +387,22 @@ def test_every_path_crossed_after_donated_steps(params, monkeypatch):
                           prefix_cache_entries=4)
     gave_away = {}
 
-    def watch(name, first):
+    def watch(name, first, n):
         real = getattr(eng, name)
 
         def call(*args, **kw):
             out = real(*args, **kw)
-            stale = args[first:first + 4]       # k, v, pos, last
+            # the cache tree and last (the paged twin: k, v, pos, last)
+            stale = jax.tree.leaves(args[first:first + n])
             assert all(a.is_deleted() for a in stale), name
             gave_away[name] = gave_away.get(name, 0) + 1
             return out
 
         setattr(eng, name, call)
 
-    for name, first in (("_install", 0), ("_resume_install", 0),
-                        ("_step_block", 1), ("_verify_block", 1)):
-        watch(name, first)
+    for name, first, n in (("_install", 0, 2), ("_resume_install", 0, 4),
+                           ("_step_block", 1, 2), ("_verify_block", 1, 2)):
+        watch(name, first, n)
 
     assert _drain(eng, wave) == want[:-1]
     assert eng.kv_parked_total > 0 and eng.spec_steps_total > 0

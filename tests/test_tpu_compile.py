@@ -174,5 +174,51 @@ def test_serving_programs_compile(one_chip):
     row = on_chip(init_cache(cfg, 1, eng.max_len))
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     eng._prefill_chunk.lower(
-        on_chip(eng.params), chunk, row["k"], row["v"], row["pos"], scalar
+        on_chip(eng.params), chunk, row, scalar
     ).compile()
+
+
+def test_latent_expert_serving_programs_compile(one_chip):
+    """Decode step and prefill chunk of ``InferenceEngine`` for the
+    latent-attention / routed-expert kinds at openPangu-Ultra-MoE's
+    published widths: one dense and one expert layer, 16 of 256 experts
+    held, an eighth of the vocabulary, weights resting in bfloat16. What
+    the CPU cannot show: the tile loop with a data trip count inside the
+    layer scan, the expert stacks read in place (no copy of a stack in
+    the program), the latent cache donated."""
+    from dlrover_tpu.models import latent
+    from dlrover_tpu.serving import engine as serving
+
+    cfg = dataclasses.replace(
+        tfm.CONFIGS["openpangu-ultra-moe-718b"], n_layers=2,
+        first_k_dense=1, experts_held=16, vocab_size=19200)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip),
+        latent.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    eng = serving.InferenceEngine(params, cfg, slots=16, max_len=5120,
+                                  prefill_len=512)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: a if isinstance(a, jax.ShapeDtypeStruct)
+            else jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                      sharding=one_chip), tree)
+
+    step = eng._step_block.lower(
+        *on_chip(eng._step_sample_args()), n_steps=1
+    ).compile(compiler_options=serving._CANONICAL_NUMERICS)
+    stack = eng._cache["latent"]
+    assert stack.shape == (2, 16, 5120, 576)
+    m = step.memory_analysis()
+    assert m.alias_size_in_bytes >= stack.size * 2      # donated whole
+    weights = cfg.param_count * 2
+    # beside the weights and the cache: no second copy of an expert stack
+    # (1.5e9 here) among the temporaries
+    assert m.temp_size_in_bytes < 1.0e9 < weights < _device_bytes(step)
+    assert _device_bytes(step) < HBM_BYTES
+    row = on_chip(jax.eval_shape(lambda: latent.init_cache(cfg, 1, 5120)))
+    chunk = eng._prefill_chunk.lower(
+        params, jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip),
+        row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile()
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
