@@ -147,6 +147,40 @@ def _write_rows(stack, new, layer, pos):
     return stack
 
 
+# The leaves `forward_cached` multiplies or adds in ``cfg.dtype``: its
+# `cast()` takes a leaf by one of these names and no other. The norm
+# scales and biases (`_norm` rounds them itself) and the capacity-routed
+# experts (`moe_ffn` reads them as they are) are not among them.
+PRODUCT_LEAVES = frozenset({
+    "embed", "pos_embed", "wq", "wk", "wv", "wo", "w_gate", "w_up",
+    "w_down", "b_ff", "b_out", "lm_head"})
+
+
+def weights_at_rest(params: Params, cfg: TransformerConfig) -> Params:
+    """``params`` as a holder that calls `forward_cached` many times
+    keeps them (``serving/engine.py``): the `PRODUCT_LEAVES`, wherever
+    they sit in the tree, in ``cfg.dtype``, so that no call converts
+    them again; every other leaf as it is. The same function, to the
+    bit: rounding a weight once gives what rounding it a call gives.
+
+    What decides is the leaf's dtype, which the code can see. A leaf
+    already in ``cfg.dtype`` comes back as the SAME array, so a tree
+    that rests there (``param_dtype``) is not held twice; the others
+    are converted one at a time, outside any ``jit`` (which would hand
+    back a copy of what it only passes through), and the tree returned
+    holds no float32 leaf of the list: what was handed in lives as long
+    as its owner keeps it."""
+    dt = jnp.dtype(cfg.dtype)
+
+    def rest(path, leaf):
+        name = getattr(path[-1], "key", None)
+        if name not in PRODUCT_LEAVES or leaf.dtype == dt:
+            return leaf
+        return jnp.asarray(leaf, dt)
+
+    return jax.tree_util.tree_map_with_path(rest, params)
+
+
 def forward_cached(
     params: Params, tokens: jax.Array, cache: dict,
     cfg: TransformerConfig,
@@ -171,26 +205,29 @@ def forward_cached(
     scalar_pos = jnp.ndim(pos) == 0  # static at trace time
     n_rep = c.n_heads // c.n_kv_heads
 
-    def cast(weight):
-        # the stored (float32) weights, converted on every call: a
-        # scope of its own, so that the trace can price it
+    def cast(tree, name):
+        # a leaf the products read, in their dtype. Nothing at all on a
+        # tree from `weights_at_rest` (the engine's); on training's
+        # float32 tree (`generate`, the RL rollouts) a conversion on
+        # every call, in a scope of its own so that a trace prices it
+        assert name in PRODUCT_LEAVES, name
         with jax.named_scope("weight_cast"):
-            return weight.astype(dt)
+            return tree[name].astype(dt)
 
     if scalar_pos:
         positions = pos + jnp.broadcast_to(jnp.arange(S_new), (B, S_new))
     else:
         positions = pos[:, None] + jnp.arange(S_new)[None]
-    x = cast(params["embed"])[tokens]
+    x = cast(params, "embed")[tokens]
     if c.variant == "gpt2":
         if scalar_pos:
             pe = lax.dynamic_slice_in_dim(
-                cast(params["pos_embed"]), pos, S_new, axis=0
+                cast(params, "pos_embed"), pos, S_new, axis=0
             )[None]
         else:
             # gather (not slice): per-row positions; clamp keeps the
             # lookup in-table for padded/inactive rows
-            pe = cast(params["pos_embed"])[
+            pe = cast(params, "pos_embed")[
                 jnp.clip(positions, 0, c.max_seq_len - 1)
             ]
         x = x + pe
@@ -222,13 +259,13 @@ def forward_cached(
         w, l = inputs
         with jax.named_scope("attn"):
             h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
-            q = jnp.einsum("bse,ehd->bshd", h, cast(w["wq"]))
+            q = jnp.einsum("bse,ehd->bshd", h, cast(w, "wq"))
             if c.mup_base_width:
                 # same order as training: scale before rope (they
                 # commute, but keep the copies textually aligned)
                 q = q / math.sqrt(c.head_dim)
-            k = jnp.einsum("bse,ehd->bshd", h, cast(w["wk"]))
-            v = jnp.einsum("bse,ehd->bshd", h, cast(w["wv"]))
+            k = jnp.einsum("bse,ehd->bshd", h, cast(w, "wk"))
+            v = jnp.einsum("bse,ehd->bshd", h, cast(w, "wv"))
             if c.variant == "llama":
                 q = _rope(q, positions, c.rope_theta)
                 k = _rope(k, positions, c.rope_theta)
@@ -244,7 +281,7 @@ def forward_cached(
                 lax.dynamic_index_in_dim(v_stack, l, keepdims=False),
                 pos, n_rep, dt, window=window,
             )
-            o = jnp.einsum("bshd,hde->bse", o, cast(w["wo"]))
+            o = jnp.einsum("bshd,hde->bse", o, cast(w, "wo"))
             x = x + o
         with jax.named_scope("mlp"):
             h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant)
@@ -256,19 +293,19 @@ def forward_cached(
                 )
             elif c.variant == "llama":
                 gate = jax.nn.silu(
-                    jnp.einsum("bse,ef->bsf", h, cast(w["w_gate"]))
+                    jnp.einsum("bse,ef->bsf", h, cast(w, "w_gate"))
                 )
-                up = jnp.einsum("bse,ef->bsf", h, cast(w["w_up"]))
+                up = jnp.einsum("bse,ef->bsf", h, cast(w, "w_up"))
                 ff = jnp.einsum("bsf,fe->bse", gate * up,
-                                cast(w["w_down"]))
+                                cast(w, "w_down"))
             else:
                 hidden = jax.nn.gelu(
-                    jnp.einsum("bse,ef->bsf", h, cast(w["w_gate"]))
-                    + cast(w["b_ff"])
+                    jnp.einsum("bse,ef->bsf", h, cast(w, "w_gate"))
+                    + cast(w, "b_ff")
                 )
                 ff = (jnp.einsum("bsf,fe->bse", hidden,
-                                 cast(w["w_down"]))
-                      + cast(w["b_out"]))
+                                 cast(w, "w_down"))
+                      + cast(w, "b_out"))
             x = x + ff
         return (x, k_stack, v_stack), None
 
@@ -281,7 +318,7 @@ def forward_cached(
     )
     with jax.named_scope("lm_head"):
         x = _norm(x, params["ln_f"], params.get("ln_f_b"), c.variant)
-        logits = jnp.einsum("bse,ev->bsv", x, cast(params["lm_head"]))
+        logits = jnp.einsum("bse,ev->bsv", x, cast(params, "lm_head"))
         if c.mup_base_width:
             logits = logits * (c.mup_base_width / c.d_model)
     new_cache = {"k": k_new, "v": v_new, "pos": pos + S_new}

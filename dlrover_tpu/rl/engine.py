@@ -200,7 +200,9 @@ class ShardedPPOTrainer(PPOTrainer):
         rl/inference_backend/vllm_backend.py:1). TPU-native: both
         engines live on one mesh, so the per-iteration "weight sync" is
         handing the serving engine the actor's parameter BUFFERS (no
-        copy, no staleness window); the decode itself is the same
+        staleness window; the engine keeps them in the dtype its
+        products run in, one conversion a push and none a decode
+        step); the decode itself is the same
         ``sample_logits`` used by the in-mesh path, so sampling
         semantics cannot drift between backends.
         """
@@ -325,8 +327,9 @@ class ShardedPPOTrainer(PPOTrainer):
         from dlrover_tpu.serving import SamplingParams
 
         # per-iteration weight handoff: the engine's jitted programs
-        # take params as an argument, so pointing it at the freshly
-        # updated actor buffers IS the sync step
+        # take params as an argument, so handing it the freshly
+        # updated actor buffers IS the sync step (it converts what its
+        # products read to cfg.dtype, once, and keeps that)
         self._serving.params = self.params["model"]
         # per-request seeds DERIVED FROM THE CALLER'S KEY: rollout stays
         # a function of (params, prompts, key) on this backend too —

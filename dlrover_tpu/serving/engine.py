@@ -109,6 +109,7 @@ from dlrover_tpu.models.decode import (
     forward_cached,
     init_cache,
     sample_logits,
+    weights_at_rest,
     zero_counters,
 )
 from dlrover_tpu.models.transformer import TransformerConfig
@@ -352,6 +353,15 @@ class InferenceEngine:
         eng = InferenceEngine(params, cfg, slots=8, max_len=256)
         rid = eng.submit([1, 2, 3], SamplingParams(max_new_tokens=32))
         results = eng.run()          # drain queue + active slots
+
+    Where the weights rest (DESIGN.md §23.6): the engine keeps
+    ``models.decode.weights_at_rest(params, cfg)`` and not the tree it
+    was handed, at construction and at every push through ``params``:
+    the leaves its products read in ``cfg.dtype``, converted once (a
+    leaf already there is kept as the same array, not copied), the norm
+    leaves and the capacity-routed experts as they came. No program of
+    the engine converts those leaves again, and a caller that drops its
+    own tree leaves one copy of the model on the device, in ``cfg.dtype``.
     """
 
     def __init__(self, params: Any, cfg: TransformerConfig, *,
@@ -359,7 +369,8 @@ class InferenceEngine:
                  prefill_len: int = 0, decode_block: int = 1,
                  prefix_cache_entries: int = 0,
                  kv_pages: int = 0, page_size: int = 0):
-        self._params = params
+        # held as the programs read them, and only so (class docstring)
+        self._params = weights_at_rest(params, cfg)
         self.cfg = cfg
         self.slots = slots
         self.engine_id = f"eng{next(_ENGINE_IDS)}"
@@ -875,7 +886,9 @@ class InferenceEngine:
         # one wave of re-prefill; the cost of a stale row is wrong
         # logits with no error. Reuse within a rollout wave survives:
         # the RL engine pushes once per iteration, before the wave.
-        self._params = value
+        # What is kept is the tree as the programs read it (see the
+        # class docstring): converted here, once a push.
+        self._params = weights_at_rest(value, self.cfg)
         self._prefix_cache.clear()
         self._prefix_lens.clear()
 

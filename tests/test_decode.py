@@ -13,10 +13,12 @@ import jax.numpy as jnp
 
 from dlrover_tpu.models import transformer as tfm
 from dlrover_tpu.models.decode import (
+    PRODUCT_LEAVES,
     _write_rows,
     forward_cached,
     generate,
     init_cache,
+    weights_at_rest,
 )
 
 
@@ -253,6 +255,111 @@ class TestStackIsCarriedAndWrittenInPlace:
         for name in ("k", "v"):
             np.testing.assert_array_equal(
                 np.asarray(c3[name][:, :2]), np.asarray(c2[name]))
+
+
+def _leaf_names(tree):
+    """{path: leaf} with the path as 'layers/wq'."""
+    return {"/".join(k.key for k in path): leaf for path, leaf
+            in jax.tree_util.tree_leaves_with_path(tree)}
+
+
+class TestWeightsAtRest:
+    """ISSUE 28: a holder that calls `forward_cached` many times keeps
+    the leaves its products read in ``cfg.dtype``, converted once; the
+    function computed is the same to the bit, and a leaf that is
+    already there is not copied."""
+
+    @pytest.mark.parametrize("s_new", [1, 4], ids=["step", "chunk"])
+    @pytest.mark.parametrize("pos_kind", ["scalar", "vector"])
+    @pytest.mark.parametrize("name", ["gpt2", "llama"])
+    def test_the_converted_tree_computes_the_same_bits(
+            self, name, pos_kind, s_new):
+        cfg = dataclasses.replace(_variant(name), dtype="bfloat16")
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        rested = weights_at_rest(params, cfg)
+        seqs = jax.random.randint(
+            jax.random.PRNGKey(5), (3, 16), 0, cfg.vocab_size)
+        lens = [6, 6, 6] if pos_kind == "scalar" else [3, 6, 11]
+        cache = _stacked_rows(cfg, params, seqs, lens, 16)
+        if pos_kind == "scalar":
+            cache["pos"] = jnp.asarray(6, jnp.int32)
+        new = jnp.stack([seqs[b, n:n + s_new]
+                         for b, n in enumerate(lens)])
+        run = jax.jit(lambda p, t, c: forward_cached(p, t, c, cfg))
+        want, want_cache = run(params, new, cache)
+        got, got_cache = run(rested, new, cache)
+        assert got.dtype == want.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for stack in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(got_cache[stack].astype(jnp.float32)),
+                np.asarray(want_cache[stack].astype(jnp.float32)))
+        assert float(jnp.abs(want).max()) > 0
+
+    @pytest.mark.parametrize("name", ["gpt2", "llama", "tiny-moe"])
+    def test_only_the_leaves_the_products_read_change_dtype(self, name):
+        cfg = (tfm.CONFIGS[name] if name == "tiny-moe" else
+               dataclasses.replace(_variant(name), dtype="bfloat16"))
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        before, after = (_leaf_names(t) for t in (
+            params, weights_at_rest(params, cfg)))
+        assert before.keys() == after.keys()
+        for path, leaf in after.items():
+            if path.split("/")[-1] in PRODUCT_LEAVES:
+                assert leaf.dtype == jnp.bfloat16, path
+                np.testing.assert_array_equal(
+                    np.asarray(leaf.astype(jnp.float32)),
+                    np.asarray(before[path].astype(jnp.bfloat16)
+                               .astype(jnp.float32)))
+            else:
+                # norm scales and biases, the capacity-routed experts
+                # and their router: the very arrays handed in
+                assert leaf is before[path], path
+                assert leaf.dtype == jnp.float32, path
+        assert after["layers/wq"].dtype == after["embed"].dtype == (
+            jnp.bfloat16)
+        kept = {"layers/ln1", "layers/ln2", "ln_f"} | (
+            {"layers/w_router", "layers/w_in", "layers/w_out"}
+            if name == "tiny-moe" else set())
+        assert all(after[path] is before[path] for path in kept)
+
+    @pytest.mark.parametrize("kind", [
+        "gpt2-converted-twice", "latent-resting-in-bfloat16",
+        "float32-products"])
+    def test_a_tree_already_at_rest_comes_back_as_the_same_arrays(
+            self, kind):
+        if kind == "latent-resting-in-bfloat16":
+            cfg = dataclasses.replace(
+                tfm.CONFIGS["tiny-latent-moe"], dtype="bfloat16",
+                param_dtype="bfloat16")
+            params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+            assert {a.dtype for a in jax.tree.leaves(params)} == {
+                jnp.dtype("bfloat16")}
+        elif kind == "float32-products":
+            cfg = _variant("gpt2")
+            params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        else:
+            cfg = dataclasses.replace(_variant("gpt2"), dtype="bfloat16")
+            params = weights_at_rest(
+                tfm.init_params(cfg, jax.random.PRNGKey(0)), cfg)
+        again = weights_at_rest(params, cfg)
+        before, after = jax.tree.leaves(params), jax.tree.leaves(again)
+        assert len(before) == len(after)
+        assert all(a is b for a, b in zip(before, after))
+
+    def test_host_leaves_land_converted_too(self):
+        """A pushed tree may be host arrays (`rl/serving_worker.py`
+        unflattens numpy off the wire)."""
+        cfg = dataclasses.replace(_variant("llama"), dtype="bfloat16")
+        params = jax.tree.map(
+            np.asarray, tfm.init_params(cfg, jax.random.PRNGKey(0)))
+        rested = weights_at_rest(params, cfg)
+        assert rested["layers"]["wq"].dtype == jnp.bfloat16
+        assert rested["layers"]["ln1"] is params["layers"]["ln1"]
+        np.testing.assert_array_equal(
+            np.asarray(rested["embed"].astype(jnp.float32)),
+            np.asarray(jnp.asarray(params["embed"]).astype(jnp.bfloat16)
+                       .astype(jnp.float32)))
 
 
 class TestSlidingWindowDecode:

@@ -9,6 +9,7 @@ lengths join and leave the batch mid-flight.
 
 from __future__ import annotations
 
+import dataclasses
 import re
 
 import numpy as np
@@ -18,7 +19,11 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models import transformer as tfm
-from dlrover_tpu.models.decode import generate
+from dlrover_tpu.models.decode import (
+    PRODUCT_LEAVES,
+    generate,
+    weights_at_rest,
+)
 from dlrover_tpu.serving import InferenceEngine, SamplingParams
 
 CFG = tfm.CONFIGS["tiny"]
@@ -605,6 +610,146 @@ class TestPrefixCache:
         out_a = {r.id: r.tokens for r in eng.run()}[rid_a]
         out_b = {r.id: r.tokens for r in fresh.run()}[rid_b]
         assert out_a == out_b
+
+
+# ------------------------- where the engine's weights rest (ISSUE 28)
+
+
+GPT2 = dataclasses.replace(CFG, variant="gpt2")
+
+
+def _serve(eng, requests):
+    ids = [eng.submit(prompt, sp) for prompt, sp in requests]
+    done = {r.id: r.tokens for r in eng.run()}
+    return [done[i] for i in ids]
+
+
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (the
+    jitted call, the step loop, the layer loop, branches)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (tuple, list))
+                          else (value,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _all_eqns(inner)
+
+
+def _weight_conversions(fn, params, *args, **static):
+    """(shape, dtype) of every `PRODUCT_LEAVES` leaf of ``params``
+    that the program ``fn(params, *args)`` converts: the operands of
+    its ``convert_element_type`` equations that look like such a leaf
+    whole, or like one layer of a stacked one."""
+    looks = set()
+    for path, leaf in jax.tree_util.tree_leaves_with_path(params):
+        if path[-1].key in PRODUCT_LEAVES:
+            shape = leaf.shape[1:] if len(path) > 1 else leaf.shape
+            looks.add((tuple(shape), jnp.dtype(leaf.dtype)))
+    jaxpr = fn.trace(params, *args, **static).jaxpr.jaxpr
+    found = set()
+    for eqn in _all_eqns(jaxpr):
+        if eqn.primitive.name == "convert_element_type":
+            aval = eqn.invars[0].aval
+            found.add((tuple(aval.shape), jnp.dtype(aval.dtype)))
+    return found & looks, looks
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("cfg", [CFG, GPT2], ids=["llama", "gpt2"])
+@pytest.mark.parametrize("program", ["decode_block", "prefill_chunk"])
+def test_no_engine_program_converts_a_weight(cfg, program):
+    """The decode block and the prefill chunk, traced on the tree the
+    engine holds, convert no leaf the products read; traced on the
+    float32 tree it was handed (what every call did before) they
+    convert each of them, which shows that the search can see it."""
+    handed = tfm.init_params(cfg, jax.random.PRNGKey(0))
+    eng = InferenceEngine(handed, cfg, slots=2, max_len=64,
+                          prefill_len=8, decode_block=4)
+    if program == "decode_block":
+        fn, static = eng._step_block, {"n_steps": 4}
+        args = eng._step_sample_args()[1:]
+    else:
+        fn, static = eng._prefill_chunk, {}
+        args = (jnp.zeros((1, 8), jnp.int32),
+                eng.prefill_begin([1, 2, 3]).row,
+                jnp.asarray(3, jnp.int32))
+    held, _ = _weight_conversions(fn, eng.params, *args, **static)
+    assert held == set()
+    before, looks = _weight_conversions(fn, handed, *args, **static)
+    assert before == looks and len(looks) >= 6
+
+
+@pytest.mark.timeout(300)
+def test_float32_and_converted_weights_serve_the_same_tokens(params):
+    """An engine handed training's float32 tree and one handed the
+    converted tree are the same server: the same tokens, greedy and
+    sampled, the same float32 logits out of a prefill; and the second
+    keeps the very arrays it was handed."""
+    rested = weights_at_rest(params, CFG)
+    a = InferenceEngine(params, CFG, slots=2, max_len=64, prefill_len=8,
+                        decode_block=4)
+    b = InferenceEngine(rested, CFG, slots=2, max_len=64, prefill_len=8,
+                        decode_block=4)
+    assert all(x is y for x, y in zip(jax.tree.leaves(b.params),
+                                      jax.tree.leaves(rested)))
+    held = jax.tree_util.tree_leaves_with_path(a.params)
+    assert {leaf.dtype for path, leaf in held
+            if path[-1].key in PRODUCT_LEAVES} == {jnp.dtype("bfloat16")}
+    assert {leaf.dtype for path, leaf in held
+            if path[-1].key not in PRODUCT_LEAVES} == {
+        jnp.dtype("float32")}
+    requests = [
+        ([5, 9, 2, 11, 4, 4, 8, 1, 3, 7], SamplingParams(
+            temperature=0.0, max_new_tokens=9)),
+        ([7, 7, 1], SamplingParams(
+            temperature=0.8, top_k=20, top_p=0.9, seed=11,
+            max_new_tokens=7)),
+        ([3], SamplingParams(temperature=1.0, seed=5, max_new_tokens=5)),
+    ]
+    assert _serve(a, requests) == _serve(b, requests)
+    logits = []
+    for eng in (a, b):
+        run = eng.prefill_begin(list(range(1, 12)))
+        while not eng.prefill_step(run):
+            pass
+        assert run.last.dtype == jnp.float32
+        logits.append(np.asarray(run.last))
+    np.testing.assert_array_equal(*logits)
+
+
+@pytest.mark.timeout(300)
+def test_a_push_converts_once_and_the_next_request_reads_it(params):
+    """`engine.params = float32_tree` (the RLHF weight push) keeps the
+    converted tree, clears the prefix cache, and what is served next is
+    what a new engine on the pushed weights serves."""
+    eng = InferenceEngine(params, CFG, slots=1, max_len=64,
+                          prefill_len=8, prefix_cache_entries=8)
+    prompt = list(range(40, 57))
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    [old] = _serve(eng, [(prompt, sp)])
+    assert eng._prefix_cache
+    pushed = jax.tree.map(
+        lambda a: a + 0.05 * jax.random.normal(
+            jax.random.PRNGKey(a.size % 97), a.shape, a.dtype), params)
+    eng.params = pushed
+    assert not eng._prefix_cache and not eng._prefix_lens
+    want = weights_at_rest(pushed, CFG)
+    for (path, got), ref in zip(
+            jax.tree_util.tree_leaves_with_path(eng.params),
+            jax.tree.leaves(want)):
+        assert got.dtype == ref.dtype, path
+        np.testing.assert_array_equal(
+            np.asarray(got.astype(jnp.float32)),
+            np.asarray(ref.astype(jnp.float32)))
+    assert eng.params["layers"]["wq"].dtype == jnp.bfloat16
+    assert eng.params["layers"]["ln1"] is pushed["layers"]["ln1"]
+    fresh = InferenceEngine(pushed, CFG, slots=1, max_len=64,
+                            prefill_len=8)
+    [new] = _serve(eng, [(prompt, sp)])
+    assert new == _serve(fresh, [(prompt, sp)])[0]
+    assert new != old
 
 
 # ------------------------------------ the stack is donated (ISSUE 26)
