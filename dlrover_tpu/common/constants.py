@@ -115,7 +115,6 @@ class EnvKey:
     COORDINATOR = "DLROVER_TPU_COORDINATOR"
     RESTART_COUNT = "DLROVER_TPU_RESTART_COUNT"
     PARAL_CONFIG_PATH = "DLROVER_TPU_PARAL_CONFIG"
-    CKPT_META_DIR = "DLROVER_TPU_CKPT_META_DIR"
     MOCK_ERR_RANK = "DLROVER_TPU_MOCK_ERR_RANK"
     DEVICE_COUNT_OVERRIDE = "DLROVER_TPU_DEVICE_COUNT"
     # coordination-service join timeout (seconds) for
@@ -177,10 +176,8 @@ class EnvKey:
     # the example's force-switch for the fallback-topology precompiler
     AOT_CACHE = "DLROVER_TPU_AOT_CACHE"
     FALLBACK_AOT = "DLROVER_TPU_FALLBACK_AOT"
-    # efficiency observatory (DESIGN.md §18): per-step phase split
-    # ("0" restores fire-and-forget dispatch) and the journal cadence
-    # of metrics_sample points
-    STEP_PHASES = "DLROVER_TPU_STEP_PHASES"
+    # efficiency observatory (DESIGN.md §18): the journal cadence of
+    # metrics_sample points
     EFFICIENCY_JOURNAL_EVERY = "DLROVER_TPU_EFFICIENCY_JOURNAL_EVERY"
     # buddy-replication of shm snapshots (checkpoint/buddy.py): "0"
     # disables, interval between pushes, per-push byte cap
@@ -247,11 +244,9 @@ class EnvKey:
     RACK_RETRY_S = "DLROVER_TPU_RACK_RETRY_S"
     LINK_STALE_S = "DLROVER_TPU_LINK_STALE_S"
     # serving memory observatory (DESIGN.md §29): the measure-only
-    # off-switch, the kv_pool sample cadence (decode steps), and the
-    # n-gram order of the draft-acceptance shadow predictor
+    # off-switch and the kv_pool sample cadence (decode steps)
     SERVING_OBSERVATORY = "DLROVER_TPU_SERVING_OBSERVATORY"
     OBSERVATORY_SAMPLE_EVERY = "DLROVER_TPU_OBSERVATORY_SAMPLE_EVERY"
-    SHADOW_ORDER = "DLROVER_TPU_SHADOW_ORDER"
     # serving raw speed (DESIGN.md §31): copy-on-write page sharing in
     # the paged KV pool, and the max self-drafted speculative-decode
     # verify depth (0 = plain decode)

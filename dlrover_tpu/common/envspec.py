@@ -110,9 +110,6 @@ SPECS: tuple[EnvVar, ...] = (
            "POSIX shm name prefix (read once at import: every shm name "
            "derives from it)", "§11", restart_required=True),
     # ----------------------------------------------------------- checkpoint
-    EnvVar("DLROVER_TPU_CKPT_META_DIR", None,
-           "where the agent-side saver finds shm checkpoint meta", "§16",
-           restart_required=True),
     EnvVar("DLROVER_TPU_SNAPSHOT_INTERVAL", None,
            "'auto' arms the master's Young-Daly cadence tuner; other "
            "values keep the trainer CLI cadence", "§16"),
@@ -192,9 +189,6 @@ SPECS: tuple[EnvVar, ...] = (
            "§14"),
     EnvVar("DLROVER_TPU_BUNDLES", "1",
            "'0' disables automatic debug bundles on hang/crash", "§14"),
-    EnvVar("DLROVER_TPU_STEP_PHASES", "1",
-           "'0' restores fire-and-forget dispatch (no per-step phase "
-           "split)", "§18", restart_required=True),
     EnvVar("DLROVER_TPU_EFFICIENCY_JOURNAL_EVERY", "25",
            "steps between metrics_sample journal points "
            "(0 disables)", "§18"),
@@ -294,9 +288,6 @@ SPECS: tuple[EnvVar, ...] = (
     EnvVar("DLROVER_TPU_OBSERVATORY_SAMPLE_EVERY", "32",
            "decode steps between kv_pool journal samples / gauge "
            "refreshes", "§29"),
-    EnvVar("DLROVER_TPU_SHADOW_ORDER", "3",
-           "n-gram order of the draft-acceptance shadow predictor "
-           "(longest-match back-off to 1)", "§29"),
     # ------------------------------------------------- serving raw speed
     EnvVar("DLROVER_TPU_KV_COW", "1",
            "copy-on-write KV page sharing: admission dedups full "

@@ -20,13 +20,18 @@ wants the update in place donates the stack to the jitted program that
 calls ``forward_cached`` and keeps no other reference to it
 (``serving/engine.py`` does).
 
-Correctness is pinned to the training forward by an equivalence test
-(tests/test_decode.py): prefill+cached-decode logits must match
-``forward`` on the same tokens bit-for-tolerance.
+The block, the embedding and the head are training's own
+(``models/transformer.py``: ``make_layer_fn``, ``embed_tokens``,
+``final_norm``, ``lm_logits``). What lives here is what a cached caller
+hands that block: the cache tree, the attention that writes a layer's
+new rows and reads its cache, and the positions. The equivalence test
+(tests/test_decode.py) pins those: prefill+cached-decode logits must
+match ``forward`` on the same tokens to tolerance.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Any
 
@@ -34,11 +39,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dlrover_tpu.models.transformer import (
-    TransformerConfig,
-    _norm,
-    _rope,
-)
+from dlrover_tpu.models import transformer as tfm
+from dlrover_tpu.models.transformer import PRODUCT_LEAVES, TransformerConfig
 
 Params = Any
 
@@ -147,15 +149,6 @@ def _write_rows(stack, new, layer, pos):
     return stack
 
 
-# The leaves `forward_cached` multiplies or adds in ``cfg.dtype``: its
-# `cast()` takes a leaf by one of these names and no other. The norm
-# scales and biases (`_norm` rounds them itself) and the capacity-routed
-# experts (`moe_ffn` reads them as they are) are not among them.
-PRODUCT_LEAVES = frozenset({
-    "embed", "pos_embed", "wq", "wk", "wv", "wo", "w_gate", "w_up",
-    "w_down", "b_ff", "b_out", "lm_head"})
-
-
 def weights_at_rest(params: Params, cfg: TransformerConfig) -> Params:
     """``params`` as a holder that calls `forward_cached` many times
     keeps them (``serving/engine.py``): the `PRODUCT_LEAVES`, wherever
@@ -199,130 +192,52 @@ def forward_cached(
         from dlrover_tpu.models import latent
 
         return latent.forward(params, tokens, c, cache)
+    if c.int8_matmuls:
+        # the cached products are plain whatever training ran
+        # (ROADMAP D12)
+        c = dataclasses.replace(c, int8_matmuls=False)
     dt = jnp.dtype(c.dtype)
     B, S_new = tokens.shape
     pos = cache["pos"]
-    scalar_pos = jnp.ndim(pos) == 0  # static at trace time
     n_rep = c.n_heads // c.n_kv_heads
-
-    def cast(tree, name):
-        # a leaf the products read, in their dtype. Nothing at all on a
-        # tree from `weights_at_rest` (the engine's); on training's
-        # float32 tree (`generate`, the RL rollouts) a conversion on
-        # every call, in a scope of its own so that a trace prices it
-        assert name in PRODUCT_LEAVES, name
-        with jax.named_scope("weight_cast"):
-            return tree[name].astype(dt)
-
-    if scalar_pos:
-        positions = pos + jnp.broadcast_to(jnp.arange(S_new), (B, S_new))
-    else:
-        positions = pos[:, None] + jnp.arange(S_new)[None]
-    x = cast(params, "embed")[tokens]
-    if c.variant == "gpt2":
-        if scalar_pos:
-            pe = lax.dynamic_slice_in_dim(
-                cast(params, "pos_embed"), pos, S_new, axis=0
-            )[None]
-        else:
-            # gather (not slice): per-row positions; clamp keeps the
-            # lookup in-table for padded/inactive rows
-            pe = cast(params, "pos_embed")[
-                jnp.clip(positions, 0, c.max_seq_len - 1)
-            ]
-        x = x + pe
-
-    if c.moe_experts:
-        from dlrover_tpu.ops.moe import MoeConfig, moe_ffn
-
-        # Same router/experts as training (the softmax-routed,
-        # capacity-limited `moe_experts` kind; the sigmoid-routed kind
-        # has no capacity and left for models/latent.py above).
-        # Capacity is per forward_cached call (B*S_new tokens), not per
-        # training sequence: a decode step routes B tokens against a
-        # fresh capacity pool, so drop patterns can differ from the
-        # training forward when experts overflow — exact train/decode
-        # equivalence holds in the no-drop regime.
-        moe_cfg = MoeConfig(
-            n_experts=c.moe_experts, top_k=c.moe_top_k,
-            capacity_factor=c.moe_capacity_factor,
-        )
-
-    # NOTE: this layer body mirrors transformer.forward_with_aux (the
-    # cache update and absolute-position math are what differ). The
-    # equivalence tests in tests/test_decode.py pin the two together —
-    # extend them when touching either copy.
+    # the window only binds when training actually used it (the splash
+    # kind): other attention kinds ignore attention_window in training,
+    # so decode must too or the masks diverge
     window = c.attention_window if c.attention == "splash" else 0
+
+    def attend(q, k, v, state):
+        k_stack, v_stack, l = state
+        with jax.named_scope("kv_write"):
+            k_stack = _write_rows(k_stack, k.astype(dt), l, pos)
+            v_stack = _write_rows(v_stack, v.astype(dt), l, pos)
+        o = _layer_attend(
+            q, lax.dynamic_index_in_dim(k_stack, l, keepdims=False),
+            lax.dynamic_index_in_dim(v_stack, l, keepdims=False),
+            pos, n_rep, dt, window=window,
+        )
+        return o, (k_stack, v_stack, l)
+
+    block = tfm.make_layer_fn(
+        c, attend=attend,
+        positions=tfm.token_positions(pos, B, S_new))
 
     def layer(carry, inputs):
         x, k_stack, v_stack = carry
         w, l = inputs
-        with jax.named_scope("attn"):
-            h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
-            q = jnp.einsum("bse,ehd->bshd", h, cast(w, "wq"))
-            if c.mup_base_width:
-                # same order as training: scale before rope (they
-                # commute, but keep the copies textually aligned)
-                q = q / math.sqrt(c.head_dim)
-            k = jnp.einsum("bse,ehd->bshd", h, cast(w, "wk"))
-            v = jnp.einsum("bse,ehd->bshd", h, cast(w, "wv"))
-            if c.variant == "llama":
-                q = _rope(q, positions, c.rope_theta)
-                k = _rope(k, positions, c.rope_theta)
-            with jax.named_scope("kv_write"):
-                k_stack = _write_rows(k_stack, k.astype(dt), l, pos)
-                v_stack = _write_rows(v_stack, v.astype(dt), l, pos)
-            # the window only binds when training actually used it (the
-            # splash kind) — other attention kinds ignore
-            # attention_window in training, so decode must too or the
-            # masks diverge
-            o = _layer_attend(
-                q, lax.dynamic_index_in_dim(k_stack, l, keepdims=False),
-                lax.dynamic_index_in_dim(v_stack, l, keepdims=False),
-                pos, n_rep, dt, window=window,
-            )
-            o = jnp.einsum("bshd,hde->bse", o, cast(w, "wo"))
-            x = x + o
-        with jax.named_scope("mlp"):
-            h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant)
-            if c.moe_experts:
-                ff, _ = moe_ffn(
-                    {"w_router": w["w_router"], "w_in": w["w_in"],
-                     "w_out": w["w_out"]},
-                    h, moe_cfg,
-                )
-            elif c.variant == "llama":
-                gate = jax.nn.silu(
-                    jnp.einsum("bse,ef->bsf", h, cast(w, "w_gate"))
-                )
-                up = jnp.einsum("bse,ef->bsf", h, cast(w, "w_up"))
-                ff = jnp.einsum("bsf,fe->bse", gate * up,
-                                cast(w, "w_down"))
-            else:
-                hidden = jax.nn.gelu(
-                    jnp.einsum("bse,ef->bsf", h, cast(w, "w_gate"))
-                    + cast(w, "b_ff")
-                )
-                ff = (jnp.einsum("bsf,fe->bse", hidden,
-                                 cast(w, "w_down"))
-                      + cast(w, "b_out"))
-            x = x + ff
+        x, _, (k_stack, v_stack, _) = block(x, w, (k_stack, v_stack, l))
         return (x, k_stack, v_stack), None
 
     # the stack rides the CARRY: a scanned input or output of the
     # per-layer shape would be sliced out and copied back whole, per
     # layer, for the sake of S_new new rows
     (x, k_new, v_new), _ = lax.scan(
-        layer, (x, cache["k"], cache["v"]),
+        layer, (tfm.embed_tokens(params, tokens, c, pos=pos),
+                cache["k"], cache["v"]),
         (params["layers"], jnp.arange(c.n_layers, dtype=jnp.int32)),
     )
     with jax.named_scope("lm_head"):
-        x = _norm(x, params["ln_f"], params.get("ln_f_b"), c.variant)
-        logits = jnp.einsum("bse,ev->bsv", x, cast(params, "lm_head"))
-        if c.mup_base_width:
-            logits = logits * (c.mup_base_width / c.d_model)
-    new_cache = {"k": k_new, "v": v_new, "pos": pos + S_new}
-    return logits.astype(jnp.float32), new_cache
+        logits = tfm.lm_logits(params, tfm.final_norm(params, x, c), c)
+    return logits, {"k": k_new, "v": v_new, "pos": pos + S_new}
 
 
 @jax.named_scope("sample")

@@ -490,28 +490,83 @@ def prefix_lm_attention(q, k, v, prefix_len: jax.Array, *,
 
 AttentionFn = Callable[..., jax.Array]
 
+# The leaves the block, the embedding and the head multiply or add in
+# ``cfg.dtype``: `_leaf` takes a leaf by one of these names and no
+# other. The norm scales and biases (`_norm` rounds them itself) and
+# the capacity-routed experts (`moe_ffn` reads them as they are) are not
+# among them. ``models/decode.weights_at_rest`` keeps exactly these in
+# ``cfg.dtype`` for a holder that runs the block many times.
+PRODUCT_LEAVES = frozenset({
+    "embed", "pos_embed", "wq", "wk", "wv", "wo", "w_gate", "w_up",
+    "w_down", "b_ff", "b_out", "lm_head"})
+
+
+def _leaf(tree, name: str, dt) -> jax.Array:
+    """A leaf the products read, in their dtype. Nothing at all where it
+    rests there (``weights_at_rest``: the serving engine's tree); on a
+    float32 tree (training, ``generate``, the RL rollouts) a conversion
+    on every call, in a scope of its own so that a trace prices it."""
+    assert name in PRODUCT_LEAVES, name
+    with jax.named_scope("weight_cast"):
+        return tree[name].astype(dt)
+
+
+def token_positions(pos, batch: int, seq: int) -> jax.Array:
+    """``[batch, seq]`` positions of a call's tokens. ``pos`` is where
+    the call starts: None (every row at 0: the uncached forward), a
+    scalar (rows in lockstep) or ``[batch]`` (rows at positions of their
+    own: the serving engine's slots)."""
+    steps = jnp.arange(seq)
+    if pos is None:
+        return jnp.broadcast_to(steps, (batch, seq))
+    if jnp.ndim(pos) == 0:
+        return pos + jnp.broadcast_to(steps, (batch, seq))
+    return pos[:, None] + steps[None]
+
 
 def make_layer_fn(
     cfg: TransformerConfig,
     attention_fn: AttentionFn | None = None,
     constrain: Callable[[jax.Array, tuple], jax.Array] | None = None,
     mask: jax.Array | None = None,
-) -> Callable[[jax.Array, Any], tuple[jax.Array, jax.Array]]:
-    """One transformer block as a reusable ``(x, w) -> (x, aux)``.
+    attend: Callable | None = None,
+    positions: jax.Array | None = None,
+) -> Callable[..., tuple[jax.Array, jax.Array, Any]]:
+    """One transformer block as a reusable ``(x, w, state=None) ->
+    (x, aux, state)``: THE definition of what a dense layer computes.
 
-    This IS the scan body of :func:`forward_with_aux` (hoisted to module
-    level so the MPMD runtime, ``parallel/mpmd.py``, can build per-stage
-    programs from the exact same math — any divergence here would break
-    the cross-schedule loss-equivalence bound ``RTOL_CROSS_LAYOUT``).
-    ``w`` is one layer's weight dict (a single slice of the stacked
-    ``params["layers"]``); ``aux`` is the MoE load-balancing increment
-    (0 for dense FFNs).
+    This is the scan body of :func:`forward_with_aux`, of the MPMD
+    runtime's per-stage programs (``parallel/mpmd.py``: any divergence
+    would break the cross-schedule loss-equivalence bound
+    ``RTOL_CROSS_LAYOUT``) and of the cached forward
+    (``models/decode.forward_cached``: prefill chunk, decode step,
+    verify block). ``w`` is one layer's weight dict (a single slice of
+    the stacked ``params["layers"]``); ``aux`` is the MoE load-balancing
+    increment (0 for dense FFNs).
+
+    What a cached caller hands over: ``attend(q, k, v, state) -> (o,
+    state)``, which owns everything between the projections and the
+    output product (it writes K and V into the layer's cache ``state``
+    and attends over it), and the ``positions [B, S]`` of the call's
+    tokens. Without them the block attends over the call's own tokens
+    through ``attention_fn``, positions from 0, and ``state`` passes
+    through untouched.
     """
     c = cfg
     dt = jnp.dtype(c.dtype)
     pin = constrain or (lambda x, a: x)
-    attn = attention_fn or dense_attention
-    n_rep = c.n_heads // c.n_kv_heads
+    if attend is None:
+        attn = attention_fn or dense_attention
+        n_rep = c.n_heads // c.n_kv_heads
+
+        def attend(q, k, v, state):
+            if n_rep > 1 and not getattr(attn, "supports_gqa", False):
+                # GQA-native impls (splash) read the shared KV directly —
+                # repeating here would multiply KV memory traffic by n_rep
+                k = jnp.repeat(k, n_rep, axis=2)
+                v = jnp.repeat(v, n_rep, axis=2)
+            return attn(q, k, v, causal=c.causal), state
+
     # muP: attention logits scale 1/d_head instead of 1/sqrt(d_head) —
     # pre-scaling q composes with the attention impl's 1/sqrt(d)
     mup_q_scale = (
@@ -521,6 +576,10 @@ def make_layer_fn(
     if c.moe_experts:
         from dlrover_tpu.ops.moe import MoeConfig, moe_ffn
 
+        # Capacity is per call: a cached decode step routes B tokens
+        # against a fresh capacity pool, so drop patterns can differ
+        # from the training forward when experts overflow; cached and
+        # uncached agree exactly in the no-drop regime.
         moe_cfg = MoeConfig(
             n_experts=c.moe_experts, top_k=c.moe_top_k,
             capacity_factor=c.moe_capacity_factor,
@@ -543,58 +602,54 @@ def make_layer_fn(
         return y.reshape(*x.shape[:x.ndim - n_contract],
                          *wt.shape[n_contract:])
 
-    def layer(x, w):
-        """One block: activations [B', S, E] -> ([B', S, E], aux_inc).
+    def layer(x, w, state=None):
+        """One block: activations [B', S, E] -> ([B', S, E], aux_inc,
+        state).
 
         B' is the full batch under scan, a microbatch under the pipeline —
-        positions derive from the input shape so both work.
+        positions not handed in derive from the input shape so both work.
         """
         aux = jnp.zeros((), jnp.float32)
-        positions = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
+        at = (positions if positions is not None
+              else token_positions(None, *x.shape[:2]))
         with jax.named_scope("attn"):
             h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
-            q = proj(h, w["wq"].astype(dt), "bse,ehd->bshd")
+            q = proj(h, _leaf(w, "wq", dt), "bse,ehd->bshd")
             if c.mup_base_width:
                 q = q * mup_q_scale
-            k = proj(h, w["wk"].astype(dt), "bse,ehd->bshd")
-            v = proj(h, w["wv"].astype(dt), "bse,ehd->bshd")
+            k = proj(h, _leaf(w, "wk", dt), "bse,ehd->bshd")
+            v = proj(h, _leaf(w, "wv", dt), "bse,ehd->bshd")
             if c.variant == "llama":
-                q = _rope(q, positions, c.rope_theta)
-                k = _rope(k, positions, c.rope_theta)
-            if n_rep > 1 and not getattr(attn, "supports_gqa", False):
-                # GQA-native impls (splash) read the shared KV directly —
-                # repeating here would multiply KV memory traffic by n_rep
-                k = jnp.repeat(k, n_rep, axis=2)
-                v = jnp.repeat(v, n_rep, axis=2)
-            o = attn(q, k, v, causal=c.causal)
-            o = proj(o, w["wo"].astype(dt), "bshd,hde->bse", n_contract=2)
+                q = _rope(q, at, c.rope_theta)
+                k = _rope(k, at, c.rope_theta)
+            o, state = attend(q, k, v, state)
+            o = proj(o, _leaf(w, "wo", dt), "bshd,hde->bse", n_contract=2)
             o = checkpoint_name(o, "attn_out")  # inert without a names policy
             x = pin(x + o, ("batch", "sequence", "embed"))
 
         with jax.named_scope("mlp"):
             h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant)
             if c.moe_experts:
-                ff, aux_l = moe_ffn(
+                ff, aux = moe_ffn(
                     {"w_router": w["w_router"], "w_in": w["w_in"],
                      "w_out": w["w_out"]},
                     h, moe_cfg, constrain=pin, token_mask=mask,
                 )
-                aux = aux_l
             elif c.variant == "llama":
-                gate = jax.nn.silu(proj(h, w["w_gate"].astype(dt),
+                gate = jax.nn.silu(proj(h, _leaf(w, "w_gate", dt),
                                         "bse,ef->bsf"))
-                up = proj(h, w["w_up"].astype(dt), "bse,ef->bsf")
-                ff = proj(gate * up, w["w_down"].astype(dt), "bsf,fe->bse")
+                up = proj(h, _leaf(w, "w_up", dt), "bse,ef->bsf")
+                ff = proj(gate * up, _leaf(w, "w_down", dt), "bsf,fe->bse")
             else:
                 hidden = jax.nn.gelu(
-                    proj(h, w["w_gate"].astype(dt), "bse,ef->bsf")
-                    + w["b_ff"].astype(dt)
+                    proj(h, _leaf(w, "w_gate", dt), "bse,ef->bsf")
+                    + _leaf(w, "b_ff", dt)
                 )
                 hidden = checkpoint_name(hidden, "ffn_hidden")
-                ff = (proj(hidden, w["w_down"].astype(dt), "bsf,fe->bse")
-                      + w["b_out"].astype(dt))
+                ff = (proj(hidden, _leaf(w, "w_down", dt), "bsf,fe->bse")
+                      + _leaf(w, "b_out", dt))
             x = pin(x + ff, ("batch", "sequence", "embed"))
-        return x, aux
+        return x, aux, state
 
     return layer
 
@@ -604,10 +659,12 @@ def embed_tokens(
     tokens: jax.Array,
     cfg: TransformerConfig,
     constrain: Callable[[jax.Array, tuple], jax.Array] | None = None,
+    pos: jax.Array | None = None,
 ) -> jax.Array:
     """Token ids [B, S] -> embedded activations [B, S, E] (the model's
-    front end, shared by :func:`forward_with_aux` and the MPMD stage-0
-    program)."""
+    front end, shared by :func:`forward_with_aux`, the MPMD stage-0
+    program and the cached forward). ``pos`` is where the call's tokens
+    start, as :func:`token_positions` takes it."""
     c = cfg
     dt = jnp.dtype(c.dtype)
     pin = constrain or (lambda x, a: x)
@@ -617,11 +674,21 @@ def embed_tokens(
     # falls back to involuntary full rematerialization of the embedding
     # (seen in the r02 4D dryrun tail)
     with jax.named_scope("embed"):
-        x = pin(params["embed"].astype(dt)[tokens],
+        x = pin(_leaf(params, "embed", dt)[tokens],
                 ("batch", "sequence", "embed"))
         if c.variant == "gpt2":
-            x = x + params["pos_embed"].astype(dt)[:tokens.shape[1]][None]
-            x = pin(x, ("batch", "sequence", "embed"))
+            table = _leaf(params, "pos_embed", dt)
+            seq = tokens.shape[1]
+            if pos is None:
+                pe = table[:seq][None]
+            elif jnp.ndim(pos) == 0:
+                pe = lax.dynamic_slice_in_dim(table, pos, seq, axis=0)[None]
+            else:
+                # gather (not slice): per-row positions; clamp keeps the
+                # lookup in-table for padded/inactive rows
+                pe = table[jnp.clip(token_positions(pos, *tokens.shape),
+                                    0, c.max_seq_len - 1)]
+            x = pin(x + pe, ("batch", "sequence", "embed"))
     return x
 
 
@@ -635,7 +702,7 @@ def lm_logits(params: Params, hidden: jax.Array,
               cfg: TransformerConfig) -> jax.Array:
     """Final-normed hidden [B, S, E] -> fp32 logits [B, S, vocab]."""
     dt = jnp.dtype(cfg.dtype)
-    logits = jnp.einsum("bse,ev->bsv", hidden, params["lm_head"].astype(dt))
+    logits = jnp.einsum("bse,ev->bsv", hidden, _leaf(params, "lm_head", dt))
     if cfg.mup_base_width:
         # muP readout multiplier keeps logit scale width-invariant
         logits = logits * (cfg.mup_base_width / cfg.d_model)
@@ -783,12 +850,12 @@ def forward_with_aux(
             x, aux = carry
             for i in range(k - 1):
                 wi = jax.tree_util.tree_map(lambda a: a[i], wg)
-                x, inc = body(x, wi)
+                x, inc, _ = body(x, wi)
                 aux = aux + inc
             # last layer of the group runs unrematted: its activations
             # become scan residuals, bought back as skipped recompute
             wl = jax.tree_util.tree_map(lambda a: a[k - 1], wg)
-            x, inc = layer(x, wl)
+            x, inc, _ = layer(x, wl)
             return (x, aux + inc), None
 
         (x, aux), _ = lax.scan(
@@ -798,7 +865,7 @@ def forward_with_aux(
     else:
         def scan_body(carry, w):
             x, aux = carry
-            x, inc = body(x, w)
+            x, inc, _ = body(x, w)
             return (x, aux + inc), None
 
         (x, aux), _ = lax.scan(

@@ -506,7 +506,6 @@ class InferenceEngine:
                 self,
                 sample_every=envspec.get_int(
                     EnvKey.OBSERVATORY_SAMPLE_EVERY, 32),
-                shadow_order=envspec.get_int(EnvKey.SHADOW_ORDER, 3),
             )
 
         # one per-request digest store feeds BOTH the COW sharing

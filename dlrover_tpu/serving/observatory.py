@@ -225,6 +225,10 @@ def page_share_stats(slot_tokens, page_size: int) -> dict:
     return digest_share_stats(slot_digests)
 
 
+# n-gram order of the engine's draft-acceptance shadow predictor
+SHADOW_ORDER = 3
+
+
 class ShadowPredictor:
     """Order-k n-gram draft shadow over one request's own context.
 
@@ -315,11 +319,9 @@ class ServingObservatory:
     sample under a small lock.
     """
 
-    def __init__(self, engine, *, sample_every: int = 32,
-                 shadow_order: int = 3) -> None:
+    def __init__(self, engine, *, sample_every: int = 32) -> None:
         self.engine = engine
         self.sample_every = max(1, int(sample_every))
-        self.shadow_order = max(1, int(shadow_order))
         self._lock = threading.Lock()
         self._steps = 0
         self._shadow: dict[int, ShadowPredictor] = {}
@@ -365,8 +367,7 @@ class ServingObservatory:
     def note_admitted(self, req) -> None:
         if req.id not in self._shadow:
             self._shadow[req.id] = ShadowPredictor(
-                self.shadow_order, req.prompt
-            )
+                SHADOW_ORDER, req.prompt)
             self._churn.setdefault(req.id, 0)
 
     def observe_token(self, rid: int, tok: int) -> None:

@@ -18,7 +18,6 @@ from typing import Any, Callable, Iterator
 import jax
 import numpy as np
 
-from dlrover_tpu.common import envspec
 from dlrover_tpu.common.constants import EnvKey
 from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.parallel.mesh import data_parallel_size
@@ -138,9 +137,7 @@ class ElasticTrainer:
         # waits for step N-1's replicated metrics, so one step is always
         # in flight and the attribution costs no host/device overlap.
         # block is then ~0 when the host is the bottleneck and ~a step
-        # when the device is. DLROVER_TPU_STEP_PHASES=0 never waits
-        # (phases then report dispatch time only).
-        self._phase_block = envspec.get_bool(EnvKey.STEP_PHASES)
+        # when the device is.
         self._prev_metrics: Any = None  # the one extra reference held
         from dlrover_tpu.utils.profiler import device_peak_flops
 
@@ -227,19 +224,16 @@ class ElasticTrainer:
         # the step's device compute — that lands in the block phase
         dispatch_wall = t_block - step_start
         self.efficiency.observe_phase("dispatch", t_block - t_dispatch)
-        if self._phase_block:
-            # the host-vs-device separator, one step late: this step is
-            # already queued behind the previous one, so waiting for the
-            # previous step's replicated metrics leaves the device busy
-            # while it tells how long the host had to wait for it
-            waited_for, self._prev_metrics = self._prev_metrics, metrics
-            if waited_for is not None:
-                with annotate("block"):
-                    jax.block_until_ready(waited_for)
-                del waited_for
-            self.efficiency.observe_phase(
-                "block", time.monotonic() - t_block
-            )
+        # the host-vs-device separator, one step late: this step is
+        # already queued behind the previous one, so waiting for the
+        # previous step's replicated metrics leaves the device busy
+        # while it tells how long the host had to wait for it
+        waited_for, self._prev_metrics = self._prev_metrics, metrics
+        if waited_for is not None:
+            with annotate("block"):
+                jax.block_until_ready(waited_for)
+            del waited_for
+        self.efficiency.observe_phase("block", time.monotonic() - t_block)
         self._host_step = step
         step_wall = time.monotonic() - step_start
         _step_seconds.observe(step_wall)

@@ -26,14 +26,29 @@ def _f32(cfg):
     return dataclasses.replace(cfg, dtype="float32")
 
 
+def _equivalence_cfg(name):
+    """f32 two-layer configs: a preset by its name, one of `_variant`'s
+    (the block's two layer bodies, GQA), or muP with a head_dim whose
+    root is no power of two (8: the query scale is then not exact)."""
+    if name in ("gpt2", "llama"):
+        return _variant(name)
+    if name == "mup":
+        return _f32(dataclasses.replace(
+            tfm.CONFIGS["tiny"], n_layers=2, max_seq_len=64, n_heads=8,
+            n_kv_heads=8, mup_base_width=32))
+    return _f32(dataclasses.replace(
+        tfm.CONFIGS[name], n_layers=2, max_seq_len=64))
+
+
 class TestCachedForwardEquivalence:
-    @pytest.mark.parametrize("name", ["tiny", "gpt2-small"])
+    """The block is training's own (ISSUE 29); what these pin is what a
+    cached caller hands it: the attention over the written rows and the
+    positions, through every body the block has."""
+
+    @pytest.mark.parametrize(
+        "name", ["tiny", "gpt2-small", "llama", "gpt2", "mup"])
     def test_prefill_matches_forward(self, name):
-        cfg = _f32(
-            dataclasses.replace(
-                tfm.CONFIGS[name], n_layers=2, max_seq_len=64
-            )
-        )
+        cfg = _equivalence_cfg(name)
         params = tfm.init_params(cfg, jax.random.PRNGKey(0))
         tokens = jax.random.randint(
             jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size
@@ -47,7 +62,7 @@ class TestCachedForwardEquivalence:
         )
 
     @pytest.mark.parametrize("name", [
-        "tiny",
+        "tiny", "llama", "gpt2", "mup",
         # slow tier (tier-1 envelope): the gpt2-small variant compiles
         # +decodes ~21s on XLA:CPU; tiny covers the equivalence in-tier
         pytest.param("gpt2-small", marks=pytest.mark.slow),
@@ -56,11 +71,7 @@ class TestCachedForwardEquivalence:
         """Prefill then one-token steps (pos > 0 — the path PPO decode
         actually runs, incl. gpt2's pos_embed dynamic slice) reproduce
         the full forward."""
-        cfg = _f32(
-            dataclasses.replace(
-                tfm.CONFIGS[name], n_layers=2, max_seq_len=64
-            )
-        )
+        cfg = _equivalence_cfg(name)
         params = tfm.init_params(cfg, jax.random.PRNGKey(0))
         tokens = jax.random.randint(
             jax.random.PRNGKey(1), (2, 12), 0, cfg.vocab_size

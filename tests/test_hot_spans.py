@@ -226,16 +226,6 @@ def test_one_extra_metrics_reference_at_most_and_none_after_the_loop():
     assert len(device.live) == 0
 
 
-def test_phases_off_never_waits(monkeypatch):
-    monkeypatch.setenv(EnvKey.STEP_PHASES, "0")
-    device = FakeDevice(0.05)
-    trainer = fake_trainer(device)
-    t0 = time.monotonic()
-    trainer.run_batches(types.SimpleNamespace(step=0), batches(6))
-    assert time.monotonic() - t0 < 0.1      # six steps of 50 ms queued
-    assert trainer._prev_metrics is None
-
-
 @pytest.mark.timeout(180)
 def test_lagged_wait_leaves_losses_and_step_count_as_the_eager_wait():
     import optax
