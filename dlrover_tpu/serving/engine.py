@@ -26,12 +26,14 @@ of its stacks:
 Static shapes everywhere: slot count, cache length and prefill length
 are engine constants, so serving never recompiles after warmup.
 
-**Chunked-prefill admission**: ``step()`` runs at most ONE prefill
-chunk (plus at most one install) of admission work between decode
-iterations, so a long prompt joining the batch never stalls active
-decodes for more than one chunk's compute — the stall is measured into
-the ``dlrover_tpu_engine_decode_stall_seconds`` histogram and each
-completed admission emits an ``engine_admit`` journal instant.
+**Chunked-prefill admission**: beside a live batch ``step()`` runs at
+most one prefill chunk per decode STEP of the block it is about to run
+(installs ride along), so a request that is decoding waits for at most
+one chunk's compute per token it is about to receive — one chunk an
+engine step at ``decode_block`` 1, up to eight at 8. The stall is
+measured into the ``dlrover_tpu_engine_decode_stall_seconds`` histogram
+(one observation per admitting step) and each completed admission emits
+an ``engine_admit`` journal instant.
 
 **Paged KV slots** (``kv_pages > 0``): a physical page pool
 ``[L, pages, page_size, kv_heads, head_dim]`` backs the dense decode
@@ -489,6 +491,9 @@ class InferenceEngine:
         # admission state machine: at most one pending chunked prefill
         # plus a FIFO of parked generations awaiting a slot
         self._pending: _PendingAdmit | None = None
+        # prefill chunks run, ever: `_step` reads the difference across
+        # its admission for the `engine_step` span's `prefill_chunks`
+        self._chunks_run = 0
         self._parked: deque[_Parked] = deque()
         self.kv_parked_total = 0
         # sampling tensors are invalidated only on admit/park/retire —
@@ -1049,6 +1054,7 @@ class InferenceEngine:
                     )
             run.next_lo = lo + P
             run.chunks += 1
+            self._chunks_run += 1
             run.done = run.next_lo >= len(run.prompt)
             jax.block_until_ready(run.last)
             # the chunk is done: its counters cost no wait of their own
@@ -1419,9 +1425,11 @@ class InferenceEngine:
 
     def _admit_tick(self) -> bool:
         """At most ONE unit of admission work — a single prefill chunk,
-        plus at most one install — so active decodes are never stalled
-        longer than one chunk's compute. Returns True when device work
-        ran (the caller observes the stall histogram)."""
+        plus at most one install (or one resume). ``_step`` runs one
+        unit per decode step of the block beside a live batch, so
+        active decodes wait for one chunk's compute per token. Returns
+        True when device work ran (the caller observes the stall
+        histogram)."""
         if self._pending is None:
             # resumes first: their pages are already paid for and their
             # requester has waited longest
@@ -1490,6 +1498,14 @@ class InferenceEngine:
         while block * 2 <= cap:
             block *= 2
         return block
+
+    def _steps_ahead(self) -> int:
+        """Decode steps the rows decoding NOW are about to run: the
+        verify block's depth when speculation plans one, else the
+        block's. Read before admission — it is the admission's budget
+        of prefill chunks."""
+        plan = self._spec_plan() if self._spec else None
+        return plan[0] if plan is not None else self._block_size()
 
     def _spec_plan(self):
         """This step's verify depth + per-slot draft feed, or None for
@@ -1580,41 +1596,56 @@ class InferenceEngine:
                 _spec_collapsed_total.inc()
 
     def step(self) -> int:
-        """Admit (at most one chunk of) waiting work, decode one token
-        (or one compiled block) for every active slot, retire finished
-        ones. Returns number of active slots."""
+        """Admit waiting work (beside a live batch: at most one chunk
+        per decode step of the block), decode one token (or one
+        compiled block) for every active slot, retire finished ones.
+        Returns number of active slots."""
         with hot_span("engine_step", queued=len(self._queue)) as span:
-            decoding, n_steps, active = self._step()
-            span.set(decoding_slots=decoding, n_steps=n_steps)
+            decoding, n_steps, chunks, active = self._step()
+            span.set(decoding_slots=decoding, n_steps=n_steps,
+                     prefill_chunks=chunks)
         # slots in this step's decode call: what `slot_occupancy`
         # (requests a replica holds) cannot see
         self._decoding_gauge.set(decoding)
         return active
 
-    def _step(self) -> tuple[int, int, int]:
-        """(slots in the decode call, its steps, slots active after)."""
+    def _step(self) -> tuple[int, int, int, int]:
+        """(slots in the decode call, its steps, prefill chunks run
+        before it, slots active after)."""
         had_active = any(r is not None for r in self._active)
-        t0 = time.monotonic()
-        admitted = self._admit_tick()
-        if had_active and admitted:
-            # the decode stall this admission cost the active batch —
-            # bounded by one prefill chunk (+ install) by construction
-            _decode_stall_seconds.observe(time.monotonic() - t0)
-        elif not had_active:
+        chunks_before = self._chunks_run
+        if not had_active:
             # nobody was decoding: no stall to bound, so fill the
             # batch like the pre-chunking admission did (cold bursts —
             # the dominant test/rollout shape — keep their old step
-            # count; the one-unit bound only governs LIVE batches)
-            while (admitted
+            # count; the per-token bound only governs LIVE batches)
+            while (self._admit_tick()
                    and any(r is None for r in self._active)
                    and (self._queue or self._parked
                         or self._pending is not None)):
-                admitted = self._admit_tick()
+                pass
+        elif self._queue or self._parked or self._pending is not None:
+            # a live batch: one unit of admission work (a chunk and an
+            # install at most, or a resume) per decode step of the block
+            # the rows already decoding are about to run, so each of
+            # them waits for at most one chunk per token it receives
+            # (one unit an engine step at decode_block 1). An install
+            # clears `_pending`, so the next unit starts the next prompt.
+            budget = self._steps_ahead()
+            t0 = time.monotonic()
+            units = 0
+            while units < budget and self._admit_tick():
+                units += 1
+            if units:
+                # the decode stall this admission cost the live batch:
+                # one observation an engine step, of <= `budget` chunks
+                _decode_stall_seconds.observe(time.monotonic() - t0)
+        chunks = self._chunks_run - chunks_before
         active_mask = np.array(
             [r is not None for r in self._active], bool
         )
         if not active_mask.any():
-            return 0, 0, 0
+            return 0, 0, chunks, 0
         decoding = int(active_mask.sum())
         temp, top_k, top_p, eos_ids = self._sampling_tensors()
         args = (
@@ -1664,7 +1695,8 @@ class InferenceEngine:
         self._cache, self._last = cache, last
         with hot_span("engine_emit", tokens=int(counts.sum())):
             self._emit(toks, counts)
-        return decoding, n_steps, sum(r is not None for r in self._active)
+        return (decoding, n_steps, chunks,
+                sum(r is not None for r in self._active))
 
     def _note_counted(self, counted: dict) -> dict:
         """A decode call's counters as span fields, and onto their

@@ -170,50 +170,178 @@ def test_eos_request_no_longer_serializes_batchmates(params):
     assert res[r_mate].tokens == want_mate
 
 
-@pytest.mark.timeout(300)
-def test_chunked_admission_bounds_decode_stall(params):
-    """ISSUE 12 tentpole (a): a long prompt joining the batch runs at
-    most ONE prefill chunk between decode steps — the active slot keeps
-    emitting tokens while the newcomer prefills, and the stall
-    histogram records each admission slice."""
-    from dlrover_tpu.serving import engine as engine_mod
-
-    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
-                          prefill_len=8)
-    chunk_calls = []
+def _count_chunks(eng) -> list:
+    """Spy on the engine's prefill program: one entry per chunk run."""
+    calls = []
     orig = eng._prefill_chunk
 
     def spy(*a):
-        chunk_calls.append(True)
+        calls.append(True)
         return orig(*a)
 
     eng._prefill_chunk = spy
-    active = eng.submit([1, 2], SamplingParams(temperature=0.0,
-                                               max_new_tokens=30))
-    eng.step()                      # admit + first token
-    assert eng._active[0] is not None
+    return calls
+
+
+def _stall_count() -> int:
+    from dlrover_tpu.serving import engine as engine_mod
+
     samp = engine_mod._decode_stall_seconds.samples()
-    count_before = samp[0]["count"] if samp else 0
-    long_prompt = list((np.arange(40) * 3 + 1) % CFG.vocab_size)
+    return samp[0]["count"] if samp else 0
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("decode_block", [1, 8])
+def test_chunked_admission_bounds_decode_stall(params, decode_block):
+    """A long prompt joining a live batch runs at most one prefill chunk
+    per decode STEP of the block beside it (ISSUE 12's one chunk per
+    engine step at ``decode_block`` 1, ISSUE 30's ``n_steps`` at 8): a
+    decoding request waits for one chunk per token it is about to
+    receive, keeps emitting on every engine step of the admission, and
+    each admitting step lands ONCE in the stall histogram."""
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
+                          prefill_len=4, decode_block=decode_block)
+    chunk_calls = _count_chunks(eng)
+    active = eng.submit([1, 2], SamplingParams(temperature=0.0,
+                                               max_new_tokens=40))
+    eng.step()                      # admit + first block
+    assert eng._active[0] is not None
+    long_prompt = list((np.arange(43) * 3 + 1) % CFG.vocab_size)
     eng.submit(long_prompt, SamplingParams(temperature=0.0,
-                                           max_new_tokens=4))  # 5 chunks
-    emitted_at = []
+                                           max_new_tokens=16))  # 11 chunks
+    emitted_at = [len(eng._emitted[0])]
+    admitting_steps = 0
+    stalls_before = _stall_count()
     while not any(r is not None and r.id != active
                   for r in eng._active):
         chunks_before = len(chunk_calls)
         eng.step()
-        # at most one chunk of admission work ran in this step...
-        assert len(chunk_calls) - chunks_before <= 1
-        # ...and the active request took a decode step alongside it
+        ran = len(chunk_calls) - chunks_before
+        # the block this step's admission ran beside: what the live
+        # request had left, on the power-of-two ladder
+        left = 40 - emitted_at[-1]
+        beside = min(decode_block, 1 << (left.bit_length() - 1))
+        assert 1 <= ran <= beside     # at decode_block 1: exactly one
+        admitting_steps += 1
         emitted_at.append(len(eng._emitted[0]))
-        assert len(emitted_at) < 30
-    # the active slot made progress on EVERY step of the admission
-    assert emitted_at == sorted(emitted_at)
-    assert emitted_at[-1] - emitted_at[0] >= 3
-    # every admission slice landed in the stall histogram
-    stall_hist = engine_mod._decode_stall_seconds.samples()[0]
-    assert stall_hist["count"] > count_before
+        assert admitting_steps < 30
+    # the live request made progress on EVERY step of the admission
+    assert all(b > a for a, b in zip(emitted_at, emitted_at[1:]))
+    assert len(chunk_calls) == 1 + 11
+    assert admitting_steps == (11 if decode_block == 1 else 2)
+    # one observation per admitting step, however many chunks it ran
+    assert _stall_count() - stalls_before == admitting_steps
     eng.run()
+
+
+def _engine_step_spans(journal_dir) -> list[dict]:
+    from dlrover_tpu.telemetry.report import load_events
+
+    return [e for e in load_events(str(journal_dir / "events.jsonl"))
+            if e["ev"] == "e" and e["name"] == "engine_step"]
+
+
+@pytest.fixture()
+def journal_dir(tmp_path, monkeypatch):
+    from dlrover_tpu.common.constants import EnvKey
+    from dlrover_tpu.telemetry import journal as journal_mod
+
+    monkeypatch.setenv(EnvKey.JOURNAL_DIR, str(tmp_path / "journal"))
+    monkeypatch.delenv(EnvKey.JOURNAL_MAX_MB, raising=False)
+    monkeypatch.setattr(journal_mod, "_cached", None)
+    yield tmp_path / "journal"
+    journal_mod._cached = None
+
+
+@pytest.mark.timeout(300)
+def test_long_prompt_admits_in_chunks_over_block_steps(params,
+                                                       journal_dir):
+    """ISSUE 30 (a): beside a live batch decoding blocks of 8, a queued
+    prompt of 11 chunks finishes its admission in ceil(11 / 8) engine
+    steps, and the ``engine_step`` span says how many chunks each ran."""
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
+                          prefill_len=4, decode_block=8)
+    eng.submit([1, 2], SamplingParams(temperature=0.0,
+                                      max_new_tokens=48))
+    eng.step()
+    long_prompt = list((np.arange(43) * 3 + 1) % CFG.vocab_size)
+    eng.submit(long_prompt, SamplingParams(temperature=0.0,
+                                           max_new_tokens=16))
+    assert eng.step() == 1          # 8 chunks beside a block of 8
+    assert eng._pending is not None and eng._pending.run.chunks == 8
+    assert eng.step() == 2          # the last 3, and the install
+    assert eng._pending is None
+    eng.step()                      # nothing left to admit
+    spans = _engine_step_spans(journal_dir)
+    assert [e["prefill_chunks"] for e in spans] == [1, 8, 3, 0]
+    assert [e["n_steps"] for e in spans] == [8, 8, 8, 8]
+    assert [e["decoding_slots"] for e in spans] == [1, 1, 2, 2]
+    eng.run()
+
+
+@pytest.mark.timeout(300)
+def test_short_prompts_fill_free_slots_in_one_engine_step(params):
+    """ISSUE 30 (b): one-chunk prompts queued behind a live batch are
+    all prefilled AND installed in one engine step while slots stand
+    free (an install clears the pending admission, so the next unit
+    starts the next prompt); the one that finds no slot is prefilled
+    ahead and waits."""
+    eng = InferenceEngine(params, CFG, slots=4, max_len=64,
+                          prefill_len=8, decode_block=8)
+    chunk_calls = _count_chunks(eng)
+    sp = SamplingParams(temperature=0.0, max_new_tokens=24)
+    first = eng.submit([1, 2], sp)
+    eng.step()
+    ids = [eng.submit([3 + i, 5, 7 + i], sp) for i in range(4)]
+    stalls_before = _stall_count()
+    assert eng.step() == 4
+    assert [r.id for r in eng._active] == [first] + ids[:3]
+    # the fourth found no slot: prefilled, pending, one chunk run
+    assert len(chunk_calls) == 1 + 4
+    assert eng._pending.req.id == ids[3] and eng._pending.run.done
+    assert _stall_count() - stalls_before == 1
+    results = {r.id: r for r in eng.run()}
+    assert sorted(results) == [first] + ids
+    assert all(len(r.tokens) == 24 for r in results.values())
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("temperature", [0.0, 0.9],
+                         ids=["greedy", "sampled"])
+def test_decode_block_changes_the_schedule_not_a_stream(params,
+                                                        temperature):
+    """ISSUE 30 (c): the same requests through ``decode_block`` 1 and 8
+    (one chunk an engine step, up to eight) return the same tokens per
+    request: sampling is keyed by the request's seed and draw index,
+    so the order and pace of admission reach no stream."""
+    rng = np.random.default_rng(7)
+    reqs = [
+        (list(rng.integers(1, CFG.vocab_size, int(n))),
+         SamplingParams(temperature=temperature, top_p=0.9,
+                        max_new_tokens=int(m), seed=100 + i))
+        for i, (n, m) in enumerate(
+            [(2, 20), (19, 6), (5, 12), (26, 9), (3, 17), (11, 5)])
+    ]
+    streams, steps = {}, {}
+    for block in (1, 8):
+        eng = InferenceEngine(params, CFG, slots=3, max_len=64,
+                              prefill_len=4, decode_block=block)
+        chunk_calls = _count_chunks(eng)
+        # one live request first, so the others admit beside a batch
+        ids = [eng.submit(*reqs[0])]
+        eng.step()
+        ids += [eng.submit(p, sp) for p, sp in reqs[1:]]
+        n = 1
+        while eng.outstanding:
+            eng.step()
+            n += 1
+        got = {r.id: r.tokens for r in eng.poll_results()}
+        streams[block] = [got[i] for i in ids]
+        steps[block] = n
+        assert len(chunk_calls) == sum(-(-len(p) // 4) for p, _ in reqs)
+    assert streams[1] == streams[8]
+    assert [len(t) for t in streams[8]] == [20, 6, 12, 9, 17, 5]
+    assert steps[8] < steps[1]
 
 
 def test_sampling_tensors_cached_between_steps(params):
