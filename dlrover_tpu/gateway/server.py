@@ -106,6 +106,10 @@ class GatewayResult:
     # monotonic arrival stamp per token (the bench derives TTFT and
     # inter-token-latency percentiles from these)
     token_times: list = dataclasses.field(default_factory=list)
+    # a block-diffusion replica: the denoising pass that unmasked each
+    # token (``serving.engine.Result.unmask_steps``); a block's tokens
+    # arrive together, so their ``token_times`` lie a callback apart
+    unmask_steps: list = dataclasses.field(default_factory=list)
 
 
 class AdmissionController:
@@ -461,6 +465,7 @@ class Gateway:
                 total_s=total, queue_s=queue_s, prefill_s=prefill_s,
                 decode_s=decode_s,
                 token_times=list(work.token_times),
+                unmask_steps=list(getattr(res, "unmask_steps", ())),
             ))
 
     def _journal_request(self, work: RequestWork, res: Any,

@@ -57,11 +57,17 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
 
         return latent.init_cache(c, batch, max_len)
     shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
-    return {
+    cache = {
         "k": jnp.zeros(shape, jnp.dtype(c.dtype)),
         "v": jnp.zeros(shape, jnp.dtype(c.dtype)),
         "pos": jnp.zeros((), jnp.int32),
     }
+    if c.held_experts:
+        from dlrover_tpu.ops.moe import held_counters
+
+        cache["counters"] = held_counters(
+            c.n_layers, tfm.routed_config(c).n_held)
+    return cache
 
 
 def cache_stacks(cache: dict) -> dict:
@@ -86,7 +92,7 @@ def zero_counters(cache: dict) -> dict:
                                               cache["counters"])}
 
 
-def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0):
+def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0, block=0):
     """q: [B, S_new, H, D] against cache [B, max_len, H_kv, D].
 
     GQA reads the cache UNEXPANDED via a grouped-head einsum — repeating
@@ -95,7 +101,11 @@ def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0):
     mask so decode matches a model trained with local attention.
     ``pos`` scalar: all rows in lockstep (one [S, K] mask). [B] vector:
     independent per-row positions (continuous batching,
-    serving/engine.py) with a [B, S, K] mask.
+    serving/engine.py) with a [B, S, K] mask. ``block > 0`` is a
+    block-diffusion model's mask in place of the causal one: a query
+    sees every key up to the END of its own block of ``block`` absolute
+    positions (``k < (q // block + 1) * block``), in every program: a
+    prefill chunk, a denoising pass, a storing pass.
     """
     B, S_new, H, D = q.shape
     scale = 1.0 / math.sqrt(D)
@@ -110,14 +120,21 @@ def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0):
     if jnp.ndim(pos) == 0:
         # causal over absolute positions: query i sits at pos + i
         q_pos = pos + jnp.arange(S_new)
-        mask = q_pos[:, None] >= k_pos[None, :]            # [S, K]
+        if block > 0:
+            mask = k_pos[None, :] < ((q_pos // block + 1) * block)[:, None]
+        else:
+            mask = q_pos[:, None] >= k_pos[None, :]        # [S, K]
         if window > 0:
             mask &= q_pos[:, None] - k_pos[None, :] < window
         mask = mask[None, None, None]
     else:
         # row b's query i sits at pos[b] + i
         q_pos = pos[:, None] + jnp.arange(S_new)[None]     # [B, S_new]
-        mask = q_pos[:, :, None] >= k_pos[None, None, :]   # [B, S, K]
+        if block > 0:
+            mask = (k_pos[None, None, :]
+                    < ((q_pos // block + 1) * block)[:, :, None])
+        else:
+            mask = q_pos[:, :, None] >= k_pos[None, None, :]  # [B, S, K]
         if window > 0:
             mask &= q_pos[:, :, None] - k_pos[None, None, :] < window
         mask = mask[:, None, None]
@@ -185,6 +202,13 @@ def forward_cached(
     compile once each (static shapes). ``cache['pos']`` may be a scalar
     (all rows in lockstep — generate()) or a [B] vector (independent
     per-row positions — the continuous-batching serving engine).
+
+    A block-diffusion model (``cfg.generation``) attends block-causally
+    (:func:`_layer_attend`), so a call's rows see each other inside a
+    block. Its DENOISING pass is this call with ``pos`` put back by the
+    caller: the block's rows are written (the call's queries read them)
+    and the next pass, and last the storing pass from the final tokens,
+    write them again; only the storing pass keeps the advance.
     """
     c = cfg
     if c.new_kinds:
@@ -204,6 +228,7 @@ def forward_cached(
     # kind): other attention kinds ignore attention_window in training,
     # so decode must too or the masks diverge
     window = c.attention_window if c.attention == "splash" else 0
+    block = c.block_length if c.generation == "block_diffusion" else 0
 
     def attend(q, k, v, state):
         k_stack, v_stack, l = state
@@ -213,31 +238,40 @@ def forward_cached(
         o = _layer_attend(
             q, lax.dynamic_index_in_dim(k_stack, l, keepdims=False),
             lax.dynamic_index_in_dim(v_stack, l, keepdims=False),
-            pos, n_rep, dt, window=window,
+            pos, n_rep, dt, window=window, block=block,
         )
         return o, (k_stack, v_stack, l)
 
-    block = tfm.make_layer_fn(
+    # the held experts' stacks are closed over the block and indexed in
+    # place by its tile loop; everything else is scanned in
+    experts, scanned = tfm.split_experts(params["layers"], c)
+    run_layer = tfm.make_layer_fn(
         c, attend=attend,
-        positions=tfm.token_positions(pos, B, S_new))
+        positions=tfm.token_positions(pos, B, S_new), experts=experts)
 
     def layer(carry, inputs):
         x, k_stack, v_stack = carry
         w, l = inputs
-        x, _, (k_stack, v_stack, _) = block(x, w, (k_stack, v_stack, l))
-        return (x, k_stack, v_stack), None
+        x, aux, (k_stack, v_stack, _) = run_layer(
+            x, w, (k_stack, v_stack, l), l)
+        return (x, k_stack, v_stack), (aux if c.held_experts else None)
 
     # the stack rides the CARRY: a scanned input or output of the
     # per-layer shape would be sliced out and copied back whole, per
     # layer, for the sake of S_new new rows
-    (x, k_new, v_new), _ = lax.scan(
+    (x, k_new, v_new), loads = lax.scan(
         layer, (tfm.embed_tokens(params, tokens, c, pos=pos),
                 cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(c.n_layers, dtype=jnp.int32)),
+        (scanned, jnp.arange(c.n_layers, dtype=jnp.int32)),
     )
     with jax.named_scope("lm_head"):
         logits = tfm.lm_logits(params, tfm.final_norm(params, x, c), c)
-    return logits, {"k": k_new, "v": v_new, "pos": pos + S_new}
+    new = {"k": k_new, "v": v_new, "pos": pos + S_new}
+    if c.held_experts:
+        from dlrover_tpu.ops.moe import count_loads
+
+        new["counters"] = count_loads(cache["counters"], loads)
+    return logits, new
 
 
 @jax.named_scope("sample")

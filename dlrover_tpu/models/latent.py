@@ -120,39 +120,15 @@ def param_shapes(cfg) -> dict:
 def init_params(cfg, key: jax.Array) -> Params:
     """Seeded weights in ``cfg.param_dtype``: matrices normal /
     sqrt(fan_in) (the contracted dim), norm scales one."""
-    dt = jnp.dtype(cfg.param_dtype)
-    shapes = param_shapes(cfg)
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        shapes, is_leaf=lambda s: isinstance(s, tuple))
-    leaves = []
-    for i, (path, shape) in enumerate(flat):
-        name = path[-1].key
-        if name.startswith("ln"):
-            leaves.append(jnp.ones(shape, dt))
-            continue
-        stacked = len(path) > 1
-        core = shape[1:] if stacked else shape
-        if name in EXPERT_STACKS:
-            fan_in = core[1]
-        elif name == "w_o":
-            fan_in = core[0] * core[1]
-        else:
-            fan_in = core[0]
-        leaves.append((jax.random.normal(jax.random.fold_in(key, i), shape,
-                                         jnp.float32)
-                       / math.sqrt(fan_in)).astype(dt))
-    return jax.tree_util.tree_unflatten(treedef, leaves)
+    from dlrover_tpu.models.transformer import init_from_shapes
+
+    return init_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
 
 
 def init_cache(cfg, batch: int, max_len: int) -> dict:
     """The cache tree: the latent stack, the position, and the expert
-    layer's counters, which every cached call adds to: ``loads [expert
-    layers, held]`` (assignments each held expert took) and the scalars
-    a span reports by these names (``decode.cache_counter_fields``):
-    ``expert_tokens`` (the sum of ``loads``), ``expert_load_max`` (its
-    largest cell), ``expert_load_max_over_mean`` (that cell against the
-    mean cell; 1.0 is even) and ``experts_hit`` (held experts that took
-    at least one assignment, summed over layers and calls)."""
+    layers' counters (``ops/moe.held_counters``), which every cached
+    call adds to."""
     c = cfg
     cache = {
         "latent": jnp.zeros(
@@ -162,28 +138,9 @@ def init_cache(cfg, batch: int, max_len: int) -> dict:
     }
     n_expert = sum(n for _, experts, n in segments(c) if experts)
     if n_expert:
-        # a buffer of its own for each: the tree is donated leaf by leaf
-        cache["counters"] = {
-            "loads": jnp.zeros((n_expert, routed_config(c).n_held),
-                               jnp.int32),
-            **{name: jnp.zeros((), jnp.int32) for name in (
-                "expert_tokens", "expert_load_max", "experts_hit")},
-            "expert_load_max_over_mean": jnp.zeros((), jnp.float32)}
+        cache["counters"] = moe.held_counters(n_expert,
+                                              routed_config(c).n_held)
     return cache
-
-
-def _count(counters: dict, loads: jax.Array) -> dict:
-    """``counters`` after a call whose expert layers took ``loads``."""
-    total = counters["loads"] + loads
-    n, top = total.sum(), total.max()
-    return {
-        "loads": total, "expert_tokens": n, "expert_load_max": top,
-        "experts_hit": counters["experts_hit"]
-        + (loads > 0).sum().astype(jnp.int32),
-        "expert_load_max_over_mean": jnp.where(
-            n > 0, top * total.size / jnp.maximum(n, 1), 0.0
-        ).astype(jnp.float32),
-    }
 
 
 def _rms(x, scale, eps):
@@ -343,7 +300,7 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
         (x, stack), loads = lax.scan(
             layer, (x, stack), (scanned, jnp.arange(n, dtype=jnp.int32)))
         if is_expert and counters is not None:
-            counters = _count(counters, loads)
+            counters = moe.count_loads(counters, loads)
         first += n
     with jax.named_scope("lm_head"):
         x = _rms(x, params["ln_f"], eps)

@@ -119,10 +119,20 @@ class TransformerConfig:
     # sigmoid-routed expert layer (ops/moe.py RoutedConfig: top
     # `moe_top_k` of `n_routed_experts`, SwiGLU experts of `moe_d_ff`)
     # beside `n_shared_experts` shared ones.
+    # THIS file's block runs two kinds more, served only (`make_layer_fn`
+    # refuses them by name where a path cannot run them): attn_kind
+    # "heads_qk_norm" (an RMSNorm over `head_dim` on every query and key
+    # head before the rotary embedding, scales `ln_q`, `ln_k`) and
+    # ffn_kind "softmax_experts" (every layer `n_routed_experts` SwiGLU
+    # experts of `moe_d_ff`, `moe_top_k` a token by a softmax router
+    # renormalised over the chosen, no capacity and no drops:
+    # ops/moe.py `softmax_topk_route` + `held_expert_ffn`).
     attn_kind: str = "heads"
     norm_kind: str = "pre"
     ffn_kind: str = ""
-    norm_eps: float = 1e-5          # the new kinds' RMSNorm
+    # the RMSNorm eps of every kind but the defaults (a llama-variant
+    # block of default kinds keeps the 1e-6 it always had)
+    norm_eps: float = 1e-5
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -143,15 +153,57 @@ class TransformerConfig:
     # the dtype the weights rest in ("bfloat16": as published, no
     # per-call conversion)
     param_dtype: str = "float32"
+    # a head's width where it is not d_model / n_heads (0: that). Set
+    # at construction: `dataclasses.replace(cfg, d_model=..)` of a
+    # config that left it 0 must pass `head_dim=0` again
+    head_dim: int = 0
+    # rotary pairing: "interleaved" pairs components (2i, 2i + 1),
+    # "half" pairs i with i + head_dim / 2 (the Hugging Face layout)
+    rope_pairing: str = "interleaved"
+    # --- how the model GENERATES (serving/engine.py compiles one decode
+    # program per (block_length, denoising_steps); no environment key).
+    # "block_diffusion": positions in blocks of `block_length` by
+    # absolute position, attention block-causal in every program (a
+    # query sees every key up to the END of its own block), a block
+    # generated by `denoising_steps` passes over `mask_token_id`
+    # placeholders (each pass unmasks the block_length /
+    # denoising_steps still-masked positions of highest confidence)
+    # and one storing pass; logits at a position predict THAT position
+    generation: str = "autoregressive"
+    block_length: int = 0
+    denoising_steps: int = 0
+    mask_token_id: int = -1
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        if self.generation == "block_diffusion":
+            if (self.block_length < 1 or self.denoising_steps < 1
+                    or self.block_length % self.denoising_steps
+                    or not 0 <= self.mask_token_id < self.vocab_size):
+                raise ValueError(
+                    "block_diffusion needs block_length >= 1, "
+                    "denoising_steps dividing it and a mask_token_id "
+                    f"inside the vocabulary: got {self.block_length}, "
+                    f"{self.denoising_steps}, {self.mask_token_id}")
+        elif self.generation != "autoregressive":
+            raise ValueError(f"unknown generation {self.generation!r}")
 
     @property
     def new_kinds(self) -> bool:
         """True where models/latent.py runs the block."""
-        return (self.attn_kind, self.norm_kind, self.ffn_kind) != (
+        return (self.attn_kind == "latent" or self.norm_kind != "pre"
+                or self.ffn_kind == "sigmoid_experts")
+
+    @property
+    def held_experts(self) -> bool:
+        """True where this file's block runs the no-drop expert layer."""
+        return self.ffn_kind == "softmax_experts"
+
+    @property
+    def default_kinds(self) -> bool:
+        return (self.attn_kind, self.norm_kind, self.ffn_kind) == (
             "heads", "pre", "")
 
     @property
@@ -159,8 +211,14 @@ class TransformerConfig:
         c = self
         if c.new_kinds:
             # the parameters HELD (the share), counted from the shapes
-            from dlrover_tpu.models.latent import param_shapes
+            from dlrover_tpu.models.latent import (
+                param_shapes as latent_shapes,
+            )
 
+            return sum(math.prod(s) for s in jax.tree.leaves(
+                latent_shapes(c), is_leaf=lambda s: isinstance(s, tuple)))
+        if not c.default_kinds:
+            # counted from the shapes, as the kinds above are
             return sum(math.prod(s) for s in jax.tree.leaves(
                 param_shapes(c), is_leaf=lambda s: isinstance(s, tuple)))
         embed = c.vocab_size * c.d_model
@@ -194,6 +252,11 @@ class TransformerConfig:
                 "train_flops_per_token: the latent / sandwich / "
                 "sigmoid_experts kinds are served, not trained "
                 "(benchmark/counts/mla_moe.py counts their forward)")
+        if not c.default_kinds:
+            raise NotImplementedError(
+                f"train_flops_per_token: attn_kind {c.attn_kind!r} / "
+                f"ffn_kind {c.ffn_kind!r} are served, not trained "
+                "(forward_flops_per_token counts their forward)")
         attn = c.d_model * c.head_dim * (c.n_heads * 2 + c.n_kv_heads * 2)
         if c.moe_experts:
             ffn = (c.d_model * c.moe_experts
@@ -204,6 +267,33 @@ class TransformerConfig:
         keys = (seq + 1) / 2 if c.causal else seq
         scores = c.n_layers * 2 * 2 * c.n_heads * c.head_dim * keys
         return 3.0 * (2.0 * matmul + scores)
+
+
+    def forward_flops_per_token(self, keys: float) -> float:
+        """Model FLOPs of one token's forward pass with ``keys`` keys in
+        its sight, from the shapes (2 per multiply-add; a token passes
+        ``moe_top_k`` of the routed experts): what this file's served
+        kinds count where training's kinds count
+        :meth:`train_flops_per_token`."""
+        c = self
+        if c.new_kinds:
+            raise NotImplementedError(
+                "forward_flops_per_token: benchmark/counts/mla_moe.py "
+                "counts the latent kinds' forward")
+        layer = param_shapes(c)["layers"]
+        matmul = 0
+        for name, shape in layer.items():
+            if name.startswith("ln") or name in ("b_ff", "b_out"):
+                continue
+            n = math.prod(shape[1:])
+            if name in ("we_gate", "we_up", "we_down"):
+                n = n // c.n_routed_experts * c.moe_top_k
+            elif name in ("w_in", "w_out"):
+                n = n // c.moe_experts * c.moe_top_k
+            matmul += n
+        scores = 2 * 2 * c.n_heads * c.head_dim * keys
+        return c.n_layers * (2.0 * matmul + scores) + (
+            2.0 * c.d_model * c.vocab_size)
 
 
 # Per-layer remat policies for remat_scan (distinct from the step-level
@@ -295,10 +385,131 @@ CONFIGS = {
         moe_top_k=8, moe_d_ff=2048, n_shared_experts=1,
         routed_scaling_factor=2.5, norm_topk_prob=True,
         param_dtype="bfloat16"),
+    # the kinds of the entry below at a size for CPU tests (head_dim is
+    # NOT d_model / n_heads there either)
+    "tiny-sdar-moe": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=2,
+        head_dim=24, d_ff=96, max_seq_len=256, rope_theta=10000.0,
+        rope_pairing="half", norm_eps=1e-6, attn_kind="heads_qk_norm",
+        ffn_kind="softmax_experts", n_routed_experts=16, moe_top_k=4,
+        moe_d_ff=32, norm_topk_prob=True, dtype="float32",
+        generation="block_diffusion", block_length=4, denoising_steps=4,
+        mask_token_id=255),
+    # SDAR-30B-A3B-Chat as published (config.json, model_type sdar_moe:
+    # Qwen3-MoE's block); block length, passes and the mask id are the
+    # family's released generation defaults (not in config.json). A
+    # deployment sets the layers it holds with dataclasses.replace.
+    "sdar-30b-a3b-chat": TransformerConfig(
+        vocab_size=151936, d_model=2048, n_layers=48, n_heads=32,
+        n_kv_heads=4, head_dim=128, d_ff=6144, max_seq_len=32768,
+        rope_theta=1000000.0, rope_pairing="half", norm_eps=1e-6,
+        attn_kind="heads_qk_norm", ffn_kind="softmax_experts",
+        n_routed_experts=128, moe_top_k=8, moe_d_ff=768,
+        norm_topk_prob=True, param_dtype="bfloat16",
+        generation="block_diffusion", block_length=4, denoising_steps=4,
+        mask_token_id=151669),
 }
 
 
 # ------------------------------------------------------------------- init
+
+
+EXPERT_STACKS = ("we_gate", "we_up", "we_down")
+
+
+def routed_config(cfg: TransformerConfig):
+    """``ops/moe.RoutedConfig`` of the 'softmax_experts' layer."""
+    from dlrover_tpu.ops.moe import RoutedConfig
+
+    return RoutedConfig(
+        n_experts=cfg.n_routed_experts, top_k=cfg.moe_top_k,
+        norm_topk=cfg.norm_topk_prob, first=cfg.expert_first,
+        held=cfg.experts_held)
+
+
+def _check_kinds(cfg: TransformerConfig) -> None:
+    """This file's block runs these kinds and names what it does not."""
+    c = cfg
+    if (c.attn_kind not in ("heads", "heads_qk_norm") or c.norm_kind != "pre"
+            or c.ffn_kind not in ("", "softmax_experts")
+            or (c.ffn_kind and c.moe_experts)
+            or (not c.default_kinds and c.variant != "llama")
+            or c.rope_pairing not in ("interleaved", "half")):
+        raise NotImplementedError(
+            f"attn_kind / norm_kind / ffn_kind ({c.attn_kind!r}, "
+            f"{c.norm_kind!r}, {c.ffn_kind!r}) with variant {c.variant!r}"
+            f", moe_experts {c.moe_experts}, rope_pairing "
+            f"{c.rope_pairing!r}: models/transformer.py runs 'heads' or "
+            "'heads_qk_norm' attention under 'pre' norms with the "
+            "variant's FFN, `moe_experts` or 'softmax_experts' (llama "
+            "variant), and models/latent.py runs ('latent', 'sandwich', "
+            "'sigmoid_experts')")
+
+
+def param_shapes(cfg: TransformerConfig) -> dict:
+    """The parameter tree of this file's block as shapes (a tuple a
+    leaf): what :func:`init_params` makes and the counts count."""
+    c = cfg
+    _check_kinds(c)
+    e, hd, n = c.d_model, c.head_dim, c.n_layers
+    layers = {
+        "wq": (e, c.n_heads, hd), "wk": (e, c.n_kv_heads, hd),
+        "wv": (e, c.n_kv_heads, hd), "wo": (c.n_heads, hd, e),
+        "ln1": (e,), "ln2": (e,),
+    }
+    if c.attn_kind == "heads_qk_norm":
+        layers.update(ln_q=(hd,), ln_k=(hd,))
+    if c.held_experts:
+        held, f = routed_config(c).n_held, c.moe_d_ff
+        layers.update(w_router=(e, c.n_routed_experts),
+                      we_gate=(held, e, f), we_up=(held, e, f),
+                      we_down=(held, f, e))
+    elif c.moe_experts:
+        layers.update(w_router=(e, c.moe_experts),
+                      w_in=(c.moe_experts, e, c.d_ff),
+                      w_out=(c.moe_experts, c.d_ff, e))
+    else:
+        layers.update(w_gate=(e, c.d_ff), w_down=(c.d_ff, e))
+        if c.variant == "llama":
+            layers["w_up"] = (e, c.d_ff)
+        else:
+            layers.update(b_ff=(c.d_ff,), b_out=(e,), ln1_b=(e,),
+                          ln2_b=(e,))
+    tree = {"embed": (c.vocab_size, e),
+            "layers": {k: (n, *v) for k, v in layers.items()},
+            "ln_f": (e,), "lm_head": (e, c.vocab_size)}
+    if c.variant == "gpt2":
+        tree.update(pos_embed=(c.max_seq_len, e), ln_f_b=(e,))
+    return tree
+
+
+def init_from_shapes(shapes: dict, key: jax.Array, dtype) -> Params:
+    """Seeded weights for a tree of shapes (a tuple a leaf; a nested
+    dict's leaves are stacked along a leading layer dim), in ``dtype``:
+    matrices normal / sqrt(fan_in) (the contracted dims: an expert
+    stack's second, an output projection's first two, else the first),
+    norm scales (``ln*``) one. The served kinds' init, here and in
+    ``models/latent.py``."""
+    dt = jnp.dtype(dtype)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda s: isinstance(s, tuple))
+    leaves = []
+    for i, (path, shape) in enumerate(flat):
+        name = path[-1].key
+        if name.startswith("ln"):
+            leaves.append(jnp.ones(shape, dt))
+            continue
+        core = shape[1:] if len(path) > 1 else shape
+        if name in EXPERT_STACKS:
+            fan_in = core[1]
+        elif name in ("wo", "w_o"):
+            fan_in = core[0] * core[1]
+        else:
+            fan_in = core[0]
+        leaves.append((jax.random.normal(jax.random.fold_in(key, i), shape,
+                                         jnp.float32)
+                       / math.sqrt(fan_in)).astype(dt))
+    return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
@@ -308,6 +519,9 @@ def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
         from dlrover_tpu.models.latent import init_params as init_latent
 
         return init_latent(c, key)
+    _check_kinds(c)
+    if not c.default_kinds:
+        return init_from_shapes(param_shapes(c), key, c.param_dtype)
     k_embed, k_layers, k_out, k_pos = jax.random.split(key, 4)
     hd = c.head_dim
 
@@ -382,6 +596,11 @@ def logical_axes(cfg: TransformerConfig) -> Params:
             "logical_axes: the latent / sandwich / sigmoid_experts kinds "
             "run on one device (models/latent.py); no rule table names "
             "their weights yet")
+    if not c.default_kinds:
+        raise NotImplementedError(
+            f"logical_axes: attn_kind {c.attn_kind!r} / ffn_kind "
+            f"{c.ffn_kind!r} are served on one device; no rule table "
+            "names ln_q, ln_k or the held experts' stacks yet")
     layers = {
         "wq": ("layers", "embed", "heads", None),
         "wk": ("layers", "embed", "kv_heads", None),
@@ -422,10 +641,11 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # ---------------------------------------------------------------- forward
 
 
-def _norm(x, scale, bias, variant: str):
-    if variant == "llama":  # RMSNorm
+def _norm(x, scale, bias, variant: str, eps: float = 1e-6):
+    if variant == "llama":  # RMSNorm; `eps` is cfg.norm_eps where the
+        # block's kinds set it (`_norm_eps`), else the 1e-6 it always was
         x32 = x.astype(jnp.float32)
-        inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+        inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
         return (x32 * inv).astype(x.dtype) * scale.astype(x.dtype)
     x32 = x.astype(jnp.float32)
     mean = jnp.mean(x32, axis=-1, keepdims=True)
@@ -435,15 +655,30 @@ def _norm(x, scale, bias, variant: str):
     return out + bias.astype(x.dtype) if bias is not None else out
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over the last dim. x: [B, S, H, D]."""
+def _norm_eps(cfg: TransformerConfig) -> float:
+    """The RMSNorm eps of a block: ``cfg.norm_eps`` where its kinds set
+    it, the 1e-6 a llama-variant block of default kinds always had."""
+    return 1e-6 if cfg.default_kinds else cfg.norm_eps
+
+
+def _rope(x: jax.Array, positions: jax.Array, theta: float,
+          pairing: str = "interleaved") -> jax.Array:
+    """Rotary embedding over the last dim. x: [B, S, H, D]. ``pairing``
+    "interleaved" rotates components (2i, 2i + 1) together, "half"
+    component i with i + D / 2."""
     d = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[:, :, None, None].astype(jnp.float32) * freqs  # B,S,1,d/2
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
+    if pairing == "half":
+        x1, x2 = x[..., : d // 2], x[..., d // 2:]
+    else:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
     cos = cos.astype(x.dtype)
     sin = sin.astype(x.dtype)
+    if pairing == "half":
+        return jnp.concatenate(
+            [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.reshape(x.shape)
 
@@ -457,6 +692,23 @@ def dense_attention(q, k, v, *, causal: bool = True) -> jax.Array:
         mask = jnp.tril(jnp.ones((s_q, s_k), bool))
         logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def block_causal_attention(q, k, v, *, block: int,
+                           causal: bool = True) -> jax.Array:
+    """Attention of a block-diffusion model over a whole sequence:
+    positions in blocks of ``block``, a query sees every key up to the
+    END of its own block (``k < (q // block + 1) * block``). [B,S,H,D];
+    fp32 softmax. ``causal`` is accepted for the call's form and has to
+    be true."""
+    assert causal
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    ends = (jnp.arange(q.shape[1]) // block + 1) * block
+    mask = jnp.arange(k.shape[1])[None, :] < ends[:, None]
+    probs = jax.nn.softmax(jnp.where(mask, logits, -1e30),
+                           axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
@@ -498,7 +750,7 @@ AttentionFn = Callable[..., jax.Array]
 # ``cfg.dtype`` for a holder that runs the block many times.
 PRODUCT_LEAVES = frozenset({
     "embed", "pos_embed", "wq", "wk", "wv", "wo", "w_gate", "w_up",
-    "w_down", "b_ff", "b_out", "lm_head"})
+    "w_down", "b_ff", "b_out", "lm_head", *EXPERT_STACKS})
 
 
 def _leaf(tree, name: str, dt) -> jax.Array:
@@ -531,9 +783,11 @@ def make_layer_fn(
     mask: jax.Array | None = None,
     attend: Callable | None = None,
     positions: jax.Array | None = None,
+    experts: dict | None = None,
 ) -> Callable[..., tuple[jax.Array, jax.Array, Any]]:
-    """One transformer block as a reusable ``(x, w, state=None) ->
-    (x, aux, state)``: THE definition of what a dense layer computes.
+    """One transformer block as a reusable ``(x, w, state=None,
+    index=None) -> (x, aux, state)``: THE definition of what a dense
+    layer computes.
 
     This is the scan body of :func:`forward_with_aux`, of the MPMD
     runtime's per-stage programs (``parallel/mpmd.py``: any divergence
@@ -551,10 +805,44 @@ def make_layer_fn(
     tokens. Without them the block attends over the call's own tokens
     through ``attention_fn``, positions from 0, and ``state`` passes
     through untouched.
+
+    ``ffn_kind='softmax_experts'`` (served only): ``experts`` holds the
+    routed experts' stacks ``we_gate``, ``we_up``, ``we_down`` of ALL
+    layers in ``cfg.dtype``, closed over and indexed in place by the
+    grouped product's tile loop (``w`` then holds the layer's other
+    leaves, and ``index`` says which layer this is: a per-layer slice
+    handed to the loop would be copied whole); ``aux`` is then the
+    layer's ``loads [held]`` int32, the assignments each held expert
+    took, which a cached caller adds to its counters.
     """
     c = cfg
+    _check_kinds(c)
     dt = jnp.dtype(c.dtype)
+    eps = _norm_eps(c)
     pin = constrain or (lambda x, a: x)
+    if c.held_experts:
+        if experts is None or mask is not None or constrain is not None:
+            raise NotImplementedError(
+                "ffn_kind 'softmax_experts' is the forward pass on one "
+                "device (forward, forward_cached): the caller closes the "
+                "experts' stacks over the block, and there is neither a "
+                "token mask, a sharding rule nor a gradient for it "
+                "(training, parallel/pipeline.py, parallel/mpmd.py)")
+        if c.int8_matmuls:
+            raise NotImplementedError(
+                "int8_matmuls with ffn_kind 'softmax_experts': the held "
+                "experts' grouped product has no int8 path")
+        from dlrover_tpu.ops import moe as _moe
+
+        rcfg = routed_config(c)
+    if c.generation == "block_diffusion" and attend is None:
+        if attention_fn is not None:
+            raise NotImplementedError(
+                "generation 'block_diffusion' attends block-causally "
+                "(block_causal_attention); the kernel attention kinds "
+                "have no such mask")
+        attention_fn = partial(block_causal_attention,
+                               block=c.block_length)
     if attend is None:
         attn = attention_fn or dense_attention
         n_rep = c.n_heads // c.n_kv_heads
@@ -602,7 +890,7 @@ def make_layer_fn(
         return y.reshape(*x.shape[:x.ndim - n_contract],
                          *wt.shape[n_contract:])
 
-    def layer(x, w, state=None):
+    def layer(x, w, state=None, index=None):
         """One block: activations [B', S, E] -> ([B', S, E], aux_inc,
         state).
 
@@ -613,23 +901,36 @@ def make_layer_fn(
         at = (positions if positions is not None
               else token_positions(None, *x.shape[:2]))
         with jax.named_scope("attn"):
-            h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant)
+            h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant, eps)
             q = proj(h, _leaf(w, "wq", dt), "bse,ehd->bshd")
             if c.mup_base_width:
                 q = q * mup_q_scale
             k = proj(h, _leaf(w, "wk", dt), "bse,ehd->bshd")
             v = proj(h, _leaf(w, "wv", dt), "bse,ehd->bshd")
+            if c.attn_kind == "heads_qk_norm":
+                with jax.named_scope("qk_norm"):
+                    q = _norm(q, w["ln_q"], None, "llama", eps)
+                    k = _norm(k, w["ln_k"], None, "llama", eps)
             if c.variant == "llama":
-                q = _rope(q, at, c.rope_theta)
-                k = _rope(k, at, c.rope_theta)
+                q = _rope(q, at, c.rope_theta, c.rope_pairing)
+                k = _rope(k, at, c.rope_theta, c.rope_pairing)
             o, state = attend(q, k, v, state)
             o = proj(o, _leaf(w, "wo", dt), "bshd,hde->bse", n_contract=2)
             o = checkpoint_name(o, "attn_out")  # inert without a names policy
             x = pin(x + o, ("batch", "sequence", "embed"))
 
         with jax.named_scope("mlp"):
-            h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant)
-            if c.moe_experts:
+            h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant, eps)
+            if c.held_experts:
+                ht = h.reshape(-1, h.shape[-1])
+                with jax.named_scope("moe_router"):
+                    idx, gate = _moe.softmax_topk_route(
+                        ht, w["w_router"], rcfg)
+                with jax.named_scope("moe_experts"):
+                    ff, aux = _moe.held_expert_ffn(
+                        ht, idx, gate, experts, index, rcfg)
+                ff = ff.reshape(h.shape).astype(dt)
+            elif c.moe_experts:
                 ff, aux = moe_ffn(
                     {"w_router": w["w_router"], "w_in": w["w_in"],
                      "w_out": w["w_out"]},
@@ -652,6 +953,19 @@ def make_layer_fn(
         return x, aux, state
 
     return layer
+
+
+def split_experts(layers: dict, cfg: TransformerConfig):
+    """``(experts, scanned)`` of a layer stack: the routed experts'
+    stacks in ``cfg.dtype`` (nothing at all where they rest there), which
+    a caller closes over its block (`make_layer_fn`), and the leaves its
+    layer loop scans in. ``(None, layers)`` for a model without held
+    experts."""
+    if not cfg.held_experts:
+        return None, layers
+    dt = jnp.dtype(cfg.dtype)
+    return ({k: _leaf(layers, k, dt) for k in EXPERT_STACKS},
+            {k: v for k, v in layers.items() if k not in EXPERT_STACKS})
 
 
 def embed_tokens(
@@ -695,7 +1009,8 @@ def embed_tokens(
 def final_norm(params: Params, x: jax.Array,
                cfg: TransformerConfig) -> jax.Array:
     """The post-stack norm (``ln_f``): the model's tail starts here."""
-    return _norm(x, params["ln_f"], params.get("ln_f_b"), cfg.variant)
+    return _norm(x, params["ln_f"], params.get("ln_f_b"), cfg.variant,
+                 _norm_eps(cfg))
 
 
 def lm_logits(params: Params, hidden: jax.Array,
@@ -770,6 +1085,31 @@ def forward_with_aux(
                                 return_hidden=return_hidden)
     dt = jnp.dtype(c.dtype)
     pin = constrain or (lambda x, a: x)
+    if c.held_experts or c.generation == "block_diffusion":
+        if (c.prefix_lm or c.remat_scan or c.pipeline_stages > 1
+                or mask is not None or constrain is not None):
+            raise NotImplementedError(
+                f"ffn_kind {c.ffn_kind!r} / generation {c.generation!r}: "
+                "the forward pass on one device, and nothing of prefix_lm, "
+                "remat_scan, pipeline stages (parallel/pipeline.py), a "
+                "token mask or a sharding rule")
+        x = (inputs_embeds.astype(dt) if inputs_embeds is not None
+             else embed_tokens(params, tokens, cfg))
+        experts, scanned = split_experts(params["layers"], c)
+        # attention_fn None: the block picks its own (block-causal for a
+        # block-diffusion model) and refuses a kernel by name
+        layer = make_layer_fn(cfg, attention_fn=attention_fn,
+                              experts=experts)
+
+        def served_body(x, inputs):
+            w, i = inputs
+            return layer(x, w, None, i)[0], None
+
+        x, _ = lax.scan(served_body, x, (
+            scanned, jnp.arange(c.n_layers, dtype=jnp.int32)))
+        x = final_norm(params, x, c)
+        return (x if return_hidden else lm_logits(params, x, c),
+                jnp.zeros((), jnp.float32))
     if c.prefix_lm:
         if attention_fn is not None and attention_fn is not dense_attention:
             raise NotImplementedError(
@@ -1039,6 +1379,13 @@ def loss_fn(
     explicit loss mask is given, one is derived so only the generated
     span (positions >= prefix_len) is scored — GLM's objective shape.
     """
+    if cfg.held_experts or cfg.generation != "autoregressive":
+        raise NotImplementedError(
+            f"loss_fn: ffn_kind {cfg.ffn_kind!r} / generation "
+            f"{cfg.generation!r} are served, not trained: the held "
+            "experts' tile loop has a data-dependent trip count (no "
+            "gradient), and a block-diffusion model's objective is a "
+            "masked-token loss this file does not have")
     tokens = batch["tokens"]
     in_mask = batch.get("mask")
     prefix_len = batch.get("prefix_len") if cfg.prefix_lm else None

@@ -32,6 +32,11 @@ Which routing each family uses:
   assignments that landed here, not ``T x held``, and an expert that
   got no token reads no weight. The loop's trip count is data: the
   layer is for the forward pass (scoring, serving), not for ``grad``.
+- ``softmax_topk_route`` + ``held_expert_ffn`` (the Qwen3-MoE / SDAR
+  family, ``ffn_kind='softmax_experts'`` of ``models/transformer.py``'s
+  block, serving): softmax over ALL routed experts in float32, the
+  ``top_k`` largest, renormalised over the chosen; the same grouped
+  product, here with every expert held (``held == n_experts``).
 """
 
 from __future__ import annotations
@@ -182,7 +187,8 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoeConfig,
 
 @dataclasses.dataclass(frozen=True)
 class RoutedConfig:
-    """A sigmoid-routed expert layer and the share of it held here."""
+    """A routed expert layer without capacity (sigmoid or softmax
+    scores) and the share of it held here."""
     n_experts: int            # the router's width: every routed expert
     top_k: int
     scaling: float = 1.0      # routed_scaling_factor
@@ -215,6 +221,53 @@ def sigmoid_topk_route(h: jax.Array, w_router: jax.Array,
     if cfg.norm_topk:
         top = top / (top.sum(-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), top * cfg.scaling
+
+
+def softmax_topk_route(h: jax.Array, w_router: jax.Array,
+                       cfg: RoutedConfig) -> tuple[jax.Array, jax.Array]:
+    """``h [T, M]`` -> (expert ids ``[T, k]`` int32, gates ``[T, k]``
+    float32): ``softmax(h W_r)`` over ALL ``n_experts``, the ``top_k``
+    largest, renormalised over the chosen (``norm_topk``). Float32
+    products at ``HIGHEST``, as :func:`sigmoid_topk_route` and for its
+    reason."""
+    probs = jax.nn.softmax(jnp.einsum(
+        "tm,me->te", h.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    top, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk:
+        top = top / top.sum(-1, keepdims=True)
+    return idx.astype(jnp.int32), top * cfg.scaling
+
+
+def held_counters(n_layers: int, n_held: int) -> dict:
+    """What a cache tree keeps of its expert layers, which every cached
+    call adds to: ``loads [expert layers, held]`` (assignments each held
+    expert took) and the scalars a span reports by these names
+    (``decode.cache_counter_fields``): ``expert_tokens`` (the sum of
+    ``loads``), ``expert_load_max`` (its largest cell),
+    ``expert_load_max_over_mean`` (that cell against the mean cell; 1.0
+    is even) and ``experts_hit`` (held experts that took at least one
+    assignment, summed over layers and calls). A buffer of its own for
+    each: the tree is donated leaf by leaf."""
+    return {
+        "loads": jnp.zeros((n_layers, n_held), jnp.int32),
+        **{name: jnp.zeros((), jnp.int32) for name in (
+            "expert_tokens", "expert_load_max", "experts_hit")},
+        "expert_load_max_over_mean": jnp.zeros((), jnp.float32)}
+
+
+def count_loads(counters: dict, loads: jax.Array) -> dict:
+    """``counters`` after a call whose expert layers took ``loads``."""
+    total = counters["loads"] + loads
+    n, top = total.sum(), total.max()
+    return {
+        "loads": total, "expert_tokens": n, "expert_load_max": top,
+        "experts_hit": counters["experts_hit"]
+        + (loads > 0).sum().astype(jnp.int32),
+        "expert_load_max_over_mean": jnp.where(
+            n > 0, top * total.size / jnp.maximum(n, 1), 0.0
+        ).astype(jnp.float32),
+    }
 
 
 def _tile_rows(tokens: int) -> int:

@@ -85,6 +85,21 @@ guess before it matched the true sample at the SAME draw index —
 greedy token streams are bit-exact by construction. Depth k comes from
 the measured §29 accept-run p50 prior; a request whose live acceptance
 collapses falls back to k=1 (plain decode) for its lifetime.
+
+**Block diffusion** (DESIGN.md §23.8, ``cfg.generation ==
+'block_diffusion'``): a model that generates a BLOCK of
+``cfg.block_length`` positions at a time. Prefill runs the prompt's
+whole blocks under the block-causal mask (chunks of ``prefill_len``, a
+multiple of the block length; the prefix cache at chunk boundaries as
+ever) and the prompt's last ``len % block_length`` tokens open the first
+generated block already unmasked. A decode call (``_denoise_blocks``)
+runs whole blocks: ``denoising_steps`` passes over the block's positions
+(each writes the block's rows and puts ``pos`` back, then unmasks the
+still-masked positions of highest confidence) and one storing pass from
+the final tokens that keeps the advance. What is masked is the engine's
+own boolean a position. ``Result.unmask_steps`` says in which pass each
+token was unmasked. Speculation, ``kv_pages``, park/resume and
+handed-over bundles raise by name for such a model.
 """
 
 from __future__ import annotations
@@ -168,6 +183,13 @@ _decoding_slots = registry().gauge(
     "dlrover_tpu_engine_decoding_slots",
     "slots that took part in the engine's newest decode call (0 when "
     "the newest step made none), per engine",
+    label_names=("engine",),
+)
+_tokens_per_pass = registry().gauge(
+    "dlrover_tpu_engine_tokens_per_pass",
+    "tokens the engine's newest block-diffusion decode call delivered "
+    "over its rows x forward passes (4 tokens a row in 5 passes: 0.8); "
+    "unset for an autoregressive model, whose pass yields one token",
     label_names=("engine",),
 )
 _kv_parked_total = registry().counter(
@@ -276,6 +298,10 @@ class Result:
     prompt: list[int]
     tokens: list[int]          # generated continuation (no prompt)
     finish_reason: str         # "eos" | "length"
+    # a block-diffusion model: for each token the denoising pass (0-based,
+    # within its block) that unmasked it: what was served cannot be
+    # replayed without it. Empty for an autoregressive model
+    unmask_steps: list[int] = dataclasses.field(default_factory=list)
 
 
 @dataclasses.dataclass
@@ -317,6 +343,14 @@ class _PrefillRun:
     # whose prompt this is, for the spans (-1: the prefill pool's)
     request: int = -1
     sctx: str = ""
+    # how many of the prompt's tokens prefill runs (-1: all of them; a
+    # block-diffusion model: the whole blocks, the rest opens the first
+    # generated block)
+    upto: int = -1
+
+    def __post_init__(self):
+        if self.upto < 0:
+            self.upto = len(self.prompt)
 
 
 @dataclasses.dataclass
@@ -409,6 +443,23 @@ class InferenceEngine:
         # advancing its cache position), so one eos-bearing request no
         # longer collapses its whole batch to token-at-a-time decode.
         self.decode_block = max(1, decode_block)
+        # a block-diffusion model (module docstring): what the engine
+        # was not made to do with one raises here, by name
+        self._diffusion = cfg.generation == "block_diffusion"
+        if self._diffusion:
+            if self.prefill_len % cfg.block_length:
+                raise ValueError(
+                    f"prefill_len {self.prefill_len} must be a multiple "
+                    f"of the model's block_length {cfg.block_length}: a "
+                    "chunk boundary inside a block would cut the block's "
+                    "own keys off its queries")
+            if kv_pages > 0:
+                raise NotImplementedError(
+                    "kv_pages > 0 (the paged store, park/resume, "
+                    "copy-on-write pages) with a block-diffusion model: a "
+                    "parked row would have to carry its open block's "
+                    "masked state, which no page holds (run it with "
+                    "kv_pages=0)")
 
         # paged KV slots: physical page pool + per-slot page lease.
         # Capacity is a PAGE ledger — a request holds
@@ -487,6 +538,11 @@ class InferenceEngine:
         self._slot_pages: list[list[int] | None] = [None] * slots
         self._slot_shared: list[set | None] = [None] * slots
         self._since_install = [0] * slots
+        # block diffusion: the prompt's remainder that opens a slot's
+        # first generated block (None once that block ran), and the
+        # denoising pass that unmasked each emitted token
+        self._opening: list[list[int] | None] = [None] * slots
+        self._unmask: list[list[int]] = [[] for _ in range(slots)]
         self._results: list[Result] = []
         # admission state machine: at most one pending chunked prefill
         # plus a FIFO of parked generations awaiting a slot
@@ -525,6 +581,11 @@ class InferenceEngine:
         # depth prior live in the observatory, so speculation requires
         # it; depth < 2 or a missing observatory means plain decode
         self.spec_depth = max(0, envspec.get_int(EnvKey.SPEC_DEPTH, 0))
+        if self._diffusion and self.spec_depth >= 2:
+            raise NotImplementedError(
+                "speculation (spec_depth >= 2) with a block-diffusion "
+                "model: `_verify_block` accepts a drafted run of NEXT "
+                "tokens, and a denoising pass has no next token")
         self._spec = self.spec_depth >= 2 and self._obs is not None
         # rid -> [accepted, scored, collapsed] live draft accounting
         self._spec_acc: dict[int, list[int]] = {}
@@ -732,6 +793,98 @@ class InferenceEngine:
             _verify_block, donate_argnums=(1, 2),
             compiler_options=_CANONICAL_NUMERICS,
         )
+
+        def _denoise_blocks(params, cache, tokens0, masked0, seeds,
+                            counts, temperature, top_k, top_p, active,
+                            eos_ids, n_blocks):
+            # block diffusion (module docstring): ``n_blocks`` whole
+            # blocks for ALL slots at their own (block-aligned)
+            # positions. ``tokens0`` / ``masked0`` [slots, B] open the
+            # call's FIRST block (a fresh row's prompt remainder sits
+            # there unmasked); later blocks open all masked. Returns
+            # the blocks' final tokens and the pass that unmasked each
+            # position, [n_blocks, slots, B] (-1: never masked).
+            B, T = cfg.block_length, cfg.denoising_steps
+            per_pass = B // T
+            sampling = jnp.any(active & (temperature > 0))
+            at = jnp.arange(B, dtype=jnp.int32)
+
+            def unmask(logits, tokens, masked, step_of, s, draw):
+                # candidates, their confidences, the choice
+                flat = logits.reshape(-1, logits.shape[-1])
+
+                def sampled(_):
+                    keys = jax.vmap(lambda k: jax.vmap(
+                        lambda j: jax.random.fold_in(k, j))(at))(
+                            _row_keys(seeds, counts + draw))
+                    return sample_logits(
+                        flat, keys.reshape(flat.shape[0], -1),
+                        jnp.repeat(temperature, B), jnp.repeat(top_k, B),
+                        jnp.repeat(top_p, B))
+
+                # all-greedy calls (a traced fact) skip the sampler's
+                # sort over [slots x B, vocab]
+                cand = lax.cond(
+                    sampling, sampled,
+                    lambda _: jnp.argmax(flat, -1).astype(jnp.int32),
+                    None).reshape(tokens.shape)
+                conf = jnp.exp(
+                    jnp.take_along_axis(logits, cand[..., None], -1)[..., 0]
+                    - jax.nn.logsumexp(logits, axis=-1))
+                for _ in range(per_pass):
+                    pick = jnp.argmax(jnp.where(masked, conf, -1.0), axis=1)
+                    chosen = ((at[None] == pick[:, None])
+                              & jnp.take_along_axis(
+                                  masked, pick[:, None], 1))
+                    tokens = jnp.where(chosen, cand, tokens)
+                    step_of = jnp.where(chosen, s, step_of)
+                    masked = masked & ~chosen
+                return tokens, masked, step_of
+
+            def one_block(carry, b):
+                cache, done = carry
+                first = b == 0
+                tokens = jnp.where(first, tokens0,
+                                   jnp.int32(cfg.mask_token_id))
+                masked = jnp.where(first, masked0, True)
+                generated = masked
+
+                def one_pass(s, state):
+                    cache, tokens, masked, step_of = state
+                    logits, new = forward_cached(params, tokens, cache,
+                                                 cfg)
+                    # the pass wrote the block's rows; they are not
+                    # final, so the position stays
+                    new["pos"] = cache["pos"]
+                    with jax.named_scope("unmask"):
+                        tokens, masked, step_of = unmask(
+                            logits, tokens, masked, step_of, s, b * T + s)
+                    return new, tokens, masked, step_of
+
+                cache, tokens, _, step_of = lax.fori_loop(
+                    0, T, one_pass,
+                    (cache, tokens, masked,
+                     jnp.full(tokens.shape, -1, jnp.int32)))
+                with jax.named_scope("block_store"):
+                    # the rows of the FINAL tokens (its logits are not
+                    # read, so no head runs)
+                    _, new = forward_cached(params, tokens, cache, cfg)
+                new["pos"] = jnp.where(active & ~done, new["pos"],
+                                       cache["pos"])
+                hit = (generated & (eos_ids[:, None] >= 0)
+                       & (tokens == eos_ids[:, None])).any(axis=1)
+                return (new, done | hit), (tokens, step_of)
+
+            (cache, _), (toks, steps) = lax.scan(
+                one_block,
+                (zero_counters(cache), jnp.zeros(active.shape, bool)),
+                jnp.arange(n_blocks))
+            return toks, steps, cache, cache_counter_fields(cache)
+
+        self._denoise_blocks = jax.jit(
+            _denoise_blocks, static_argnames=("n_blocks",),
+            donate_argnums=(1,), compiler_options=_CANONICAL_NUMERICS,
+        )
         # per-depth AOT verify programs (warm_aot_verify); missing
         # depths fall back to the jit shape ladder above
         self._aot_verify: dict[int, Any] = {}
@@ -793,6 +946,10 @@ class InferenceEngine:
             load_or_compile,
         )
 
+        if self._diffusion:
+            # no one-step program to arm: the decode call is
+            # `_denoise_blocks`, on the jit path
+            return None
         try:
             self._cache = launder(self._cache)
             self._last = launder(self._last)
@@ -936,6 +1093,7 @@ class InferenceEngine:
         chunks) instead of re-running the prompt."""
         if bundle is None:
             raise ValueError("submit_prefilled requires a KVBundle")
+        self._no_bundles()
         params = params or SamplingParams()
         prompt = list(prompt)
         self._validate(prompt, params)
@@ -1013,10 +1171,15 @@ class InferenceEngine:
             )
         if row is None:
             row = init_cache(self.cfg, 1, self.max_len)
+        upto = len(prompt)
+        if self._diffusion:
+            # whole blocks only: the remainder opens the first
+            # generated block, unmasked
+            upto -= upto % self.cfg.block_length
         return _PrefillRun(
             prompt=list(prompt), row=row,
             last=last, next_lo=start, start=start,
-            done=start >= len(prompt),
+            done=start >= upto, upto=upto,
         )
 
     def prefill_step(self, run: _PrefillRun) -> bool:
@@ -1028,7 +1191,7 @@ class InferenceEngine:
         P = self.prefill_len
         t0 = time.monotonic()
         lo = run.next_lo
-        chunk = run.prompt[lo: lo + P]
+        chunk = run.prompt[lo: min(lo + P, run.upto)]
         with hot_span("prefill_chunk", remote_parent=run.sctx,
                       request=run.request, tokens=len(chunk),
                       chunk=run.chunks, context=lo) as span:
@@ -1038,7 +1201,7 @@ class InferenceEngine:
                 self.params, jnp.asarray(toks), run.row,
                 jnp.asarray(len(chunk), jnp.int32),
             )
-            final_top = len(run.prompt) // P * P
+            final_top = run.upto // P * P
             if self.prefix_cache_entries and len(chunk) == P:
                 # snapshot the FINAL aligned boundary always;
                 # intermediate boundaries only when extending an
@@ -1055,7 +1218,7 @@ class InferenceEngine:
             run.next_lo = lo + P
             run.chunks += 1
             self._chunks_run += 1
-            run.done = run.next_lo >= len(run.prompt)
+            run.done = run.next_lo >= run.upto
             jax.block_until_ready(run.last)
             # the chunk is done: its counters cost no wait of their own
             span.set(**_counted(jax.device_get(counted)))
@@ -1065,6 +1228,7 @@ class InferenceEngine:
     def make_bundle(self, run: _PrefillRun) -> KVBundle:
         """Package a finished prefill run as a page-granular host
         bundle for handoff to a decode engine."""
+        self._no_bundles()
         if not run.done:
             raise ValueError("prefill run not finished")
         P = self.page_size
@@ -1084,6 +1248,15 @@ class InferenceEngine:
             last=np.asarray(jax.device_get(run.last)),
             page_size=P, prefix_key=tuple(run.prompt[:top]),
         )
+
+    def _no_bundles(self) -> None:
+        if self._diffusion:
+            raise NotImplementedError(
+                "handed-over KVBundles (make_bundle, submit_prefilled) "
+                "with a block-diffusion model: a bundle carries (pos, "
+                "last) for a next token, and this model's first block "
+                "opens with the prompt's remainder, which no bundle "
+                "holds")
 
     def _run_from_bundle(self, req: Request) -> _PrefillRun:
         """Rebuild a finished working row from a handoff bundle (the
@@ -1357,14 +1530,21 @@ class InferenceEngine:
 
     def _install_admit(self, slot: int, pa: _PendingAdmit) -> None:
         req, run = pa.req, pa.run
+        last = run.last
+        if last is None:
+            # a prompt shorter than one block prefills nothing
+            last = jnp.zeros((self.cfg.vocab_size,), jnp.float32)
         self._cache, self._last = self._install(
-            self._cache, self._last, run.row, run.last,
+            self._cache, self._last, run.row, last,
             jnp.asarray(slot, jnp.int32),
-            jnp.asarray(len(req.prompt), jnp.int32),
+            jnp.asarray(run.upto, jnp.int32),
         )
         jax.block_until_ready(self._last)
         self._active[slot] = req
         self._emitted[slot] = []
+        self._unmask[slot] = []
+        self._opening[slot] = (list(req.prompt[run.upto:])
+                               if self._diffusion else None)
         self._slot_pages[slot] = pa.pages
         self._slot_shared[slot] = set(pa.shared)
         self._since_install[slot] = 0
@@ -1493,17 +1673,29 @@ class InferenceEngine:
             req.params.max_new_tokens - len(self._emitted[s])
             for s, req in enumerate(self._active) if req is not None
         ]
-        cap = min(self.decode_block, min(remaining))
+        if self._diffusion:
+            # in TOKENS a row, as ever: whole blocks, `decode_block //
+            # block_length` of them (at least one), never a block past
+            # a row's budget (its LAST block may be cut by it: -(-r //
+            # B) blocks hold r tokens)
+            B = self.cfg.block_length
+            cap = min(max(1, self.decode_block // B),
+                      -(-min(remaining) // B))
+        else:
+            cap = min(self.decode_block, min(remaining))
         block = 1
         while block * 2 <= cap:
             block *= 2
-        return block
+        return block * B if self._diffusion else block
 
     def _steps_ahead(self) -> int:
-        """Decode steps the rows decoding NOW are about to run: the
-        verify block's depth when speculation plans one, else the
-        block's. Read before admission — it is the admission's budget
-        of prefill chunks."""
+        """TOKENS each row decoding NOW is about to receive: the verify
+        block's depth when speculation plans one, else the block's
+        steps (an autoregressive step yields a token a row; a
+        block-diffusion call's blocks yield ``block_length`` each, in
+        ``denoising_steps + 1`` passes). Read before admission — it is
+        the admission's budget of units (DESIGN.md §23.1: a row
+        decoding waits for at most one unit per token it receives)."""
         plan = self._spec_plan() if self._spec else None
         return plan[0] if plan is not None else self._block_size()
 
@@ -1653,6 +1845,13 @@ class InferenceEngine:
             jnp.asarray(self._seeds), jnp.asarray(self._sampled),
             temp, top_k, top_p, jnp.asarray(active_mask), eos_ids,
         )
+        if self._diffusion:
+            n_steps, toks, counts, steps = self._denoise_call(
+                args, active_mask, decoding)
+            with hot_span("engine_emit", tokens=int(counts.sum())):
+                self._emit(toks, counts, steps)
+            return (decoding, n_steps, chunks,
+                    sum(r is not None for r in self._active))
         plan = self._spec_plan() if self._spec else None
         if plan is not None:
             depth, guesses = plan
@@ -1698,6 +1897,53 @@ class InferenceEngine:
         return (decoding, n_steps, chunks,
                 sum(r is not None for r in self._active))
 
+    def _denoise_call(self, args, active_mask, decoding: int):
+        """One block-diffusion decode call: ``(forward passes run, tokens
+        [most a row, slots], how many each row generated, the pass that
+        unmasked each)``. ``_emit`` cuts a row at its budget and at its
+        first eos, as ever."""
+        c = self.cfg
+        B, T = c.block_length, c.denoising_steps
+        n_blocks = self._block_size() // B
+        tokens0 = np.full((self.slots, B), c.mask_token_id, np.int32)
+        masked0 = np.ones((self.slots, B), bool)
+        for s, opening in enumerate(self._opening):
+            if opening:
+                tokens0[s, : len(opening)] = opening
+                masked0[s, : len(opening)] = False
+            self._opening[s] = None
+        params, cache, _last, *rest = args
+        n_steps = n_blocks * (T + 1)
+        with hot_span("decode_block", slots=decoding, n_steps=n_steps,
+                      blocks=n_blocks, denoise_passes=n_blocks * T,
+                      store_passes=n_blocks) as span:
+            toks_dev, steps_dev, cache, counted = self._denoise_blocks(
+                params, cache, jnp.asarray(tokens0), jnp.asarray(masked0),
+                *rest, n_blocks=n_blocks)
+            blocks, unmasked, counted = jax.device_get(
+                (toks_dev, steps_dev, counted))
+            # [n_blocks, slots, B] -> a row's generated positions in
+            # order: all of a block but the prompt's remainder
+            generated = np.ones(blocks.shape, bool)
+            generated[0] = masked0
+            counts = np.where(active_mask, generated.sum(axis=(0, 2)), 0)
+            toks = np.zeros((int(counts.max()), self.slots), np.int32)
+            steps = np.zeros_like(toks)
+            delivered = 0
+            for s in np.flatnonzero(active_mask):
+                keep = generated[:, s]
+                toks[: counts[s], s] = blocks[:, s][keep]
+                steps[: counts[s], s] = unmasked[:, s][keep]
+                req = self._active[s]
+                delivered += min(int(counts[s]), req.params.max_new_tokens
+                                 - len(self._emitted[s]))
+            span.set(tokens_out=delivered, **self._note_counted(counted))
+        _tokens_per_pass.labels(self.engine_id).set(
+            delivered / (decoding * n_steps))
+        self._sampled[active_mask] += n_blocks * T
+        self._cache = cache
+        return n_steps, toks, counts, steps
+
     def _note_counted(self, counted: dict) -> dict:
         """A decode call's counters as span fields, and onto their
         gauges (none for a model that counts nothing)."""
@@ -1706,7 +1952,7 @@ class InferenceEngine:
             _COUNTER_GAUGES[name].labels(self.engine_id).set(fields[name])
         return fields
 
-    def _emit(self, toks, counts) -> None:
+    def _emit(self, toks, counts, steps=None) -> None:
         """The host's share of a step: every new token to its request
         (digest store, observatory, the streaming callback), finished
         requests retired."""
@@ -1717,6 +1963,8 @@ class InferenceEngine:
             for j in range(int(counts[s])):
                 t = int(toks[j, s])
                 self._emitted[s].append(t)
+                if steps is not None:
+                    self._unmask[s].append(int(steps[j, s]))
                 self._since_install[s] += 1
                 if self._digest_store is not None:
                     self._digest_store.extend(req.id, t)
@@ -1744,6 +1992,7 @@ class InferenceEngine:
         self._results.append(Result(
             id=req.id, prompt=req.prompt,
             tokens=list(self._emitted[slot]), finish_reason=reason,
+            unmask_steps=list(self._unmask[slot]),
         ))
         submitted = self._submit_time.pop(req.id, None)
         if submitted is not None:
@@ -1764,6 +2013,8 @@ class InferenceEngine:
             )
         self._active[slot] = None
         self._emitted[slot] = []
+        self._unmask[slot] = []
+        self._opening[slot] = None
         self._samp_cache = None
         pages = self._slot_pages[slot]
         if pages:
