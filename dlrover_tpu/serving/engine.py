@@ -185,6 +185,14 @@ _decoding_slots = registry().gauge(
     "the newest step made none), per engine",
     label_names=("engine",),
 )
+_frozen_row_share = registry().gauge(
+    "dlrover_tpu_engine_frozen_row_share",
+    "row-steps of the engine's newest autoregressive decode call spent "
+    "by rows already past their budget, over its rows x steps (what "
+    "whole blocks cost: a row that ends inside a block idles to its "
+    "end); unset for a block-diffusion model",
+    label_names=("engine",),
+)
 _tokens_per_pass = registry().gauge(
     "dlrover_tpu_engine_tokens_per_pass",
     "tokens the engine's newest block-diffusion decode call delivered "
@@ -435,13 +443,14 @@ class InferenceEngine:
         # decode_block > 1: run up to that many decode iterations inside
         # ONE compiled scan before syncing tokens to the host — the
         # per-token host round trip (sync + dispatch) otherwise bounds
-        # throughput on high-RTT hosts. Shrunk per step to the smallest
-        # remaining budget among active slots (power-of-two ladder, so
-        # compiles stay bounded). eos is observed INSIDE the compiled
-        # block (per-slot [slots] eos ids; a row that samples its eos
-        # keeps emitting eos for the rest of the block and stops
-        # advancing its cache position), so one eos-bearing request no
-        # longer collapses its whole batch to token-at-a-time decode.
+        # throughput on high-RTT hosts. A call is a whole block while
+        # any active row has that much left (`_block_size`); budget and
+        # eos are both observed INSIDE the compiled block, per slot (a
+        # row past its ``remaining`` budget, or one that sampled its
+        # eos, stops advancing its cache position and the host reads
+        # none of its later tokens), so neither a row in its last
+        # tokens nor an eos-bearing request shortens its batchmates'
+        # call.
         self.decode_block = max(1, decode_block)
         # a block-diffusion model (module docstring): what the engine
         # was not made to do with one raises here, by name
@@ -696,13 +705,17 @@ class InferenceEngine:
 
         def _step_block(params, cache, last, seeds, counts,
                         temperature, top_k, top_p, active, eos_ids,
-                        n_steps):
+                        remaining, n_steps):
             # per-row sampling params as VECTORS: one compiled program
             # regardless of the mix of requests in the batch. eos_ids
             # [slots] (-1 = none): a row that samples its eos keeps
             # emitting eos and stops advancing — the host retires it
             # after the block, so the batchmates never drop to
-            # token-at-a-time decode.
+            # token-at-a-time decode. remaining [slots]: tokens each
+            # row's budget still allows; a row stops advancing at step
+            # ``remaining`` the same way (its pos never passes prompt +
+            # max_new_tokens, so neither its page lease nor max_len),
+            # and the host reads none of its tokens from there on.
             def body(carry, i):
                 cache, last, done = carry
                 nxt = sample_logits(
@@ -717,7 +730,7 @@ class InferenceEngine:
                 # inactive/finished rows must not advance (their pos
                 # would creep past max_len and clamp the next install's
                 # attention)
-                run = active & ~done
+                run = active & ~done & (i < remaining)
                 new["pos"] = jnp.where(run, new["pos"], cache["pos"])
                 return (new, logits[:, 0], done | hit), nxt
 
@@ -889,14 +902,16 @@ class InferenceEngine:
         # depths fall back to the jit shape ladder above
         self._aot_verify: dict[int, Any] = {}
         self.aot_verify_info: dict[int, Any] = {}
-        # the AOT decode-step program (warm_aot_step): replaces the
-        # n_steps=1 jit dispatch when armed, so a fresh serving replica
-        # whose (model, slots, max_len) was compiled by ANY earlier
-        # replica skips the cold compile (DESIGN.md §17 / ROADMAP item
-        # 1 leftover). Other block sizes keep the jit ladder.
+        # the AOT decode program (warm_aot_step): replaces the jit
+        # dispatch of a whole block (n_steps = decode_block, what a
+        # live batch runs) when armed, so a fresh serving replica whose
+        # (model, slots, max_len) was compiled by ANY earlier replica
+        # skips the cold compile (DESIGN.md §17 / ROADMAP item 1
+        # leftover). The short calls of a draining batch stay on jit.
         self._aot_step = None
         self.aot_info = None
         self._decoding_gauge = _decoding_slots.labels(self.engine_id)
+        self._frozen_gauge = _frozen_row_share.labels(self.engine_id)
         _LIVE_ENGINES.add(self)
 
     # ------------------------------------------------------- AOT cold start
@@ -919,17 +934,26 @@ class InferenceEngine:
                 "kv_stack": "tree-carried-donated"}
 
     def _step_sample_args(self) -> tuple:
-        """The exact runtime argument tuple of a decode step (zero
-        requests active), built through the same conversions ``step()``
-        performs — lowering against these pins the true avals."""
+        """The exact runtime argument tuple the decode programs share
+        (zero requests active), built through the same conversions
+        ``step()`` performs — lowering against these pins the true
+        avals. ``_verify_block`` takes its guesses after them,
+        ``_step_block`` each row's remaining budget
+        (`_block_sample_args`)."""
         temp, top_k, top_p, eos_ids = self._sampling_tensors()
         active = np.zeros((self.slots,), bool)
         return (self.params, self._cache, self._last,
                 jnp.asarray(self._seeds), jnp.asarray(self._sampled),
                 temp, top_k, top_p, jnp.asarray(active), eos_ids)
 
+    def _block_sample_args(self) -> tuple:
+        """`_step_sample_args` with ``_step_block``'s last vector."""
+        return self._step_sample_args() + (
+            jnp.zeros((self.slots,), jnp.int32),)
+
     def warm_aot_step(self, cache=None):
-        """Compile-or-load the n_steps=1 decode-step program through the
+        """Compile-or-load the decode program a live batch runs (a whole
+        block: ``n_steps`` = ``decode_block``) through the
         elastic compile cache; returns the ``AotStep`` evidence (None
         when jax/caching is unavailable). Safe to skip: the jit path
         stays fully functional. What the program DONATES (the cache
@@ -947,26 +971,27 @@ class InferenceEngine:
         )
 
         if self._diffusion:
-            # no one-step program to arm: the decode call is
+            # no `_step_block` to arm: the decode call is
             # `_denoise_blocks`, on the jit path
             return None
         try:
             self._cache = launder(self._cache)
             self._last = launder(self._last)
             self._samp_cache = None
-            sample = self._step_sample_args()
+            sample = self._block_sample_args()
             key, inputs = compile_fingerprint(
                 num_nodes=1,
                 total_devices=jax.local_device_count(),
                 mesh_axes={},
                 model=self.cfg,
-                strategy=self._aot_strategy("serving_step", n_steps=1),
+                strategy=self._aot_strategy(
+                    "serving_step", n_steps=self.decode_block),
                 args_signature=abstract_signature(sample),
             )
             aot = load_or_compile(
                 key, inputs,
                 lambda: self._step_block.lower(
-                    *sample, n_steps=1
+                    *sample, n_steps=self.decode_block
                 ).compile(compiler_options=_CANONICAL_NUMERICS),
                 cache=cache,
             )
@@ -1664,29 +1689,41 @@ class InferenceEngine:
                             jnp.asarray(top_p), jnp.asarray(eos))
         return self._samp_cache
 
+    def _remaining(self) -> np.ndarray:
+        """Tokens each slot's budget still allows, [slots] int32 (an
+        empty slot: 0)."""
+        return np.array(
+            [0 if req is None
+             else req.params.max_new_tokens - len(self._emitted[s])
+             for s, req in enumerate(self._active)], np.int32)
+
     def _block_size(self) -> int:
-        """Largest safe compiled block: never past any active slot's
-        remaining budget; power-of-two ladder keeps distinct compiles
-        bounded. eos no longer caps the block — stops are observed
-        per-slot inside the compiled scan and retired on the host."""
-        remaining = [
-            req.params.max_new_tokens - len(self._emitted[s])
-            for s, req in enumerate(self._active) if req is not None
-        ]
+        """Steps of the next decode call, sized by the active row with
+        the MOST left: a whole ``decode_block`` while any row has that
+        much, else the smallest power of two that holds the longest
+        tail (a lone request's last tokens and a draining batch ride
+        one short call; the powers keep distinct compiles bounded). A
+        row with less left stops inside the call, at its budget as at
+        its eos: both are observed per slot inside the compiled scan
+        and retired on the host."""
+        remaining = self._remaining()
         if self._diffusion:
             # in TOKENS a row, as ever: whole blocks, `decode_block //
             # block_length` of them (at least one), never a block past
             # a row's budget (its LAST block may be cut by it: -(-r //
-            # B) blocks hold r tokens)
+            # B) blocks hold r tokens), on the power-of-two ladder
             B = self.cfg.block_length
-            cap = min(max(1, self.decode_block // B),
-                      -(-min(remaining) // B))
-        else:
-            cap = min(self.decode_block, min(remaining))
+            least = int(remaining[remaining > 0].min())
+            cap = min(max(1, self.decode_block // B), -(-least // B))
+            block = 1
+            while block * 2 <= cap:
+                block *= 2
+            return block * B
+        most = int(remaining.max())
         block = 1
-        while block * 2 <= cap:
+        while block < most:
             block *= 2
-        return block * B if self._diffusion else block
+        return min(self.decode_block, block)
 
     def _steps_ahead(self) -> int:
         """TOKENS each row decoding NOW is about to receive: the verify
@@ -1695,7 +1732,9 @@ class InferenceEngine:
         block-diffusion call's blocks yield ``block_length`` each, in
         ``denoising_steps + 1`` passes). Read before admission — it is
         the admission's budget of units (DESIGN.md §23.1: a row
-        decoding waits for at most one unit per token it receives)."""
+        decoding waits for at most one unit per token it receives; a
+        row inside its LAST block receives fewer than the block's
+        steps, the one exception)."""
         plan = self._spec_plan() if self._spec else None
         return plan[0] if plan is not None else self._block_size()
 
@@ -1877,9 +1916,15 @@ class InferenceEngine:
             self._spec_score(guesses, toks_sn, depth)
         else:
             n_steps = block = self._block_size()
-            with hot_span("decode_block", slots=decoding,
-                          n_steps=block) as span:
-                if block == 1 and self._aot_step is not None:
+            remaining = self._remaining()
+            # a row's tokens of this call: up to its budget (an empty
+            # slot: 0); past it the row is frozen in the program
+            counts = np.minimum(remaining, block)
+            frozen = decoding * block - int(counts.sum())
+            args += (jnp.asarray(remaining),)
+            with hot_span("decode_block", slots=decoding, n_steps=block,
+                          frozen_row_steps=frozen) as span:
+                if block == self.decode_block and self._aot_step is not None:
                     toks_dev, cache, last, counted = self._aot_step(*args)
                 else:
                     toks_dev, cache, last, counted = self._step_block(
@@ -1889,8 +1934,8 @@ class InferenceEngine:
                 toks, counted = jax.device_get((toks_dev, counted))
                 toks = np.asarray(toks)
                 span.set(**self._note_counted(counted))
-            self._sampled[active_mask] += block
-            counts = np.where(active_mask, block, 0)
+            self._frozen_gauge.set(frozen / (decoding * block))
+            self._sampled += counts
         self._cache, self._last = cache, last
         with hot_span("engine_emit", tokens=int(counts.sum())):
             self._emit(toks, counts)

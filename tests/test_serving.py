@@ -144,14 +144,7 @@ def test_eos_request_no_longer_serializes_batchmates(params):
     ref_mate = eng.submit([4, 2], SamplingParams(
         temperature=0.0, max_new_tokens=12))
     want_mate = {r.id: r for r in eng.run()}[ref_mate].tokens
-    blocks = []
-    orig = eng._step_block
-
-    def spy(*a, n_steps=1):
-        blocks.append(n_steps)
-        return orig(*a, n_steps=n_steps)
-
-    eng._step_block = spy
+    blocks = _spy_step_block(eng)
     probe = generate(params, jnp.asarray([[5, 9, 2]], jnp.int32), CFG,
                      gen_len=1, key=jax.random.PRNGKey(0),
                      temperature=0.0)
@@ -164,8 +157,10 @@ def test_eos_request_no_longer_serializes_batchmates(params):
     # the eos request still stops AT its eos...
     assert res[r_eos].finish_reason == "eos"
     assert res[r_eos].tokens == [eos]
-    # ...while blocks > 1 actually ran (pre-fix this was all 1s)
-    assert max(blocks) > 1, blocks
+    # ...while whole blocks ran (pre-fix this was all 1s): the eos row
+    # is frozen inside the first, and the mate's 12 tokens are a whole
+    # block and ONE call of 4 for its tail
+    assert [n for n, _ in blocks] == [8, 4], blocks
     # and the mate decoded exactly what a no-eos batch produces
     assert res[r_mate].tokens == want_mate
 
@@ -217,10 +212,11 @@ def test_chunked_admission_bounds_decode_stall(params, decode_block):
         chunks_before = len(chunk_calls)
         eng.step()
         ran = len(chunk_calls) - chunks_before
-        # the block this step's admission ran beside: what the live
-        # request had left, on the power-of-two ladder
+        # the block this step's admission ran beside: a whole one
+        # while the live request has that much left (ISSUE 32), else
+        # the smallest power of two that holds its tail
         left = 40 - emitted_at[-1]
-        beside = min(decode_block, 1 << (left.bit_length() - 1))
+        beside = min(decode_block, 1 << (left - 1).bit_length())
         assert 1 <= ran <= beside     # at decode_block 1: exactly one
         admitting_steps += 1
         emitted_at.append(len(eng._emitted[0]))
@@ -232,6 +228,172 @@ def test_chunked_admission_bounds_decode_stall(params, decode_block):
     # one observation per admitting step, however many chunks it ran
     assert _stall_count() - stalls_before == admitting_steps
     eng.run()
+
+
+def _spy_step_block(eng) -> list:
+    """Spy on the decode program: ``(n_steps, remaining of each slot)``
+    per call."""
+    calls = []
+    orig = eng._step_block
+
+    def spy(*a, n_steps):
+        calls.append((n_steps, np.asarray(a[10]).tolist()))
+        return orig(*a, n_steps=n_steps)
+
+    eng._step_block = spy
+    return calls
+
+
+def _budget_requests(temperature, eos_of=None):
+    """One long row that keeps every block whole, beside rows whose
+    budgets end at every remainder 1-7 of a block of 8 (a row is
+    installed between calls, so ``max_new_tokens`` 8k + r ends r steps
+    into its last block)."""
+    rng = np.random.default_rng(11)
+    budgets = [72, 1, 9, 2, 10, 3, 11, 4, 12, 5, 13, 6, 14, 7, 15]
+    return [
+        (list(rng.integers(1, CFG.vocab_size, 2 + i % 5)),
+         SamplingParams(temperature=temperature, top_p=0.9,
+                        max_new_tokens=m, seed=300 + i,
+                        eos_id=None if eos_of is None else eos_of(i)))
+        for i, m in enumerate(budgets)]
+
+
+def _streams(params, reqs, block):
+    eng = InferenceEngine(params, CFG, slots=3, max_len=128,
+                          prefill_len=8, decode_block=block)
+    calls = _spy_step_block(eng)
+    ids = [eng.submit(p, sp) for p, sp in reqs]
+    got = {r.id: r for r in eng.run()}
+    return [got[i] for i in ids], calls
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("eos", [False, True], ids=["no_eos", "eos"])
+@pytest.mark.parametrize("temperature", [0.0, 0.9],
+                         ids=["greedy", "sampled"])
+def test_rows_frozen_at_their_budget_serve_the_same_tokens(
+        params, temperature, eos):
+    """ISSUE 32 (a): ``decode_block`` 1 against 8 with rows whose
+    budgets end at every remainder of a block, greedy and seeded
+    sampling, with and without an eos inside a block: every request
+    receives the same tokens, and at 8 every call beside the long row
+    is a whole block, the short rows frozen inside it."""
+    reqs = _budget_requests(temperature)
+    one, calls1 = _streams(params, reqs, 1)
+    if eos:
+        # each short row's third token (where it has more) as ITS eos:
+        # it stops the row inside a block, ahead of its budget
+        plain = [r.tokens for r in one]
+        reqs = _budget_requests(
+            temperature, lambda i: (plain[i][2]
+                                    if 3 < len(plain[i]) < 72 else None))
+        one, calls1 = _streams(params, reqs, 1)
+    eight, calls8 = _streams(params, reqs, 8)
+    assert [r.tokens for r in eight] == [r.tokens for r in one]
+    assert ([r.finish_reason for r in eight]
+            == [r.finish_reason for r in one])
+    if eos:
+        assert {r.finish_reason for r in one} == {"eos", "length"}
+        assert all(r.tokens[-1] == sp.eos_id for r, (_, sp)
+                   in zip(one, reqs) if r.finish_reason == "eos")
+    else:
+        assert [len(r.tokens) for r in one] == [
+            sp.max_new_tokens for _, sp in reqs]
+    assert {n for n, _ in calls1} == {1}
+    whole = [(n, rem) for n, rem in calls8 if max(rem) >= 8]
+    assert len(whole) >= 9 and {n for n, _ in whole} == {8}
+    # rows with less than a block left rode those whole calls
+    assert {r for _, rem in whole for r in rem if 0 < r < 8} >= (
+        {1, 2, 3} if eos else set(range(1, 8)))
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("case", ["batchmate", "lone"])
+def test_block_is_sized_by_the_row_with_the_most_left(params, case):
+    """ISSUE 32 (b): while one row has a whole block left every call is
+    a whole block, though a batchmate has 3 left; a lone row with 3
+    left rides ONE call of 4 and waits for no dead steps."""
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
+                          prefill_len=8, decode_block=8)
+    calls = _spy_step_block(eng)
+    short = eng.submit([4, 2], SamplingParams(temperature=0.0,
+                                              max_new_tokens=3))
+    if case == "batchmate":
+        eng.submit([9, 1, 5], SamplingParams(temperature=0.0,
+                                             max_new_tokens=19))
+    res = {r.id: r for r in eng.run()}
+    assert len(res[short].tokens) == 3
+    if case == "lone":
+        assert calls == [(4, [3, 0])]
+        assert eng._steps_ahead() == 1    # an idle engine: one unit
+    else:
+        # 19 tokens: two whole blocks, then a tail of 3 in a call of 4
+        assert calls == [(8, [3, 19]), (8, [0, 11]), (4, [0, 3])]
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("case", ["inside", "at_max_len"])
+def test_frozen_row_stops_at_its_budget(params, case):
+    """ISSUE 32 (c): a row whose budget ends inside a block keeps its
+    ``pos`` and its draw count at prompt + budget, also when that is
+    ``max_len`` itself, and the request installed next in its slot
+    decodes what it decodes in a fresh engine."""
+    max_len = 64
+    plen = 5 if case == "inside" else max_len - 3
+    prompt = list((np.arange(plen) * 7 + 3) % CFG.vocab_size)
+    long_sp = SamplingParams(temperature=0.8, max_new_tokens=30, seed=5)
+    nxt = ([8, 6, 4], SamplingParams(temperature=0.8, max_new_tokens=9,
+                                     seed=6))
+
+    def engine():
+        return InferenceEngine(params, CFG, slots=2, max_len=max_len,
+                               prefill_len=8, decode_block=8)
+
+    fresh = engine()
+    want = fresh.submit(*nxt)
+    want = {r.id: r.tokens for r in fresh.run()}[want]
+
+    eng = engine()
+    eng.submit([1, 2], long_sp)
+    eng.submit(prompt, SamplingParams(temperature=0.8, max_new_tokens=3,
+                                      seed=7))
+    calls = _spy_step_block(eng)
+    eng.step()
+    assert calls == [(8, [30, 3])]
+    assert eng._active[1] is None            # retired after the call
+    pos = np.asarray(eng._cache["pos"])
+    assert pos.tolist() == [2 + 8, plen + 3]
+    assert eng._sampled.tolist() == [8, 3]
+    got = eng.submit(*nxt)
+    res = {r.id: r for r in eng.run()}
+    assert res[got].tokens == want
+    assert len(res[got].tokens) == 9
+
+
+@pytest.mark.timeout(300)
+def test_kv_ledgers_clean_after_rows_finish_inside_blocks(params):
+    """ISSUE 32 (d): with the page pool on, rows that reach their
+    budget inside a block hold no page past it: every lease returns and
+    the ledgers balance."""
+    from dlrover_tpu.serving.engine import check_kv_ledgers
+
+    eng = InferenceEngine(params, CFG, slots=3, max_len=64,
+                          prefill_len=8, decode_block=8, kv_pages=24)
+    calls = _spy_step_block(eng)
+    reqs = _budget_requests(0.0)
+    reqs[0] = (reqs[0][0], dataclasses.replace(reqs[0][1],
+                                               max_new_tokens=40))
+    ids = [eng.submit(p, sp) for p, sp in reqs]
+    res = {r.id: r for r in eng.run()}
+    assert [len(res[i].tokens) for i in ids] == [
+        sp.max_new_tokens for _, sp in reqs]
+    assert any(n == 8 and 0 < min(r for r in rem if r) < 8
+               for n, rem in calls)
+    ledger = eng.kv_page_ledger()
+    assert ledger["ok"] and ledger["leased"] == 0
+    assert eng.free_pages == 24
+    assert check_kv_ledgers() == []
 
 
 def _engine_step_spans(journal_dir) -> list[dict]:
@@ -277,6 +439,79 @@ def test_long_prompt_admits_in_chunks_over_block_steps(params,
     assert [e["n_steps"] for e in spans] == [8, 8, 8, 8]
     assert [e["decoding_slots"] for e in spans] == [1, 1, 2, 2]
     eng.run()
+
+
+@pytest.mark.timeout(300)
+def test_decode_block_span_counts_frozen_row_steps(params, journal_dir):
+    """ISSUE 32 (e): the ``decode_block`` span says how many row-steps
+    of the call went to rows past their budget, the gauge their share
+    of the call's rows x steps; ``slots`` stays the rows in the call."""
+    from dlrover_tpu.serving import engine as engine_mod
+    from dlrover_tpu.telemetry.report import load_events
+
+    eng = InferenceEngine(params, CFG, slots=4, max_len=64,
+                          prefill_len=8, decode_block=8)
+    gauge = engine_mod._frozen_row_share.labels(eng.engine_id)
+    for m in (20, 3, 6):
+        eng.submit([1, 2, m], SamplingParams(temperature=0.0,
+                                             max_new_tokens=m))
+    shares = []
+    while eng.outstanding:
+        eng.step()
+        shares.append(gauge.value)
+    blocks = [e for e in load_events(str(journal_dir / "events.jsonl"))
+              if e["ev"] == "b" and e["name"] == "decode_block"]
+    # 20 / 3 / 6 left: (8 - 3) + (8 - 6) frozen row-steps of 3 x 8;
+    # then the long row alone: a whole block, and a tail of 4 in 4
+    assert [(e["slots"], e["n_steps"], e["frozen_row_steps"])
+            for e in blocks] == [(3, 8, 7), (1, 8, 0), (1, 4, 0)]
+    assert shares == [7 / 24, 0.0, 0.0]
+    steps = _engine_step_spans(journal_dir)
+    assert [e["n_steps"] for e in steps] == [8, 8, 4]
+    assert [e["decoding_slots"] for e in steps] == [3, 1, 1]
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("decode_block", [1, 8])
+def test_warm_aot_step_arms_the_block_a_live_batch_runs(
+        params, decode_block, tmp_path):
+    """ISSUE 32 (f): the AOT decode program is the one the engine calls
+    beside a live batch, ``n_steps`` = ``decode_block``; once it is
+    armed a whole block goes through it and compiles nothing."""
+    from dlrover_tpu.parallel.compile_cache import CompileCacheClient
+
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64,
+                          prefill_len=8, decode_block=decode_block)
+    aot = eng.warm_aot_step(cache=CompileCacheClient(str(tmp_path)))
+    assert aot is not None and eng._aot_step is aot.fn
+    armed, real = [], eng._aot_step
+
+    def through_aot(*a):
+        armed.append(np.asarray(a[10]).tolist())
+        out = real(*a)
+        assert out[0].shape == (decode_block, eng.slots)   # n_steps
+        return out
+
+    eng._aot_step = through_aot
+    jitted = _spy_step_block(eng)
+    sp = SamplingParams(temperature=0.0, max_new_tokens=2 * decode_block)
+    eng.submit([5, 9, 2], sp)
+    eng.run()                     # prefill and install compile here
+    compiles = []
+
+    def on_compile(name, secs, **kw):
+        if name.endswith("backend_compile_duration"):
+            compiles.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        rid = eng.submit([7, 7, 1], sp)
+        res = {r.id: r for r in eng.run()}
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert len(res[rid].tokens) == 2 * decode_block
+    assert compiles == [] and jitted == []
+    assert armed == [[2 * decode_block, 0], [decode_block, 0]] * 2
 
 
 @pytest.mark.timeout(300)
@@ -797,7 +1032,7 @@ def test_no_engine_program_converts_a_weight(cfg, program):
                           prefill_len=8, decode_block=4)
     if program == "decode_block":
         fn, static = eng._step_block, {"n_steps": 4}
-        args = eng._step_sample_args()[1:]
+        args = eng._block_sample_args()[1:]
     else:
         fn, static = eng._prefill_chunk, {}
         args = (jnp.zeros((1, 8), jnp.int32),
@@ -913,7 +1148,8 @@ def test_programs_that_return_the_stack_donate_it(params, program):
     slot = jnp.asarray(0, jnp.int32)
     table = jnp.zeros((eng.pages_per_slot,), jnp.int32)
     lowered = {
-        "_step_block": lambda: eng._step_block.lower(*step, n_steps=4),
+        "_step_block": lambda: eng._step_block.lower(
+            *eng._block_sample_args(), n_steps=4),
         "_verify_block": lambda: eng._verify_block.lower(
             *step, jnp.full((eng.slots, 4), -1, jnp.int32)),
         "_install": lambda: eng._install.lower(
@@ -964,7 +1200,8 @@ def test_aot_digest_names_the_stack_contract(params, monkeypatch, kind):
     eng = InferenceEngine(params, CFG, slots=2, max_len=64,
                           prefill_len=8)
     if kind == "serving_step":
-        aot, facts, extra = eng.warm_aot_step(), {"n_steps": 1}, ()
+        aot, facts = eng.warm_aot_step(), {"n_steps": eng.decode_block}
+        extra = eng._block_sample_args()[-1:]
     else:
         [aot], facts = eng.warm_aot_verify(depths=[2]), {}
         extra = (jnp.full((eng.slots, 2), -1, jnp.int32),)

@@ -163,7 +163,7 @@ def test_serving_programs_compile(one_chip):
             tree)
 
     step = eng._step_block.lower(
-        *on_chip(eng._step_sample_args()), n_steps=1
+        *on_chip(eng._block_sample_args()), n_steps=1
     ).compile(compiler_options=serving._CANONICAL_NUMERICS)
     # ISSUE 26: the chip's compiler takes the donation: K and V alias
     # input to output (with pos and last), one stack resident, not two
@@ -205,7 +205,7 @@ def test_latent_expert_serving_programs_compile(one_chip):
                                       sharding=one_chip), tree)
 
     step = eng._step_block.lower(
-        *on_chip(eng._step_sample_args()), n_steps=1
+        *on_chip(eng._block_sample_args()), n_steps=1
     ).compile(compiler_options=serving._CANONICAL_NUMERICS)
     stack = eng._cache["latent"]
     assert stack.shape == (2, 16, 5120, 576)
