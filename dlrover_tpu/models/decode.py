@@ -7,11 +7,15 @@ equivalent is a cache-carrying decode step under jit — static shapes
 step program, O(S) per generated token instead of the O(S^2) recompute of
 calling the full forward per step.
 
-The cache is the model's TREE (DESIGN.md §23.5): a position and
-stacks laid out ``[L, B, max_len, ...]``: one per K and V, ``[L, B,
-max_len, H_kv, D]``, for per-head attention; one latent stack for
-``attn_kind='latent'`` (models/latent.py). A stack is never copied
-whole (§23.1): the layer loop CARRIES it, layer ``l`` writes its
+The cache is the model's TREE (DESIGN.md §23.5): a position, ROWS
+(stacks laid out ``[L, B, len, ...]``, a position axis third: one per K
+and V, ``[L, B, max_len, H_kv, D]``, for per-head attention; one latent
+stack for ``attn_kind='latent'``, models/latent.py; ``k``, ``v`` and a
+shorter stack of compressed keys for ``attn_kind='mixers'``,
+models/hybrid.py) and, under ``state``, stacks ``[L, B, ...]`` that no
+token position addresses (a linear-attention layer's matrix). Stacks
+may differ in their layer count and rows in their length. A stack is
+never copied whole (§23.1): the layer loop CARRIES it, layer ``l`` writes its
 ``S_new`` new rows into it in place (the update is the new rows alone)
 and attends over ``stack[l]`` read out of the carry. Nothing of a
 layer's shape is scanned in or out: a scanned input is sliced out
@@ -46,12 +50,18 @@ Params = Any
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
-    """The model's cache TREE (DESIGN.md §23.5): ``pos``, stacks laid
-    out ``[L, B, max_len, ...]`` under names of the model's choosing
+    """The model's cache TREE (DESIGN.md §23.5): ``pos``, rows laid
+    out ``[L, B, len, ...]`` under names of the model's choosing
     (``k`` and ``v`` per head here; one ``latent`` stack for
-    ``attn_kind='latent'``), and optionally ``counters``. Callers carry
-    it whole and name none of its stacks: :func:`cache_stacks`."""
+    ``attn_kind='latent'``), optionally ``state`` (stacks ``[L, B, ...]``
+    with no position axis) and ``counters``. Callers carry
+    it whole and name none of its stacks: :func:`cache_stacks`,
+    :func:`cache_state`."""
     c = cfg
+    if c.mixers:
+        from dlrover_tpu.models import hybrid
+
+        return hybrid.init_cache(c, batch, max_len)
     if c.new_kinds:
         from dlrover_tpu.models import latent
 
@@ -71,9 +81,21 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
 
 
 def cache_stacks(cache: dict) -> dict:
-    """The stacks of a cache tree, ``[L, B, max_len, ...]`` each: all
-    but the position and the counters."""
-    return {k: v for k, v in cache.items() if k not in ("pos", "counters")}
+    """The ROWS of a cache tree, ``[L, B, len, ...]`` each (token
+    positions along the third axis; ``len`` is the cache's length or a
+    fixed fraction of it): all but the position, the state and the
+    counters."""
+    return {k: v for k, v in cache.items()
+            if k not in ("pos", "counters", "state")}
+
+
+def cache_state(cache: dict) -> dict:
+    """The STATE of a cache tree: stacks ``[L, B, ...]`` that no token
+    position addresses (empty for a model that keeps rows alone). What a
+    caller may do with both kinds alike is index the SECOND axis by row;
+    what assumes a position axis (pages, bundles, a draft's rejected
+    tail put back by its position) holds for rows only."""
+    return cache.get("state", {})
 
 
 def cache_counter_fields(cache: dict) -> dict:
@@ -193,7 +215,7 @@ def weights_at_rest(params: Params, cfg: TransformerConfig) -> Params:
 
 def forward_cached(
     params: Params, tokens: jax.Array, cache: dict,
-    cfg: TransformerConfig,
+    cfg: TransformerConfig, real: jax.Array | None = None,
 ) -> tuple[jax.Array, dict]:
     """Run S_new tokens starting at cache['pos'].
 
@@ -209,8 +231,20 @@ def forward_cached(
     caller: the block's rows are written (the call's queries read them)
     and the next pass, and last the storing pass from the final tokens,
     write them again; only the storing pass keeps the advance.
+
+    ``real`` (a scalar, or ``[B]``; None: all of them): how many of each
+    row's ``S_new`` tokens are real, the first ones. A caller that holds
+    a row back by putting its ``pos`` back says so here too: rows lie
+    under the next write at that position, but a model that keeps STATE
+    (:func:`cache_state`) has folded in whatever it was fed. Such a model
+    takes in the real tokens alone; one that keeps rows alone does not
+    read ``real`` at all.
     """
     c = cfg
+    if c.mixers:
+        from dlrover_tpu.models import hybrid
+
+        return hybrid.forward(params, tokens, c, cache, real=real)
     if c.new_kinds:
         # one definition of those kinds' block, cached or not
         from dlrover_tpu.models import latent

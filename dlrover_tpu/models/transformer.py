@@ -173,6 +173,35 @@ class TransformerConfig:
     block_length: int = 0
     denoising_steps: int = 0
     mask_token_id: int = -1
+    # --- attn_kind "mixers" (served only; models/hybrid.py owns the two
+    # mixers and the cache, THIS file's block runs them): a stack that is
+    # not homogeneous. `mixer_types` names each layer's mixer, "sparse"
+    # (block-sparse attention over `sparse_kv_heads` key/value heads, no
+    # rotary embedding: a query past `sparse_dense_len` keys attends to
+    # the `sparse_topk` blocks of `sparse_block` keys that its scores over
+    # COMPRESSED keys, means of `sparse_kernel` keys every `sparse_stride`,
+    # rank highest, the first `sparse_init_blocks` and the
+    # `sparse_window` / `sparse_block` blocks ending with its own among
+    # them) or "lightning" (linear attention: per head a decayed
+    # float32 state [head_dim, head_dim] in place of rows; `n_kv_heads`
+    # heads, rotary embedding, a norm over the concatenated output).
+    # Both take q/k norms and an output gate. `n_layers` is
+    # len(mixer_types)
+    mixer_types: tuple = ()
+    sparse_kv_heads: int = 0
+    sparse_kernel: int = 32
+    sparse_stride: int = 16
+    sparse_block: int = 64
+    sparse_topk: int = 64
+    sparse_init_blocks: int = 1
+    sparse_window: int = 2048
+    sparse_dense_len: int = 8192
+    # the multipliers of a model parametrised for width transfer that are
+    # not `mup_base_width`'s: on the embedding, on every residual branch,
+    # on the head's input
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         if not self.head_dim:
@@ -189,12 +218,38 @@ class TransformerConfig:
                     f"{self.denoising_steps}, {self.mask_token_id}")
         elif self.generation != "autoregressive":
             raise ValueError(f"unknown generation {self.generation!r}")
+        if self.attn_kind == "mixers":
+            c = self
+            forced = c.sparse_init_blocks + c.sparse_window // max(
+                1, c.sparse_block)
+            if (len(c.mixer_types) != c.n_layers
+                    or set(c.mixer_types) != {"sparse", "lightning"}
+                    or c.sparse_kv_heads < 1
+                    or c.n_heads % c.sparse_kv_heads
+                    or c.sparse_kernel != 2 * c.sparse_stride
+                    or c.sparse_block % c.sparse_stride
+                    or c.sparse_window % c.sparse_block
+                    or forced > c.sparse_topk
+                    or c.sparse_dense_len < c.sparse_topk * c.sparse_block):
+                raise ValueError(
+                    "attn_kind 'mixers' needs mixer_types of n_layers "
+                    "'sparse' / 'lightning' entries (both present), "
+                    "sparse_kv_heads dividing n_heads, sparse_kernel == 2 * "
+                    "sparse_stride, sparse_block a multiple of the stride, "
+                    "sparse_window of the block, the forced blocks within "
+                    "sparse_topk and sparse_dense_len >= sparse_topk * "
+                    f"sparse_block: got {c}")
 
     @property
     def new_kinds(self) -> bool:
         """True where models/latent.py runs the block."""
         return (self.attn_kind == "latent" or self.norm_kind != "pre"
                 or self.ffn_kind == "sigmoid_experts")
+
+    @property
+    def mixers(self) -> bool:
+        """True where the stack is a list of mixers (models/hybrid.py)."""
+        return self.attn_kind == "mixers"
 
     @property
     def held_experts(self) -> bool:
@@ -276,10 +331,11 @@ class TransformerConfig:
         kinds count where training's kinds count
         :meth:`train_flops_per_token`."""
         c = self
-        if c.new_kinds:
+        if c.new_kinds or c.mixers:
             raise NotImplementedError(
                 "forward_flops_per_token: benchmark/counts/mla_moe.py "
-                "counts the latent kinds' forward")
+                "counts the latent kinds' forward and benchmark/counts/"
+                "sala.py the mixers'")
         layer = param_shapes(c)["layers"]
         matmul = 0
         for name, shape in layer.items():
@@ -408,6 +464,38 @@ CONFIGS = {
         norm_topk_prob=True, param_dtype="bfloat16",
         generation="block_diffusion", block_length=4, denoising_steps=4,
         mask_token_id=151669),
+    # the kinds of the entry below at a size for CPU tests: sparse sizes
+    # small enough that the selection binds inside 100 tokens
+    "tiny-sala": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=4, n_heads=4, n_kv_heads=4,
+        head_dim=16, d_ff=160, max_seq_len=256, rope_theta=10000.0,
+        norm_eps=1e-6, attn_kind="mixers",
+        mixer_types=("sparse", "lightning", "lightning", "sparse"),
+        sparse_kv_heads=2, sparse_kernel=8, sparse_stride=4,
+        sparse_block=16, sparse_topk=4, sparse_init_blocks=1,
+        sparse_window=32, sparse_dense_len=64, embed_scale=12.0,
+        residual_scale=1.4 / math.sqrt(4), logit_scale=16 / 64,
+        dtype="float32"),
+    # MiniCPM-SALA as published (config.json, model_type minicpm_sala):
+    # `minicpm4` layers are "sparse" (InfLLM-v2; the sparse sizes are
+    # MiniCPM4's published sparse_config, which this config.json lacks),
+    # `lightning-attn` layers "lightning"; scale_emb 12, scale_depth 1.4
+    # over sqrt(32 layers), hidden 4096 / dim_model_base 256 at the head.
+    # A deployment sets the layers it holds with dataclasses.replace
+    # (mixer_types, n_layers); residual_scale stays the published stack's.
+    "minicpm-sala": TransformerConfig(
+        vocab_size=73448, d_model=4096, n_layers=32, n_heads=32,
+        n_kv_heads=32, head_dim=128, d_ff=16384, max_seq_len=524288,
+        rope_theta=10000.0, norm_eps=1e-6, attn_kind="mixers",
+        mixer_types=(
+            ("sparse",) + ("lightning",) * 8 + ("sparse",)
+            + ("lightning",) * 6 + ("sparse",) * 2 + ("lightning",) * 4
+            + ("sparse",) + ("lightning",) * 6 + ("sparse",) * 3),
+        sparse_kv_heads=2, sparse_kernel=32, sparse_stride=16,
+        sparse_block=64, sparse_topk=64, sparse_init_blocks=1,
+        sparse_window=2048, sparse_dense_len=8192, embed_scale=12.0,
+        residual_scale=1.4 / math.sqrt(32), logit_scale=256 / 4096,
+        param_dtype="bfloat16"),
 }
 
 
@@ -430,9 +518,11 @@ def routed_config(cfg: TransformerConfig):
 def _check_kinds(cfg: TransformerConfig) -> None:
     """This file's block runs these kinds and names what it does not."""
     c = cfg
-    if (c.attn_kind not in ("heads", "heads_qk_norm") or c.norm_kind != "pre"
+    if (c.attn_kind not in ("heads", "heads_qk_norm", "mixers")
+            or c.norm_kind != "pre"
             or c.ffn_kind not in ("", "softmax_experts")
             or (c.ffn_kind and c.moe_experts)
+            or (c.mixers and (c.ffn_kind or c.moe_experts))
             or (not c.default_kinds and c.variant != "llama")
             or c.rope_pairing not in ("interleaved", "half")):
         raise NotImplementedError(
@@ -442,7 +532,8 @@ def _check_kinds(cfg: TransformerConfig) -> None:
             f"{c.rope_pairing!r}: models/transformer.py runs 'heads' or "
             "'heads_qk_norm' attention under 'pre' norms with the "
             "variant's FFN, `moe_experts` or 'softmax_experts' (llama "
-            "variant), and models/latent.py runs ('latent', 'sandwich', "
+            "variant), 'mixers' (models/hybrid.py) with the llama FFN, and "
+            "models/latent.py runs ('latent', 'sandwich', "
             "'sigmoid_experts')")
 
 
@@ -451,6 +542,10 @@ def param_shapes(cfg: TransformerConfig) -> dict:
     leaf): what :func:`init_params` makes and the counts count."""
     c = cfg
     _check_kinds(c)
+    if c.mixers:
+        from dlrover_tpu.models.hybrid import param_shapes as mixer_shapes
+
+        return mixer_shapes(c)
     e, hd, n = c.d_model, c.head_dim, c.n_layers
     layers = {
         "wq": (e, c.n_heads, hd), "wk": (e, c.n_kv_heads, hd),
@@ -749,7 +844,7 @@ AttentionFn = Callable[..., jax.Array]
 # among them. ``models/decode.weights_at_rest`` keeps exactly these in
 # ``cfg.dtype`` for a holder that runs the block many times.
 PRODUCT_LEAVES = frozenset({
-    "embed", "pos_embed", "wq", "wk", "wv", "wo", "w_gate", "w_up",
+    "embed", "pos_embed", "wq", "wk", "wv", "wo", "w_og", "w_gate", "w_up",
     "w_down", "b_ff", "b_out", "lm_head", *EXPERT_STACKS})
 
 
@@ -784,6 +879,7 @@ def make_layer_fn(
     attend: Callable | None = None,
     positions: jax.Array | None = None,
     experts: dict | None = None,
+    mixer: str = "",
 ) -> Callable[..., tuple[jax.Array, jax.Array, Any]]:
     """One transformer block as a reusable ``(x, w, state=None,
     index=None) -> (x, aux, state)``: THE definition of what a dense
@@ -814,12 +910,33 @@ def make_layer_fn(
     handed to the loop would be copied whole); ``aux`` is then the
     layer's ``loads [held]`` int32, the assignments each held expert
     took, which a cached caller adds to its counters.
+
+    ``attn_kind='mixers'`` (served only): ``mixer`` says which of the
+    stack's mixers this block runs ("sparse" or "lightning";
+    ``models/hybrid.py`` builds one block a run of equal layers and hands
+    over the ``attend`` that owns the rows or the state). Both take q/k
+    norms and an output gate ``o * sigmoid(h W_og)``; "sparse" takes no
+    rotary embedding; "lightning" norms the concatenated heads' output
+    (``ln_o``) before the gate. ``cfg.residual_scale`` multiplies both
+    residual branches of every kind (1.0: nothing).
     """
     c = cfg
     _check_kinds(c)
     dt = jnp.dtype(c.dtype)
     eps = _norm_eps(c)
     pin = constrain or (lambda x, a: x)
+    if c.mixers and (mixer not in ("sparse", "lightning") or attend is None
+                     or mask is not None or constrain is not None
+                     or c.int8_matmuls):
+        raise NotImplementedError(
+            "attn_kind 'mixers' is the forward pass on one device through "
+            "models/hybrid.py, which names each run's mixer and owns its "
+            "cache; there is neither a token mask, a sharding rule, an "
+            "int8 path nor a gradient for it (training, parallel/"
+            "pipeline.py, parallel/mpmd.py)")
+    qk_norm = c.attn_kind == "heads_qk_norm" or c.mixers
+    rotary = c.variant == "llama" and mixer != "sparse"
+    res = c.residual_scale
     if c.held_experts:
         if experts is None or mask is not None or constrain is not None:
             raise NotImplementedError(
@@ -907,16 +1024,26 @@ def make_layer_fn(
                 q = q * mup_q_scale
             k = proj(h, _leaf(w, "wk", dt), "bse,ehd->bshd")
             v = proj(h, _leaf(w, "wv", dt), "bse,ehd->bshd")
-            if c.attn_kind == "heads_qk_norm":
+            if qk_norm:
                 with jax.named_scope("qk_norm"):
                     q = _norm(q, w["ln_q"], None, "llama", eps)
                     k = _norm(k, w["ln_k"], None, "llama", eps)
-            if c.variant == "llama":
+            if rotary:
                 q = _rope(q, at, c.rope_theta, c.rope_pairing)
                 k = _rope(k, at, c.rope_theta, c.rope_pairing)
             o, state = attend(q, k, v, state)
+            if mixer:
+                with jax.named_scope("out_gate"):
+                    if mixer == "lightning":
+                        o = _norm(o.reshape(*o.shape[:2], -1), w["ln_o"],
+                                  None, "llama", eps).reshape(o.shape)
+                    gate = proj(h, _leaf(w, "w_og", dt), "bse,ehd->bshd")
+                    o = o * jax.nn.sigmoid(
+                        gate.astype(jnp.float32)).astype(dt)
             o = proj(o, _leaf(w, "wo", dt), "bshd,hde->bse", n_contract=2)
             o = checkpoint_name(o, "attn_out")  # inert without a names policy
+            if res != 1.0:
+                o = o * res
             x = pin(x + o, ("batch", "sequence", "embed"))
 
         with jax.named_scope("mlp"):
@@ -949,6 +1076,8 @@ def make_layer_fn(
                 hidden = checkpoint_name(hidden, "ffn_hidden")
                 ff = (proj(hidden, _leaf(w, "w_down", dt), "bsf,fe->bse")
                       + _leaf(w, "b_out", dt))
+            if res != 1.0:
+                ff = ff * res
             x = pin(x + ff, ("batch", "sequence", "embed"))
         return x, aux, state
 
@@ -1003,6 +1132,8 @@ def embed_tokens(
                 pe = table[jnp.clip(token_positions(pos, *tokens.shape),
                                     0, c.max_seq_len - 1)]
             x = pin(x + pe, ("batch", "sequence", "embed"))
+        if c.embed_scale != 1.0:
+            x = x * c.embed_scale
     return x
 
 
@@ -1017,6 +1148,8 @@ def lm_logits(params: Params, hidden: jax.Array,
               cfg: TransformerConfig) -> jax.Array:
     """Final-normed hidden [B, S, E] -> fp32 logits [B, S, vocab]."""
     dt = jnp.dtype(cfg.dtype)
+    if cfg.logit_scale != 1.0:
+        hidden = hidden * cfg.logit_scale
     logits = jnp.einsum("bse,ev->bsv", hidden, _leaf(params, "lm_head", dt))
     if cfg.mup_base_width:
         # muP readout multiplier keeps logit scale width-invariant
@@ -1081,6 +1214,17 @@ def forward_with_aux(
             raise NotImplementedError(
                 "the latent / sandwich / sigmoid_experts kinds take "
                 "tokens and nothing else (models/latent.py)")
+        return forward_uncached(params, tokens, c,
+                                return_hidden=return_hidden)
+    if c.mixers:
+        from dlrover_tpu.models.hybrid import forward_uncached
+
+        if (attention_fn is not None or c.prefix_lm or c.remat_scan
+                or c.pipeline_stages > 1 or inputs_embeds is not None
+                or mask is not None or constrain is not None):
+            raise NotImplementedError(
+                "attn_kind 'mixers' takes tokens and nothing else "
+                "(models/hybrid.py)")
         return forward_uncached(params, tokens, c,
                                 return_hidden=return_hidden)
     dt = jnp.dtype(c.dtype)

@@ -100,6 +100,18 @@ the final tokens that keeps the advance. What is masked is the engine's
 own boolean a position. ``Result.unmask_steps`` says in which pass each
 token was unmasked. Speculation, ``kv_pages``, park/resume and
 handed-over bundles raise by name for such a model.
+
+**State beside rows** (DESIGN.md §23.5): a model's cache tree may hold,
+beside rows ``[L, B, len, ...]``, STATE that no token position addresses
+(``models.decode.cache_state``: a linear-attention layer's matrix).
+Everywhere this engine keeps a row from advancing by putting ``pos``
+back (an idle slot, a row past its eos or frozen past its budget inside
+a block, the pad tail of a prompt's final chunk) it also tells the
+cached forward how many of the call's tokens are real, a row, and such a
+model takes in no other. ``_install``, the working row and the prefix
+cache carry state with the rows (an entry holds the state AT its chunk
+boundary); ``kv_pages``, speculation and bundles, which assume a
+position axis, raise by name.
 """
 
 from __future__ import annotations
@@ -123,6 +135,7 @@ from dlrover_tpu.common.log import get_logger
 from dlrover_tpu.models.decode import (
     cache_counter_fields,
     cache_stacks,
+    cache_state,
     forward_cached,
     init_cache,
     sample_logits,
@@ -177,6 +190,18 @@ _COUNTER_GAUGES = {
         "dlrover_tpu_engine_expert_load_max_over_mean",
         "the busiest held expert's assignments in the newest decode "
         "call over the mean of all held experts (1.0: even)",
+        label_names=("engine",)),
+    "sparse_keys_share": registry().gauge(
+        "dlrover_tpu_engine_sparse_keys_share",
+        "keys the selection of a block-sparse model chose in the engine's "
+        "newest decode call over the keys whose scores its attention "
+        "computed (1.0: it read what it selected and no more)",
+        label_names=("engine",)),
+    "state_bytes_per_slot": registry().gauge(
+        "dlrover_tpu_engine_state_bytes_per_slot",
+        "bytes of cache a slot holds that no token position addresses "
+        "(a linear-attention layer's state), whatever its context; set "
+        "once, by an engine whose model keeps such state",
         label_names=("engine",)),
 }
 _decoding_slots = registry().gauge(
@@ -489,6 +514,16 @@ class InferenceEngine:
         self.kv_pages = int(kv_pages)
         self.pages_per_slot = self.max_len // self.page_size
         self._paging = self.kv_pages > 0
+        # what the model's cache tree holds (DESIGN.md §23.5), as shapes:
+        # rows and, for some, state that no token position addresses
+        tree = jax.eval_shape(lambda: init_cache(cfg, slots, self.max_len))
+        self._stateful = bool(cache_state(tree))
+        if self._paging and self._stateful:
+            raise NotImplementedError(
+                "kv_pages > 0 (the paged store, park/resume, copy-on-write "
+                "pages) with a model that carries state: a page holds "
+                "token-addressed rows, and a parked row's state has no "
+                "page (run it with kv_pages=0)")
         if self._paging and cfg.attn_kind != "heads":
             raise NotImplementedError(
                 f"kv_pages > 0 with attn_kind {cfg.attn_kind!r}: the paged "
@@ -595,6 +630,12 @@ class InferenceEngine:
                 "speculation (spec_depth >= 2) with a block-diffusion "
                 "model: `_verify_block` accepts a drafted run of NEXT "
                 "tokens, and a denoising pass has no next token")
+        if self._stateful and self.spec_depth >= 2:
+            raise NotImplementedError(
+                "speculation (spec_depth >= 2) with a model that carries "
+                "state: `_verify_block` takes a rejected draft back by "
+                "putting the position back, and a state has already "
+                "folded the draft in")
         self._spec = self.spec_depth >= 2 and self._obs is not None
         # rid -> [accepted, scored, collapsed] live draft accounting
         self._spec_acc: dict[int, list[int]] = {}
@@ -615,14 +656,22 @@ class InferenceEngine:
         self._cache = init_cache(cfg, slots, self.max_len)
         self._cache["pos"] = jnp.zeros((slots,), jnp.int32)
         self._last = jnp.zeros((slots, cfg.vocab_size), jnp.float32)
-        # what a token costs to keep, over all layers and stacks
+        # what a token costs to keep, over all layers and ROWS (a stack
+        # shorter than max_len, compressed keys, costs its share), and
+        # what a slot keeps whatever its context
         self.cache_bytes_per_token = sum(
-            int(np.prod(a.shape[3:])) * a.shape[0] * a.dtype.itemsize
-            for a in cache_stacks(self._cache).values())
-        # what a working row carries beside stacks and position
+            a.nbytes for a in cache_stacks(self._cache).values()
+        ) // (slots * self.max_len)
+        self.state_bytes_per_slot = sum(
+            a.nbytes for a in jax.tree.leaves(cache_state(self._cache))
+        ) // slots
+        if self._stateful:
+            _COUNTER_GAUGES["state_bytes_per_slot"].labels(
+                self.engine_id).set(self.state_bytes_per_slot)
+        # what a working row carries beside rows, state and position
         self._row_extras = {
-            k: v for k, v in init_cache(cfg, 1, 1).items()
-            if k == "counters"}
+            "counters": jax.tree.map(jnp.zeros_like, self._cache["counters"])
+        } if "counters" in self._cache else {}
         # per-slot sampling randomness: a seed per REQUEST + a count of
         # tokens sampled so far — the per-draw key is derived from both,
         # so a request's stream never depends on batch composition
@@ -631,27 +680,37 @@ class InferenceEngine:
         self._seed_gen = np.random.default_rng(0)
 
         # --- compiled programs ---------------------------------------
+        stateful = self._stateful
         def _prefill_chunk(params, tokens, row, true_len):
             # one prefill_len chunk into a [1, max_len] working row;
             # long prompts loop this program (the row's pos carries
             # across chunks, so only the FINAL chunk may be pad-tailed —
             # a mid-sequence pad would sit under later queries' causal
-            # mask). Returns the last REAL token's logits of the chunk
-            # and what the model counted in it.
+            # mask; a model that keeps state is told how many of the
+            # chunk's tokens are real, and takes in no pad). Returns the
+            # last REAL token's logits of the chunk and what the model
+            # counted in it.
             logits, row = forward_cached(params, tokens,
-                                         zero_counters(row), cfg)
+                                         zero_counters(row), cfg,
+                                         real=true_len)
             return row, logits[0, true_len - 1], cache_counter_fields(row)
 
         self._prefill_chunk = jax.jit(_prefill_chunk)
 
         def _install(cache, last_all, row, last_row, slot, true_len):
-            # write the prefilled row into slot `slot` of every stack
-            stacks = {
-                name: lax.dynamic_update_index_in_dim(
-                    stack, row[name][:, 0], slot, axis=1)
-                for name, stack in cache_stacks(cache).items()}
+            # write the prefilled row into slot `slot` of every stack:
+            # rows and state alike are indexed by slot on their second axis
+            def put(stack, mine):
+                return lax.dynamic_update_index_in_dim(
+                    stack, mine[:, 0], slot, axis=1)
+
+            stacks = {name: put(stack, row[name])
+                      for name, stack in cache_stacks(cache).items()}
             cache = {**cache, **stacks,
                      "pos": cache["pos"].at[slot].set(true_len)}
+            if cache_state(cache):
+                cache["state"] = jax.tree.map(put, cache_state(cache),
+                                              cache_state(row))
             return cache, last_all.at[slot].set(last_row)
 
         self._install = jax.jit(_install, donate_argnums=(0, 1))
@@ -724,13 +783,22 @@ class InferenceEngine:
                 )
                 nxt = jnp.where(done, jnp.maximum(eos_ids, 0), nxt)
                 hit = (eos_ids >= 0) & (nxt == eos_ids)
-                logits, new = forward_cached(
-                    params, nxt[:, None], cache, cfg
-                )
                 # inactive/finished rows must not advance (their pos
                 # would creep past max_len and clamp the next install's
-                # attention)
-                run = active & ~done & (i < remaining)
+                # attention): their position is put back. A model that
+                # keeps state has to be told BEFORE the forward that
+                # their token is not real; for a model of rows alone the
+                # mask is made where it always was, so that its program
+                # stays what it was to the letter
+                def advancing():
+                    return active & ~done & (i < remaining)
+
+                run = advancing() if stateful else None
+                logits, new = forward_cached(
+                    params, nxt[:, None], cache, cfg, real=run
+                )
+                if run is None:
+                    run = advancing()
                 new["pos"] = jnp.where(run, new["pos"], cache["pos"])
                 return (new, logits[:, 0], done | hit), nxt
 
@@ -1219,7 +1287,8 @@ class InferenceEngine:
         chunk = run.prompt[lo: min(lo + P, run.upto)]
         with hot_span("prefill_chunk", remote_parent=run.sctx,
                       request=run.request, tokens=len(chunk),
-                      chunk=run.chunks, context=lo) as span:
+                      real_tokens=len(chunk), chunk=run.chunks,
+                      context=lo) as span:
             toks = np.zeros((1, P), np.int32)
             toks[0, : len(chunk)] = chunk
             run.row, run.last, counted = self._prefill_chunk(
@@ -1275,6 +1344,12 @@ class InferenceEngine:
         )
 
     def _no_bundles(self) -> None:
+        if self._stateful:
+            raise NotImplementedError(
+                "handed-over KVBundles (make_bundle, submit_prefilled) "
+                "with a model that carries state: a bundle is pages of "
+                "token-addressed rows, and the state at the prompt's end "
+                "has no page")
         if self._diffusion:
             raise NotImplementedError(
                 "handed-over KVBundles (make_bundle, submit_prefilled) "
@@ -1656,7 +1731,8 @@ class InferenceEngine:
             if slot is not None:
                 with hot_span("kv_install", remote_parent=pa.req.sctx,
                               request=pa.req.id, slot=slot,
-                              tokens=len(pa.req.prompt)):
+                              tokens=len(pa.req.prompt),
+                              state_bytes=self.state_bytes_per_slot):
                     self._install_admit(slot, pa)
                 self._pending = None
                 worked = True

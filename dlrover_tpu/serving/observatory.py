@@ -508,6 +508,10 @@ class ServingObservatory:
             # or one latent row a layer)
             "cache_bytes_per_token": int(
                 getattr(eng, "cache_bytes_per_token", 0)),
+            # and what a slot keeps that no token position addresses (a
+            # linear-attention state): 0 for a model of rows alone
+            "state_bytes_per_slot": int(
+                getattr(eng, "state_bytes_per_slot", 0)),
         }
         eid = eng.engine_id
         _pages_free.labels(eid).set(free)

@@ -222,3 +222,53 @@ def test_latent_expert_serving_programs_compile(one_chip):
         row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     ).compile()
     assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_hybrid_cache_serving_programs_compile(one_chip):
+    """Decode step and prefill chunk of ``InferenceEngine`` for a cache of
+    rows AND state (``attn_kind='mixers'``, models/hybrid.py) at
+    MiniCPM-SALA's published widths: one block-sparse and one lightning
+    layer, 16 slots of 33792 positions, weights resting in bfloat16. What
+    the CPU cannot show: the gather of 64 selected blocks a row and group
+    straight from the stack (no copy of a layer's rows: the first layout
+    of the stacks made the compiler re-lay 830 MB a call), rows, compressed
+    keys and state all donated."""
+    from dlrover_tpu.models import hybrid
+    from dlrover_tpu.serving import engine as serving
+
+    cfg = dataclasses.replace(
+        tfm.CONFIGS["minicpm-sala"], n_layers=2,
+        mixer_types=("sparse", "lightning"), dtype="bfloat16")
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip),
+        hybrid.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    eng = serving.InferenceEngine(params, cfg, slots=16, max_len=33792,
+                                  prefill_len=512)
+    assert eng.cache_bytes_per_token == 1024 + 32
+    assert eng.state_bytes_per_slot == 32 * 128 * 128 * 4
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: a if isinstance(a, jax.ShapeDtypeStruct)
+            else jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                      sharding=one_chip), tree)
+
+    step = eng._step_block.lower(
+        *on_chip(eng._block_sample_args()), n_steps=1
+    ).compile(compiler_options=serving._CANONICAL_NUMERICS)
+    rows = eng._cache["k"]
+    assert rows.shape == (2, 16, 33792, 128)            # G heads side by side
+    held = (2 * rows.size * 2 + eng._cache["kc"].size * 2
+            + eng._cache["state"]["s"].size * 4)
+    m = step.memory_analysis()
+    assert m.alias_size_in_bytes >= held                # donated whole
+    # beside the weights and the cache: nothing the size of a row stack
+    # (0.277e9) among the temporaries but the projections' re-laid weights
+    assert m.temp_size_in_bytes < 0.5e9
+    assert _device_bytes(step) < HBM_BYTES
+    row = on_chip(jax.eval_shape(lambda: hybrid.init_cache(cfg, 1, 33792)))
+    chunk = eng._prefill_chunk.lower(
+        params, jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip),
+        row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile()
+    assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
