@@ -1313,9 +1313,17 @@ class InferenceEngine:
             run.chunks += 1
             self._chunks_run += 1
             run.done = run.next_lo >= run.upto
+            indexed = 0
+            if self._obs is not None and run.request >= 0:
+                # an admission's chunk: the shadow indexes its tokens
+                # while the device runs them, where this thread would
+                # only wait (the prefill pool's runs have no request)
+                indexed = self._obs.note_prefilled(
+                    run.request, run.prompt, lo, lo + len(chunk))
             jax.block_until_ready(run.last)
             # the chunk is done: its counters cost no wait of their own
-            span.set(**_counted(jax.device_get(counted)))
+            span.set(indexed_tokens=indexed,
+                     **_counted(jax.device_get(counted)))
         run.work_s += time.monotonic() - t0
         return run.done
 
@@ -1628,7 +1636,10 @@ class InferenceEngine:
                                       shared=set(range(shared_n)))
         return True
 
-    def _install_admit(self, slot: int, pa: _PendingAdmit) -> None:
+    def _install_admit(self, slot: int, pa: _PendingAdmit) -> int:
+        """Install a finished prefill run into ``slot``; returns the
+        prompt tokens the shadow had left to index here (what no chunk
+        carried: under ``prefill_len`` for a cold prompt)."""
         req, run = pa.req, pa.run
         last = run.last
         if last is None:
@@ -1639,6 +1650,10 @@ class InferenceEngine:
             jnp.asarray(slot, jnp.int32),
             jnp.asarray(run.upto, jnp.int32),
         )
+        # under the install's device time, as a chunk's tokens under the
+        # chunk's
+        indexed = (self._obs.note_admitted(req)
+                   if self._obs is not None else 0)
         jax.block_until_ready(self._last)
         self._active[slot] = req
         self._emitted[slot] = []
@@ -1657,8 +1672,6 @@ class InferenceEngine:
         self._seeds[slot] = np.uint32(seed % (2**32))
         self._sampled[slot] = 0
         self._samp_cache = None
-        if self._obs is not None:
-            self._obs.note_admitted(req)
         journal = get_journal()
         journal.emit(
             "engine_admit", request=req.id, kind=pa.kind,
@@ -1676,6 +1689,7 @@ class InferenceEngine:
                               for a in req.bundle.stacks.values())),
                 remote_parent=req.sctx,
             )
+        return indexed
 
     def _materialize_prefix(self, slot: int, pa: _PendingAdmit) -> None:
         """Scatter the freshly installed row's FULL prompt-prefix
@@ -1732,8 +1746,10 @@ class InferenceEngine:
                 with hot_span("kv_install", remote_parent=pa.req.sctx,
                               request=pa.req.id, slot=slot,
                               tokens=len(pa.req.prompt),
-                              state_bytes=self.state_bytes_per_slot):
-                    self._install_admit(slot, pa)
+                              state_bytes=self.state_bytes_per_slot
+                              ) as span:
+                    span.set(
+                        indexed_tokens=self._install_admit(slot, pa))
                 self._pending = None
                 worked = True
         return worked

@@ -32,7 +32,11 @@ on live traffic before either is built (DESIGN.md §29):
    prompt + generated context, deterministic, no RNG) scores every
    emitted decode token. The resulting ``draft_accept_rate`` and
    run-length histogram of consecutive accepts are the measured prior
-   for choosing draft depth k later.
+   for choosing draft depth k later. A prompt enters the predictor a
+   prefill chunk at a time, on the engine's thread, between the chunk's
+   dispatch and the wait for it (``note_prefilled``); the install
+   (``note_admitted``) counts only what no chunk carried, and from then
+   on the predictor holds the whole prompt, as one built whole would.
 
 The observatory is on by default (``DLROVER_TPU_SERVING_OBSERVATORY=0``
 disables it) and touches only host-side bookkeeping: it never reads
@@ -236,30 +240,76 @@ class ShadowPredictor:
     token id; back-off is longest-match k→1), so the acceptance
     estimate is reproducible and the measure-only pin is trivially
     safe — the predictor only ever *observes* emitted tokens.
+
+    A table entry depends only on a position and the tokens before it,
+    never on when it was counted, so a prompt may enter the tables in
+    slices: ``whole=False`` holds the prompt as context and counts none
+    of it, ``index(lo, hi)`` counts a slice (the engine: under the
+    prefill chunk that carries those tokens) and ``finish()`` counts
+    what no slice covered (the engine: at the install). After
+    ``finish()`` the predictor is the one built whole, table for table;
+    before it, ``predict`` / ``draft`` / ``observe`` are not to be asked.
     """
 
-    def __init__(self, order: int, prompt) -> None:
+    def __init__(self, order: int, prompt, whole: bool = True) -> None:
         self.order = max(1, int(order))
-        self._ctx: list[int] = []
-        self._tables: list[dict[tuple, Counter]] = [
+        self._ctx: list[int] = [int(t) for t in prompt]
+        # context (j tokens) -> follower -> times seen, for j = 1..order
+        self._tables: list[dict[tuple, dict[int, int]]] = [
             {} for _ in range(self.order)
         ]
+        # the prompt positions counted so far, one interval (a prefill's
+        # chunks each start where the last ended); None once all are
+        self._indexed: tuple[int, int] | None = (0, 0)
         self.scored = 0
         self.accepted = 0
-        for t in prompt:
-            self._absorb(int(t))
+        if whole:
+            self.finish()
+
+    def _count(self, lo: int, hi: int) -> None:
+        """Count the followers at context positions ``lo..hi``."""
+        ctx, tables, order = self._ctx, self._tables, self.order
+        for i in range(lo, hi):
+            tok = ctx[i]
+            for j in range(1, min(order, i) + 1):
+                key = tuple(ctx[i - j: i])
+                followers = tables[j - 1].get(key)
+                if followers is None:
+                    tables[j - 1][key] = {tok: 1}
+                else:
+                    followers[tok] = followers.get(tok, 0) + 1
+
+    def index(self, lo: int, hi: int) -> int:
+        """Count prompt positions ``lo..hi``; returns how many were
+        counted here. A slice that does not start where the counted
+        interval ends counts nothing: ``finish()`` has it."""
+        done = self._indexed
+        if done is None:
+            return 0
+        if done[0] == done[1]:
+            done = (lo, lo)
+        hi = min(hi, len(self._ctx))
+        if lo != done[1] or hi <= lo:
+            return 0
+        self._count(lo, hi)
+        self._indexed = (done[0], hi)
+        return hi - lo
+
+    def finish(self) -> int:
+        """Count the prompt positions no ``index`` slice covered (all of
+        them for a prompt that had none); returns how many. A finished
+        predictor has nothing left, so a second call counts nothing."""
+        if self._indexed is None:
+            return 0
+        lo, hi = self._indexed
+        self._indexed = None
+        self._count(0, lo)
+        self._count(hi, len(self._ctx))
+        return lo + len(self._ctx) - hi
 
     def _absorb(self, tok: int) -> None:
-        ctx = self._ctx
-        for j in range(1, self.order + 1):
-            if len(ctx) >= j:
-                key = tuple(ctx[-j:])
-                table = self._tables[j - 1]
-                followers = table.get(key)
-                if followers is None:
-                    followers = table[key] = Counter()
-                followers[tok] += 1
-        ctx.append(tok)
+        self._ctx.append(tok)
+        self._count(len(self._ctx) - 1, len(self._ctx))
 
     def _predict_ctx(self, ctx, min_order: int = 1):
         for j in range(min(self.order, len(ctx)), 0, -1):
@@ -317,6 +367,13 @@ class ServingObservatory:
     All hooks run on the engine's single decode thread; ``snapshot()``
     (the gateway health-tick reader) only copies the last published
     sample under a small lock.
+
+    A request's shadow is made at its first prefill chunk
+    (``note_prefilled``: the chunk's tokens, indexed while the device
+    runs the chunk) or, for an admission that runs none, at its install
+    (``note_admitted``: whatever of the prompt is not yet indexed). It is
+    whole from the install on, which is when ``observe_token`` and the
+    engine's speculation first read it, and goes at ``note_retire``.
     """
 
     def __init__(self, engine, *, sample_every: int = 32) -> None:
@@ -364,11 +421,26 @@ class ServingObservatory:
 
     # ----------------------------------------------- shadow-draft hooks
 
-    def note_admitted(self, req) -> None:
-        if req.id not in self._shadow:
-            self._shadow[req.id] = ShadowPredictor(
-                SHADOW_ORDER, req.prompt)
-            self._churn.setdefault(req.id, 0)
+    def _shadow_of(self, rid: int, prompt) -> ShadowPredictor:
+        shadow = self._shadow.get(rid)
+        if shadow is None:
+            shadow = self._shadow[rid] = ShadowPredictor(
+                SHADOW_ORDER, prompt, whole=False)
+        return shadow
+
+    def note_prefilled(self, rid: int, prompt, lo: int, hi: int) -> int:
+        """A prefill chunk carrying ``prompt[lo:hi]`` has been
+        dispatched: index those tokens while the device runs it (the
+        engine's thread would only wait). Returns the tokens indexed."""
+        return self._shadow_of(rid, prompt).index(lo, hi)
+
+    def note_admitted(self, req) -> int:
+        """The install: index what no chunk carried (a prefix-cache
+        hit's head, a handed-over bundle's whole prompt, a
+        block-diffusion prompt's remainder), after which the shadow
+        holds the whole prompt. Returns the tokens indexed here."""
+        self._churn.setdefault(req.id, 0)
+        return self._shadow_of(req.id, req.prompt).finish()
 
     def observe_token(self, rid: int, tok: int) -> None:
         shadow = self._shadow.get(rid)

@@ -9,6 +9,9 @@ The properties that make a measure-only instrument trustworthy:
   pages (overlap / no-overlap / partial-page cases);
 - the n-gram shadow predictor is deterministic: same stream, same
   acceptance, no RNG anywhere;
+- a prompt enters the predictor in slices (ISSUE 34: under its own
+  prefill chunks, the rest at the install) and the predictor is the one
+  built whole, table for table, answer for answer;
 - `bench.py --compare` gates by category, so the committed r06/r07
   pair (whose stage configs legitimately diverged) runs green while a
   genuine quality drop still fails.
@@ -25,12 +28,20 @@ import pytest
 import jax
 
 import bench
+from dlrover_tpu.common.constants import EnvKey
 from dlrover_tpu.models import transformer as tfm
-from dlrover_tpu.serving import InferenceEngine, SamplingParams
+from dlrover_tpu.serving import (
+    InferenceEngine,
+    PrefillEngine,
+    SamplingParams,
+)
 from dlrover_tpu.serving.observatory import (
+    SHADOW_ORDER,
     ShadowPredictor,
     page_share_stats,
 )
+from dlrover_tpu.telemetry import journal as journal_mod
+from dlrover_tpu.telemetry.report import load_events
 
 CFG = tfm.CONFIGS["tiny"]
 REPO = Path(__file__).resolve().parent.parent
@@ -158,6 +169,208 @@ class TestShadowPredictor:
         sp = ShadowPredictor(2, [1])
         assert sp.observe(2) is False   # no evidence -> miss, scored
         assert sp.scored == 1 and sp.accepted == 0
+
+    @pytest.mark.parametrize("order", [1, 2, 3, 5])
+    @pytest.mark.parametrize("widths", [(1,), (64,), (512,),
+                                        (7, 130, 1, 300, 64)],
+                             ids=["1", "64", "512", "uneven"])
+    @pytest.mark.parametrize("start", [0, 96])
+    def test_fed_in_slices_equals_built_whole(self, order, widths, start):
+        """A prompt indexed slice by slice from ``start`` on (a prefill's
+        chunks; ``start`` > 0: past a prefix-cache hit's head), then
+        finished, is the predictor built whole: equal tables and context,
+        equal ``predict()`` / ``draft(k)`` / ``observe()`` over a stream
+        with repeats."""
+        rng = random.Random(1000 * order + sum(widths) + start)
+        motif = [rng.randrange(40) for _ in range(9)]
+        prompt = []
+        while len(prompt) < 700:
+            prompt += (motif if rng.random() < 0.4
+                       else [rng.randrange(40) for _ in range(5)])
+        whole = ShadowPredictor(order, prompt)
+        sliced = ShadowPredictor(order, prompt, whole=False)
+        lo, i, under_slices = start, 0, 0
+        while lo < len(prompt) - 30:    # the tail is left to finish()
+            hi = min(lo + widths[i % len(widths)], len(prompt) - 30)
+            under_slices += sliced.index(lo, hi)
+            lo, i = hi, i + 1
+        assert under_slices == len(prompt) - 30 - start
+        assert sliced.finish() == start + 30
+        assert sliced.finish() == 0     # nothing is counted twice
+        assert sliced.index(0, len(prompt)) == 0
+        assert sliced._tables == whole._tables
+        assert sliced._ctx == whole._ctx == prompt
+        stream = [rng.choice(motif) if rng.random() < 0.6
+                  else rng.randrange(40) for _ in range(120)]
+        for t in stream:
+            assert sliced.predict() == whole.predict()
+            assert sliced.draft(4) == whole.draft(4)
+            assert sliced.draft(3, min_order=1) == whole.draft(
+                3, min_order=1)
+            assert sliced.observe(t) == whole.observe(t)
+        assert (sliced.accepted, sliced.scored) == (
+            whole.accepted, whole.scored)
+        assert whole.accepted > 0       # the stream did repeat
+        assert sliced._tables == whole._tables
+
+    def test_a_slice_that_does_not_extend_is_left_to_finish(self):
+        prompt = [1, 2, 3, 1, 2, 3, 1, 2, 4, 1, 2]
+        sp = ShadowPredictor(3, prompt, whole=False)
+        assert sp.index(4, 7) == 3
+        assert sp.index(0, 4) == 0      # behind the counted interval
+        assert sp.index(9, 11) == 0     # a hole before it
+        assert sp.index(7, 99) == 4     # cut at the prompt's end
+        assert sp.finish() == 4
+        assert sp._tables == ShadowPredictor(3, prompt)._tables
+
+    def test_tables_count_what_the_counter_tables_counted(self):
+        """The tables' values are plain counts (ISSUE 34); they hold
+        what the ``Counter`` tables held, built a token at a time."""
+        from collections import Counter
+
+        rng = random.Random(5)
+        toks = [rng.randrange(12) for _ in range(400)]
+        tables = [{} for _ in range(3)]
+        ctx = []
+        for t in toks:
+            for j in range(1, 4):
+                if len(ctx) >= j:
+                    tables[j - 1].setdefault(
+                        tuple(ctx[-j:]), Counter())[t] += 1
+            ctx.append(t)
+        sp = ShadowPredictor(3, toks[:250])
+        for t in toks[250:]:
+            sp.observe(t)
+        assert sp._tables == tables
+
+
+# ----------------------------- a prompt indexed under its own prefill
+
+
+@pytest.fixture()
+def journal_dir(tmp_path, monkeypatch):
+    monkeypatch.setenv(EnvKey.JOURNAL_DIR, str(tmp_path / "journal"))
+    monkeypatch.setattr(journal_mod, "_cached", None)
+    yield str(tmp_path / "journal")
+    journal_mod._cached = None
+
+
+def _indexed_by_span(journal_dir: str, rid: int) -> dict[str, list[int]]:
+    """``indexed_tokens`` of a request's ``prefill_chunk`` and
+    ``kv_install`` spans, in time order."""
+    events = load_events(journal_dir)
+    mine = {e["span"] for e in events
+            if e.get("ev") == "b" and e.get("request") == rid}
+    out = {"prefill_chunk": [], "kv_install": []}
+    for e in events:
+        if e.get("ev") == "e" and e["span"] in mine and e["name"] in out:
+            out[e["name"]].append(e["indexed_tokens"])
+    return out
+
+
+_SP = SamplingParams(temperature=0.0, max_new_tokens=6, seed=3)
+
+
+def _prompt(n, salt=0):
+    rng = random.Random(100 * n + salt)
+    return [rng.randrange(CFG.vocab_size) for _ in range(n)]
+
+
+def _cold(params):
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64, prefill_len=8)
+    prompt = _prompt(21)
+    # chunks of 8, 8 and 5; the install has nothing left
+    return eng, eng.submit(prompt, _SP), prompt, [8, 8, 5], 0
+
+
+def _short(params):
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64, prefill_len=8)
+    prompt = _prompt(3)
+    return eng, eng.submit(prompt, _SP), prompt, [3], 0
+
+
+def _hit(params):
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64, prefill_len=8,
+                          prefix_cache_entries=4)
+    head = _prompt(16)
+    eng.submit(head + [1, 2, 3], _SP)
+    eng.run()
+    prompt = head + [4, 5, 6, 7, 8]
+    rid = eng.submit(prompt, _SP)
+    # the run resumes at 16: one chunk of 5, the head is the install's
+    return eng, rid, prompt, [5], 16
+
+
+def _handoff(params):
+    pe = PrefillEngine(InferenceEngine(params, CFG, slots=2, max_len=64,
+                                       prefill_len=8))
+    prompt = _prompt(19)
+    pe.submit(prompt)
+    while pe.step():
+        pass
+    [res] = pe.poll_results()
+    # the prefill pool's runs belong to no request: nothing is indexed
+    assert pe.engine._obs._shadow == {}
+    eng = InferenceEngine(params, CFG, slots=2, max_len=64, prefill_len=8)
+    rid = eng.submit_prefilled(prompt, _SP, bundle=res.bundle)
+    return eng, rid, prompt, [], 19
+
+
+def _diffusion(_params):
+    cfg = tfm.CONFIGS["tiny-sdar-moe"]
+    eng = InferenceEngine(tfm.init_params(cfg, jax.random.PRNGKey(1)), cfg,
+                          slots=2, max_len=64, prefill_len=8, decode_block=4)
+    prompt = [t % cfg.vocab_size for t in _prompt(14)]
+    # whole blocks of 4 are prefilled (12: chunks of 8 and 4); the
+    # remainder opens the first generated block
+    return eng, eng.submit(prompt, _SP), prompt, [8, 4], 2
+
+
+def _diffusion_short(_params):
+    cfg = tfm.CONFIGS["tiny-sdar-moe"]
+    eng = InferenceEngine(tfm.init_params(cfg, jax.random.PRNGKey(1)), cfg,
+                          slots=2, max_len=64, prefill_len=8, decode_block=4)
+    prompt = [5, 6, 7]                   # under one block: zero chunks
+    return eng, eng.submit(prompt, _SP), prompt, [], 3
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("case", [_cold, _short, _hit, _handoff,
+                                  _diffusion, _diffusion_short],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_shadow_after_install_is_the_one_built_whole(
+        case, params, journal_dir, monkeypatch):
+    """ISSUE 34: a prompt's tokens enter the request's shadow under the
+    prefill chunks that carry them, the install indexes what no chunk
+    carried, and after it the shadow is ``ShadowPredictor(3, prompt)``;
+    the spans say how many tokens each absorbed."""
+    monkeypatch.setenv("DLROVER_TPU_SERVING_OBSERVATORY", "1")
+    eng, rid, prompt, want_chunks, want_install = case(params)
+    eng._admit()                         # chunks and install, no decode
+    shadow = eng._obs._shadow[rid]
+    whole = ShadowPredictor(SHADOW_ORDER, prompt)
+    assert shadow._indexed is None
+    assert shadow._tables == whole._tables
+    assert shadow._ctx == whole._ctx
+    assert shadow.draft(4) == whole.draft(4)
+    got = _indexed_by_span(journal_dir, rid)
+    assert got == {"prefill_chunk": want_chunks,
+                   "kv_install": [want_install]}
+    assert sum(want_chunks) + want_install == len(prompt)
+    # ... and the request decodes to its end with the shadow scoring
+    [result] = [r for r in eng.run() if r.id == rid]
+    assert eng._obs.scored >= len(result.tokens) > 0
+    assert rid not in eng._obs._shadow   # retired with the request
+
+
+@pytest.mark.timeout(300)
+def test_observatory_off_indexes_nothing(params, journal_dir, monkeypatch):
+    monkeypatch.setenv("DLROVER_TPU_SERVING_OBSERVATORY", "0")
+    eng, rid, prompt, _, _ = _cold(params)
+    eng._admit()
+    assert eng._obs is None
+    assert _indexed_by_span(journal_dir, rid) == {
+        "prefill_chunk": [0, 0, 0], "kv_install": [0]}
 
 
 # --------------------------------------------------- bench --compare
