@@ -147,7 +147,7 @@ from dlrover_tpu.serving.observatory import (
     PrefixDigestStore,
     ServingObservatory,
 )
-from dlrover_tpu.telemetry.journal import get_journal, hot_span
+from dlrover_tpu.telemetry.journal import HotSpan, get_journal, hot_span
 from dlrover_tpu.telemetry.metrics import registry
 
 logger = get_logger(__name__)
@@ -156,14 +156,14 @@ logger = get_logger(__name__)
 # are disambiguated by a per-process engine id label
 _ENGINE_IDS = itertools.count()
 
-_request_seconds = registry().histogram(
-    "dlrover_tpu_serving_request_seconds",
-    "submit -> retire latency per request",
-    label_names=("finish",),
-)
-_tokens_total = registry().counter(
-    "dlrover_tpu_serving_tokens_total",
-    "generated tokens across all requests",
+_queue_wait_seconds = registry().histogram(
+    "dlrover_tpu_engine_queue_wait_seconds",
+    "submit -> the admission pipeline taking the request up (the "
+    "`kv_install` span's `queue_wait_s`): the engine's own queue, which "
+    "the gateway's queue histogram ends before",
+    label_names=("engine",),
+    buckets=(0.005, 0.025, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0,
+             60.0),
 )
 _decode_stall_seconds = registry().histogram(
     "dlrover_tpu_engine_decode_stall_seconds",
@@ -397,6 +397,12 @@ class _PendingAdmit:
     # table indices (into `pages`) attached to SHARED physical pages
     # at admission — already incref'd, never scattered to
     shared: set = dataclasses.field(default_factory=set)
+    # the request's time inside the engine, for its `kv_install` span:
+    # when `_start_admission` took it up (monotonic), how long it had
+    # queued by then, and the host seconds that call cost
+    taken: float = 0.0
+    queue_wait_s: float = 0.0
+    start_s: float = 0.0
 
 
 @dataclasses.dataclass
@@ -1277,8 +1283,12 @@ class InferenceEngine:
 
     def prefill_step(self, run: _PrefillRun) -> bool:
         """Run ONE prefill chunk of ``run``; returns True when the
-        prompt is fully prefilled. Blocks on the chunk so admission
-        stall accounting is honest."""
+        prompt is fully prefilled. Between the chunk's dispatch and the
+        wait for it the host does what it can under the chunk's device
+        time (the prefix row stored, the chunk's tokens indexed by the
+        request's draft-acceptance shadow); then it blocks on the chunk,
+        so admission stall accounting is honest (DESIGN.md §32 has the
+        span's host phases)."""
         if run.done:
             return True
         P = self.prefill_len
@@ -1289,12 +1299,15 @@ class InferenceEngine:
                       request=run.request, tokens=len(chunk),
                       real_tokens=len(chunk), chunk=run.chunks,
                       context=lo) as span:
+            opened = time.monotonic()
             toks = np.zeros((1, P), np.int32)
             toks[0, : len(chunk)] = chunk
+            tokens = jnp.asarray(toks)
+            true_len = jnp.asarray(len(chunk), jnp.int32)
+            built = time.monotonic()
             run.row, run.last, counted = self._prefill_chunk(
-                self.params, jnp.asarray(toks), run.row,
-                jnp.asarray(len(chunk), jnp.int32),
-            )
+                self.params, tokens, run.row, true_len)
+            dispatched = time.monotonic()
             final_top = run.upto // P * P
             if self.prefix_cache_entries and len(chunk) == P:
                 # snapshot the FINAL aligned boundary always;
@@ -1320,10 +1333,19 @@ class InferenceEngine:
                 # only wait (the prefill pool's runs have no request)
                 indexed = self._obs.note_prefilled(
                     run.request, run.prompt, lo, lo + len(chunk))
+            hidden = time.monotonic()
             jax.block_until_ready(run.last)
-            # the chunk is done: its counters cost no wait of their own
+            waited = time.monotonic()
+            # the chunk is done: its counters cost no wait of their own.
+            # The host's seconds while the device holds nothing of this
+            # chunk (`build_s`, `dispatch_s`, `after_s`) and its wait;
+            # what ran under the chunk's device time is in none of them
             span.set(indexed_tokens=indexed,
-                     **_counted(jax.device_get(counted)))
+                     **_counted(jax.device_get(counted)),
+                     build_s=round(built - opened, 6),
+                     dispatch_s=round(dispatched - built, 6),
+                     wait_s=round(waited - hidden, 6),
+                     after_s=round(time.monotonic() - waited, 6))
         run.work_s += time.monotonic() - t0
         return run.done
 
@@ -1588,10 +1610,15 @@ class InferenceEngine:
     def _start_admission(self) -> bool:
         """Pop the queue head into a pending admission (reserving its
         pages) if capacity allows. FIFO on purpose: head-of-line
-        bypass would starve long prompts under page pressure."""
+        bypass would starve long prompts under page pressure. The
+        admission carries what the request's `kv_install` span will say
+        of its time so far: how long it queued, and the host seconds
+        THIS call spent taking it up (a page-blocked head's earlier
+        tries lie in its queue wait)."""
         if not self._queue:
             return False
         req = self._queue[0]
+        taken = time.monotonic()
         if self._digest_store is not None:
             self._digest_store.start(req.id, req.prompt)
         pages: list[int] = []
@@ -1631,9 +1658,13 @@ class InferenceEngine:
             run = self.prefill_begin(req.prompt)
             kind = "hit" if run.start else "cold"
         run.request, run.sctx = req.id, req.sctx
-        self._pending = _PendingAdmit(req=req, run=run, pages=pages,
-                                      kind=kind,
-                                      shared=set(range(shared_n)))
+        queue_wait_s = round(taken - self._submit_time.pop(req.id), 6)
+        _queue_wait_seconds.labels(self.engine_id).observe(queue_wait_s)
+        self._pending = _PendingAdmit(
+            req=req, run=run, pages=pages, kind=kind,
+            shared=set(range(shared_n)), taken=taken,
+            queue_wait_s=queue_wait_s,
+            start_s=round(time.monotonic() - taken, 6))
         return True
 
     def _install_admit(self, slot: int, pa: _PendingAdmit) -> int:
@@ -1743,11 +1774,15 @@ class InferenceEngine:
         if pa.run.done:
             slot = self._take_slot()
             if slot is not None:
-                with hot_span("kv_install", remote_parent=pa.req.sctx,
-                              request=pa.req.id, slot=slot,
-                              tokens=len(pa.req.prompt),
-                              state_bytes=self.state_bytes_per_slot
-                              ) as span:
+                with hot_span(
+                        "kv_install", remote_parent=pa.req.sctx,
+                        request=pa.req.id, slot=slot,
+                        tokens=len(pa.req.prompt),
+                        state_bytes=self.state_bytes_per_slot,
+                        queue_wait_s=pa.queue_wait_s, start_s=pa.start_s,
+                        admit_wall_s=round(time.monotonic() - pa.taken, 6),
+                        chunk_work_s=round(pa.run.work_s, 6),
+                        chunks=pa.run.chunks) as span:
                     span.set(
                         indexed_tokens=self._install_admit(slot, pa))
                 self._pending = None
@@ -1924,19 +1959,20 @@ class InferenceEngine:
         compiled block) for every active slot, retire finished ones.
         Returns number of active slots."""
         with hot_span("engine_step", queued=len(self._queue)) as span:
-            decoding, n_steps, chunks, active = self._step()
-            span.set(decoding_slots=decoding, n_steps=n_steps,
-                     prefill_chunks=chunks)
+            fields, active = self._step()
+            span.set(**fields)
         # slots in this step's decode call: what `slot_occupancy`
         # (requests a replica holds) cannot see
-        self._decoding_gauge.set(decoding)
+        self._decoding_gauge.set(fields["decoding_slots"])
         return active
 
-    def _step(self) -> tuple[int, int, int, int]:
-        """(slots in the decode call, its steps, prefill chunks run
-        before it, slots active after)."""
+    def _step(self) -> tuple[dict, int]:
+        """(the `engine_step` span's late fields: slots in the decode
+        call, its steps, prefill chunks run before it, the host's
+        seconds for the decode call; slots active after)."""
         had_active = any(r is not None for r in self._active)
         chunks_before = self._chunks_run
+        admitted = time.monotonic()
         if not had_active:
             # nobody was decoding: no stall to bound, so fill the
             # batch like the pre-chunking admission did (cold bursts —
@@ -1947,6 +1983,7 @@ class InferenceEngine:
                    and (self._queue or self._parked
                         or self._pending is not None)):
                 pass
+            admitted = time.monotonic()
         elif self._queue or self._parked or self._pending is not None:
             # a live batch: one unit of admission work (a chunk and an
             # install at most, or a resume) per decode step of the block
@@ -1959,16 +1996,19 @@ class InferenceEngine:
             units = 0
             while units < budget and self._admit_tick():
                 units += 1
+            admitted = time.monotonic()
             if units:
                 # the decode stall this admission cost the live batch:
                 # one observation an engine step, of <= `budget` chunks
-                _decode_stall_seconds.observe(time.monotonic() - t0)
-        chunks = self._chunks_run - chunks_before
+                _decode_stall_seconds.observe(admitted - t0)
+        fields = {"decoding_slots": 0, "n_steps": 0,
+                  "prefill_chunks": self._chunks_run - chunks_before,
+                  "decode_host_s": 0.0}
         active_mask = np.array(
             [r is not None for r in self._active], bool
         )
         if not active_mask.any():
-            return 0, 0, chunks, 0
+            return fields, 0
         decoding = int(active_mask.sum())
         temp, top_k, top_p, eos_ids = self._sampling_tensors()
         args = (
@@ -1976,15 +2016,12 @@ class InferenceEngine:
             jnp.asarray(self._seeds), jnp.asarray(self._sampled),
             temp, top_k, top_p, jnp.asarray(active_mask), eos_ids,
         )
-        if self._diffusion:
-            n_steps, toks, counts, steps = self._denoise_call(
-                args, active_mask, decoding)
-            with hot_span("engine_emit", tokens=int(counts.sum())):
-                self._emit(toks, counts, steps)
-            return (decoding, n_steps, chunks,
-                    sum(r is not None for r in self._active))
+        steps = None
         plan = self._spec_plan() if self._spec else None
-        if plan is not None:
+        if self._diffusion:
+            n_steps, toks, counts, steps, wait_s = self._denoise_call(
+                args, active_mask, decoding)
+        elif plan is not None:
             depth, guesses = plan
             n_steps = depth
             fn = self._aot_verify.get(depth, self._verify_block)
@@ -1992,8 +2029,8 @@ class InferenceEngine:
                           n_steps=depth) as span:
                 toks_dev, cache, last, acc_dev, counted = fn(
                     *args, jnp.asarray(guesses))
-                toks_sn, acc, counted = jax.device_get(
-                    (toks_dev, acc_dev, counted))
+                (toks_sn, acc, counted), wait_s = _fetch(
+                    span, (toks_dev, acc_dev, counted))
                 toks_sn, acc = np.asarray(toks_sn), np.asarray(acc)
                 span.set(**self._note_counted(counted))
             toks = toks_sn.T                     # [depth, slots]
@@ -2006,6 +2043,7 @@ class InferenceEngine:
                 self.spec_extra_tokens_total += extra
                 _spec_extra_tokens_total.inc(extra)
             self._spec_score(guesses, toks_sn, depth)
+            self._cache, self._last = cache, last
         else:
             n_steps = block = self._block_size()
             remaining = self._remaining()
@@ -2023,22 +2061,29 @@ class InferenceEngine:
                         *args, n_steps=block,
                     )
                 # the model's counters ride the tokens' device_get
-                toks, counted = jax.device_get((toks_dev, counted))
+                (toks, counted), wait_s = _fetch(span,
+                                                 (toks_dev, counted))
                 toks = np.asarray(toks)
                 span.set(**self._note_counted(counted))
             self._frozen_gauge.set(frozen / (decoding * block))
             self._sampled += counts
-        self._cache, self._last = cache, last
+            self._cache, self._last = cache, last
+        # the host's seconds for this step's decode call: all that ran
+        # between the admission's end and the tokens' hand-out, but the
+        # wait for the device
+        fields.update(
+            decoding_slots=decoding, n_steps=n_steps,
+            decode_host_s=round(time.monotonic() - admitted - wait_s, 6))
         with hot_span("engine_emit", tokens=int(counts.sum())):
-            self._emit(toks, counts)
-        return (decoding, n_steps, chunks,
-                sum(r is not None for r in self._active))
+            self._emit(toks, counts, steps)
+        return fields, sum(r is not None for r in self._active)
 
     def _denoise_call(self, args, active_mask, decoding: int):
         """One block-diffusion decode call: ``(forward passes run, tokens
         [most a row, slots], how many each row generated, the pass that
-        unmasked each)``. ``_emit`` cuts a row at its budget and at its
-        first eos, as ever."""
+        unmasked each, the seconds the host waited for the device)``.
+        ``_emit`` cuts a row at its budget and at its first eos, as
+        ever."""
         c = self.cfg
         B, T = c.block_length, c.denoising_steps
         n_blocks = self._block_size() // B
@@ -2057,8 +2102,8 @@ class InferenceEngine:
             toks_dev, steps_dev, cache, counted = self._denoise_blocks(
                 params, cache, jnp.asarray(tokens0), jnp.asarray(masked0),
                 *rest, n_blocks=n_blocks)
-            blocks, unmasked, counted = jax.device_get(
-                (toks_dev, steps_dev, counted))
+            (blocks, unmasked, counted), wait_s = _fetch(
+                span, (toks_dev, steps_dev, counted))
             # [n_blocks, slots, B] -> a row's generated positions in
             # order: all of a block but the prompt's remainder
             generated = np.ones(blocks.shape, bool)
@@ -2079,7 +2124,7 @@ class InferenceEngine:
             delivered / (decoding * n_steps))
         self._sampled[active_mask] += n_blocks * T
         self._cache = cache
-        return n_steps, toks, counts, steps
+        return n_steps, toks, counts, steps, wait_s
 
     def _note_counted(self, counted: dict) -> dict:
         """A decode call's counters as span fields, and onto their
@@ -2131,12 +2176,6 @@ class InferenceEngine:
             tokens=list(self._emitted[slot]), finish_reason=reason,
             unmask_steps=list(self._unmask[slot]),
         ))
-        submitted = self._submit_time.pop(req.id, None)
-        if submitted is not None:
-            _request_seconds.labels(reason).observe(
-                time.monotonic() - submitted
-            )
-        _tokens_total.inc(len(self._emitted[slot]))
         if self._obs is not None:
             self._obs.note_retire(req.id)
         if self._digest_store is not None:
@@ -2231,6 +2270,16 @@ class InferenceEngine:
             )
         out, self._results = self._results, []
         return out
+
+
+def _fetch(span: HotSpan, outputs) -> tuple:
+    """A decode call's results on the host, and the seconds the host
+    waited for them, which are the `decode_block` span's `wait_s`."""
+    asked = time.monotonic()
+    got = jax.device_get(outputs)
+    wait_s = round(time.monotonic() - asked, 6)
+    span.set(wait_s=wait_s)
+    return got, wait_s
 
 
 def _counted(counted: dict) -> dict:
