@@ -31,7 +31,11 @@ tokens a row reads it as it lies: ``W_kvb`` is absorbed into the
 query (``q~_h = q_nope_h W_kvb,k,h^T``, ``score_h = [q~_h | q_rope_h] .
 [c_kv | k_rope]``) and into the output (``o_h = (sum p c_kv) W_kvb,v,h``):
 the same function. A wider call (a prefill chunk) expands keys and
-values, a group of heads at a time.
+values, a group of heads at a time, over the row only as far as its
+last query reaches (:func:`key_reaches`: the first of a few lengths that
+holds it, chosen on the device from the call's positions; the keys past
+it were masked to exactly 0.0 before). The cache's ``counters`` say how
+far such a call read (``keys_read``).
 
 Parameters: ``embed [V, E]``, ``ln_f [E]``, ``lm_head [E, V]``,
 ``dense_layers`` (the first ``first_k_dense``) and ``layers`` (the
@@ -43,6 +47,7 @@ The routed experts' stacks ``we_*`` hold ``experts_held`` experts from
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Any
 
 import jax
@@ -126,20 +131,22 @@ def init_params(cfg, key: jax.Array) -> Params:
 
 
 def init_cache(cfg, batch: int, max_len: int) -> dict:
-    """The cache tree: the latent stack, the position, and the expert
-    layers' counters (``ops/moe.held_counters``), which every cached
-    call adds to."""
+    """The cache tree: the latent stack, the position, and the counters
+    every cached call adds to: ``keys_read`` (the length of the row a
+    call's expanded attention read, :func:`key_reaches`; an absorbed
+    call adds none) beside the expert layers' (``ops/moe.held_counters``)."""
     c = cfg
     cache = {
         "latent": jnp.zeros(
             (c.n_layers, batch, max_len,
              c.kv_lora_rank + c.qk_rope_head_dim), jnp.dtype(c.dtype)),
         "pos": jnp.zeros((), jnp.int32),
+        "counters": {"keys_read": jnp.zeros((), jnp.int32)},
     }
     n_expert = sum(n for _, experts, n in segments(c) if experts)
     if n_expert:
-        cache["counters"] = moe.held_counters(n_expert,
-                                              routed_config(c).n_held)
+        cache["counters"].update(
+            moe.held_counters(n_expert, routed_config(c).n_held))
     return cache
 
 
@@ -170,9 +177,31 @@ def _masked_softmax(scores, q_pos, dt):
     return jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1).astype(dt)
 
 
+def key_reaches(tokens: int, keys: int) -> list[int]:
+    """The lengths an expanded call of ``tokens`` new tokens a row may
+    read a row of ``keys`` to: its own width doubled up to the row's
+    length, which ends the list. One length where the row is the call
+    (the uncached forward)."""
+    reach = []
+    while tokens < keys:
+        reach.append(tokens)
+        tokens *= 2
+    return reach + [keys]
+
+
+def reach_of(q_pos, tokens: int, keys: int):
+    """``(lengths, which)``: :func:`key_reaches` and the index of the
+    first that holds the last query of ``q_pos [B, S]`` (the keys a
+    query sees end at its own position)."""
+    reach = key_reaches(tokens, keys)
+    return reach, jnp.sum(jnp.asarray(reach) < jnp.max(q_pos) + 1)
+
+
 def attend(q_nope, q_rope, rows, w_kvb, q_pos, cfg, absorbed: bool):
     """``q_nope [B, S, H, nope]``, ``q_rope [B, S, H, rope]`` over the
-    latent ``rows [B, K, rank + rope]`` -> ``[B, S, H, v]``."""
+    latent ``rows [B, K, rank + rope]`` -> ``[B, S, H, v]``. The
+    expanded path reads ``rows[:, :keys]``, ``keys`` from
+    :func:`reach_of`."""
     c = cfg
     dt = q_nope.dtype
     rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
@@ -186,28 +215,36 @@ def attend(q_nope, q_rope, rows, w_kvb, q_pos, cfg, absorbed: bool):
         o_lat = jnp.einsum("bhsk,bkr->bshr", probs, rows[..., :rank])
         return jnp.einsum("bshr,rhv->bshv", o_lat, w_kvb[..., nope:])
 
-    c_kv, k_rope = rows[..., :rank], rows[..., rank:]
     B, S, H, _ = q_nope.shape
     hg = min(H, HEAD_GROUP)
     if H % hg:
         raise ValueError(f"{H} heads do not split into groups of {hg}")
 
-    def group(_, inputs):
-        qn, qr, w = inputs                     # [B,S,hg,*], [rank,hg,*]
-        kv = jnp.einsum("bkr,rhn->bkhn", c_kv, w)
-        scores = (jnp.einsum("bshn,bkhn->bhsk", qn, kv[..., :nope])
-                  + jnp.einsum("bshn,bkn->bhsk", qr, k_rope)
-                  ).astype(jnp.float32) * scale
-        probs = _masked_softmax(scores, q_pos, dt)
-        return None, jnp.einsum("bhsk,bkhv->bshv", probs, kv[..., nope:])
-
     def split(a, axis):                        # heads -> [groups, ..., hg]
         shape = a.shape[:axis] + (H // hg, hg) + a.shape[axis + 1:]
         return jnp.moveaxis(a.reshape(shape), axis, 0)
 
-    _, out = lax.scan(group, None,
-                      (split(q_nope, 2), split(q_rope, 2), split(w_kvb, 1)))
-    return jnp.moveaxis(out, 0, 2).reshape(B, S, H, -1)
+    def expanded(keys: int):
+        c_kv, k_rope = rows[:, :keys, :rank], rows[:, :keys, rank:]
+
+        def group(_, inputs):
+            qn, qr, w = inputs                 # [B,S,hg,*], [rank,hg,*]
+            kv = jnp.einsum("bkr,rhn->bkhn", c_kv, w)
+            scores = (jnp.einsum("bshn,bkhn->bhsk", qn, kv[..., :nope])
+                      + jnp.einsum("bshn,bkn->bhsk", qr, k_rope)
+                      ).astype(jnp.float32) * scale
+            probs = _masked_softmax(scores, q_pos, dt)
+            return None, jnp.einsum("bhsk,bkhv->bshv", probs,
+                                    kv[..., nope:])
+
+        _, out = lax.scan(group, None, (split(q_nope, 2), split(q_rope, 2),
+                                        split(w_kvb, 1)))
+        return jnp.moveaxis(out, 0, 2).reshape(B, S, H, -1)
+
+    reach, which = reach_of(q_pos, S, rows.shape[1])
+    if len(reach) == 1:
+        return expanded(reach[0])
+    return lax.switch(which, [partial(expanded, keys) for keys in reach])
 
 
 def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
@@ -230,6 +267,11 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
                  else jnp.broadcast_to(pos + steps, (B, S)))
     absorbed = cache is not None and S <= ABSORB_UPTO
     rank = c.kv_lora_rank
+    counters = None if cache is None else cache["counters"]
+    if cache is not None and not absorbed:
+        reach, which = reach_of(positions, S, cache["latent"].shape[2])
+        counters = {**counters, "keys_read": counters["keys_read"]
+                    + jnp.asarray(reach, jnp.int32)[which]}
 
     def block(x, stack, w, experts, layer, global_layer):
         """One layer on ``x [B, S, E]``; ``stack`` is the cache's latent
@@ -281,7 +323,6 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
 
     x = params["embed"].astype(dt)[tokens]
     stack = cache["latent"] if cache is not None else None
-    counters = (cache or {}).get("counters")
     first = 0
     for key, is_expert, n in segments(c):
         seg = params[key]
@@ -300,7 +341,7 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
         (x, stack), loads = lax.scan(
             layer, (x, stack), (scanned, jnp.arange(n, dtype=jnp.int32)))
         if is_expert and counters is not None:
-            counters = moe.count_loads(counters, loads)
+            counters = {**counters, **moe.count_loads(counters, loads)}
         first += n
     with jax.named_scope("lm_head"):
         x = _rms(x, params["ln_f"], eps)
@@ -309,10 +350,7 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
         ).astype(jnp.float32)
     if cache is None:
         return out, None
-    new_cache = {"latent": stack, "pos": pos + S}
-    if counters is not None:
-        new_cache["counters"] = counters
-    return out, new_cache
+    return out, {"latent": stack, "pos": pos + S, "counters": counters}
 
 
 def forward_uncached(params: Params, tokens: jax.Array, cfg,

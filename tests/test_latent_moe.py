@@ -14,11 +14,14 @@ import pytest
 from benchmark import serve_child_ref as child
 from benchmark.drivers.serve_gateway_ref import REHEARSAL_CONFIG as FILE
 from benchmark.reference import pangu_ultra_moe as ref
+from dlrover_tpu.common.constants import EnvKey
 from dlrover_tpu.models import decode, latent
 from dlrover_tpu.models import transformer as tfm
 from dlrover_tpu.ops import moe
 from dlrover_tpu.serving import InferenceEngine
 from dlrover_tpu.serving.engine import SamplingParams
+from dlrover_tpu.telemetry import journal as journal_mod
+from dlrover_tpu.telemetry.report import load_events
 
 SEED = 2**31 + 7
 TOL = 2e-5
@@ -178,3 +181,119 @@ def test_every_token_to_one_held_expert_and_none_is_dropped(tokens_n):
         experts["we_down"][1, 1])
     assert loads.tolist() == [0, tokens_n, 0, 0]
     assert float(jnp.abs(got - want).max()) < TOL
+
+
+# ------------------- a wide call reads its row as far as its last query
+
+
+def _first_reach(pos, S, K):
+    """What the rule should pick, written out plainly: the first of
+    S, 2S, 4S, ..., K that holds position ``pos + S - 1``."""
+    n = S
+    while n < min(pos + S, K):
+        n *= 2
+    return min(n, K)
+
+
+@pytest.mark.parametrize("n_tokens,start", [
+    *[(n, 0) for n in (15, 16, 17, 32, 33, 64, 65, 80)],
+    *[(n, 32) for n in (33, 64, 65, 80)]])
+def test_chunks_that_end_around_every_reach_agree_with_the_uncached_forward(
+        model, n_tokens, start, monkeypatch):
+    """Chunks of 16 into a row of 80 (reaches 16, 32, 64, 80) for prompts
+    whose last chunk ends just under, at and just over each of them; the
+    last chunk is pad-tailed as the engine's is. ``start`` 32: the row's
+    first 32 positions were written by an earlier call, as a prefix-cache
+    hit hands them over."""
+    cfg, params = model
+    monkeypatch.setattr(latent, "ABSORB_UPTO", 0)
+    S, K = 16, 80
+    toks = tokens(n_tokens, 40 + n_tokens)
+    want = tfm.forward(params, jnp.asarray(toks)[None], cfg)[0]
+    step = jax.jit(lambda t, c: decode.forward_cached(
+        params, t, decode.zero_counters(c), cfg))
+    cache = decode.init_cache(cfg, 1, K)
+    outs = []
+    if start:
+        lg, cache = step(jnp.asarray(toks[:start])[None], cache)
+        outs.append(lg[0])
+    for lo in range(start, n_tokens, S):
+        chunk = np.zeros(S, np.int64)
+        real = min(S, n_tokens - lo)
+        chunk[:real] = toks[lo: lo + real]
+        lg, cache = step(jnp.asarray(chunk)[None], cache)
+        outs.append(lg[0, :real])
+        assert int(cache["counters"]["keys_read"]) == _first_reach(lo, S, K)
+    assert float(jnp.abs(jnp.concatenate(outs) - want).max()) < TOL
+
+
+@pytest.mark.parametrize("S,K", [(16, 80), (16, 64), (64, 1000), (128, 128),
+                                 (512, 5120), (65, 66)])
+def test_the_chosen_reach_holds_the_last_query_at_every_position(S, K):
+    reach = latent.key_reaches(S, K)
+    assert reach[0] == S and reach[-1] == K and reach == sorted(set(reach))
+    assert all(b == 2 * a for a, b in zip(reach[:-2], reach[1:-1]))
+    for pos in sorted({*range(0, K - S + 1, max(1, S // 4)), K - S}):
+        q_pos = pos + np.arange(S)[None]
+        got, which = latent.reach_of(jnp.asarray(q_pos), S, K)
+        keys = got[int(which)]
+        assert got == reach
+        assert keys >= pos + S and keys == _first_reach(pos, S, K)
+        # doubling: never more than twice what the call can see
+        assert keys < 2 * (pos + S) or keys == S
+    # rows at positions of their own: the farthest decides
+    _, which = latent.reach_of(jnp.asarray([[0, 1], [K - 2, K - 1]]), S, K)
+    assert reach[int(which)] == K
+
+
+def test_the_uncached_forward_holds_no_branch_and_a_chunk_program_one_switch(
+        model):
+    """Where the row IS the call there is one length and no switch; a
+    chunk into a longer row is one program that holds every reach."""
+    cfg, params = model
+    toks = jnp.zeros((1, 80), jnp.int32)
+    text = jax.jit(lambda p, t: tfm.forward(p, t, cfg)).lower(
+        params, toks).as_text()
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    cache = decode.init_cache(cfg, 1, 400)
+    text = jax.jit(lambda p, t, c: decode.forward_cached(p, t, c, cfg)).lower(
+        params, toks, cache).as_text()
+    # one switch a scanned segment (dense layers, expert layers), each
+    # over the reaches 80, 160, 320, 400
+    assert text.count("stablehlo.case") == len(latent.segments(cfg))
+    # an absorbed call (a decode step) keeps none
+    text = jax.jit(lambda p, t, c: decode.forward_cached(p, t, c, cfg)).lower(
+        params, toks[:, :4], cache).as_text()
+    assert "stablehlo.case" not in text
+
+
+def test_every_prefill_chunk_span_says_how_far_its_row_was_read(
+        model, tmp_path, monkeypatch):
+    cfg, params = model
+    monkeypatch.setenv(EnvKey.JOURNAL_DIR, str(tmp_path / "journal"))
+    monkeypatch.setattr(journal_mod, "_cached", None)
+    try:
+        S, K = 128, 640                    # reaches 128, 256, 512, 640
+        eng = InferenceEngine(params, cfg, slots=2, max_len=K, prefill_len=S,
+                              decode_block=4)
+        for n in (100, 300, 600):
+            eng.submit(tokens(n, n).tolist(),
+                       SamplingParams(temperature=0.0, max_new_tokens=4))
+        eng.run()
+    finally:
+        journal_mod._cached = None
+    events = load_events(str(tmp_path / "journal"))
+    begun = {e["span"]: e for e in events if e.get("ev") == "b"}
+    chunks = [{**begun[e["span"]], **e} for e in events
+              if e.get("ev") == "e" and e["name"] == "prefill_chunk"]
+    assert sorted(c["context"] for c in chunks) == [
+        0, 0, 0, 128, 128, 256, 256, 384, 512]
+    for c in chunks:
+        assert c["keys_read"] == _first_reach(c["context"], S, K), c
+    read = sum(c["keys_read"] for c in chunks)
+    seen = sum(c["context"] + c["tokens"] for c in chunks)
+    assert 1.0 <= read / seen <= 2.0
+    # a decode call is absorbed: it reads the rows as they lie
+    blocks = [e for e in events
+              if e.get("ev") == "e" and e["name"] == "decode_block"]
+    assert blocks and all(e["keys_read"] == 0 for e in blocks)
