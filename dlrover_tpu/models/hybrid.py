@@ -43,6 +43,35 @@ nothing else of a row; a wider call (a prefill chunk, the uncached
 forward) computes its attention densely, a few heads at a time, and masks
 it to each query's selection. The model's ``counters`` say how many keys
 each scored and how many the selection chose.
+
+**The second family** (``transformer.SINGLE_MIXERS``: nemotron_h), layers of
+ONE sublayer each, through the same ``runs`` / ``init_cache`` / ``forward``:
+
+  rows    ``k``, ``v`` ``[L_attention, B, max_len, G, D]`` (``models/
+          decode.py``'s layout and its ``_layer_attend``: no rotary
+          embedding reaches it)
+  state   ``ssm`` ``[L_mamba2, B, H, P, N]`` float32 and ``conv``
+          ``[L_mamba2, B, K - 1, C]``: the convolution's WINDOW, the last
+          ``K - 1`` inputs of its ``C = H P + 2 G N`` channels
+
+``mamba2``, per head ``h`` (group ``g = h // (H / G)``), ``dt`` after its
+softplus, ``a_t = exp(dt_t A_h)``:
+  S_t = a_t S_(t-1) + dt_t x_t (outer) B_t,g;   y_t = S_t C_t,g + D_h x_t
+  a call of one token runs that; a wider one the chunked form over chunks of
+  ``ssm_chunk`` (the same function): inside a chunk ``y_l = sum_(s<=l)
+  (C_l . B_s) exp(cum_l - cum_s) dt_s x_s + exp(cum_l) C_l S_in`` with
+  ``cum`` the running sum of ``dt A``, and the chunk hands on
+  ``S_out = exp(cum_end) S_in + sum_s exp(cum_end - cum_s) dt_s x_s (outer) B_s``.
+  A token that is not real gets ``dt = 0`` (it decays nothing and adds
+  nothing), and the window kept is the ``K - 1`` inputs ending with the
+  row's last REAL token: unlike a decayed sum, a window depends on WHICH
+  tokens came last.
+``latent_experts`` keeps no cache; its loads ride the counters
+(``ops/moe.held_counters``) beside ``ssm_row_steps`` (real tokens x mamba2
+layers that entered a state), ``context_tokens`` (as above),
+``expert_steps`` (calls counted: each passes
+every expert layer once) and ``experts_hit_share`` (``experts_hit`` over held
+experts x expert layers x ``expert_steps``).
 """
 
 from __future__ import annotations
@@ -56,10 +85,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlrover_tpu.models import transformer as tfm
-from dlrover_tpu.models.decode import _write_rows
+from dlrover_tpu.models.decode import _layer_attend, _write_rows
+from dlrover_tpu.ops import moe
 
 Params = Any
 KINDS = ("sparse", "lightning")
+# with the second family's (a stack holds one family's kinds: `kinds_of`)
+EVERY_KIND = (*KINDS, *tfm.SINGLE_MIXERS)
 # bytes of float32 scores a wide call's attention holds at once
 SCORE_BYTES = 300e6
 # a wide call reads a row up to one of this many lengths (a compiled
@@ -70,7 +102,7 @@ KEY_REACHES = 4
 def runs(cfg) -> list[tuple[str, int, int]]:
     """The stack as ``(mixer, first layer OF ITS KIND, layers)`` runs of
     equal mixers: each is one scan over its kind's stacked weights."""
-    out, seen = [], {k: 0 for k in KINDS}
+    out, seen = [], {k: 0 for k in EVERY_KIND}
     for kind in cfg.mixer_types:
         if out and out[-1][0] == kind:
             out[-1][2] += 1
@@ -84,19 +116,67 @@ def n_of(cfg, kind: str) -> int:
     return sum(1 for k in cfg.mixer_types if k == kind)
 
 
-def param_shapes(cfg) -> dict:
-    """The parameter tree as shapes (a tuple a leaf): ``sparse_layers``
-    and ``lightning_layers``, each stacked over the layers of its kind in
-    their order in the stack."""
+def kinds_of(cfg) -> tuple:
+    """The kinds the stack holds, in ``EVERY_KIND``'s order."""
+    return tuple(k for k in EVERY_KIND if k in cfg.mixer_types)
+
+
+def _single(cfg) -> bool:
+    return cfg.mixer_types[0] in tfm.SINGLE_MIXERS
+
+
+def _ssm_sizes(cfg) -> tuple:
+    """``(inner width H P, B and C's width 2 G N, convolved channels)``."""
+    inner = cfg.ssm_heads * cfg.ssm_head_dim
+    bc = 2 * cfg.ssm_groups * cfg.ssm_state
+    return inner, bc, inner + bc
+
+
+def _single_layer_shapes(cfg) -> dict:
+    """One layer's leaves of each single-sublayer kind."""
     c = cfg
+    e, h, g, d = c.d_model, c.n_heads, c.n_kv_heads, c.head_dim
+    inner, bc, conv = _ssm_sizes(c)
+    lat, f = c.moe_latent, c.moe_d_ff
+    held = tfm.routed_config(c).n_held
+    return {
+        "mamba2": {
+            "ln1": (e,), "w_ssm_in": (e, 2 * inner + bc + c.ssm_heads),
+            "conv_w": (conv, c.ssm_conv), "conv_b": (conv,),
+            "a_log": (c.ssm_heads,), "d_skip": (c.ssm_heads,),
+            "dt_bias": (c.ssm_heads,), "ln_y": (inner,),
+            "w_ssm_out": (inner, e)},
+        "attention": {"ln1": (e,), "wq": (e, h, d), "wk": (e, g, d),
+                      "wv": (e, g, d), "wo": (h, d, e)},
+        "latent_experts": {
+            "ln2": (e,), "w_router": (e, c.n_routed_experts),
+            "b_router": (c.n_routed_experts,), "w_lat_down": (e, lat),
+            "w_lat_up": (lat, e), "we_up": (held, lat, f),
+            "we_down": (held, f, lat), "ws_up": (e, c.moe_shared_d_ff),
+            "ws_down": (c.moe_shared_d_ff, e)},
+    }
+
+
+def param_shapes(cfg) -> dict:
+    """The parameter tree as shapes (a tuple a leaf): ``<kind>_layers`` for
+    each kind the stack holds (``sparse_layers`` and ``lightning_layers``,
+    or the single-sublayer kinds'), each stacked over the layers of its
+    kind in their order in the stack."""
+    c = cfg
+    tree = {"embed": (c.vocab_size, c.d_model), "ln_f": (c.d_model,),
+            "lm_head": (c.d_model, c.vocab_size)}
+    if _single(c):
+        for kind in kinds_of(c):
+            tree[f"{kind}_layers"] = {
+                name: (n_of(c, kind), *shape)
+                for name, shape in _single_layer_shapes(c)[kind].items()}
+        return tree
     e, h, d, f = c.d_model, c.n_heads, c.head_dim, c.d_ff
     common = {"wq": (e, h, d), "wo": (h, d, e), "w_og": (e, h, d),
               "ln1": (e,), "ln2": (e,), "ln_q": (d,), "ln_k": (d,),
               "w_gate": (e, f), "w_up": (e, f), "w_down": (f, e)}
     kv = {"sparse": c.sparse_kv_heads, "lightning": c.n_kv_heads}
-    tree = {"embed": (c.vocab_size, e), "ln_f": (e,),
-            "lm_head": (e, c.vocab_size)}
-    for kind in KINDS:
+    for kind in kinds_of(c):
         layer = {**common, "wk": (e, kv[kind], d), "wv": (e, kv[kind], d)}
         if kind == "lightning":
             layer["ln_o"] = (h * d,)
@@ -108,6 +188,27 @@ def param_shapes(cfg) -> dict:
 def init_cache(cfg, batch: int, max_len: int) -> dict:
     """The cache tree: rows, ``state``, the position and the counters."""
     c = cfg
+    if _single(c):
+        dt = jnp.dtype(c.dtype)
+        rows = (n_of(c, "attention"), batch, max_len, c.n_kv_heads,
+                c.head_dim)
+        layers = n_of(c, "mamba2")
+        return {
+            "k": jnp.zeros(rows, dt), "v": jnp.zeros(rows, dt),
+            "pos": jnp.zeros((), jnp.int32),
+            "state": {
+                "ssm": jnp.zeros((layers, batch, c.ssm_heads, c.ssm_head_dim,
+                                  c.ssm_state), jnp.float32),
+                "conv": jnp.zeros((layers, batch, c.ssm_conv - 1,
+                                   _ssm_sizes(c)[2]), dt)},
+            "counters": {
+                **moe.held_counters(n_of(c, "latent_experts"),
+                                    tfm.routed_config(c).n_held),
+                "ssm_row_steps": jnp.zeros((), jnp.int32),
+                "context_tokens": jnp.zeros((), jnp.int32),
+                "expert_steps": jnp.zeros((), jnp.int32),
+                "experts_hit_share": jnp.zeros((), jnp.float32)},
+        }
     if max_len % c.sparse_block:
         raise ValueError(
             f"max_len {max_len} is not a multiple of the sparse block "
@@ -170,6 +271,142 @@ def _lightning_attend(q, k, v, state, *, real_b):
                               preferred_element_type=f32))
         s_stack = lax.dynamic_update_index_in_dim(s_stack, s_new, layer, 0)
     return o.astype(dt), (s_stack, layer)
+
+
+# ---------------------------------------------------------------- mamba2
+
+
+def _ssm_step(x, b, c, dt, a_log_rate, s_prev):
+    """The recurrence for one token a row: ``x [B, H, P]``, ``b, c [B, G,
+    N]``, ``dt [B, H]`` (0 where the token is not real), ``a_log_rate =
+    A [H]`` -> ``(y [B, H, P], S [B, H, P, N])``, all float32 and
+    elementwise: the state is read once and written once."""
+    rep = x.shape[1] // b.shape[1]
+    bh, ch = jnp.repeat(b, rep, axis=1), jnp.repeat(c, rep, axis=1)
+    s_new = (jnp.exp(dt * a_log_rate)[..., None, None] * s_prev
+             + (dt[..., None] * x)[..., None] * bh[:, :, None, :])
+    return (s_new * ch[:, :, None, :]).sum(axis=-1), s_new
+
+
+def _ssm_chunks(x, b, c, dt, rate, s_prev, chunk: int, dtype):
+    """The chunked (state-space-dual) form of the same recurrence for a
+    call of ``S`` tokens: ``x [B, S, H, P]``, ``b, c [B, S, G, N]`` float32,
+    ``dt [B, S, H]`` (0 where a token is not real) -> ``(y [B, S, H, P],
+    S_out)``. Products over tokens run in ``dtype`` with float32
+    accumulation; the running sums, the decays and what touches the state
+    are float32."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    B, S, H, P = x.shape
+    G, N = b.shape[2:]
+    R = H // G
+    pad = -S % chunk
+    if pad:      # dt = 0: the padding decays nothing and adds nothing
+        x, b, c, dt = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                       for a in (x, b, c, dt))
+    nc = (S + pad) // chunk
+    x, b, c, dt = (a.reshape(B, nc, chunk, *a.shape[2:])
+                   for a in (x, b, c, dt))
+    cum = jnp.cumsum(dt * rate, axis=2).transpose(0, 1, 3, 2)   # [B,nc,H,L]
+    xdt = x * dt[..., None]                                   # [B,nc,L,H,P]
+    # inside a chunk: (C_l . B_s) exp(cum_l - cum_s) for s <= l
+    at = jnp.arange(chunk)
+    since = jnp.where(at[:, None] >= at[None, :],
+                      cum[..., :, None] - cum[..., None, :], -jnp.inf)
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", c.astype(dtype), b.astype(dtype),
+                    preferred_element_type=f32)
+    mix = (jnp.repeat(cb, R, axis=2) * jnp.exp(since)).astype(dtype)
+    y = jnp.einsum("bchls,bcshp->bclhp", mix, xdt.astype(dtype),
+                   preferred_element_type=f32)
+    # what a chunk adds to the state, and the states entering each chunk
+    to_end = jnp.exp(cum[..., -1:] - cum).transpose(0, 1, 3, 2)  # [B,nc,L,H]
+    added = jnp.einsum(
+        "bcsgrp,bcsgn->bcgrpn",
+        (xdt * to_end[..., None]).astype(dtype).reshape(B, nc, chunk, G, R, P),
+        b.astype(dtype), preferred_element_type=f32
+    ).reshape(B, nc, H, P, N)
+
+    def hand_on(state, inputs):
+        mine, decay = inputs
+        return decay[..., None, None] * state + mine, state
+
+    s_out, s_in = lax.scan(
+        hand_on, s_prev, (jnp.moveaxis(added, 1, 0),
+                          jnp.moveaxis(jnp.exp(cum[..., -1]), 1, 0)))
+    from_state = jnp.einsum(
+        "bclgn,cbgrpn->bclgrp", c, s_in.reshape(nc, B, G, R, P, N),
+        precision=hi).reshape(B, nc, chunk, H, P)
+    y = y + from_state * jnp.exp(cum).transpose(0, 1, 3, 2)[..., None]
+    return y.reshape(B, nc * chunk, H, P)[:, :S], s_out
+
+
+def _mamba2_attend(xbc, dt_raw, w, state, *, cfg, real_b):
+    """The Mamba-2 mixer's ``attend`` (``make_layer_fn``): ``xbc [B, S, C]``
+    and ``dt_raw [B, S, H]`` from the in-projection, the layer's small
+    leaves in ``w`` -> ``y [B, S, H, P]``. The window and the state take in
+    the first ``real_b[b]`` tokens of row ``b`` and no other."""
+    ssm_stack, conv_stack, steps, layer = state
+    c = cfg
+    f32 = jnp.float32
+    dtype = xbc.dtype
+    B, S, _ = xbc.shape
+    H, P, G, N, K = (c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state,
+                     c.ssm_conv)
+    inner = H * P
+    with jax.named_scope("ssm_conv"):
+        window = lax.dynamic_index_in_dim(conv_stack, layer, keepdims=False)
+        full = jnp.concatenate([window, xbc], axis=1)        # [B, K-1+S, C]
+        taps = w["conv_w"].astype(f32)
+        conv = w["conv_b"].astype(f32) + sum(
+            full[:, j:j + S].astype(f32) * taps[:, j] for j in range(K))
+        conv = jax.nn.silu(conv)
+        # the K - 1 inputs that end with the row's last real token
+        if S == 1:
+            window = jnp.where((real_b > 0)[:, None, None], full[:, 1:],
+                               window)
+        else:
+            window = jnp.take_along_axis(
+                full, (real_b[:, None] + jnp.arange(K - 1))[..., None],
+                axis=1)
+        conv_stack = lax.dynamic_update_index_in_dim(
+            conv_stack, window, layer, 0)
+    with jax.named_scope("ssm_scan"):
+        x = conv[..., :inner].reshape(B, S, H, P)
+        b = conv[..., inner:inner + G * N].reshape(B, S, G, N)
+        cm = conv[..., inner + G * N:].reshape(B, S, G, N)
+        if dtype != f32:      # the convolution's output rests in `dtype`
+            x, b, cm = (a.astype(dtype).astype(f32) for a in (x, b, cm))
+        live = jnp.arange(S)[None] < real_b[:, None]
+        dt = jnp.where(live[..., None], jax.nn.softplus(
+            dt_raw.astype(f32) + w["dt_bias"].astype(f32)), 0.0)
+        rate = -jnp.exp(w["a_log"].astype(f32))
+        s_prev = lax.dynamic_index_in_dim(ssm_stack, layer, keepdims=False)
+        if S == 1:
+            y, s_new = _ssm_step(x[:, 0], b[:, 0], cm[:, 0], dt[:, 0], rate,
+                                 s_prev)
+            y = y[:, None]
+        else:
+            y, s_new = _ssm_chunks(x, b, cm, dt, rate, s_prev, c.ssm_chunk,
+                                   dtype)
+        y = y + w["d_skip"].astype(f32)[:, None] * x
+        ssm_stack = lax.dynamic_update_index_in_dim(ssm_stack, s_new, layer, 0)
+    return y.astype(dtype), (ssm_stack, conv_stack,
+                             steps + jnp.sum(real_b), layer)
+
+
+def _heads_attend(q, k, v, state, *, cfg, pos):
+    """The attention kind's ``attend``: the call's rows into ``k``/``v``
+    (``models/decode.py``'s layout), then its grouped-head attention over
+    the layer's rows."""
+    k_stack, v_stack, layer = state
+    dt = q.dtype
+    with jax.named_scope("kv_write"):
+        k_stack = _write_rows(k_stack, k.astype(dt), layer, pos)
+        v_stack = _write_rows(v_stack, v.astype(dt), layer, pos)
+    o = _layer_attend(
+        q, lax.dynamic_index_in_dim(k_stack, layer, keepdims=False),
+        lax.dynamic_index_in_dim(v_stack, layer, keepdims=False),
+        pos, cfg.n_heads // cfg.n_kv_heads, dt)
+    return o, (k_stack, v_stack, layer)
 
 
 # ---------------------------------------------------------------- sparse
@@ -445,23 +682,50 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict,
         jnp.broadcast_to(jnp.asarray(real).astype(jnp.int32), (B,)), 0, S)
     positions = tfm.token_positions(pos, B, S)
     x = tfm.embed_tokens(params, tokens, c, pos=pos)
-    held = {"sparse": (cache["k"], cache["v"], cache["kc"],
-                       jnp.zeros((3,), jnp.int32)),
-            "lightning": (cache["state"]["s"],)}
+    state = cache["state"]
+    # what each kind's layers carry through its runs, and the `attend`
+    # that owns it (none for a kind that keeps no cache)
+    held = {
+        "sparse": lambda: (cache["k"], cache["v"], cache["kc"],
+                           jnp.zeros((3,), jnp.int32)),
+        "lightning": lambda: (state["s"],),
+        "mamba2": lambda: (state["ssm"], state["conv"],
+                           jnp.zeros((), jnp.int32)),
+        "attention": lambda: (cache["k"], cache["v"]),
+        "latent_experts": lambda: (
+            jnp.zeros_like(cache["counters"]["loads"]),),
+    }
+    held = {kind: held[kind]() for kind in kinds_of(c)}
     attends = {
         "sparse": partial(_sparse_attend, cfg=c, pos=pos, pos_b=pos_b,
                           real_b=real_b),
-        "lightning": partial(_lightning_attend, real_b=real_b)}
+        "lightning": partial(_lightning_attend, real_b=real_b),
+        "mamba2": partial(_mamba2_attend, cfg=c, real_b=real_b),
+        "attention": partial(_heads_attend, cfg=c, pos=pos)}
     for kind, first, n in runs(c):
         stack = params[f"{kind}_layers"]
-        run_layer = tfm.make_layer_fn(c, attend=attends[kind],
-                                      positions=positions, mixer=kind)
+        experts = None
+        if kind == "latent_experts":
+            # closed over the block and indexed in place by its tile loop
+            # (`make_layer_fn`); everything else is sliced a layer
+            dt = jnp.dtype(c.dtype)
+            experts = {k: tfm._leaf(stack, k, dt)
+                       for k in tfm.EXPERT_STACKS if k in stack}
+            stack = {k: v for k, v in stack.items() if k not in experts}
+        run_layer = tfm.make_layer_fn(
+            c, attend=attends.get(kind), positions=positions, mixer=kind,
+            experts=experts,
+            mask=jnp.arange(S)[None] < real_b[:, None] if experts else None)
 
-        def layer(carry, i, stack=stack, run_layer=run_layer):
+        def layer(carry, i, stack=stack, run_layer=run_layer, kind=kind):
             x, mine = carry
             w = jax.tree.map(
                 lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
                 stack)
+            if kind == "latent_experts":
+                x, loads, _ = run_layer(x, w, None, i)
+                return (x, (lax.dynamic_update_index_in_dim(
+                    mine[0], loads, i, 0),)), None
             x, _, (*mine, _) = run_layer(x, w, (*mine, i), i)
             return (x, tuple(mine)), None
 
@@ -472,8 +736,24 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict,
     with jax.named_scope("lm_head"):
         x = tfm.final_norm(params, x, c)
         out = x if return_hidden else tfm.lm_logits(params, x, c)
-    k, v, kc, tally = held["sparse"]
     old = cache["counters"]
+    if _single(c):
+        k, v = held["attention"]
+        ssm, conv, row_steps = held["mamba2"]
+        counters = moe.count_loads(old, held["latent_experts"][0])
+        counters["ssm_row_steps"] = old["ssm_row_steps"] + row_steps
+        steps = jnp.arange(S)[None]
+        counters["context_tokens"] = old["context_tokens"] + jnp.sum(
+            jnp.where(steps < real_b[:, None], pos_b[:, None] + steps, 0))
+        # calls counted: each passes every expert layer once
+        counters["expert_steps"] = old["expert_steps"] + 1
+        counters["experts_hit_share"] = (
+            counters["experts_hit"].astype(jnp.float32)
+            / (counters["expert_steps"] * max(1, counters["loads"].size)))
+        return out, {"k": k, "v": v, "pos": pos + S,
+                     "state": {"ssm": ssm, "conv": conv},
+                     "counters": counters}
+    k, v, kc, tally = held["sparse"]
     steps = jnp.arange(S)[None]
     counters = {
         "sparse_keys_selected": old["sparse_keys_selected"] + tally[0],
@@ -496,7 +776,7 @@ def forward_uncached(params: Params, tokens: jax.Array, cfg,
     from an empty cache just long enough (the same function, and the one
     definition of it); no balancing loss, so the aux term is zero."""
     B, S = tokens.shape
-    blk = cfg.sparse_block
+    blk = 1 if _single(cfg) else cfg.sparse_block
     out, _ = forward(params, tokens, cfg,
                      init_cache(cfg, B, -(-S // blk) * blk),
                      return_hidden=return_hidden)

@@ -202,6 +202,28 @@ class TransformerConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_scale: float = 1.0
+    # --- attn_kind "mixers", the second family (nemotron_h; served only):
+    # a stack whose layers are ONE pre-normed residual branch each,
+    # `x + f(norm(x))`. `mixer_types` then names each layer "mamba2" (a
+    # Mamba-2 state-space mixer: `ssm_heads` heads of `ssm_head_dim`, a
+    # float32 state [ssm_head_dim, ssm_state] a head, B and C in
+    # `ssm_groups` groups, a causal depthwise convolution over the last
+    # `ssm_conv` inputs, the chunked scan over chunks of `ssm_chunk`),
+    # "attention" (`n_heads` on `n_kv_heads`, NO rotary embedding, no q/k
+    # norm, no gate) or "latent_experts" (`moe_top_k` of `n_routed_experts`
+    # sigmoid-routed squared-ReLU experts of `moe_d_ff` at a LATENT width
+    # `moe_latent` behind a shared down-projection and in front of a shared
+    # up-projection, chosen by score + bias, beside one squared-ReLU shared
+    # expert of `moe_shared_d_ff` at the full width; `experts_held` /
+    # `expert_first` as above). The two families do not mix in one stack
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    moe_latent: int = 0
+    moe_shared_d_ff: int = 0
 
     def __post_init__(self):
         if not self.head_dim:
@@ -218,7 +240,29 @@ class TransformerConfig:
                     f"{self.denoising_steps}, {self.mask_token_id}")
         elif self.generation != "autoregressive":
             raise ValueError(f"unknown generation {self.generation!r}")
-        if self.attn_kind == "mixers":
+        if self.attn_kind == "mixers" and set(self.mixer_types) & set(
+                SINGLE_MIXERS):
+            c = self
+            if (len(c.mixer_types) != c.n_layers
+                    or not set(c.mixer_types) <= set(SINGLE_MIXERS)
+                    or ("mamba2" in c.mixer_types and (
+                        min(c.ssm_heads, c.ssm_head_dim, c.ssm_state,
+                            c.ssm_groups, c.ssm_chunk) < 1 or c.ssm_conv < 2
+                        or c.ssm_heads % c.ssm_groups))
+                    or ("attention" in c.mixer_types
+                        and c.n_heads % c.n_kv_heads)
+                    or ("latent_experts" in c.mixer_types and min(
+                        c.moe_latent, c.moe_d_ff, c.moe_shared_d_ff,
+                        c.n_routed_experts, c.moe_top_k) < 1)):
+                raise ValueError(
+                    "attn_kind 'mixers' with single-sublayer layers needs "
+                    "mixer_types of n_layers entries among "
+                    f"{SINGLE_MIXERS} (none of 'sparse' / 'lightning' beside "
+                    "them), ssm_groups dividing ssm_heads and every ssm_* "
+                    "size set, n_kv_heads dividing n_heads, and moe_latent, "
+                    "moe_d_ff, moe_shared_d_ff, n_routed_experts, moe_top_k "
+                    f"set: got {c}")
+        elif self.attn_kind == "mixers":
             c = self
             forced = c.sparse_init_blocks + c.sparse_window // max(
                 1, c.sparse_block)
@@ -350,6 +394,16 @@ class TransformerConfig:
         scores = 2 * 2 * c.n_heads * c.head_dim * keys
         return c.n_layers * (2.0 * matmul + scores) + (
             2.0 * c.d_model * c.vocab_size)
+
+
+# the mixers of a stack whose layers are one sublayer each (nemotron_h)
+SINGLE_MIXERS = ("mamba2", "attention", "latent_experts")
+
+
+def single_mixers(pattern: str) -> tuple:
+    """``mixer_types`` of a published ``hybrid_override_pattern``."""
+    names = dict(zip("M*E", SINGLE_MIXERS))
+    return tuple(names[letter] for letter in pattern)
 
 
 # Per-layer remat policies for remat_scan (distinct from the step-level
@@ -496,6 +550,36 @@ CONFIGS = {
         sparse_window=2048, sparse_dense_len=8192, embed_scale=12.0,
         residual_scale=1.4 / math.sqrt(32), logit_scale=256 / 4096,
         param_dtype="bfloat16"),
+    # the kinds of the entry below at a size for CPU tests: every kind
+    # present, a scan chunk of 8 so that a call of a few dozen tokens
+    # crosses chunks; all 8 experts held (a test holds a share)
+    "tiny-nemotron-h": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=7, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=24, max_seq_len=256, norm_eps=1e-5,
+        attn_kind="mixers", mixer_types=single_mixers("MEM*EME"),
+        ssm_heads=4, ssm_head_dim=16, ssm_state=8, ssm_groups=2, ssm_conv=4,
+        ssm_chunk=8, n_routed_experts=8, moe_top_k=3, moe_d_ff=24,
+        moe_latent=16, moe_shared_d_ff=48, n_shared_experts=1,
+        routed_scaling_factor=2.5, norm_topk_prob=True, dtype="float32"),
+    # NVIDIA-Nemotron-3-Super-120B-A12B as published (config.json,
+    # model_type nemotron_h): every layer ONE sublayer, by
+    # hybrid_override_pattern (M Mamba-2, E LatentMoE, * attention with no
+    # rotary embedding: rope_theta is inert). A deployment sets the share
+    # it holds with dataclasses.replace (n_layers, mixer_types,
+    # experts_held, expert_first, vocab_size). Its multi-token-prediction
+    # module is not modelled.
+    "nemotron-3-super-120b-a12b": TransformerConfig(
+        vocab_size=131072, d_model=4096, n_layers=88, n_heads=32,
+        n_kv_heads=2, head_dim=128, d_ff=2688, max_seq_len=262144,
+        rope_theta=10000.0, norm_eps=1e-5, attn_kind="mixers",
+        mixer_types=single_mixers(
+            "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+            "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"),
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+        ssm_conv=4, ssm_chunk=128, n_routed_experts=512, moe_top_k=22,
+        moe_d_ff=2688, moe_latent=1024, moe_shared_d_ff=5376,
+        n_shared_experts=1, routed_scaling_factor=5.0, norm_topk_prob=True,
+        param_dtype="bfloat16"),
 }
 
 
@@ -506,13 +590,17 @@ EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 
 
 def routed_config(cfg: TransformerConfig):
-    """``ops/moe.RoutedConfig`` of the 'softmax_experts' layer."""
+    """``ops/moe.RoutedConfig`` of the 'softmax_experts' layer (SwiGLU
+    experts, no scaling) or of a 'latent_experts' one (squared-ReLU
+    experts at ``moe_latent``, scaled)."""
     from dlrover_tpu.ops.moe import RoutedConfig
 
     return RoutedConfig(
         n_experts=cfg.n_routed_experts, top_k=cfg.moe_top_k,
         norm_topk=cfg.norm_topk_prob, first=cfg.expert_first,
-        held=cfg.experts_held)
+        held=cfg.experts_held,
+        **({"scaling": cfg.routed_scaling_factor, "form": "relu2"}
+           if cfg.moe_latent else {}))
 
 
 def _check_kinds(cfg: TransformerConfig) -> None:
@@ -578,6 +666,25 @@ def param_shapes(cfg: TransformerConfig) -> dict:
     return tree
 
 
+def _log_uniform(key, shape, low: float, high: float) -> jax.Array:
+    return jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                      math.log(low), math.log(high)))
+
+
+# a state-space mixer's and a biased router's small leaves, which are no
+# matrices: the decay rates' logarithms `log(uniform[1, 16])`, a time-step
+# bias whose softplus is log-uniform in [1e-3, 1e-1], a skip of one, and
+# biases drawn small and non-zero (so that leaving one out moves a logit)
+_SMALL_LEAVES = {
+    "a_log": lambda k, s: jnp.log(jax.random.uniform(
+        k, s, jnp.float32, 1.0, 16.0)),
+    "dt_bias": lambda k, s: jnp.log(jnp.expm1(_log_uniform(k, s, 1e-3, 1e-1))),
+    "d_skip": lambda k, s: jnp.ones(s, jnp.float32),
+    "conv_b": lambda k, s: 0.1 * jax.random.normal(k, s, jnp.float32),
+    "b_router": lambda k, s: 0.1 * jax.random.normal(k, s, jnp.float32),
+}
+
+
 def init_from_shapes(shapes: dict, key: jax.Array, dtype) -> Params:
     """Seeded weights for a tree of shapes (a tuple a leaf; a nested
     dict's leaves are stacked along a leading layer dim), in ``dtype``:
@@ -594,8 +701,12 @@ def init_from_shapes(shapes: dict, key: jax.Array, dtype) -> Params:
         if name.startswith("ln"):
             leaves.append(jnp.ones(shape, dt))
             continue
+        if name in _SMALL_LEAVES:
+            leaves.append(_SMALL_LEAVES[name](
+                jax.random.fold_in(key, i), shape).astype(dt))
+            continue
         core = shape[1:] if len(path) > 1 else shape
-        if name in EXPERT_STACKS:
+        if name in EXPERT_STACKS or name == "conv_w":
             fan_in = core[1]
         elif name in ("wo", "w_o"):
             fan_in = core[0] * core[1]
@@ -845,7 +956,8 @@ AttentionFn = Callable[..., jax.Array]
 # ``cfg.dtype`` for a holder that runs the block many times.
 PRODUCT_LEAVES = frozenset({
     "embed", "pos_embed", "wq", "wk", "wv", "wo", "w_og", "w_gate", "w_up",
-    "w_down", "b_ff", "b_out", "lm_head", *EXPERT_STACKS})
+    "w_down", "b_ff", "b_out", "lm_head", *EXPERT_STACKS, "w_ssm_in",
+    "w_ssm_out", "w_lat_down", "w_lat_up", "ws_up", "ws_down"})
 
 
 def _leaf(tree, name: str, dt) -> jax.Array:
@@ -919,24 +1031,47 @@ def make_layer_fn(
     rotary embedding; "lightning" norms the concatenated heads' output
     (``ln_o``) before the gate. ``cfg.residual_scale`` multiplies both
     residual branches of every kind (1.0: nothing).
+
+    ``SINGLE_MIXERS`` (served only, the same path): the block is ONE half
+    alone, ``x + f(norm(x))``. "attention" is the attention half as it
+    stands with no rotary embedding, q/k norm or gate. "mamba2" is the
+    mixer half around ``attend(xBC, dt, w, state) -> (y, state)``: the
+    block owns the in-projection ``[z | xBC | dt]``, the gated norm
+    ``RMSNorm_groups(y * silu(z))`` and the out-projection; the hook owns
+    the convolution's window and the state (it reads the layer's small
+    leaves from ``w``). "latent_experts" is the feed-forward half:
+    sigmoid scores, the choice by score + bias, squared-ReLU experts of
+    ``experts`` (``we_up``, ``we_down``, closed over as above) at the
+    latent width between ``w_lat_down`` and ``w_lat_up``, beside a
+    squared-ReLU shared expert at the full width; ``aux`` is ``loads``;
+    ``mask [B, S]`` (None: all) says which tokens are real, and the rest
+    reach no routed expert.
     """
     c = cfg
     _check_kinds(c)
     dt = jnp.dtype(c.dtype)
     eps = _norm_eps(c)
     pin = constrain or (lambda x, a: x)
-    if c.mixers and (mixer not in ("sparse", "lightning") or attend is None
-                     or mask is not None or constrain is not None
-                     or c.int8_matmuls):
+    if c.mixers and (mixer not in ("sparse", "lightning", *SINGLE_MIXERS)
+                     or (attend is None) != (mixer == "latent_experts")
+                     or (experts is None) == (mixer == "latent_experts")
+                     or (mask is not None and mixer != "latent_experts")
+                     or constrain is not None or c.int8_matmuls):
         raise NotImplementedError(
             "attn_kind 'mixers' is the forward pass on one device through "
             "models/hybrid.py, which names each run's mixer and owns its "
-            "cache; there is neither a token mask, a sharding rule, an "
-            "int8 path nor a gradient for it (training, parallel/"
+            "cache (or, for 'latent_experts', closes the experts' stacks "
+            "over the block); there is neither a token mask, a sharding "
+            "rule, an int8 path nor a gradient for it (training, parallel/"
             "pipeline.py, parallel/mpmd.py)")
-    qk_norm = c.attn_kind == "heads_qk_norm" or c.mixers
-    rotary = c.variant == "llama" and mixer != "sparse"
+    qk_norm = (c.attn_kind == "heads_qk_norm"
+               or mixer in ("sparse", "lightning"))
+    rotary = c.variant == "llama" and mixer not in ("sparse", "attention")
     res = c.residual_scale
+    if mixer == "latent_experts":
+        from dlrover_tpu.ops import moe as _moe
+
+        rcfg = routed_config(c)
     if c.held_experts:
         if experts is None or mask is not None or constrain is not None:
             raise NotImplementedError(
@@ -1007,6 +1142,51 @@ def make_layer_fn(
         return y.reshape(*x.shape[:x.ndim - n_contract],
                          *wt.shape[n_contract:])
 
+    def ssm_half(x, w, state):
+        """The Mamba-2 mixer half alone: ``(x + f(norm(x)), state)``."""
+        di = c.ssm_heads * c.ssm_head_dim
+        bc = 2 * c.ssm_groups * c.ssm_state
+        with jax.named_scope("ssm"):
+            h = _norm(x, w["ln1"], None, "llama", eps)
+            with jax.named_scope("ssm_in_proj"):
+                zxd = proj(h, _leaf(w, "w_ssm_in", dt), "bse,ef->bsf")
+            z, xbc, dt_raw = (zxd[..., :di], zxd[..., di:2 * di + bc],
+                              zxd[..., 2 * di + bc:])
+            y, state = attend(xbc, dt_raw, w, state)     # [B, S, H, P]
+            with jax.named_scope("ssm_gate_norm"):
+                # the norm AFTER the gate, over each group's channels
+                y = (y.reshape(z.shape).astype(jnp.float32)
+                     * jax.nn.silu(z.astype(jnp.float32)))
+                grouped = (*z.shape[:2], c.ssm_groups, di // c.ssm_groups)
+                y = _norm(y.reshape(grouped), w["ln_y"].reshape(grouped[2:]),
+                          None, "llama", eps).reshape(z.shape).astype(dt)
+            with jax.named_scope("ssm_out_proj"):
+                o = proj(y, _leaf(w, "w_ssm_out", dt), "bsf,fe->bse")
+        return x + o, state
+
+    def latent_expert_half(x, w, index):
+        with jax.named_scope("mlp"):
+            h = _norm(x, w["ln2"], None, "llama", eps)
+            ht = h.reshape(-1, h.shape[-1])
+            with jax.named_scope("moe_router"):
+                idx, gate = _moe.sigmoid_topk_route(
+                    ht, w["w_router"], rcfg, bias=w["b_router"])
+                if mask is not None:   # a token that is not real reaches
+                    # no expert: nothing is read or counted for it
+                    idx = jnp.where(mask.reshape(-1, 1), idx, -1)
+            with jax.named_scope("moe_latent"):
+                lat = proj(ht, _leaf(w, "w_lat_down", dt), "te,ef->tf")
+            with jax.named_scope("moe_experts"):
+                routed, loads = _moe.held_expert_ffn(
+                    lat, idx, gate, experts, index, rcfg)
+            with jax.named_scope("moe_latent"):
+                ff = proj(routed.astype(dt), _leaf(w, "w_lat_up", dt),
+                          "tf,fe->te")
+            with jax.named_scope("moe_shared"):
+                ff = ff + _moe.relu2(ht, _leaf(w, "ws_up", dt),
+                                     _leaf(w, "ws_down", dt))
+        return x + ff.reshape(x.shape), loads
+
     def layer(x, w, state=None, index=None):
         """One block: activations [B', S, E] -> ([B', S, E], aux_inc,
         state).
@@ -1015,6 +1195,11 @@ def make_layer_fn(
         positions not handed in derive from the input shape so both work.
         """
         aux = jnp.zeros((), jnp.float32)
+        if mixer == "mamba2":
+            x, state = ssm_half(x, w, state)
+            return x, aux, state
+        if mixer == "latent_experts":
+            return latent_expert_half(x, w, index) + (state,)
         at = (positions if positions is not None
               else token_positions(None, *x.shape[:2]))
         with jax.named_scope("attn"):
@@ -1032,7 +1217,7 @@ def make_layer_fn(
                 q = _rope(q, at, c.rope_theta, c.rope_pairing)
                 k = _rope(k, at, c.rope_theta, c.rope_pairing)
             o, state = attend(q, k, v, state)
-            if mixer:
+            if mixer in ("sparse", "lightning"):
                 with jax.named_scope("out_gate"):
                     if mixer == "lightning":
                         o = _norm(o.reshape(*o.shape[:2], -1), w["ln_o"],
@@ -1045,6 +1230,8 @@ def make_layer_fn(
             if res != 1.0:
                 o = o * res
             x = pin(x + o, ("batch", "sequence", "embed"))
+        if mixer == "attention":
+            return x, aux, state
 
         with jax.named_scope("mlp"):
             h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant, eps)

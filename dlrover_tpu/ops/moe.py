@@ -195,6 +195,9 @@ class RoutedConfig:
     norm_topk: bool = True    # gates normalised over the chosen
     first: int = 0            # index of the first expert held here
     held: int = 0             # how many are held (0: all)
+    # an expert's form: "swiglu" (three stacks we_gate, we_up, we_down) or
+    # "relu2" (two, we_up and we_down: Down(relu(Up h)^2))
+    form: str = "swiglu"
 
     @property
     def n_held(self) -> int:
@@ -208,16 +211,31 @@ def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
                       gate * jnp.einsum("...m,mf->...f", h, w_up), w_down)
 
 
+def relu2(h: jax.Array, w_up, w_down) -> jax.Array:
+    """``Down(relu(Up h)^2)`` on ``h [..., M]``: the feed-forward kind with
+    no gate."""
+    return jnp.einsum("...f,fm->...m", jnp.square(jax.nn.relu(
+        jnp.einsum("...m,mf->...f", h, w_up))), w_down)
+
+
 def sigmoid_topk_route(h: jax.Array, w_router: jax.Array,
-                       cfg: RoutedConfig) -> tuple[jax.Array, jax.Array]:
+                       cfg: RoutedConfig, bias: jax.Array | None = None
+                       ) -> tuple[jax.Array, jax.Array]:
     """``h [T, M]`` -> (expert ids ``[T, k]`` int32, gates ``[T, k]``
     float32) over ALL ``n_experts``. Scores are float32 products at
     ``HIGHEST``: the k-th and (k+1)-th score of a token can lie a
-    rounding apart, and a flipped choice is a different function."""
+    rounding apart, and a flipped choice is a different function.
+    ``bias [n_experts]`` (a score-correction bias; None: the function
+    without one) enters the CHOICE alone: the k largest of ``score +
+    bias``, their gates from the scores."""
     scores = jax.nn.sigmoid(jnp.einsum(
         "tm,me->te", h.astype(jnp.float32), w_router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    top, idx = jax.lax.top_k(scores, cfg.top_k)
+    if bias is None:
+        top, idx = jax.lax.top_k(scores, cfg.top_k)
+    else:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), cfg.top_k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
     if cfg.norm_topk:
         top = top / (top.sum(-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), top * cfg.scaling
@@ -281,9 +299,11 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, gate: jax.Array,
                     ) -> tuple[jax.Array, jax.Array]:
     """The held experts' part of the routed sum.
 
-    ``x [T, M]``; ``idx``, ``gate`` ``[T, k]`` from the router;
+    ``x [T, M]`` (``M`` is the stacks' input width: the model's, or a
+    latent's); ``idx``, ``gate`` ``[T, k]`` from the router;
     ``experts`` holds ``we_gate``, ``we_up`` ``[L, held, M, F]`` and
-    ``we_down`` ``[L, held, F, M]`` for ALL layers and ``layer`` picks
+    ``we_down`` ``[L, held, F, M]`` (``cfg.form`` "relu2": ``we_up`` and
+    ``we_down`` alone) for ALL layers and ``layer`` picks
     one (a traced index: the tile loop reads ``experts[layer, e]`` in
     place, where a per-layer slice handed to the loop would be copied
     whole). Returns ``(y [T, M] float32, loads [held] int32)``: the
@@ -294,7 +314,8 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, gate: jax.Array,
     never touched); expert ``e``'s rows are walked in tiles of
     ``_tile_rows(T)``: the tile's tokens are picked by a one-hot product
     (no gather or scatter: the TPU runs those a row at a time), pass the
-    expert's SwiGLU, and are added back through the transposed one-hot.
+    expert (its SwiGLU, or its squared ReLU), and are added back through
+    the transposed one-hot.
     """
     T, M = x.shape
     k, held = idx.shape[1], cfg.n_held
@@ -329,7 +350,10 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, gate: jax.Array,
             return jax.lax.dynamic_slice(
                 stack, (layer, e, 0, 0), (1, 1) + stack.shape[2:])[0, 0]
 
-        out = swiglu(xt, w("we_gate"), w("we_up"), w("we_down"))
+        if cfg.form == "relu2":
+            out = relu2(xt, w("we_up"), w("we_down"))
+        else:
+            out = swiglu(xt, w("we_gate"), w("we_up"), w("we_down"))
         out = (out.astype(jnp.float32) * g[:, None]).astype(x.dtype)
         return y + jnp.einsum("rt,rm->tm", pick, out,
                               preferred_element_type=jnp.float32)

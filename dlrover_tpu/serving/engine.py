@@ -200,8 +200,16 @@ _COUNTER_GAUGES = {
     "state_bytes_per_slot": registry().gauge(
         "dlrover_tpu_engine_state_bytes_per_slot",
         "bytes of cache a slot holds that no token position addresses "
-        "(a linear-attention layer's state), whatever its context; set "
-        "once, by an engine whose model keeps such state",
+        "(a linear-attention layer's state, a state-space layer's state "
+        "and convolution window), whatever its context; set once, by an "
+        "engine whose model keeps such state",
+        label_names=("engine",)),
+    "experts_hit_share": registry().gauge(
+        "dlrover_tpu_engine_experts_hit_share",
+        "held experts that took at least one assignment in the engine's "
+        "newest decode call over the held experts of its expert layers "
+        "and steps (each one hit is one expert's weights read; 1.0: "
+        "every step read them all)",
         label_names=("engine",)),
 }
 _decoding_slots = registry().gauge(

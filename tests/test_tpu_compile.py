@@ -272,3 +272,54 @@ def test_hybrid_cache_serving_programs_compile(one_chip):
         row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     ).compile()
     assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+def test_state_space_serving_programs_compile(one_chip):
+    """Decode step and prefill chunk of ``InferenceEngine`` for a stack of
+    single-sublayer layers (``transformer.SINGLE_MIXERS``, models/hybrid.py)
+    at Nemotron-3-Super's published widths: one Mamba-2, one latent-expert
+    (128 of 512 held) and one attention layer, 32 slots of 3072 positions,
+    weights resting in bfloat16. What the CPU cannot show: the float32 state
+    of 32 rows (134 MB a layer) and the window donated and updated in place,
+    the tile loop reading an expert's two latent-width matrices straight
+    from the stacks, nothing the size of a layer's experts (1.4 GB) among
+    the temporaries."""
+    from dlrover_tpu.models import hybrid
+    from dlrover_tpu.serving import engine as serving
+
+    cfg = dataclasses.replace(
+        tfm.CONFIGS["nemotron-3-super-120b-a12b"], n_layers=3,
+        mixer_types=tfm.single_mixers("ME*"), experts_held=128,
+        vocab_size=32768, dtype="bfloat16")
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip),
+        hybrid.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    eng = serving.InferenceEngine(params, cfg, slots=32, max_len=3072,
+                                  prefill_len=512)
+    assert eng.cache_bytes_per_token == 1024
+    assert eng.state_bytes_per_slot == 128 * 64 * 128 * 4 + 3 * 10240 * 2
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: a if isinstance(a, jax.ShapeDtypeStruct)
+            else jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                      sharding=one_chip), tree)
+
+    step = eng._step_block.lower(
+        *on_chip(eng._block_sample_args()), n_steps=1
+    ).compile(compiler_options=serving._CANONICAL_NUMERICS)
+    state = eng._cache["state"]
+    assert state["ssm"].shape == (1, 32, 128, 64, 128)
+    held = (state["ssm"].size * 4 + state["conv"].size * 2
+            + 2 * eng._cache["k"].size * 2)
+    m = step.memory_analysis()
+    assert m.alias_size_in_bytes >= held                # donated whole
+    # beside the weights and the cache: no copy of the experts' stacks
+    assert m.temp_size_in_bytes < 0.7e9
+    assert _device_bytes(step) < HBM_BYTES
+    row = on_chip(jax.eval_shape(lambda: hybrid.init_cache(cfg, 1, 3072)))
+    chunk = eng._prefill_chunk.lower(
+        params, jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip),
+        row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile()
+    assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
