@@ -30,8 +30,10 @@ Which routing each family uses:
   stands in for the exchange. Assignments are sorted by expert and run
   as a grouped product over row tiles: the work follows the
   assignments that landed here, not ``T x held``, and an expert that
-  got no token reads no weight. The loop's trip count is data: the
-  layer is for the forward pass (scoring, serving), not for ``grad``.
+  got no token reads no weight. On a TPU the grouped product is one
+  Pallas kernel (``ops/grouped_ffn.py``), elsewhere a ``while`` whose
+  trip count is data; either way the layer is for the forward pass
+  (scoring, serving), not for ``grad``.
 - ``softmax_topk_route`` + ``held_expert_ffn`` (the Qwen3-MoE / SDAR
   family, ``ffn_kind='softmax_experts'`` of ``models/transformer.py``'s
   block, serving): softmax over ALL routed experts in float32, the
@@ -294,6 +296,21 @@ def _tile_rows(tokens: int) -> int:
     return min(128, -(-tokens // 16) * 16)
 
 
+def _sorted_assignments(idx: jax.Array, gate: jax.Array, cfg: RoutedConfig
+                        ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """``idx``, ``gate`` ``[T, k]`` sorted by held expert (a stable
+    sort; what landed elsewhere, and an ``idx`` of -1, sorts last):
+    each assignment's token ``[T * k]`` int32, its gate, and how many
+    each held expert took ``[held]`` int32."""
+    k, held = idx.shape[1], cfg.n_held
+    local = idx - cfg.first
+    on = (local >= 0) & (local < held)
+    key = jnp.where(on, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    loads = (key[:, None] == jnp.arange(held)[None]).sum(0).astype(jnp.int32)
+    return (order // k).astype(jnp.int32), gate.reshape(-1)[order], loads
+
+
 def held_expert_ffn(x: jax.Array, idx: jax.Array, gate: jax.Array,
                     experts: dict, layer, cfg: RoutedConfig
                     ) -> tuple[jax.Array, jax.Array]:
@@ -303,32 +320,66 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, gate: jax.Array,
     latent's); ``idx``, ``gate`` ``[T, k]`` from the router;
     ``experts`` holds ``we_gate``, ``we_up`` ``[L, held, M, F]`` and
     ``we_down`` ``[L, held, F, M]`` (``cfg.form`` "relu2": ``we_up`` and
-    ``we_down`` alone) for ALL layers and ``layer`` picks
-    one (a traced index: the tile loop reads ``experts[layer, e]`` in
-    place, where a per-layer slice handed to the loop would be copied
-    whole). Returns ``(y [T, M] float32, loads [held] int32)``: the
-    gate-weighted sum over the assignments that landed on held experts,
-    and how many each held expert took.
+    ``we_down`` alone) for ALL layers and ``layer`` picks one (a traced
+    index: ``experts[layer, e]`` is read in place, never a per-layer
+    slice, which would be copied whole). Returns ``(y [T, M] float32,
+    loads [held] int32)``: the gate-weighted sum over the assignments
+    that landed on held experts, and how many each held expert took.
 
-    Assignments are sorted by held expert (the rest sort last and are
-    never touched); expert ``e``'s rows are walked in tiles of
-    ``_tile_rows(T)``: the tile's tokens are picked by a one-hot product
-    (no gather or scatter: the TPU runs those a row at a time), pass the
-    expert (its SwiGLU, or its squared ReLU), and are added back through
-    the transposed one-hot.
+    Assignments are sorted by held expert and run as a grouped product
+    over row tiles of ``_tile_rows(T)``. On a TPU that is ONE Pallas
+    kernel (:func:`held_expert_kernel`, ``ops/grouped_ffn.py``) whose
+    weight reads run on from expert to expert; elsewhere the ``while``
+    loop of :func:`held_expert_loop`, which is also the oracle the
+    kernel is tested against. The backend chooses, nothing else does.
     """
+    if jax.default_backend() == "tpu":
+        return held_expert_kernel(x, idx, gate, experts, layer, cfg)
+    return held_expert_loop(x, idx, gate, experts, layer, cfg)
+
+
+def _expert_stacks(experts: dict, cfg: RoutedConfig) -> tuple:
+    """The expert's matrices in the order of its products."""
+    names = (("we_up", "we_down") if cfg.form == "relu2"
+             else ("we_gate", "we_up", "we_down"))
+    return tuple(experts[name] for name in names)
+
+
+def held_expert_kernel(x: jax.Array, idx: jax.Array, gate: jax.Array,
+                       experts: dict, layer, cfg: RoutedConfig, *,
+                       interpret: bool = False
+                       ) -> tuple[jax.Array, jax.Array]:
+    """:func:`held_expert_ffn` through ``grouped_ffn.grouped_ffn``: one
+    kernel over the (row tile, expert) pairs that hold an assignment,
+    ``x`` and ``y`` resident in VMEM, each expert's products fused, the
+    gate applied in float32, one rounding to the products' dtype before
+    the float32 sum. Its block sizes follow from ``T``, ``M``, ``F``,
+    the tile and the dtype (``grouped_ffn.f_block``). ``interpret``:
+    the tests' way to run it off the TPU."""
+    from dlrover_tpu.ops.grouped_ffn import grouped_ffn
+
+    tm = _tile_rows(x.shape[0])
+    tok_s, gate_s, loads = _sorted_assignments(idx, gate, cfg)
+    pad = (0, -tok_s.shape[0] % tm)
+    y = grouped_ffn(x, jnp.pad(tok_s, pad), jnp.pad(gate_s, pad),
+                    _expert_stacks(experts, cfg), layer, loads,
+                    form=cfg.form, tm=tm, interpret=interpret)
+    return y, loads
+
+
+def held_expert_loop(x: jax.Array, idx: jax.Array, gate: jax.Array,
+                     experts: dict, layer, cfg: RoutedConfig
+                     ) -> tuple[jax.Array, jax.Array]:
+    """:func:`held_expert_ffn` as a ``while`` over (expert, tile) pairs
+    whose trip count is data: expert ``e``'s rows are walked in tiles,
+    each picked by a one-hot product, passed through the expert (its
+    SwiGLU, or its squared ReLU) and added back through the transposed
+    one-hot. An expert that got no token reads no weight."""
     T, M = x.shape
-    k, held = idx.shape[1], cfg.n_held
-    n = T * k
     tm = _tile_rows(T)
-    local = idx - cfg.first
-    on = (local >= 0) & (local < held)
-    key = jnp.where(on, local, held).reshape(n)
-    order = jnp.argsort(key, stable=True)
-    pad = jnp.zeros((tm,), jnp.int32)
-    tok_s = jnp.concatenate([(order // k).astype(jnp.int32), pad])
-    gate_s = jnp.concatenate([gate.reshape(n)[order], pad.astype(gate.dtype)])
-    loads = (key[:, None] == jnp.arange(held)[None]).sum(0).astype(jnp.int32)
+    tok_s, gate_s, loads = _sorted_assignments(idx, gate, cfg)
+    tok_s = jnp.pad(tok_s, (0, tm))
+    gate_s = jnp.pad(gate_s, (0, tm))
     ends = jnp.cumsum(loads)
     starts = ends - loads
     tiles = -(-loads // tm)                        # tiles of expert e

@@ -41,6 +41,7 @@ can import the fingerprint helpers without initializing a backend.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -108,6 +109,27 @@ def abstract_signature(tree: Any) -> list:
     return sig
 
 
+@functools.lru_cache(maxsize=1)
+def code_digest() -> str:
+    """sha256 of this package's Python sources (paths and bytes, read
+    once a process: ~2 MB, ~20 ms). It rides every fingerprint because
+    the other inputs name what a program is compiled FOR (topology,
+    config, calling convention), not what it computes: a change to a
+    kernel or a layer leaves them all as they were, and without this an
+    executable cached by the code before the change would be served to
+    the code after it."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    digest = hashlib.sha256()
+    for folder, dirs, files in os.walk(root):
+        dirs.sort()
+        for name in sorted(f for f in files if f.endswith(".py")):
+            path = os.path.join(folder, name)
+            digest.update(os.path.relpath(path, root).encode())
+            with open(path, "rb") as f:
+                digest.update(f.read())
+    return digest.hexdigest()[:32]
+
+
 def compile_fingerprint(
     *,
     num_nodes: int,
@@ -135,6 +157,7 @@ def compile_fingerprint(
         "strategy": json.loads(strategy_json),
         "args": _canonical(args_signature) if args_signature else [],
         "jax": jax.__version__,
+        "code": code_digest(),
         "platform": jax.default_backend(),
         "extra": _canonical(extra or {}),
     }
@@ -297,8 +320,9 @@ def cache_root() -> str:
     host. ``JAX_COMPILATION_CACHE_DIR`` places it from outside; unset,
     it is a fixed git-ignored directory in the checkout — the path is
     part of XLA's cache key, so it never moves with a job name, a pid
-    or a temp dir. Keys carry the model, strategy and topology, so jobs
-    that share it cannot cross-hit."""
+    or a temp dir. Keys carry the model, strategy, topology and the
+    package's code digest, so jobs (and versions of this code) that
+    share it cannot cross-hit."""
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
         os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__)))),

@@ -118,6 +118,15 @@ class TestFingerprint:
         assert key1.startswith(topology_tag(8, 2) + "/")
         assert inputs["jax"] == jax.__version__
 
+    def test_the_packages_code_is_an_input(self, monkeypatch):
+        """What a program computes is not in the config or the calling
+        convention: an executable cached by other code must not match."""
+        base, inputs = self._fp()
+        assert inputs["code"] == cc.code_digest() and len(inputs["code"]) == 32
+        monkeypatch.setattr(cc, "code_digest", lambda: "0" * 32)
+        other, other_inputs = self._fp()
+        assert other != base and other_inputs != inputs
+
     def test_every_input_changes_the_key(self):
         base, _ = self._fp()
         assert self._fp(model={"layers": 3})[0] != base

@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, SingleDeviceSharding
 
 from dlrover_tpu.models import transformer as tfm
 from dlrover_tpu.parallel import strategy as strat_lib
+from dlrover_tpu.parallel.compile_cache import executable_stats
 from dlrover_tpu.trainer.train_step import compile_train
 
 HBM_BYTES = 16 * 10**9  # v5e, utils/profiler.py PEAKS
@@ -178,16 +179,19 @@ def test_serving_programs_compile(one_chip):
     ).compile()
 
 
-def test_latent_expert_serving_programs_compile(one_chip):
+def test_latent_expert_serving_programs_compile(one_chip, monkeypatch):
     """Decode step and prefill chunk of ``InferenceEngine`` for the
     latent-attention / routed-expert kinds at openPangu-Ultra-MoE's
     published widths: one dense and one expert layer, 16 of 256 experts
     held, an eighth of the vocabulary, weights resting in bfloat16. What
-    the CPU cannot show: the tile loop with a data trip count inside the
-    layer scan, the expert stacks read in place (no copy of a stack in
-    the program), the latent cache donated."""
+    the CPU cannot show: the grouped kernel inside the layer scan (the
+    program is traced as on a TPU: ``held_expert_ffn`` asks the
+    backend), the expert stacks read in place (no copy of a stack in the
+    program), the latent cache donated."""
     from dlrover_tpu.models import latent
     from dlrover_tpu.serving import engine as serving
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     cfg = dataclasses.replace(
         tfm.CONFIGS["openpangu-ultra-moe-718b"], n_layers=2,
@@ -209,6 +213,7 @@ def test_latent_expert_serving_programs_compile(one_chip):
     ).compile(compiler_options=serving._CANONICAL_NUMERICS)
     stack = eng._cache["latent"]
     assert stack.shape == (2, 16, 5120, 576)
+    assert executable_stats(step)["pallas_calls"] == 1
     m = step.memory_analysis()
     assert m.alias_size_in_bytes >= stack.size * 2      # donated whole
     weights = cfg.param_count * 2
@@ -221,6 +226,7 @@ def test_latent_expert_serving_programs_compile(one_chip):
         params, jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip),
         row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     ).compile()
+    assert executable_stats(chunk)["pallas_calls"] == 1
     assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
@@ -274,18 +280,20 @@ def test_hybrid_cache_serving_programs_compile(one_chip):
     assert chunk.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
-def test_state_space_serving_programs_compile(one_chip):
+def test_state_space_serving_programs_compile(one_chip, monkeypatch):
     """Decode step and prefill chunk of ``InferenceEngine`` for a stack of
     single-sublayer layers (``transformer.SINGLE_MIXERS``, models/hybrid.py)
     at Nemotron-3-Super's published widths: one Mamba-2, one latent-expert
     (128 of 512 held) and one attention layer, 32 slots of 3072 positions,
     weights resting in bfloat16. What the CPU cannot show: the float32 state
     of 32 rows (134 MB a layer) and the window donated and updated in place,
-    the tile loop reading an expert's two latent-width matrices straight
-    from the stacks, nothing the size of a layer's experts (1.4 GB) among
-    the temporaries."""
+    the grouped kernel (the program is traced as on a TPU) reading an
+    expert's two latent-width matrices straight from the stacks, nothing
+    the size of a layer's experts (1.4 GB) among the temporaries."""
     from dlrover_tpu.models import hybrid
     from dlrover_tpu.serving import engine as serving
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     cfg = dataclasses.replace(
         tfm.CONFIGS["nemotron-3-super-120b-a12b"], n_layers=3,
@@ -310,6 +318,7 @@ def test_state_space_serving_programs_compile(one_chip):
     ).compile(compiler_options=serving._CANONICAL_NUMERICS)
     state = eng._cache["state"]
     assert state["ssm"].shape == (1, 32, 128, 64, 128)
+    assert executable_stats(step)["pallas_calls"] == 1
     held = (state["ssm"].size * 4 + state["conv"].size * 2
             + 2 * eng._cache["k"].size * 2)
     m = step.memory_analysis()
@@ -322,4 +331,49 @@ def test_state_space_serving_programs_compile(one_chip):
         params, jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip),
         row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     ).compile()
+    assert executable_stats(chunk)["pallas_calls"] == 1
     assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
+# one call of ``held_expert_ffn`` as the three expert cells make it, decode
+# step and prefill chunk: tokens, M, F, held of experts, a token's, first
+EXPERT_CALLS = {
+    "sdar.decode": (64, 2048, 768, 128, 128, 8, 0, "swiglu"),
+    "sdar.chunk": (512, 2048, 768, 128, 128, 8, 0, "swiglu"),
+    "nemotron.decode": (32, 1024, 2688, 128, 512, 22, 128, "relu2"),
+    "nemotron.chunk": (512, 1024, 2688, 128, 512, 22, 128, "relu2"),
+    "openpangu.decode": (16, 7680, 2048, 16, 256, 8, 16, "swiglu"),
+    "openpangu.chunk": (512, 7680, 2048, 16, 256, 8, 16, "swiglu"),
+}
+
+
+@pytest.mark.parametrize("call", EXPERT_CALLS)
+def test_held_expert_kernel_compiles_at_the_cells_widths(one_chip, call):
+    """The grouped kernel (``ops/grouped_ffn.py``) under a traced layer
+    index at each expert cell's decode and chunk shape, bfloat16 stacks
+    of six layers. What interpret mode cannot show: Mosaic takes the
+    block shapes the rule gives (openPangu's F in four blocks under the
+    VMEM limit asked for), the stacks are read in place (no temporary
+    the size of a layer's experts), and no ``while`` is left."""
+    from dlrover_tpu.ops import moe
+
+    T, M, F, held, n_experts, k, first, form = EXPERT_CALLS[call]
+    rcfg = moe.RoutedConfig(n_experts=n_experts, top_k=k, first=first,
+                            held=held, form=form)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    names = ("we_up", "we_down") + (("we_gate",) * (form == "swiglu"))
+    experts = {n: on_chip((6, held) + ((F, M) if n == "we_down" else (M, F)),
+                          jnp.bfloat16) for n in names}
+    compiled = jax.jit(
+        lambda x, idx, gate, experts, layer: moe.held_expert_kernel(
+            x, idx, gate, experts, layer, rcfg)
+    ).lower(on_chip((T, M), jnp.bfloat16), on_chip((T, k), jnp.int32),
+            on_chip((T, k), jnp.float32), experts, on_chip((), jnp.int32)
+            ).compile()
+    assert executable_stats(compiled)["pallas_calls"] == 1
+    assert " while(" not in compiled.as_text()
+    layer_bytes = len(names) * held * M * F * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes / 4
