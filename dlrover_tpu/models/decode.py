@@ -14,7 +14,12 @@ stack for ``attn_kind='latent'``, models/latent.py; ``k``, ``v`` and a
 shorter stack of compressed keys for ``attn_kind='mixers'``,
 models/hybrid.py) and, under ``state``, stacks ``[L, B, ...]`` that no
 token position addresses (a linear-attention layer's matrix). Stacks
-may differ in their layer count and rows in their length. A stack is
+may differ in their layer count and rows in their length. A WINDOWED
+layer's rows (``cfg.layer_windows``) are a RING of ``window`` slots,
+``k_win`` / ``v_win`` ``[L_win, B, H_kv, window, D]``: position ``p``
+lives in slot ``p % window``, and the ring is STATE to everyone outside
+this file (it is kept under ``state``): no one else may index its third
+axis by position. A stack is
 never copied whole (§23.1): the layer loop CARRIES it, layer ``l`` writes its
 ``S_new`` new rows into it in place (the update is the new rows alone)
 and attends over ``stack[l]`` read out of the carry. Nothing of a
@@ -56,7 +61,23 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
     ``attn_kind='latent'``), optionally ``state`` (stacks ``[L, B, ...]``
     with no position axis) and ``counters``. Callers carry
     it whole and name none of its stacks: :func:`cache_stacks`,
-    :func:`cache_state`."""
+    :func:`cache_state`.
+
+    ``cfg.layer_windows`` / ``cfg.layer_rope``: the full layers' rows are
+    ``k``, ``v`` ``[L_full, B, H_kv, max_len, D]``; the windowed layers'
+    are RINGS under ``state``, ``k_win``, ``v_win`` ``[L_win, B, H_kv,
+    window, D]``, whose length follows from the window and never from
+    ``max_len``. The key/value heads lie BEFORE the positions in both:
+    the layout the grouped-head products read a row in (with positions
+    first the TPU compiler copies all the stacks into this layout at the
+    start of every decode call and back at its end: 2.9 GB of temporaries
+    for 24 slots of 16384, PERF.md section 6, PR 41); such a tree has
+    state, so nothing outside this file addresses a position of it. A
+    ring holds the last ``window`` keys of its row, position ``p`` in
+    slot ``p % window``; which position a slot holds
+    follows from the row's ``pos`` alone (:func:`_ring_positions`), so a
+    ring is valid only together with the ``pos`` it was left at: a
+    holder that copies a row copies its rings whole, as it does state."""
     c = cfg
     if c.mixers:
         from dlrover_tpu.models import hybrid
@@ -66,7 +87,11 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
         from dlrover_tpu.models import latent
 
         return latent.init_cache(c, batch, max_len)
-    shape = (c.n_layers, batch, max_len, c.n_kv_heads, c.head_dim)
+    n_win = sum(1 for w in c.layer_windows if w)
+    shape = (c.n_layers - n_win, batch, max_len, c.n_kv_heads, c.head_dim)
+    if c.layer_kinds:
+        # heads before positions (below)
+        shape = (*shape[:2], c.n_kv_heads, max_len, c.head_dim)
     cache = {
         "k": jnp.zeros(shape, jnp.dtype(c.dtype)),
         "v": jnp.zeros(shape, jnp.dtype(c.dtype)),
@@ -77,7 +102,24 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
 
         cache["counters"] = held_counters(
             c.n_layers, tfm.routed_config(c).n_held)
+    if n_win:
+        ring = (n_win, batch, c.n_kv_heads, max(c.layer_windows), c.head_dim)
+        cache["state"] = {"k_win": jnp.zeros(ring, jnp.dtype(c.dtype)),
+                          "v_win": jnp.zeros(ring, jnp.dtype(c.dtype))}
+        cache["counters"] = {
+            **cache.get("counters", {}),
+            **{name: jnp.zeros((), jnp.int32) for name in WINDOW_COUNTERS}}
     return cache
+
+
+# what a tree with rings counts of its live row-steps (a row-step: one
+# real token of one row in one call): how many there were, their
+# positions summed (`context_tokens`, as models/hybrid.py counts it: what
+# a full layer reads of a row), `min(position, window)` summed (what a
+# windowed layer reads of it) and how many lay past the window (the ring
+# had wrapped)
+WINDOW_COUNTERS = ("row_steps", "context_tokens", "window_keys",
+                   "ring_wrapped_row_steps")
 
 
 def cache_stacks(cache: dict) -> dict:
@@ -177,7 +219,9 @@ def _write_rows(stack, new, layer, pos):
     each, unrolled: as ONE scatter the TPU compiler runs a loop over
     the rows that costs 2.8 us a row (a block of 8 decode steps at 16
     slots on a v5e: 97.2 ms against 81.6; PERF.md §6, PR 26). A start past
-    ``max_len - S_new`` is clamped so that the rows fit."""
+    ``max_len - S_new`` is clamped so that the rows fit. A RING is not
+    written here: its write wraps and leaves pads out (:func:`_write_ring`),
+    and only this file may address a slot of it by position."""
     rest = (0,) * (stack.ndim - 3)
     if jnp.ndim(pos) == 0:
         return lax.dynamic_update_slice(
@@ -186,6 +230,130 @@ def _write_rows(stack, new, layer, pos):
         stack = lax.dynamic_update_slice(
             stack, new[None, b:b + 1], (layer, b, pos[b], *rest))
     return stack
+
+
+def _heads_attend(q, k_rows, v_rows, mask, n_rep, dt):
+    """``q [B, S, H, D]`` over ``k_rows``, ``v_rows`` ``[B, H_kv, K, D]``
+    (key/value heads BEFORE the keys: the layout of a tree with rings,
+    ``init_cache``) under ``mask`` (broadcast to ``[B, G, n_rep, S, K]``):
+    :func:`_layer_attend`'s grouped-head softmax attention, the cache read
+    unexpanded."""
+    B, S_new, H, D = q.shape
+    scale = 1.0 / math.sqrt(D)
+    qg = q.reshape(B, S_new, k_rows.shape[1], n_rep, D)
+    with jax.named_scope("kv_read"):
+        logits = jnp.einsum("bqgrd,bgkd->bgrqk", qg, k_rows).astype(
+            jnp.float32) * scale
+    probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1).astype(dt)
+    with jax.named_scope("kv_read"):
+        o = jnp.einsum("bgrqk,bgkd->bqgrd", probs, v_rows)
+    return o.reshape(B, S_new, H, D)
+
+
+def _ring_positions(last, ring: int):
+    """The absolute position each slot of a ring holds once its row has
+    been written up to position ``last`` (``[B]``; -1: nothing yet):
+    ``[B, ring]``, the largest ``p <= last`` with ``p % ring == slot``;
+    negative where the row has not reached the slot. The mask of a ring
+    is made of THESE, never of the slot index."""
+    last = last[:, None]
+    return last - (last - jnp.arange(ring)[None]) % ring
+
+
+def _write_heads_major(stack, new, layer, at):
+    """Write ``new`` [B, S_new, H_kv, D] into ``stack`` [L, B, H_kv, len,
+    D] at ``[layer, b, :, at[b] : at[b] + S_new]`` (``at`` a scalar: rows
+    in lockstep, one update; ``[B]``: one a row, unrolled, as
+    :func:`_write_rows` and for its reason)."""
+    new = jnp.swapaxes(new, 1, 2)[None]
+    if jnp.ndim(at) == 0:
+        return lax.dynamic_update_slice(stack, new, (layer, 0, 0, at, 0))
+    for b in range(new.shape[1]):
+        stack = lax.dynamic_update_slice(
+            stack, new[:, b:b + 1], (layer, b, 0, at[b], 0))
+    return stack
+
+
+def _write_ring(stack, new, layer, pos_b, real_b):
+    """Write the REAL ones of ``new`` [B, S_new, H_kv, D] into the ring
+    ``stack`` [L, B, H_kv, ring, D]: token ``t`` of row ``b`` into slot
+    ``(pos_b[b] + t) % ring``, wrapping. One token a row is one
+    ``dynamic_update_slice`` a row, real or not: a token that is not
+    real lands on the slot of position ``pos - ring``, which neither the
+    row's next query (at ``pos``) nor a later one sees, and the next real
+    token overwrites it. A wider call (a prefill chunk: few rows) rewrites
+    each row's ring whole, a slot taking the real token that maps to it
+    (of a call wider than the ring, the last such) and keeping its key
+    where none does: a pad is never written, for it would lie over a key
+    that the row's next queries still see."""
+    ring, S = stack.shape[3], new.shape[1]
+    if S == 1:
+        return _write_heads_major(stack, new, layer, pos_b % ring)
+    # the window of the call that a ring can hold: all of it, padded to
+    # the ring's length, or its last `ring` real tokens
+    slots = jnp.arange(ring)
+    for b in range(new.shape[0]):
+        if S <= ring:
+            start = 0
+            mine = jnp.pad(new[b], ((0, ring - S), (0, 0), (0, 0)))
+        else:
+            start = jnp.clip(real_b[b] - ring, 0, S - ring)
+            mine = lax.dynamic_slice_in_dim(new[b], start, ring, axis=0)
+        # slot j takes token `t`: rolled, not gathered
+        shift = (pos_b[b] + start) % ring
+        t = start + (slots - shift) % ring
+        old = lax.dynamic_slice(
+            stack, (layer, b, 0, 0, 0), (1, 1, *stack.shape[2:]))
+        mine = jnp.swapaxes(jnp.roll(mine, shift, axis=0), 0, 1)
+        stack = lax.dynamic_update_slice(
+            stack, jnp.where((t >= real_b[b])[None, None, None, :, None],
+                             old, mine[None, None]),
+            (layer, b, 0, 0, 0))
+    return stack
+
+
+def _ring_attend(q, k, v, k_stack, v_stack, layer, pos_b, real_b, window,
+                 n_rep, dt):
+    """A WINDOWED layer's attention and the write of its new rows into
+    the layer's rings: query ``i`` (absolute position) sees key ``j`` iff
+    ``0 <= i - j < window``. One new token a row (a decode step) is
+    written first and attends over the ring, which then holds exactly
+    the ``window`` keys it may see. A wider call's first query still
+    needs the ``window - 1`` keys before it while its last keys would
+    overwrite them, so it attends over the ring AS IT STOOD beside its
+    own new keys, and writes after. Either way the mask is made of each
+    slot's absolute position (:func:`_ring_positions`)."""
+    S = q.shape[1]
+    ring = k_stack.shape[3]
+    q_pos = pos_b[:, None] + jnp.arange(S)[None]            # [B, S]
+
+    def seen(k_pos):
+        back = q_pos[:, :, None] - k_pos[:, None, :]
+        return ((k_pos >= 0)[:, None, :] & (back >= 0)
+                & (back < window))[:, None, None]
+
+    def rows(stack):
+        return lax.dynamic_index_in_dim(stack, layer, keepdims=False)
+
+    def write(k_stack, v_stack):
+        with jax.named_scope("kv_write"):
+            return (_write_ring(k_stack, k.astype(dt), layer, pos_b, real_b),
+                    _write_ring(v_stack, v.astype(dt), layer, pos_b, real_b))
+
+    if S == 1:
+        k_stack, v_stack = write(k_stack, v_stack)
+        o = _heads_attend(q, rows(k_stack), rows(v_stack),
+                          seen(_ring_positions(pos_b, ring)), n_rep, dt)
+        return o, k_stack, v_stack
+    mask = jnp.concatenate(
+        [seen(_ring_positions(pos_b - 1, ring)), seen(q_pos)], axis=-1)
+    o = _heads_attend(
+        q, jnp.concatenate(
+            [rows(k_stack), jnp.swapaxes(k.astype(dt), 1, 2)], axis=2),
+        jnp.concatenate(
+            [rows(v_stack), jnp.swapaxes(v.astype(dt), 1, 2)], axis=2),
+        mask, n_rep, dt)
+    return (o, *write(k_stack, v_stack))
 
 
 def weights_at_rest(params: Params, cfg: TransformerConfig) -> Params:
@@ -254,6 +422,8 @@ def forward_cached(
         # the cached products are plain whatever training ran
         # (ROADMAP D12)
         c = dataclasses.replace(c, int8_matmuls=False)
+    if c.layer_kinds:
+        return _forward_runs(params, tokens, cache, c, real)
     dt = jnp.dtype(c.dtype)
     B, S_new = tokens.shape
     pos = cache["pos"]
@@ -306,6 +476,104 @@ def forward_cached(
 
         new["counters"] = count_loads(cache["counters"], loads)
     return logits, new
+
+
+def _forward_runs(params, tokens, cache, cfg, real):
+    """:func:`forward_cached` for a stack whose layers are of several
+    kinds (``cfg.layer_windows`` / ``cfg.layer_rope``): one scan a run of
+    equal layers (``transformer.layer_runs``), the full layers' rows and
+    the windowed layers' rings each riding the carry of their own runs.
+    A full layer attends over its row as ever (a wide call only as far
+    as its last query reaches, ``latent.key_reaches``'s lengths; a decode
+    step reads what its mask leaves), a windowed one
+    through :func:`_ring_attend`. ``real``: the rings take in the real
+    tokens alone (:func:`_write_ring`), and the counters count them."""
+    c = cfg
+    dt = jnp.dtype(c.dtype)
+    B, S = tokens.shape
+    pos = cache["pos"]
+    pos_b = jnp.broadcast_to(pos, (B,)).astype(jnp.int32)
+    real_b = jnp.full((B,), S, jnp.int32) if real is None else jnp.clip(
+        jnp.broadcast_to(jnp.asarray(real).astype(jnp.int32), (B,)), 0, S)
+    n_rep = c.n_heads // c.n_kv_heads
+    window = max(c.layer_windows, default=0)
+    from dlrover_tpu.models.latent import key_reaches
+
+    keys = cache["k"].shape[3]
+    reaches = [keys] if S == 1 else key_reaches(S, keys)
+    reach = jnp.sum(jnp.asarray(reaches) < jnp.max(pos_b) + S)
+    q_pos = pos_b[:, None] + jnp.arange(S)[None]            # [B, S]
+
+    def attend_full(q, k, v, state):
+        k_stack, v_stack, l = state
+        with jax.named_scope("attn_full"):
+            with jax.named_scope("kv_write"):
+                k_stack = _write_heads_major(k_stack, k.astype(dt), l, pos)
+                v_stack = _write_heads_major(v_stack, v.astype(dt), l, pos)
+            k_rows = lax.dynamic_index_in_dim(k_stack, l, keepdims=False)
+            v_rows = lax.dynamic_index_in_dim(v_stack, l, keepdims=False)
+            o = lax.switch(reach, [
+                lambda q, k_rows, v_rows, n=n: _heads_attend(
+                    q, k_rows[:, :, :n], v_rows[:, :, :n],
+                    (q_pos[:, :, None] >= jnp.arange(n)[None, None]
+                     )[:, None, None], n_rep, dt)
+                for n in reaches], q, k_rows, v_rows)
+        return o, (k_stack, v_stack, l)
+
+    def attend_window(q, k, v, state):
+        k_stack, v_stack, l = state
+        with jax.named_scope("attn_window"):
+            o, k_stack, v_stack = _ring_attend(
+                q, k, v, k_stack, v_stack, l, pos_b, real_b, window,
+                n_rep, dt)
+        return o, (k_stack, v_stack, l)
+
+    experts, layers = tfm.split_experts(params["layers"], c)
+    positions = tfm.token_positions(pos, B, S)
+    rings = cache.get("state", {})
+    held = {False: (cache["k"], cache["v"]),
+            True: (rings.get("k_win"), rings.get("v_win"))}
+    x = tfm.embed_tokens(params, tokens, c, pos=pos)
+    loads = []
+    for win, rope, first, first_of_kind, n in tfm.layer_runs(c):
+        run_layer = tfm.make_layer_fn(
+            c, attend=attend_window if win else attend_full,
+            positions=positions, experts=experts, kind=(win, rope))
+
+        def layer(carry, i, run_layer=run_layer,
+                  offset=first_of_kind - first):
+            x, k_stack, v_stack = carry
+            w = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
+                layers)
+            x, aux, (k_stack, v_stack, _) = run_layer(
+                x, w, (k_stack, v_stack, i + offset), i)
+            return (x, k_stack, v_stack), (aux if c.held_experts else None)
+
+        # rows and rings ride the CARRY (module docstring)
+        (x, *held[win > 0]), aux = lax.scan(
+            layer, (x, *held[win > 0]),
+            jnp.arange(first, first + n, dtype=jnp.int32))
+        loads.append(aux)
+    with jax.named_scope("lm_head"):
+        logits = tfm.lm_logits(params, tfm.final_norm(params, x, c), c)
+    new = {"k": held[False][0], "v": held[False][1], "pos": pos + S}
+    old, counters = cache.get("counters", {}), {}
+    if c.held_experts:
+        from dlrover_tpu.ops.moe import count_loads
+
+        counters = count_loads(old, jnp.concatenate(loads))
+    if not window:
+        return logits, {**new, **({"counters": counters} if counters else {})}
+    live = jnp.arange(S)[None] < real_b[:, None]
+    for name, what in (
+            ("row_steps", live), ("context_tokens", q_pos),
+            ("window_keys", jnp.minimum(q_pos, window)),
+            ("ring_wrapped_row_steps", q_pos >= window)):
+        counters[name] = old[name] + jnp.sum(
+            jnp.where(live, what, 0).astype(jnp.int32))
+    return logits, {**new, "counters": counters, "state": {
+        "k_win": held[True][0], "v_win": held[True][1]}}
 
 
 @jax.named_scope("sample")

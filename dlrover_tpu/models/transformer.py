@@ -224,11 +224,44 @@ class TransformerConfig:
     ssm_chunk: int = 128
     moe_latent: int = 0
     moe_shared_d_ff: int = 0
+    # --- per-layer kinds of THIS file's block (served only; the stack is
+    # then run as scanned runs of equal layers, `layer_runs`), one entry a
+    # layer beside `n_layers`: `layer_windows[l]` > 0 makes layer l's
+    # attention WINDOWED (query i sees key j iff 0 <= i - j < window) and
+    # its cache a RING of that many rows (models/decode.py), 0 leaves it
+    # full; `layer_rope[l]` False leaves layer l's queries and keys
+    # without the rotary embedding. Empty: every layer full / rotary as
+    # the variant says
+    layer_windows: tuple = ()
+    layer_rope: tuple = ()
+    # ffn_kind "softmax_experts": where the router reads, "ffn" (the
+    # feed-forward half's normed input) or "attention" (the ATTENTION
+    # half's normed input: the choice is made before attention runs); and
+    # an expert's form, "swiglu" or "reglu" (relu where swiglu has silu)
+    router_input: str = "ffn"
+    expert_form: str = "swiglu"
 
     def __post_init__(self):
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
+        for name in ("layer_windows", "layer_rope"):
+            kinds = tuple(getattr(self, name))
+            object.__setattr__(self, name, kinds)
+            if kinds and len(kinds) != self.n_layers:
+                raise ValueError(
+                    f"{name} has {len(kinds)} entries for n_layers "
+                    f"{self.n_layers}: one a layer, or none")
+        if len({w for w in self.layer_windows if w}) > 1:
+            raise ValueError(
+                f"layer_windows {self.layer_windows}: the windowed layers "
+                "share one window (one ring length, models/decode.py)")
+        if (self.router_input not in ("ffn", "attention")
+                or self.expert_form not in ("swiglu", "reglu")):
+            raise ValueError(
+                f"router_input {self.router_input!r} / expert_form "
+                f"{self.expert_form!r}: 'ffn' or 'attention', 'swiglu' or "
+                "'reglu'")
         if self.generation == "block_diffusion":
             if (self.block_length < 1 or self.denoising_steps < 1
                     or self.block_length % self.denoising_steps
@@ -301,9 +334,14 @@ class TransformerConfig:
         return self.ffn_kind == "softmax_experts"
 
     @property
+    def layer_kinds(self) -> bool:
+        """True where the layers are not all of one kind (`layer_runs`)."""
+        return bool(self.layer_windows or self.layer_rope)
+
+    @property
     def default_kinds(self) -> bool:
         return (self.attn_kind, self.norm_kind, self.ffn_kind) == (
-            "heads", "pre", "")
+            "heads", "pre", "") and not self.layer_kinds
 
     @property
     def param_count(self) -> int:
@@ -580,6 +618,32 @@ CONFIGS = {
         moe_d_ff=2688, moe_latent=1024, moe_shared_d_ff=5376,
         n_shared_experts=1, routed_scaling_factor=5.0, norm_topk_prob=True,
         param_dtype="bfloat16"),
+    # the kinds of the entry below at a size for CPU tests: two periods of
+    # [full without rotary, windowed x 3], a window of 16
+    "tiny-smallthinker": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=32, max_seq_len=256, rope_theta=10000.0,
+        rope_pairing="half", norm_eps=1e-6, ffn_kind="softmax_experts",
+        n_routed_experts=8, moe_top_k=3, moe_d_ff=32, norm_topk_prob=True,
+        router_input="attention", expert_form="reglu",
+        layer_windows=(0, 16, 16, 16) * 2,
+        layer_rope=(False, True, True, True) * 2, dtype="float32"),
+    # SmallThinker-21BA3B-Instruct as published (config.json, model_name
+    # smallthinker_21b_instruct): rope_layout and sliding_window_layout
+    # [0, 1, 1, 1] x 13 (a full layer WITHOUT rotary embedding, then three
+    # windowed layers with one), 64 primary ReGLU experts 6 a token, the
+    # router on the attention half's normed input; it has no dense width
+    # (`d_ff` is inert). A deployment sets the layers it holds with
+    # dataclasses.replace (n_layers, layer_windows, layer_rope).
+    "smallthinker-21b-a3b-instruct": TransformerConfig(
+        vocab_size=151936, d_model=2560, n_layers=52, n_heads=28,
+        n_kv_heads=4, head_dim=128, d_ff=768, max_seq_len=16384,
+        rope_theta=1500000.0, rope_pairing="half", norm_eps=1e-6,
+        ffn_kind="softmax_experts", n_routed_experts=64, moe_top_k=6,
+        moe_d_ff=768, norm_topk_prob=True, router_input="attention",
+        expert_form="reglu", layer_windows=(0, 4096, 4096, 4096) * 13,
+        layer_rope=(False, True, True, True) * 13,
+        param_dtype="bfloat16"),
 }
 
 
@@ -600,7 +664,27 @@ def routed_config(cfg: TransformerConfig):
         norm_topk=cfg.norm_topk_prob, first=cfg.expert_first,
         held=cfg.experts_held,
         **({"scaling": cfg.routed_scaling_factor, "form": "relu2"}
-           if cfg.moe_latent else {}))
+           if cfg.moe_latent else {"form": cfg.expert_form}))
+
+
+def layer_runs(cfg: TransformerConfig) -> list[tuple[int, bool, int, int, int]]:
+    """The stack as runs of equal layers, ``(window, rope, first layer,
+    first layer OF ITS KIND, layers)``: each is one scan. The kind is
+    what decides a layer's cache, windowed (a ring) or full (rows), so
+    the fourth entry is where the run's rows start in its kind's stack.
+    One run of everything where `layer_windows` / `layer_rope` are
+    empty."""
+    c = cfg
+    windows = c.layer_windows or (0,) * c.n_layers
+    ropes = c.layer_rope or (True,) * c.n_layers
+    out, seen = [], {True: 0, False: 0}
+    for l, (w, r) in enumerate(zip(windows, ropes)):
+        if out and out[-1][:2] == [w, bool(r)]:
+            out[-1][4] += 1
+        else:
+            out.append([w, bool(r), l, seen[w > 0], 1])
+        seen[w > 0] += 1
+    return [tuple(r) for r in out]
 
 
 def _check_kinds(cfg: TransformerConfig) -> None:
@@ -612,7 +696,11 @@ def _check_kinds(cfg: TransformerConfig) -> None:
             or (c.ffn_kind and c.moe_experts)
             or (c.mixers and (c.ffn_kind or c.moe_experts))
             or (not c.default_kinds and c.variant != "llama")
-            or c.rope_pairing not in ("interleaved", "half")):
+            or c.rope_pairing not in ("interleaved", "half")
+            or ((c.router_input != "ffn" or c.expert_form != "swiglu")
+                and not c.held_experts)
+            or (c.layer_kinds and (c.mixers or c.moe_experts
+                                   or c.generation != "autoregressive"))):
         raise NotImplementedError(
             f"attn_kind / norm_kind / ffn_kind ({c.attn_kind!r}, "
             f"{c.norm_kind!r}, {c.ffn_kind!r}) with variant {c.variant!r}"
@@ -620,9 +708,11 @@ def _check_kinds(cfg: TransformerConfig) -> None:
             f"{c.rope_pairing!r}: models/transformer.py runs 'heads' or "
             "'heads_qk_norm' attention under 'pre' norms with the "
             "variant's FFN, `moe_experts` or 'softmax_experts' (llama "
-            "variant), 'mixers' (models/hybrid.py) with the llama FFN, and "
-            "models/latent.py runs ('latent', 'sandwich', "
-            "'sigmoid_experts')")
+            "variant; `router_input` and `expert_form` are that kind's), "
+            "'mixers' (models/hybrid.py) with the llama FFN, "
+            "`layer_windows` / `layer_rope` for autoregressive 'heads' "
+            "kinds without `moe_experts`, and models/latent.py runs "
+            "('latent', 'sandwich', 'sigmoid_experts')")
 
 
 def param_shapes(cfg: TransformerConfig) -> dict:
@@ -918,6 +1008,22 @@ def block_causal_attention(q, k, v, *, block: int,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def windowed_attention(q, k, v, *, window: int,
+                       causal: bool = True) -> jax.Array:
+    """Causal attention of a WINDOWED layer over a whole sequence: query
+    ``i`` sees key ``j`` iff ``0 <= i - j < window``. [B,S,H,D]; fp32
+    softmax. ``causal`` is accepted for the call's form and has to be
+    true."""
+    assert causal
+    scale = 1.0 / math.sqrt(q.shape[-1])
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    back = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None, :]
+    probs = jax.nn.softmax(
+        jnp.where((back >= 0) & (back < window), logits, -1e30),
+        axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
 def prefix_lm_attention(q, k, v, prefix_len: jax.Array, *,
                         causal: bool = True) -> jax.Array:
     """GLM-class prefix-LM mask: bidirectional inside the per-row
@@ -992,6 +1098,7 @@ def make_layer_fn(
     positions: jax.Array | None = None,
     experts: dict | None = None,
     mixer: str = "",
+    kind: tuple | None = None,
 ) -> Callable[..., tuple[jax.Array, jax.Array, Any]]:
     """One transformer block as a reusable ``(x, w, state=None,
     index=None) -> (x, aux, state)``: THE definition of what a dense
@@ -1046,12 +1153,32 @@ def make_layer_fn(
     squared-ReLU shared expert at the full width; ``aux`` is ``loads``;
     ``mask [B, S]`` (None: all) says which tokens are real, and the rest
     reach no routed expert.
+
+    ``cfg.layer_windows`` / ``cfg.layer_rope`` (served only): the layers
+    are not all of one kind, so the caller runs the stack as
+    :func:`layer_runs` and builds one block a run, ``kind=(window,
+    rope)``: ``rope`` False leaves the rotary embedding out; ``window``
+    > 0 is the mask of the block's own attention (a cached caller's
+    ``attend`` owns its mask, and its ring). ``cfg.router_input``
+    "attention": the expert layer's router reads the ATTENTION half's
+    normed input, so the choice is made before attention runs.
     """
     c = cfg
     _check_kinds(c)
     dt = jnp.dtype(c.dtype)
     eps = _norm_eps(c)
     pin = constrain or (lambda x, a: x)
+    if c.layer_kinds and (kind is None or constrain is not None
+                          or mask is not None or attention_fn is not None
+                          or c.int8_matmuls or c.mixers):
+        raise NotImplementedError(
+            "layer_windows / layer_rope (layers of several kinds) are the "
+            "forward pass on one device (forward, forward_cached): the "
+            "caller runs the stack as `layer_runs` and says which kind "
+            "each block is; there is neither a kernel attention, a token "
+            "mask, a sharding rule, an int8 path nor a gradient for it "
+            "(training, parallel/pipeline.py, parallel/mpmd.py)")
+    window, rope = kind or (0, True)
     if c.mixers and (mixer not in ("sparse", "lightning", *SINGLE_MIXERS)
                      or (attend is None) != (mixer == "latent_experts")
                      or (experts is None) == (mixer == "latent_experts")
@@ -1066,7 +1193,8 @@ def make_layer_fn(
             "pipeline.py, parallel/mpmd.py)")
     qk_norm = (c.attn_kind == "heads_qk_norm"
                or mixer in ("sparse", "lightning"))
-    rotary = c.variant == "llama" and mixer not in ("sparse", "attention")
+    rotary = (c.variant == "llama" and rope
+              and mixer not in ("sparse", "attention"))
     res = c.residual_scale
     if mixer == "latent_experts":
         from dlrover_tpu.ops import moe as _moe
@@ -1087,6 +1215,7 @@ def make_layer_fn(
         from dlrover_tpu.ops import moe as _moe
 
         rcfg = routed_config(c)
+    router_early = c.held_experts and c.router_input == "attention"
     if c.generation == "block_diffusion" and attend is None:
         if attention_fn is not None:
             raise NotImplementedError(
@@ -1095,6 +1224,8 @@ def make_layer_fn(
                 "have no such mask")
         attention_fn = partial(block_causal_attention,
                                block=c.block_length)
+    if window and attend is None:
+        attention_fn = partial(windowed_attention, window=window)
     if attend is None:
         attn = attention_fn or dense_attention
         n_rep = c.n_heads // c.n_kv_heads
@@ -1204,6 +1335,10 @@ def make_layer_fn(
               else token_positions(None, *x.shape[:2]))
         with jax.named_scope("attn"):
             h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant, eps)
+            if router_early:
+                with jax.named_scope("moe_router"):
+                    idx, gate = _moe.softmax_topk_route(
+                        h.reshape(-1, h.shape[-1]), w["w_router"], rcfg)
             q = proj(h, _leaf(w, "wq", dt), "bse,ehd->bshd")
             if c.mup_base_width:
                 q = q * mup_q_scale
@@ -1237,9 +1372,10 @@ def make_layer_fn(
             h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant, eps)
             if c.held_experts:
                 ht = h.reshape(-1, h.shape[-1])
-                with jax.named_scope("moe_router"):
-                    idx, gate = _moe.softmax_topk_route(
-                        ht, w["w_router"], rcfg)
+                if not router_early:
+                    with jax.named_scope("moe_router"):
+                        idx, gate = _moe.softmax_topk_route(
+                            ht, w["w_router"], rcfg)
                 with jax.named_scope("moe_experts"):
                     ff, aux = _moe.held_expert_ffn(
                         ht, idx, gate, experts, index, rcfg)
@@ -1416,28 +1552,34 @@ def forward_with_aux(
                                 return_hidden=return_hidden)
     dt = jnp.dtype(c.dtype)
     pin = constrain or (lambda x, a: x)
-    if c.held_experts or c.generation == "block_diffusion":
+    if c.held_experts or c.generation == "block_diffusion" or c.layer_kinds:
         if (c.prefix_lm or c.remat_scan or c.pipeline_stages > 1
                 or mask is not None or constrain is not None):
             raise NotImplementedError(
-                f"ffn_kind {c.ffn_kind!r} / generation {c.generation!r}: "
+                f"ffn_kind {c.ffn_kind!r} / generation {c.generation!r} / "
+                "layer_windows, layer_rope: "
                 "the forward pass on one device, and nothing of prefix_lm, "
                 "remat_scan, pipeline stages (parallel/pipeline.py), a "
                 "token mask or a sharding rule")
         x = (inputs_embeds.astype(dt) if inputs_embeds is not None
              else embed_tokens(params, tokens, cfg))
         experts, scanned = split_experts(params["layers"], c)
-        # attention_fn None: the block picks its own (block-causal for a
-        # block-diffusion model) and refuses a kernel by name
-        layer = make_layer_fn(cfg, attention_fn=attention_fn,
-                              experts=experts)
+        for window, rope, first, _, n in layer_runs(c):
+            # attention_fn None: the block picks its own (block-causal
+            # for a block-diffusion model, windowed for a windowed run)
+            # and refuses a kernel by name
+            layer = make_layer_fn(
+                cfg, attention_fn=attention_fn, experts=experts,
+                kind=(window, rope) if c.layer_kinds else None)
 
-        def served_body(x, inputs):
-            w, i = inputs
-            return layer(x, w, None, i)[0], None
+            def served_body(x, inputs, layer=layer):
+                w, i = inputs
+                return layer(x, w, None, i)[0], None
 
-        x, _ = lax.scan(served_body, x, (
-            scanned, jnp.arange(c.n_layers, dtype=jnp.int32)))
+            x, _ = lax.scan(served_body, x, (
+                scanned if n == c.n_layers else jax.tree.map(
+                    lambda a: a[first:first + n], scanned),
+                jnp.arange(first, first + n, dtype=jnp.int32)))
         x = final_norm(params, x, c)
         return (x if return_hidden else lm_logits(params, x, c),
                 jnp.zeros((), jnp.float32))

@@ -116,7 +116,8 @@ def _kernel(e_ref, tile_ref, lo_ref, hi_ref, total_ref, layer_ref,
             h = jnp.square(jnp.maximum(dot(rows, up_ref), 0.0))
         else:
             gate_ref, up_ref, down_ref = w_refs
-            h = jax.nn.silu(dot(rows, gate_ref)) * dot(rows, up_ref)
+            act = jax.nn.relu if form == "reglu" else jax.nn.silu
+            h = act(dot(rows, gate_ref)) * dot(rows, up_ref)
         part = dot(h, down_ref)
         if n_f > 1:
             @pl.when(j == 0)
@@ -144,7 +145,7 @@ def grouped_ffn(x: jax.Array, tokens: jax.Array, gates: jax.Array,
     ``loads[e]`` of them behind the experts before it; what lies behind
     the last expert's is never read); ``stacks`` the expert's matrices
     ``[L, held, M, F]`` ... ``[L, held, F, M]`` in the order of its
-    products (``form`` "swiglu": gate, up, down; "relu2": up, down).
+    products (``form`` "swiglu" / "reglu": gate, up, down; "relu2": up, down).
     Returns ``y [T, M]`` float32: the sum over assignments ``r`` of
     expert ``e`` of ``gates[r] * expert_e(x[tokens[r]])``, each term
     float32 until it is rounded once to the products' dtype, the sum in

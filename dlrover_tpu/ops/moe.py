@@ -197,8 +197,9 @@ class RoutedConfig:
     norm_topk: bool = True    # gates normalised over the chosen
     first: int = 0            # index of the first expert held here
     held: int = 0             # how many are held (0: all)
-    # an expert's form: "swiglu" (three stacks we_gate, we_up, we_down) or
-    # "relu2" (two, we_up and we_down: Down(relu(Up h)^2))
+    # an expert's form: "swiglu" (three stacks we_gate, we_up, we_down),
+    # "reglu" (the same three: Down(relu(Gate h) * Up h)) or "relu2" (two,
+    # we_up and we_down: Down(relu(Up h)^2))
     form: str = "swiglu"
 
     @property
@@ -206,9 +207,12 @@ class RoutedConfig:
         return self.held or self.n_experts
 
 
-def swiglu(h: jax.Array, w_gate, w_up, w_down) -> jax.Array:
-    """``Down(silu(Gate h) * Up h)`` on ``h [..., M]``."""
-    gate = jax.nn.silu(jnp.einsum("...m,mf->...f", h, w_gate))
+def swiglu(h: jax.Array, w_gate, w_up, w_down,
+           act=jax.nn.silu) -> jax.Array:
+    """``Down(silu(Gate h) * Up h)`` on ``h [..., M]``; ``act`` in the
+    place of ``silu`` is the gated form's other members (``jax.nn.relu``:
+    ReGLU)."""
+    gate = act(jnp.einsum("...m,mf->...f", h, w_gate))
     return jnp.einsum("...f,fm->...m",
                       gate * jnp.einsum("...m,mf->...f", h, w_up), w_down)
 
@@ -373,7 +377,7 @@ def held_expert_loop(x: jax.Array, idx: jax.Array, gate: jax.Array,
     """:func:`held_expert_ffn` as a ``while`` over (expert, tile) pairs
     whose trip count is data: expert ``e``'s rows are walked in tiles,
     each picked by a one-hot product, passed through the expert (its
-    SwiGLU, or its squared ReLU) and added back through the transposed
+    SwiGLU, ReGLU or squared ReLU) and added back through the transposed
     one-hot. An expert that got no token reads no weight."""
     T, M = x.shape
     tm = _tile_rows(T)
@@ -404,7 +408,8 @@ def held_expert_loop(x: jax.Array, idx: jax.Array, gate: jax.Array,
         if cfg.form == "relu2":
             out = relu2(xt, w("we_up"), w("we_down"))
         else:
-            out = swiglu(xt, w("we_gate"), w("we_up"), w("we_down"))
+            out = swiglu(xt, w("we_gate"), w("we_up"), w("we_down"),
+                         jax.nn.relu if cfg.form == "reglu" else jax.nn.silu)
         out = (out.astype(jnp.float32) * g[:, None]).astype(x.dtype)
         return y + jnp.einsum("rt,rm->tm", pick, out,
                               preferred_element_type=jnp.float32)

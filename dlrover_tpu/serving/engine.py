@@ -111,7 +111,12 @@ cached forward how many of the call's tokens are real, a row, and such a
 model takes in no other. ``_install``, the working row and the prefix
 cache carry state with the rows (an entry holds the state AT its chunk
 boundary); ``kv_pages``, speculation and bundles, which assume a
-position axis, raise by name.
+position axis, raise by name. A windowed layer's RING
+(``models/decode.py``: the last ``window`` keys of a row, position ``p``
+in slot ``p % window``) is state in this sense and is kept there: only
+the model may index it by position, a prefix-cache entry holds it as it
+stood AT its boundary, and a pad, told to the model as not real, is
+never written into it.
 """
 
 from __future__ import annotations
@@ -535,9 +540,10 @@ class InferenceEngine:
         if self._paging and self._stateful:
             raise NotImplementedError(
                 "kv_pages > 0 (the paged store, park/resume, copy-on-write "
-                "pages) with a model that carries state: a page holds "
-                "token-addressed rows, and a parked row's state has no "
-                "page (run it with kv_pages=0)")
+                "pages) with a model that carries state (or a ring: a "
+                "windowed layer's rows): a page holds token-addressed "
+                "rows, and a parked row's state or ring has no page (run "
+                "it with kv_pages=0)")
         if self._paging and cfg.attn_kind != "heads":
             raise NotImplementedError(
                 f"kv_pages > 0 with attn_kind {cfg.attn_kind!r}: the paged "
@@ -647,9 +653,10 @@ class InferenceEngine:
         if self._stateful and self.spec_depth >= 2:
             raise NotImplementedError(
                 "speculation (spec_depth >= 2) with a model that carries "
-                "state: `_verify_block` takes a rejected draft back by "
-                "putting the position back, and a state has already "
-                "folded the draft in")
+                "state (or a ring: a windowed layer's rows): "
+                "`_verify_block` takes a rejected draft back by putting "
+                "the position back, and a state has already folded the "
+                "draft in, a ring has lost the keys the draft lay over")
         self._spec = self.spec_depth >= 2 and self._obs is not None
         # rid -> [accepted, scored, collapsed] live draft accounting
         self._spec_acc: dict[int, list[int]] = {}
@@ -1385,9 +1392,9 @@ class InferenceEngine:
         if self._stateful:
             raise NotImplementedError(
                 "handed-over KVBundles (make_bundle, submit_prefilled) "
-                "with a model that carries state: a bundle is pages of "
-                "token-addressed rows, and the state at the prompt's end "
-                "has no page")
+                "with a model that carries state (or a ring: a windowed "
+                "layer's rows): a bundle is pages of token-addressed rows, "
+                "and the state or ring at the prompt's end has no page")
         if self._diffusion:
             raise NotImplementedError(
                 "handed-over KVBundles (make_bundle, submit_prefilled) "

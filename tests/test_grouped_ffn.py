@@ -22,7 +22,7 @@ def _experts(key, held, M, F, form, layers=2):
     ks = jax.random.split(key, 3)
     ex = {"we_up": jax.random.normal(ks[0], (layers, held, M, F)) / 8,
           "we_down": jax.random.normal(ks[1], (layers, held, F, M)) / 6}
-    if form == "swiglu":
+    if form != "relu2":
         ex["we_gate"] = jax.random.normal(ks[2], (layers, held, M, F)) / 8
     return ex
 
@@ -34,9 +34,13 @@ def _dense(x, idx, gate, ex, layer, rcfg):
     for e in range(rcfg.n_held):
         g = jnp.where(idx == e + rcfg.first, gate, 0.0).sum(-1)
         w = {k: v[layer, e] for k, v in ex.items()}
-        out = (moe.relu2(x, w["we_up"], w["we_down"])
-               if rcfg.form == "relu2"
-               else moe.swiglu(x, w["we_gate"], w["we_up"], w["we_down"]))
+        if rcfg.form == "relu2":
+            out = moe.relu2(x, w["we_up"], w["we_down"])
+        elif rcfg.form == "reglu":
+            out = (jax.nn.relu(x @ w["we_gate"]) * (x @ w["we_up"])
+                   ) @ w["we_down"]
+        else:
+            out = moe.swiglu(x, w["we_gate"], w["we_up"], w["we_down"])
         want = want + g[:, None] * out
     return want
 
@@ -76,6 +80,10 @@ CASES = {
     "swiglu_16_of_256_held_F_in_blocks": (40, 128, 384, moe.RoutedConfig(
         n_experts=256, top_k=8, first=32, held=16, scaling=2.5),
         "router", True),
+    "reglu_all_held_k6": (24, 64, 32, moe.RoutedConfig(
+        n_experts=16, top_k=6, form="reglu"), "router", False),
+    "reglu_F_in_blocks_some_rows_not_real": (20, 128, 256, moe.RoutedConfig(
+        n_experts=8, top_k=3, form="reglu"), "masked", True),
     # (b) the edges
     "an_expert_with_no_row": (3, 64, 32, moe.RoutedConfig(
         n_experts=16, top_k=2, first=0, held=8), "router", False),

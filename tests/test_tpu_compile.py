@@ -335,7 +335,65 @@ def test_state_space_serving_programs_compile(one_chip, monkeypatch):
     assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
 
 
-# one call of ``held_expert_ffn`` as the three expert cells make it, decode
+def test_windowed_ring_serving_programs_compile(one_chip, monkeypatch):
+    """Decode block and prefill chunk of ``InferenceEngine`` for a stack of
+    windowed and full layers (``layer_windows``, models/decode.py) at
+    SmallThinker's published widths: one period (a full layer without
+    rotary embedding, three windowed ones), 24 slots of 16384 positions,
+    weights resting in bfloat16. What the CPU cannot show: full rows and
+    rings (heads before positions) donated and updated in place with no
+    copy of a stack into another layout (with positions first the decode
+    call held 2.9 GB of temporaries for 8 layers: a copy of every stack),
+    the grouped kernel's ReGLU form read straight from the stacks, and a
+    chunk's attention over a row cut to what its last query reaches."""
+    from dlrover_tpu.models import decode
+    from dlrover_tpu.serving import engine as serving
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    base = tfm.CONFIGS["smallthinker-21b-a3b-instruct"]
+    cfg = dataclasses.replace(
+        base, n_layers=4, layer_windows=base.layer_windows[:4],
+        layer_rope=base.layer_rope[:4], vocab_size=32768, dtype="bfloat16")
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip),
+        tfm.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    eng = serving.InferenceEngine(params, cfg, slots=24, max_len=16384,
+                                  prefill_len=512, decode_block=8)
+    row_bytes = 2 * 4 * 128 * 2
+    assert eng.cache_bytes_per_token == row_bytes            # one full layer
+    assert eng.state_bytes_per_slot == 3 * 4096 * row_bytes  # three rings
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: a if isinstance(a, jax.ShapeDtypeStruct)
+            else jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                      sharding=one_chip), tree)
+
+    step = eng._step_block.lower(
+        *on_chip(eng._block_sample_args()), n_steps=8
+    ).compile(compiler_options=serving._CANONICAL_NUMERICS)
+    rings = eng._cache["state"]
+    assert rings["k_win"].shape == (3, 24, 4, 4096, 128)
+    assert eng._cache["k"].shape == (1, 24, 4, 16384, 128)
+    assert executable_stats(step)["pallas_calls"] == 2      # one a run
+    held = 2 * 2 * (rings["k_win"].size + eng._cache["k"].size)
+    m = step.memory_analysis()
+    assert m.alias_size_in_bytes >= held                # donated whole
+    # beside the weights and the cache: no copy of a stack (0.8 and 0.6
+    # GB each), nor of a layer's experts (0.75 GB)
+    assert m.temp_size_in_bytes < 0.7e9
+    assert _device_bytes(step) < HBM_BYTES
+    row = on_chip(jax.eval_shape(lambda: decode.init_cache(cfg, 1, 16384)))
+    chunk = eng._prefill_chunk.lower(
+        params, jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip),
+        row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile()
+    assert executable_stats(chunk)["pallas_calls"] == 2
+    assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
+# one call of ``held_expert_ffn`` as the four expert cells make it, decode
 # step and prefill chunk: tokens, M, F, held of experts, a token's, first
 EXPERT_CALLS = {
     "sdar.decode": (64, 2048, 768, 128, 128, 8, 0, "swiglu"),
@@ -344,6 +402,8 @@ EXPERT_CALLS = {
     "nemotron.chunk": (512, 1024, 2688, 128, 512, 22, 128, "relu2"),
     "openpangu.decode": (16, 7680, 2048, 16, 256, 8, 16, "swiglu"),
     "openpangu.chunk": (512, 7680, 2048, 16, 256, 8, 16, "swiglu"),
+    "smallthinker.decode": (24, 2560, 768, 64, 64, 6, 0, "reglu"),
+    "smallthinker.chunk": (512, 2560, 768, 64, 64, 6, 0, "reglu"),
 }
 
 
@@ -364,7 +424,7 @@ def test_held_expert_kernel_compiles_at_the_cells_widths(one_chip, call):
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    names = ("we_up", "we_down") + (("we_gate",) * (form == "swiglu"))
+    names = ("we_up", "we_down") + (("we_gate",) * (form != "relu2"))
     experts = {n: on_chip((6, held) + ((F, M) if n == "we_down" else (M, F)),
                           jnp.bfloat16) for n in names}
     compiled = jax.jit(
