@@ -101,6 +101,17 @@ own boolean a position. ``Result.unmask_steps`` says in which pass each
 token was unmasked. Speculation, ``kv_pages``, park/resume and
 handed-over bundles raise by name for such a model.
 
+**The sampler under a gate**: every decode program takes per-row
+sampling parameters as vectors (one program whatever mix of requests
+holds the slots) and holds ``sample_logits`` under ``lax.cond`` on a
+traced fact of the call, ``any(active & (temperature > 0))``: a call
+whose LIVE rows are all greedy takes ``argmax(logits)`` and skips the
+sort of ``[slots, vocab]``, the softmax and the draws; one with any live
+sampling row runs the sampler whole, so sampled streams are what they
+were and greedy rows beside them too. ``active`` is in the predicate
+because an empty slot's default temperature is 1.0. The ``decode_block``
+span's ``sampling_rows`` is the host's count of the same rows.
+
 **State beside rows** (DESIGN.md §23.5): a model's cache tree may hold,
 beside rows ``[L, B, len, ...]``, STATE that no token position addresses
 (``models.decode.cache_state``: a linear-attention layer's matrix).
@@ -783,6 +794,19 @@ class InferenceEngine:
                 )
             )(seeds, counts)
 
+        def _draw(sampling, logits, seeds, counts, temperature, top_k,
+                  top_p):
+            # the sampler under its gate (module docstring): `sampling`
+            # is a traced fact of the call. A greedy row's token is the
+            # same on both branches: masking entries below the maximum
+            # never moves the first index of the maximum
+            return lax.cond(
+                sampling,
+                lambda: sample_logits(
+                    logits, _row_keys(seeds, counts), temperature,
+                    top_k, top_p),
+                lambda: jnp.argmax(logits, -1).astype(jnp.int32))
+
         def _step_block(params, cache, last, seeds, counts,
                         temperature, top_k, top_p, active, eos_ids,
                         remaining, n_steps):
@@ -796,12 +820,14 @@ class InferenceEngine:
             # ``remaining`` the same way (its pos never passes prompt +
             # max_new_tokens, so neither its page lease nor max_len),
             # and the host reads none of its tokens from there on.
+            # `active` is in the predicate because an EMPTY slot's
+            # default temperature is 1.0 (`_sampling_tensors`).
+            sampling = jnp.any(active & (temperature > 0))
+
             def body(carry, i):
                 cache, last, done = carry
-                nxt = sample_logits(
-                    last, _row_keys(seeds, counts + i), temperature,
-                    top_k, top_p,
-                )
+                nxt = _draw(sampling, last, seeds, counts + i,
+                            temperature, top_k, top_p)
                 nxt = jnp.where(done, jnp.maximum(eos_ids, 0), nxt)
                 hit = (eos_ids >= 0) & (nxt == eos_ids)
                 # inactive/finished rows must not advance (their pos
@@ -856,10 +882,9 @@ class InferenceEngine:
             # correction bit-identically AND writes its KV. An eos
             # inside the accepted window truncates it, §23-style.
             n = guesses.shape[1]
-            x0 = sample_logits(
-                last, _row_keys(seeds, counts), temperature, top_k,
-                top_p,
-            )
+            sampling = jnp.any(active & (temperature > 0))
+            x0 = _draw(sampling, last, seeds, counts, temperature,
+                       top_k, top_p)
             fed = jnp.concatenate(
                 [x0[:, None], jnp.maximum(guesses[:, 1:], 0)], axis=1
             )
@@ -868,10 +893,9 @@ class InferenceEngine:
                                            zero_counters(cache), cfg)
             toks = [x0]
             for i in range(1, n):
-                toks.append(sample_logits(
-                    logits[:, i - 1], _row_keys(seeds, counts + i),
-                    temperature, top_k, top_p,
-                ))
+                toks.append(_draw(
+                    sampling, logits[:, i - 1], seeds, counts + i,
+                    temperature, top_k, top_p))
             toks = jnp.stack(toks, axis=1)              # [slots, n]
             match = (guesses[:, 1:] == toks[:, 1:]).astype(jnp.int32)
             run = jnp.cumprod(match, axis=1).sum(axis=1)
@@ -1831,6 +1855,14 @@ class InferenceEngine:
                             jnp.asarray(top_p), jnp.asarray(eos))
         return self._samp_cache
 
+    def _sampling_rows(self) -> int:
+        """Live rows that sample (``temperature`` > 0 as the programs
+        compare it, in float32): the decode programs run the sampler
+        when this is over 0 and take the argmax when it is 0."""
+        return sum(req is not None
+                   and bool(np.float32(req.params.temperature) > 0)
+                   for req in self._active)
+
     def _remaining(self) -> np.ndarray:
         """Tokens each slot's budget still allows, [slots] int32 (an
         empty slot: 0)."""
@@ -2025,6 +2057,7 @@ class InferenceEngine:
         if not active_mask.any():
             return fields, 0
         decoding = int(active_mask.sum())
+        sampling_rows = self._sampling_rows()
         temp, top_k, top_p, eos_ids = self._sampling_tensors()
         args = (
             self.params, self._cache, self._last,
@@ -2035,13 +2068,14 @@ class InferenceEngine:
         plan = self._spec_plan() if self._spec else None
         if self._diffusion:
             n_steps, toks, counts, steps, wait_s = self._denoise_call(
-                args, active_mask, decoding)
+                args, active_mask, decoding, sampling_rows)
         elif plan is not None:
             depth, guesses = plan
             n_steps = depth
             fn = self._aot_verify.get(depth, self._verify_block)
             with hot_span("decode_block", slots=decoding,
-                          n_steps=depth) as span:
+                          n_steps=depth,
+                          sampling_rows=sampling_rows) as span:
                 toks_dev, cache, last, acc_dev, counted = fn(
                     *args, jnp.asarray(guesses))
                 (toks_sn, acc, counted), wait_s = _fetch(
@@ -2068,7 +2102,8 @@ class InferenceEngine:
             frozen = decoding * block - int(counts.sum())
             args += (jnp.asarray(remaining),)
             with hot_span("decode_block", slots=decoding, n_steps=block,
-                          frozen_row_steps=frozen) as span:
+                          frozen_row_steps=frozen,
+                          sampling_rows=sampling_rows) as span:
                 if block == self.decode_block and self._aot_step is not None:
                     toks_dev, cache, last, counted = self._aot_step(*args)
                 else:
@@ -2093,7 +2128,8 @@ class InferenceEngine:
             self._emit(toks, counts, steps)
         return fields, sum(r is not None for r in self._active)
 
-    def _denoise_call(self, args, active_mask, decoding: int):
+    def _denoise_call(self, args, active_mask, decoding: int,
+                      sampling_rows: int):
         """One block-diffusion decode call: ``(forward passes run, tokens
         [most a row, slots], how many each row generated, the pass that
         unmasked each, the seconds the host waited for the device)``.
@@ -2113,7 +2149,8 @@ class InferenceEngine:
         n_steps = n_blocks * (T + 1)
         with hot_span("decode_block", slots=decoding, n_steps=n_steps,
                       blocks=n_blocks, denoise_passes=n_blocks * T,
-                      store_passes=n_blocks) as span:
+                      store_passes=n_blocks,
+                      sampling_rows=sampling_rows) as span:
             toks_dev, steps_dev, cache, counted = self._denoise_blocks(
                 params, cache, jnp.asarray(tokens0), jnp.asarray(masked0),
                 *rest, n_blocks=n_blocks)

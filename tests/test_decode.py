@@ -570,6 +570,30 @@ class TestSampling:
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(jnp.argmax(logits, axis=-1)))
 
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
+    def test_a_greedy_rows_token_is_the_plain_argmax_under_any_filter(
+            self, seed):
+        """What the engine's gate rests on (ISSUE 42): for a row at
+        temperature 0 the per-row sampler returns the FIRST index of the
+        maximum of the logits as given, whatever its top-k and top-p
+        mask below it, ties, ``-inf`` entries and an all ``-inf`` row
+        included."""
+        from dlrover_tpu.models.decode import sample_logits
+
+        rng = np.random.default_rng(seed)
+        B, V = 6, 96
+        logits = np.round(rng.normal(size=(B, V)) * 2).astype(np.float32)
+        logits[rng.random((B, V)) < 0.2] = -np.inf
+        logits[0, rng.choice(V, 3, replace=False)] = 9.0   # a tied maximum
+        logits[1] = -np.inf
+        keys = jax.random.split(jax.random.PRNGKey(seed), B)
+        out = sample_logits(
+            jnp.asarray(logits), keys, jnp.zeros((B,), jnp.float32),
+            jnp.asarray(rng.integers(0, V + 2, B), jnp.int32),
+            jnp.asarray(rng.choice([1.0, 0.9, 0.3, 1e-3], B), jnp.float32))
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.argmax(logits, axis=-1))
+
     def test_generate_eos_pads_finished_rows(self):
         from dlrover_tpu.models.decode import generate
 

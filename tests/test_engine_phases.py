@@ -217,6 +217,40 @@ def test_a_page_blocked_heads_tries_lie_in_its_queue_wait(
     assert eng.kv_page_ledger()["ok"] and len(returned) > 3
 
 
+# ------------------------------------------- which calls ran the sampler
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("site", SITES)
+def test_decode_block_counts_the_live_rows_that_sample(
+        site, journal_dir, monkeypatch):
+    """ISSUE 42: ``decode_block`` at its three sites carries
+    ``sampling_rows``, the live rows with ``temperature`` > 0: 0 on every
+    call of a greedy run (a free slot's default temperature is 1.0 and
+    does not count), 1 while one sampling request lives, 0 once it has
+    retired."""
+    eng = build(site, monkeypatch, slots=4)
+    serve(eng)                     # three requests: a slot stays free
+    greedy_run = ended(events_of(journal_dir), "decode_block")
+    assert greedy_run and all(e["slots"] < 4 for e in greedy_run)
+    assert [e["sampling_rows"] for e in greedy_run] == [0] * len(greedy_run)
+    sampling = eng.submit(CYCLIC[1], E.SamplingParams(
+        temperature=0.7, max_new_tokens=4, seed=5))
+    eng.submit(CYCLIC[0], E.SamplingParams(temperature=0.0,
+                                           max_new_tokens=24))
+    lived = []
+    while eng.outstanding:
+        eng.step()
+        lived.append(any(r is not None and r.id == sampling
+                         for r in eng._active))
+    rows = [e["sampling_rows"] for e in ended(
+        events_of(journal_dir), "decode_block")[len(greedy_run):]]
+    assert rows == sorted(rows, reverse=True)
+    assert set(rows) == {1, 0} and rows.count(1) <= 1 + sum(lived)
+    if site == "verify":
+        assert eng.spec_steps_total > 0
+
+
 # ------------------------------------------------- nothing on, nothing written
 
 

@@ -9,6 +9,7 @@ lengths join and leave the batch mid-flight.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import re
 
@@ -987,17 +988,22 @@ def _serve(eng, requests):
     return [done[i] for i in ids]
 
 
-def _all_eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside it (the
-    jitted call, the step loop, the layer loop, branches)."""
+def _eqns_under(jaxpr, under=()):
+    """``(enclosing equations, equation)`` for every equation of a jaxpr
+    and of the jaxprs inside it (the jitted call, the step loop, the
+    layer loop, branches)."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        yield under, eqn
         for value in eqn.params.values():
             for inner in (value if isinstance(value, (tuple, list))
                           else (value,)):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    yield from _all_eqns(inner)
+                    yield from _eqns_under(inner, under + (eqn,))
+
+
+def _all_eqns(jaxpr):
+    return (eqn for _, eqn in _eqns_under(jaxpr))
 
 
 def _weight_conversions(fn, params, *args, **static):
@@ -1221,3 +1227,209 @@ def test_aot_digest_names_the_stack_contract(params, monkeypatch, kind):
                             "numerics", *facts}
     assert aot.key == key(ours)
     assert key(parents) != key(ours)
+
+
+# ------------------------------------- ISSUE 42: an all-greedy call skips
+# the sampler. `_step_block` and `_verify_block` put `sample_logits` under
+# `lax.cond(any(active & (temperature > 0)), ...)`: the tokens are what the
+# ungated sampler gives, bit for bit.
+
+GATED = ["step_block_1", "step_block_8", "verify_block"]
+LIVE = [True, True, True, False]        # the last slot is EMPTY
+
+
+class _TodaysLax:
+    """``jax.lax`` with a ``cond`` that always takes its first branch:
+    an engine traced under it runs ``sample_logits`` on every call and
+    for every row, which is the program every engine had before."""
+
+    def __getattr__(self, name):
+        return getattr(jax.lax, name)
+
+    @staticmethod
+    def cond(pred, true_fn, false_fn, *operands):
+        return true_fn(*operands)
+
+
+@contextlib.contextmanager
+def _ungated(monkeypatch):
+    """A context in which the engine's programs are traced ungated."""
+    from dlrover_tpu.serving import engine as engine_mod
+
+    with monkeypatch.context() as patch:
+        patch.setattr(engine_mod, "lax", _TodaysLax())
+        yield
+
+
+def _gate_args(eng, mixed: bool) -> tuple:
+    """The decode programs' arguments with crafted logits: ties (at the
+    maximum too), ``-inf`` entries, three live rows and an EMPTY slot
+    that holds what `_sampling_tensors` gives one: ``temperature`` 1.0.
+    ``mixed``: live row 1 samples (0.8, top-k 40, top-p 0.9)."""
+    rng = np.random.default_rng(42)
+    V = CFG.vocab_size
+    last = np.round(rng.normal(size=(4, V)) * 3).astype(np.float32) / 2
+    last[rng.random((4, V)) < 0.15] = -np.inf
+    for row in range(3):          # the maximum twice in every live row
+        last[row, rng.choice(V, 2, replace=False)] = 7.0
+    temp = np.array([0.0, 0.8 if mixed else 0.0, 0.0, 1.0], np.float32)
+    top_k = np.array([0, 40 if mixed else 0, 0, 0], np.int32)
+    top_p = np.array([1.0, 0.9 if mixed else 1.0, 1.0, 1.0], np.float32)
+    cache = jax.tree.map(jnp.copy, eng._cache)
+    cache["pos"] = jnp.asarray([5, 9, 2, 0], jnp.int32)
+    return (eng.params, cache, jnp.asarray(last),
+            jnp.asarray([11, 22, 33, 0], jnp.int32),      # seeds
+            jnp.asarray([0, 3, 7, 0], jnp.int32),         # draw indices
+            jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
+            jnp.asarray(LIVE), jnp.full((4,), -1, jnp.int32))
+
+
+def _run_gated_program(eng, program: str, mixed: bool):
+    """``(tokens [slots, steps], the rows' next logits, pos)``."""
+    args = _gate_args(eng, mixed)
+    if program == "verify_block":
+        guesses = np.random.default_rng(7).integers(
+            0, CFG.vocab_size, (4, 4)).astype(np.int32)
+        guesses[1, 0] = guesses[3, 0] = -1   # plain rows: one token
+        toks, cache, last, acc, _ = eng._verify_block(
+            *args, jnp.asarray(guesses))
+        return np.asarray(toks), np.asarray(last), np.asarray(
+            cache["pos"]), np.asarray(acc)
+    n = int(program.rsplit("_", 1)[1])
+    toks, cache, last, _ = eng._step_block(
+        *args, jnp.asarray([8, 8, 8, 0], jnp.int32), n_steps=n)
+    return np.asarray(toks).T, np.asarray(last), np.asarray(
+        cache["pos"])
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("mixed", [False, True],
+                         ids=["all_greedy", "one_row_samples"])
+@pytest.mark.parametrize("program", GATED)
+def test_gated_sampler_gives_the_ungated_samplers_tokens(
+        params, monkeypatch, program, mixed):
+    """ISSUE 42 (a): on logits with ties, ``-inf`` entries and an EMPTY
+    slot at ``temperature`` 1.0, the gated programs return what the
+    ungated ones do for every LIVE row (all rows when one samples: the
+    call then takes the sampler whole), and their first tokens are
+    ``sample_logits``' own with the same seeds and draw indices."""
+    from dlrover_tpu.models.decode import sample_logits
+
+    sizes = dict(slots=4, max_len=64, prefill_len=8, decode_block=8)
+    with _ungated(monkeypatch):
+        want = _run_gated_program(
+            InferenceEngine(params, CFG, **sizes), program, mixed)
+    eng = InferenceEngine(params, CFG, **sizes)
+    got = _run_gated_program(eng, program, mixed)
+    rows = slice(None) if mixed else np.flatnonzero(LIVE)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g[rows], w[rows])
+    _, _, last, seeds, counts, temp, top_k, top_p, _, _ = _gate_args(
+        eng, mixed)
+    keys = jax.vmap(lambda s, c: jax.random.fold_in(
+        jax.random.fold_in(jax.random.PRNGKey(0), s), c))(seeds, counts)
+    first = np.asarray(jax.jit(sample_logits)(last, keys, temp, top_k,
+                                              top_p))
+    np.testing.assert_array_equal(got[0][rows, 0], first[rows])
+    if not mixed:
+        # the EMPTY slot's temperature did not buy the call a sampler:
+        # its row too is the plain argmax (first index of a tied maximum)
+        np.testing.assert_array_equal(
+            got[0][:, 0], np.argmax(np.asarray(last), axis=-1))
+
+
+def _top_level_sources(jaxpr, var) -> set[int]:
+    """Indices of ``jaxpr``'s inputs that ``var`` is computed from (an
+    equation's outputs read all of its inputs)."""
+    made_by = {out: eqn for eqn in jaxpr.eqns for out in eqn.outvars}
+    inputs = {v: i for i, v in enumerate(jaxpr.invars)}
+    found, todo, seen = set(), [var], set()
+    while todo:
+        v = todo.pop()
+        if not hasattr(v, "count") or v in seen:    # a literal
+            continue
+        seen.add(v)
+        if v in inputs:
+            found.add(inputs[v])
+        elif v in made_by:
+            todo.extend(made_by[v].invars)
+    return found
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("program", ["_step_block", "_verify_block"])
+def test_every_sort_of_a_decode_program_sits_under_the_gate(params,
+                                                            program):
+    """ISSUE 42 (b): in the decode programs' jaxprs every ``sort`` is
+    inside a ``cond`` branch, none outside, and the branch index is
+    computed from ``active`` and ``temperature``."""
+    eng = InferenceEngine(params, CFG, slots=4, max_len=64,
+                          prefill_len=8, decode_block=8)
+    if program == "_step_block":
+        args, static = eng._block_sample_args(), {"n_steps": 8}
+    else:
+        args = eng._step_sample_args() + (
+            jnp.full((4, 4), -1, jnp.int32),)
+        static = {}
+    top = getattr(eng, program).trace(*args, **static).jaxpr.jaxpr
+    leaves = jax.tree_util.tree_leaves(args)
+    active = next(i for i, leaf in enumerate(leaves) if leaf is args[8])
+    temperature = next(i for i, leaf in enumerate(leaves)
+                       if leaf is args[5])
+    found = [under for under, eqn in _eqns_under(top)
+             if eqn.primitive.name == "sort"]
+    assert found                       # the sampler is still in there
+    for under in found:
+        names = [e.primitive.name for e in under]
+        assert "cond" in names, names
+        # the gate's index, followed out through the step loop (a
+        # scan's body reads its operands in the equation's own order)
+        loops = under[:names.index("cond")]
+        var = under[len(loops)].invars[0]
+        for loop in reversed(loops):
+            [i] = _top_level_sources(loop.params["jaxpr"].jaxpr, var)
+            var = loop.invars[i]
+        assert {active, temperature} <= _top_level_sources(top, var)
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["all_greedy", "one_request_samples"])
+def test_decode_block_span_counts_the_live_rows_that_sample(
+        params, journal_dir, monkeypatch, sampled):
+    """ISSUE 42 (c): ``sampling_rows`` on the ``decode_block`` span is
+    the program's own predicate counted (same mask, same comparison): 0
+    on every call of a greedy run with a free slot, 1 while a sampling
+    request lives and 0 once it has retired; and every stream, the
+    seeded sampled one too, is the ungated engine's."""
+    from dlrover_tpu.telemetry.report import load_events
+
+    reqs = [([5, 9, 2], SamplingParams(temperature=0.0,
+                                       max_new_tokens=30)),
+            ([7, 7, 1], SamplingParams(
+                temperature=0.8 if sampled else 0.0, top_k=40, top_p=0.9,
+                max_new_tokens=10, seed=1234)),
+            ([3], SamplingParams(temperature=0.0, max_new_tokens=20))]
+    sizes = dict(slots=4, max_len=64, prefill_len=8, decode_block=8)
+    with _ungated(monkeypatch):
+        want = _serve(InferenceEngine(params, CFG, **sizes), reqs)
+    eng = InferenceEngine(params, CFG, **sizes)
+    program_saw, orig = [], eng._step_block
+
+    def spy(*a, n_steps):
+        # the predicate's terms as the program receives them
+        temperature, active = np.asarray(a[5]), np.asarray(a[8])
+        assert temperature[~active].tolist() == [1.0] * int(
+            (~active).sum())
+        program_saw.append(int(np.sum(active & (temperature > 0))))
+        return orig(*a, n_steps=n_steps)
+
+    eng._step_block = spy
+    assert _serve(eng, reqs) == want
+    blocks = [e for e in load_events(str(journal_dir / "events.jsonl"))
+              if e["ev"] == "b" and e["name"] == "decode_block"]
+    rows = [e["sampling_rows"] for e in blocks[-len(program_saw):]]
+    assert rows == program_saw
+    # 10 tokens in blocks of 8: two calls hold the sampling row
+    assert rows == ([1, 1] if sampled else [0, 0]) + [0] * (len(rows) - 2)
+    assert len(rows) >= 4 and all(e["slots"] < 4 for e in blocks)
