@@ -9,12 +9,19 @@ calling the full forward per step.
 
 The cache is the model's TREE (DESIGN.md §23.5): a position, ROWS
 (stacks laid out ``[L, B, len, ...]``, a position axis third: one per K
-and V, ``[L, B, max_len, H_kv, D]``, for per-head attention; one latent
+and V, ``[L, B, max_len, H_kv * D]``, for per-head attention: a key's
+heads side by side, so the default layout is row-major with full
+128-lane tiles and a head of 64 is not padded; one latent
 stack for ``attn_kind='latent'``, models/latent.py; ``k``, ``v`` and a
 shorter stack of compressed keys for ``attn_kind='mixers'``,
 models/hybrid.py) and, under ``state``, stacks ``[L, B, ...]`` that no
 token position addresses (a linear-attention layer's matrix). Stacks
-may differ in their layer count and rows in their length. A WINDOWED
+may differ in their layer count and rows in their length. On a TPU a
+decode call of the plain per-head tree (rows at positions of their own,
+few queries a row) reads its rows through ONE kernel that is given each
+query's key limit and fetches a row's key blocks IN PLACE only as far as
+the row reaches (``ops/cached_attention.py``); every other call takes
+the einsum :func:`_layer_attend`, which is also the kernel's oracle. A WINDOWED
 layer's rows (``cfg.layer_windows``) are a RING of ``window`` slots,
 ``k_win`` / ``v_win`` ``[L_win, B, H_kv, window, D]``: position ``p``
 lives in slot ``p % window``, and the ring is STATE to everyone outside
@@ -57,11 +64,14 @@ Params = Any
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
     """The model's cache TREE (DESIGN.md §23.5): ``pos``, rows laid
     out ``[L, B, len, ...]`` under names of the model's choosing
-    (``k`` and ``v`` per head here; one ``latent`` stack for
+    (``k`` and ``v`` ``[L, B, max_len, H_kv * D]`` here, a key's heads
+    side by side on the last axis; one ``latent`` stack for
     ``attn_kind='latent'``), optionally ``state`` (stacks ``[L, B, ...]``
-    with no position axis) and ``counters``. Callers carry
+    with no position axis) and ``counters`` (the plain tree's
+    `READ_COUNTERS`, the expert layers', the rings'). Callers carry
     it whole and name none of its stacks: :func:`cache_stacks`,
-    :func:`cache_state`.
+    :func:`cache_state`. Only this file and ``ops/cached_attention.py``
+    read a row's trailing dims as heads.
 
     ``cfg.layer_windows`` / ``cfg.layer_rope``: the full layers' rows are
     ``k``, ``v`` ``[L_full, B, H_kv, max_len, D]``; the windowed layers'
@@ -88,20 +98,26 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
 
         return latent.init_cache(c, batch, max_len)
     n_win = sum(1 for w in c.layer_windows if w)
-    shape = (c.n_layers - n_win, batch, max_len, c.n_kv_heads, c.head_dim)
+    # a key's heads side by side (above)
+    shape = (c.n_layers, batch, max_len, c.n_kv_heads * c.head_dim)
     if c.layer_kinds:
         # heads before positions (below)
-        shape = (*shape[:2], c.n_kv_heads, max_len, c.head_dim)
+        shape = (c.n_layers - n_win, batch, c.n_kv_heads, max_len,
+                 c.head_dim)
     cache = {
         "k": jnp.zeros(shape, jnp.dtype(c.dtype)),
         "v": jnp.zeros(shape, jnp.dtype(c.dtype)),
         "pos": jnp.zeros((), jnp.int32),
     }
+    if not c.layer_kinds:
+        cache["counters"] = {
+            name: jnp.zeros((), jnp.int32) for name in READ_COUNTERS}
     if c.held_experts:
         from dlrover_tpu.ops.moe import held_counters
 
-        cache["counters"] = held_counters(
-            c.n_layers, tfm.routed_config(c).n_held)
+        cache["counters"] = {
+            **cache.get("counters", {}),
+            **held_counters(c.n_layers, tfm.routed_config(c).n_held)}
     if n_win:
         ring = (n_win, batch, c.n_kv_heads, max(c.layer_windows), c.head_dim)
         cache["state"] = {"k_win": jnp.zeros(ring, jnp.dtype(c.dtype)),
@@ -111,6 +127,13 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
             **{name: jnp.zeros((), jnp.int32) for name in WINDOW_COUNTERS}}
     return cache
 
+
+# what the plain tree counts of each row of each call (a rows-only model
+# is not told which rows are frozen or idle, so every row counts, as
+# `expert_tokens` does): the keys attention fetched of the row (the live
+# key blocks' widths through `ops/cached_attention.py`, `max_len` through
+# the einsum) and the row's key limit, what the floor would read
+READ_COUNTERS = ("attn_keys_read", "context_tokens")
 
 # what a tree with rings counts of its live row-steps (a row-step: one
 # real token of one row in one call): how many there were, their
@@ -211,7 +234,7 @@ def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0, block=0):
 
 def _write_rows(stack, new, layer, pos):
     """Write ``new`` [B, S_new, ...] into ``stack`` [L, B, max_len, ...]
-    (per-head rows ``[H_kv, D]`` or one latent row: any trailing dims)
+    (per-head rows ``[H_kv * D]`` or one latent row: any trailing dims)
     at ``[layer, b, pos[b] : pos[b] + S_new]``, in place where
     the stack is a loop's carry: the update is the new rows alone,
     never a layer. Rows in lockstep (scalar ``pos``) take one
@@ -434,16 +457,44 @@ def forward_cached(
     window = c.attention_window if c.attention == "splash" else 0
     block = c.block_length if c.generation == "block_diffusion" else 0
 
+    max_len = cache["k"].shape[2]
+    # one behind the last key each query sees (`_layer_attend`'s masks)
+    q_pos = (jnp.broadcast_to(pos, (B,)).astype(jnp.int32)[:, None]
+             + jnp.arange(S_new, dtype=jnp.int32)[None])
+    limits = (q_pos // block + 1) * block if block else q_pos + 1
+    # the kernel takes a decode call of rows at positions of their own,
+    # on a TPU, where the shapes allow it (`cached_attention.takes`: a
+    # prefill chunk is too wide); everything else takes the einsum
+    walk = None
+    if jax.default_backend() == "tpu" and jnp.ndim(pos) == 1 and not window:
+        # imported where it runs: Pallas costs a process 1.4 s to import
+        from dlrover_tpu.ops import cached_attention
+
+        if cached_attention.takes((B, S_new, c.n_heads, c.head_dim),
+                                  cache["k"].shape, n_rep, dt.itemsize):
+            # which key blocks of which rows: the same for every layer
+            walk = cached_attention.walk(
+                limits, cache["k"].shape, dt.itemsize, c.n_kv_heads, n_rep)
+
+    def rows_of(new):
+        return new.astype(dt).reshape(B, S_new, -1)
+
+    def heads_of(stack, l):
+        return lax.dynamic_index_in_dim(stack, l, keepdims=False).reshape(
+            B, max_len, c.n_kv_heads, c.head_dim)
+
     def attend(q, k, v, state):
         k_stack, v_stack, l = state
         with jax.named_scope("kv_write"):
-            k_stack = _write_rows(k_stack, k.astype(dt), l, pos)
-            v_stack = _write_rows(v_stack, v.astype(dt), l, pos)
-        o = _layer_attend(
-            q, lax.dynamic_index_in_dim(k_stack, l, keepdims=False),
-            lax.dynamic_index_in_dim(v_stack, l, keepdims=False),
-            pos, n_rep, dt, window=window, block=block,
-        )
+            k_stack = _write_rows(k_stack, rows_of(k), l, pos)
+            v_stack = _write_rows(v_stack, rows_of(v), l, pos)
+        if walk is not None:
+            with jax.named_scope("kv_read"):
+                o = cached_attention.cached_attention(
+                    q, k_stack, v_stack, l, walk, n_rep=n_rep)
+        else:
+            o = _layer_attend(q, heads_of(k_stack, l), heads_of(v_stack, l),
+                              pos, n_rep, dt, window=window, block=block)
         return o, (k_stack, v_stack, l)
 
     # the held experts' stacks are closed over the block and indexed in
@@ -471,10 +522,19 @@ def forward_cached(
     with jax.named_scope("lm_head"):
         logits = tfm.lm_logits(params, tfm.final_norm(params, x, c), c)
     new = {"k": k_new, "v": v_new, "pos": pos + S_new}
+    if "counters" not in cache:     # a tree made by hand counts nothing
+        return logits, new
+    old = cache["counters"]
+    reach = jnp.minimum(jnp.max(limits, axis=1), max_len)
+    read = jnp.full((B,), max_len) if walk is None else walk.keys_read
+    new["counters"] = {
+        "attn_keys_read": old["attn_keys_read"]
+        + jnp.sum(read).astype(jnp.int32),
+        "context_tokens": old["context_tokens"] + jnp.sum(reach)}
     if c.held_experts:
         from dlrover_tpu.ops.moe import count_loads
 
-        new["counters"] = count_loads(cache["counters"], loads)
+        new["counters"].update(count_loads(old, loads))
     return logits, new
 
 

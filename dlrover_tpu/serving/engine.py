@@ -36,7 +36,8 @@ measured into the ``dlrover_tpu_engine_decode_stall_seconds`` histogram
 an ``engine_admit`` journal instant.
 
 **Paged KV slots** (``kv_pages > 0``): a physical page pool
-``[L, pages, page_size, kv_heads, head_dim]`` backs the dense decode
+``[L, pages, page_size, kv_heads * head_dim]`` (the dense stacks'
+trailing dims) backs the dense decode
 cache. Admission reserves ``ceil((prompt+max_new)/page_size)`` pages —
 capacity is a page ledger, not a dense-slot count — and a long-running
 generation can be PARKED (its dense row scattered to its pages through
@@ -558,15 +559,17 @@ class InferenceEngine:
         if self._paging and cfg.attn_kind != "heads":
             raise NotImplementedError(
                 f"kv_pages > 0 with attn_kind {cfg.attn_kind!r}: the paged "
-                "store, park/resume and copy-on-write hold [kv_heads, "
-                "head_dim] pages; a latent cache has none (run it with "
+                "store, park/resume and copy-on-write hold per-head [kv_heads "
+                "* head_dim] pages; a latent cache has none (run it with "
                 "kv_pages=0)")
         if self._paging:
-            c = cfg
-            pool_shape = (c.n_layers, self.kv_pages + 1, self.page_size,
-                          c.n_kv_heads, c.head_dim)
-            self._kpool = jnp.zeros(pool_shape, jnp.dtype(c.dtype))
-            self._vpool = jnp.zeros(pool_shape, jnp.dtype(c.dtype))
+            # a page holds `page_size` positions of a row as the stacks
+            # hold them: their trailing dims, whatever those are
+            rows = tree["k"]
+            pool_shape = (rows.shape[0], self.kv_pages + 1, self.page_size,
+                          *rows.shape[3:])
+            self._kpool = jnp.zeros(pool_shape, rows.dtype)
+            self._vpool = jnp.zeros(pool_shape, rows.dtype)
             self._free_pages: list[int] = list(
                 range(1, self.kv_pages + 1))
         else:

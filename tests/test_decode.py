@@ -137,7 +137,7 @@ class TestStackIsCarriedAndWrittenInPlace:
         jaxpr = jax.make_jaxpr(
             lambda p, t, c: forward_cached(p, t, c, cfg)
         )(params, tokens, cache).jaxpr
-        stack = cache["k"].shape       # [L, B, max_len, Hkv, D]
+        stack = cache["k"].shape       # [L, B, max_len, Hkv * D]
         layer = stack[1:]
         loops = [e for e in jaxpr.eqns if e.primitive.name == "scan"
                  and e.params["length"] == cfg.n_layers]
@@ -156,8 +156,8 @@ class TestStackIsCarriedAndWrittenInPlace:
         assert len([v for v in carry
                     if tuple(v.aval.shape) == stack]) == 2   # K and V
         # and inside the loop whatever yields a stack is an in-place
-        # write of new rows alone: one [1, B, S_new, Hkv, D] update for
-        # rows in lockstep, one [1, 1, S_new, Hkv, D] a row otherwise
+        # write of new rows alone: one [1, B, S_new, Hkv * D] update for
+        # rows in lockstep, one [1, 1, S_new, Hkv * D] a row otherwise
         body = loop.params["jaxpr"].jaxpr
         writes = [e for e in body.eqns
                   if any(tuple(getattr(v.aval, "shape", ())) == stack

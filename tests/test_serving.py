@@ -1170,8 +1170,9 @@ def test_programs_that_return_the_stack_donate_it(params, program):
     assert f"{eng.slots}xi32" in donated                  # pos
     assert f"{eng.slots}x{CFG.vocab_size}xf32" in donated  # last
     # what a prefill holds (working rows, a row's logits) and the
-    # weights are never given away
-    assert len(donated) == 4, donated
+    # weights are never given away; the model's counters (scalars) ride
+    # the tree where a program passes them through
+    assert len([d for d in donated if d != "i32"]) == 4, donated
 
 
 @pytest.mark.timeout(300)

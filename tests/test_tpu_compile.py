@@ -393,6 +393,103 @@ def test_windowed_ring_serving_programs_compile(one_chip, monkeypatch):
     assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
 
 
+# the plain per-head tree's two cells: the model's widths (a smaller
+# vocabulary, few experts: neither is in attention's way), the engine's
+# slots and row length, and the stacks they give
+ATTENTION_CELLS = {
+    "gpt2-medium": (dict(n_layers=2), 16, 1024, (2, 16, 1024, 1024)),
+    "sdar-30b-a3b-chat": (dict(n_layers=2, n_routed_experts=8, moe_top_k=2,
+                               vocab_size=8192, mask_token_id=8000),
+                          16, 2560, (2, 16, 2560, 512)),
+}
+
+
+@pytest.mark.parametrize("cell", ATTENTION_CELLS)
+def test_decode_block_reads_the_stacks_in_place(one_chip, monkeypatch, cell):
+    """ISSUE 43: the decode program of the plain per-head tree, traced
+    as on a TPU at gpt2-medium's heads (16 x 64: a decode block's steps)
+    and at SDAR's (32 on 4 of 128: a block's denoising and storing
+    passes), holds the cached attention kernel
+    (``ops/cached_attention.py``) and no operation that copies or
+    re-lays a whole stack: the stacks are donated, written in place and
+    read in place, so beside them the program's temporaries stay far
+    under ONE stack (with positions before heads and the einsum the
+    compiler copied both stacks into the layout its products read at
+    the start of every call and back at its end: 3.2 GB of temporaries
+    at gpt2-medium's 24 layers)."""
+    from dlrover_tpu.serving import engine as serving
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    change, slots, max_len, stack = ATTENTION_CELLS[cell]
+    cfg = dataclasses.replace(tfm.CONFIGS[cell], **change)
+    params = jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))))
+    eng = serving.InferenceEngine(params, cfg, slots=slots, max_len=max_len,
+                                  prefill_len=64)
+    assert eng._cache["k"].shape == eng._cache["v"].shape == stack
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                np.shape(a), jnp.asarray(a).dtype, sharding=one_chip),
+            tree)
+
+    if cfg.generation == "block_diffusion":
+        weights, cache, _, *rest = on_chip(eng._step_sample_args())
+        block = jax.ShapeDtypeStruct((slots, cfg.block_length), jnp.int32,
+                                     sharding=one_chip)
+        lowered = eng._denoise_blocks.lower(
+            weights, cache, block,
+            jax.ShapeDtypeStruct(block.shape, jnp.bool_, sharding=one_chip),
+            *rest, n_blocks=1)
+    else:
+        lowered = eng._step_block.lower(
+            *on_chip(eng._block_sample_args()), n_steps=2)
+    compiled = lowered.compile(compiler_options=serving._CANONICAL_NUMERICS)
+    text = compiled.as_text()
+    assert executable_stats(compiled)["pallas_calls"] >= 1
+    assert "cached_decode_attention" in text
+    whole = "bf16[" + ",".join(map(str, stack)) + "]"
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if " copy(" in line and whole in line.split(" copy(")[0]]
+    assert not copies, copies
+    m = compiled.memory_analysis()
+    stack_bytes = int(np.prod(stack)) * 2
+    assert m.alias_size_in_bytes >= 2 * stack_bytes       # donated whole
+    assert m.temp_size_in_bytes < stack_bytes
+
+
+@pytest.mark.parametrize("call", ATTENTION_CELLS)
+def test_cached_attention_kernel_compiles_at_the_cells_heads(one_chip, call):
+    """The kernel alone under a traced layer index at each cell's decode
+    shape and at a verify block's (five causal queries a row), bfloat16
+    stacks of six layers: Mosaic takes the block shapes the rule gives,
+    and the stacks are read in place (no temporary the size of a
+    layer's rows)."""
+    from dlrover_tpu.ops.cached_attention import cached_attention, walk
+
+    cfg = tfm.CONFIGS[call]
+    _, slots, max_len, (_, _, _, lanes) = ATTENTION_CELLS[call]
+    n_rep = cfg.n_heads // cfg.n_kv_heads
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stack = on_chip((6, slots, max_len, lanes), jnp.bfloat16)
+    for queries in (1, cfg.block_length or 5):
+        compiled = jax.jit(
+            lambda q, k, v, layer, limits: cached_attention(
+                q, k, v, layer,
+                walk(limits, k.shape, 2, cfg.n_kv_heads, n_rep), n_rep=n_rep)
+        ).lower(on_chip((slots, queries, cfg.n_heads, cfg.head_dim),
+                        jnp.bfloat16), stack, stack, on_chip((), jnp.int32),
+                on_chip((slots, queries), jnp.int32)).compile()
+        assert executable_stats(compiled)["pallas_calls"] == 1
+        layer_bytes = slots * max_len * lanes * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes / 4
+
+
 # one call of ``held_expert_ffn`` as the four expert cells make it, decode
 # step and prefill chunk: tokens, M, F, held of experts, a token's, first
 EXPERT_CALLS = {
