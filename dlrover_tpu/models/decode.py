@@ -21,13 +21,11 @@ decode call of the plain per-head tree (rows at positions of their own,
 few queries a row) reads its rows through ONE kernel that is given each
 query's key limit and fetches a row's key blocks IN PLACE only as far as
 the row reaches (``ops/cached_attention.py``); every other call takes
-the einsum :func:`_layer_attend`, which is also the kernel's oracle. A WINDOWED
-layer's rows (``cfg.layer_windows``) are a RING of ``window`` slots,
-``k_win`` / ``v_win`` ``[L_win, B, H_kv, window, D]``: position ``p``
-lives in slot ``p % window``, and the ring is STATE to everyone outside
-this file (it is kept under ``state``): no one else may index its third
-axis by position. A stack is
-never copied whole (§23.1): the layer loop CARRIES it, layer ``l`` writes its
+the einsum ``cache.layer_attend``, which is also the kernel's oracle. A
+WINDOWED layer's rows (``cfg.layer_windows``) are a RING of ``window``
+slots (:func:`init_ring_cache`), STATE to everyone outside
+``models/cache.py``: no one else may index it by position. A stack is never
+copied whole (§23.1): the layer loop CARRIES it, layer ``l`` writes its
 ``S_new`` new rows into it in place (the update is the new rows alone)
 and attends over ``stack[l]`` read out of the carry. Nothing of a
 layer's shape is scanned in or out: a scanned input is sliced out
@@ -38,9 +36,11 @@ calls ``forward_cached`` and keeps no other reference to it
 
 The block, the embedding and the head are training's own
 (``models/transformer.py``: ``make_layer_fn``, ``embed_tokens``,
-``final_norm``, ``lm_logits``). What lives here is what a cached caller
-hands that block: the cache tree, the attention that writes a layer's
-new rows and reads its cache, and the positions. The equivalence test
+``final_norm``, ``lm_logits``), the loop over the stack's runs every
+family's (``transformer.scan_runs``). This file is the ENTRY, which asks
+``transformer.family`` once, and the family of training's block (rows, and
+rings beside rows): its cache tree and the ``attend`` hooks that write a
+layer's new rows and read its cache. The equivalence test
 (tests/test_decode.py) pins those: prefill+cached-decode logits must
 match ``forward`` on the same tokens to tolerance.
 """
@@ -48,7 +48,6 @@ match ``forward`` on the same tokens to tolerance.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any
 
 import jax
@@ -56,24 +55,51 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlrover_tpu.models import transformer as tfm
+from dlrover_tpu.models.cache import (  # noqa: F401  (the views are callers')
+    attend_heads_major, cache_counter_fields, cache_stacks, cache_state,
+    key_reaches, layer_attend, ring_attend, write_heads_major, write_rows,
+    zero_counters)
 from dlrover_tpu.models.transformer import PRODUCT_LEAVES, TransformerConfig
+from dlrover_tpu.ops.moe import count_loads, held_counters
 
 Params = Any
 
 
 def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
     """The model's cache TREE (DESIGN.md §23.5): ``pos``, rows laid
-    out ``[L, B, len, ...]`` under names of the model's choosing
-    (``k`` and ``v`` ``[L, B, max_len, H_kv * D]`` here, a key's heads
-    side by side on the last axis; one ``latent`` stack for
-    ``attn_kind='latent'``), optionally ``state`` (stacks ``[L, B, ...]``
-    with no position axis) and ``counters`` (the plain tree's
-    `READ_COUNTERS`, the expert layers', the rings'). Callers carry
-    it whole and name none of its stacks: :func:`cache_stacks`,
-    :func:`cache_state`. Only this file and ``ops/cached_attention.py``
-    read a row's trailing dims as heads.
+    out ``[L, B, len, ...]`` under names of the family's choosing,
+    optionally ``state`` (stacks ``[L, B, ...]`` with no position axis)
+    and ``counters``. Callers carry it whole and name none of its
+    stacks: :func:`cache_stacks`, :func:`cache_state`."""
+    return tfm.family(cfg).init_cache(cfg, batch, max_len)
 
-    ``cfg.layer_windows`` / ``cfg.layer_rope``: the full layers' rows are
+
+def _kv_cache(cfg, shape: tuple, counted: tuple) -> dict:
+    """``k`` and ``v`` of ``shape``, the position, the counters ``counted``
+    beside the held experts' (none: a tree that counts nothing)."""
+    c = cfg
+    counters = {name: jnp.zeros((), jnp.int32) for name in counted}
+    if c.held_experts:
+        counters = {**counters, **held_counters(
+            c.n_layers, tfm.routed_config(c).n_held)}
+    cache = {"k": jnp.zeros(shape, jnp.dtype(c.dtype)),
+             "v": jnp.zeros(shape, jnp.dtype(c.dtype)),
+             "pos": jnp.zeros((), jnp.int32)}
+    return {**cache, "counters": counters} if counters else cache
+
+
+def init_row_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
+    """Layers all of one kind: ``k`` and ``v`` ``[L, B, max_len, H_kv *
+    D]``, a key's heads side by side on the last axis (module docstring),
+    and `READ_COUNTERS` beside the expert layers'. Only :func:`forward_rows`
+    and ``ops/cached_attention.py`` read a row's trailing dim as heads."""
+    c = cfg
+    rows = (c.n_layers, batch, max_len, c.n_kv_heads * c.head_dim)
+    return _kv_cache(c, rows, READ_COUNTERS)
+
+
+def init_ring_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
+    """``cfg.layer_windows`` / ``cfg.layer_rope``: the full layers' rows are
     ``k``, ``v`` ``[L_full, B, H_kv, max_len, D]``; the windowed layers'
     are RINGS under ``state``, ``k_win``, ``v_win`` ``[L_win, B, H_kv,
     window, D]``, whose length follows from the window and never from
@@ -82,49 +108,21 @@ def init_cache(cfg: TransformerConfig, batch: int, max_len: int) -> dict:
     first the TPU compiler copies all the stacks into this layout at the
     start of every decode call and back at its end: 2.9 GB of temporaries
     for 24 slots of 16384, PERF.md section 6, PR 41); such a tree has
-    state, so nothing outside this file addresses a position of it. A
+    state, so nothing outside ``models/`` addresses a position of it. A
     ring holds the last ``window`` keys of its row, position ``p`` in
     slot ``p % window``; which position a slot holds
-    follows from the row's ``pos`` alone (:func:`_ring_positions`), so a
+    follows from the row's ``pos`` alone (``cache.ring_positions``), so a
     ring is valid only together with the ``pos`` it was left at: a
     holder that copies a row copies its rings whole, as it does state."""
     c = cfg
-    if c.mixers:
-        from dlrover_tpu.models import hybrid
-
-        return hybrid.init_cache(c, batch, max_len)
-    if c.new_kinds:
-        from dlrover_tpu.models import latent
-
-        return latent.init_cache(c, batch, max_len)
     n_win = sum(1 for w in c.layer_windows if w)
-    # a key's heads side by side (above)
-    shape = (c.n_layers, batch, max_len, c.n_kv_heads * c.head_dim)
-    if c.layer_kinds:
-        # heads before positions (below)
-        shape = (c.n_layers - n_win, batch, c.n_kv_heads, max_len,
-                 c.head_dim)
-    cache = {
-        "k": jnp.zeros(shape, jnp.dtype(c.dtype)),
-        "v": jnp.zeros(shape, jnp.dtype(c.dtype)),
-        "pos": jnp.zeros((), jnp.int32),
-    }
-    if not c.layer_kinds:
-        cache["counters"] = {
-            name: jnp.zeros((), jnp.int32) for name in READ_COUNTERS}
-    if c.held_experts:
-        from dlrover_tpu.ops.moe import held_counters
-
-        cache["counters"] = {
-            **cache.get("counters", {}),
-            **held_counters(c.n_layers, tfm.routed_config(c).n_held)}
+    cache = _kv_cache(
+        c, (c.n_layers - n_win, batch, c.n_kv_heads, max_len, c.head_dim),
+        WINDOW_COUNTERS if n_win else ())
     if n_win:
         ring = (n_win, batch, c.n_kv_heads, max(c.layer_windows), c.head_dim)
         cache["state"] = {"k_win": jnp.zeros(ring, jnp.dtype(c.dtype)),
                           "v_win": jnp.zeros(ring, jnp.dtype(c.dtype))}
-        cache["counters"] = {
-            **cache.get("counters", {}),
-            **{name: jnp.zeros((), jnp.int32) for name in WINDOW_COUNTERS}}
     return cache
 
 
@@ -143,240 +141,6 @@ READ_COUNTERS = ("attn_keys_read", "context_tokens")
 # had wrapped)
 WINDOW_COUNTERS = ("row_steps", "context_tokens", "window_keys",
                    "ring_wrapped_row_steps")
-
-
-def cache_stacks(cache: dict) -> dict:
-    """The ROWS of a cache tree, ``[L, B, len, ...]`` each (token
-    positions along the third axis; ``len`` is the cache's length or a
-    fixed fraction of it): all but the position, the state and the
-    counters."""
-    return {k: v for k, v in cache.items()
-            if k not in ("pos", "counters", "state")}
-
-
-def cache_state(cache: dict) -> dict:
-    """The STATE of a cache tree: stacks ``[L, B, ...]`` that no token
-    position addresses (empty for a model that keeps rows alone). What a
-    caller may do with both kinds alike is index the SECOND axis by row;
-    what assumes a position axis (pages, bundles, a draft's rejected
-    tail put back by its position) holds for rows only."""
-    return cache.get("state", {})
-
-
-def cache_counter_fields(cache: dict) -> dict:
-    """What a span says of a model's counters: their scalars, under the
-    names the model gave them (none for a model that counts nothing)."""
-    return {name: value for name, value in cache.get("counters", {}).items()
-            if jnp.ndim(value) == 0}
-
-
-def zero_counters(cache: dict) -> dict:
-    """``cache`` with its counters at zero: a program that reports them
-    a call at a time starts from here."""
-    if "counters" not in cache:
-        return cache
-    return {**cache, "counters": jax.tree.map(jnp.zeros_like,
-                                              cache["counters"])}
-
-
-def _layer_attend(q, k_cache, v_cache, pos, n_rep, dt, window=0, block=0):
-    """q: [B, S_new, H, D] against cache [B, max_len, H_kv, D].
-
-    GQA reads the cache UNEXPANDED via a grouped-head einsum — repeating
-    it to H heads would multiply per-token decode memory traffic by
-    ``n_rep`` on the hot path. ``window > 0`` applies the sliding-window
-    mask so decode matches a model trained with local attention.
-    ``pos`` scalar: all rows in lockstep (one [S, K] mask). [B] vector:
-    independent per-row positions (continuous batching,
-    serving/engine.py) with a [B, S, K] mask. ``block > 0`` is a
-    block-diffusion model's mask in place of the causal one: a query
-    sees every key up to the END of its own block of ``block`` absolute
-    positions (``k < (q // block + 1) * block``), in every program: a
-    prefill chunk, a denoising pass, a storing pass.
-    """
-    B, S_new, H, D = q.shape
-    scale = 1.0 / math.sqrt(D)
-    G = k_cache.shape[2]  # kv heads
-    qg = q.reshape(B, S_new, G, n_rep, D)
-    with jax.named_scope("kv_read"):
-        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache).astype(
-            jnp.float32
-        ) * scale
-    max_len = k_cache.shape[1]
-    k_pos = jnp.arange(max_len)
-    if jnp.ndim(pos) == 0:
-        # causal over absolute positions: query i sits at pos + i
-        q_pos = pos + jnp.arange(S_new)
-        if block > 0:
-            mask = k_pos[None, :] < ((q_pos // block + 1) * block)[:, None]
-        else:
-            mask = q_pos[:, None] >= k_pos[None, :]        # [S, K]
-        if window > 0:
-            mask &= q_pos[:, None] - k_pos[None, :] < window
-        mask = mask[None, None, None]
-    else:
-        # row b's query i sits at pos[b] + i
-        q_pos = pos[:, None] + jnp.arange(S_new)[None]     # [B, S_new]
-        if block > 0:
-            mask = (k_pos[None, None, :]
-                    < ((q_pos // block + 1) * block)[:, :, None])
-        else:
-            mask = q_pos[:, :, None] >= k_pos[None, None, :]  # [B, S, K]
-        if window > 0:
-            mask &= q_pos[:, :, None] - k_pos[None, None, :] < window
-        mask = mask[:, None, None]
-    logits = jnp.where(mask, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1).astype(dt)
-    with jax.named_scope("kv_read"):
-        o = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_cache)
-    return o.reshape(B, S_new, H, D)
-
-
-def _write_rows(stack, new, layer, pos):
-    """Write ``new`` [B, S_new, ...] into ``stack`` [L, B, max_len, ...]
-    (per-head rows ``[H_kv * D]`` or one latent row: any trailing dims)
-    at ``[layer, b, pos[b] : pos[b] + S_new]``, in place where
-    the stack is a loop's carry: the update is the new rows alone,
-    never a layer. Rows in lockstep (scalar ``pos``) take one
-    ``dynamic_update_slice``; rows at positions of their own take one
-    each, unrolled: as ONE scatter the TPU compiler runs a loop over
-    the rows that costs 2.8 us a row (a block of 8 decode steps at 16
-    slots on a v5e: 97.2 ms against 81.6; PERF.md §6, PR 26). A start past
-    ``max_len - S_new`` is clamped so that the rows fit. A RING is not
-    written here: its write wraps and leaves pads out (:func:`_write_ring`),
-    and only this file may address a slot of it by position."""
-    rest = (0,) * (stack.ndim - 3)
-    if jnp.ndim(pos) == 0:
-        return lax.dynamic_update_slice(
-            stack, new[None], (layer, 0, pos, *rest))
-    for b in range(new.shape[0]):
-        stack = lax.dynamic_update_slice(
-            stack, new[None, b:b + 1], (layer, b, pos[b], *rest))
-    return stack
-
-
-def _heads_attend(q, k_rows, v_rows, mask, n_rep, dt):
-    """``q [B, S, H, D]`` over ``k_rows``, ``v_rows`` ``[B, H_kv, K, D]``
-    (key/value heads BEFORE the keys: the layout of a tree with rings,
-    ``init_cache``) under ``mask`` (broadcast to ``[B, G, n_rep, S, K]``):
-    :func:`_layer_attend`'s grouped-head softmax attention, the cache read
-    unexpanded."""
-    B, S_new, H, D = q.shape
-    scale = 1.0 / math.sqrt(D)
-    qg = q.reshape(B, S_new, k_rows.shape[1], n_rep, D)
-    with jax.named_scope("kv_read"):
-        logits = jnp.einsum("bqgrd,bgkd->bgrqk", qg, k_rows).astype(
-            jnp.float32) * scale
-    probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1).astype(dt)
-    with jax.named_scope("kv_read"):
-        o = jnp.einsum("bgrqk,bgkd->bqgrd", probs, v_rows)
-    return o.reshape(B, S_new, H, D)
-
-
-def _ring_positions(last, ring: int):
-    """The absolute position each slot of a ring holds once its row has
-    been written up to position ``last`` (``[B]``; -1: nothing yet):
-    ``[B, ring]``, the largest ``p <= last`` with ``p % ring == slot``;
-    negative where the row has not reached the slot. The mask of a ring
-    is made of THESE, never of the slot index."""
-    last = last[:, None]
-    return last - (last - jnp.arange(ring)[None]) % ring
-
-
-def _write_heads_major(stack, new, layer, at):
-    """Write ``new`` [B, S_new, H_kv, D] into ``stack`` [L, B, H_kv, len,
-    D] at ``[layer, b, :, at[b] : at[b] + S_new]`` (``at`` a scalar: rows
-    in lockstep, one update; ``[B]``: one a row, unrolled, as
-    :func:`_write_rows` and for its reason)."""
-    new = jnp.swapaxes(new, 1, 2)[None]
-    if jnp.ndim(at) == 0:
-        return lax.dynamic_update_slice(stack, new, (layer, 0, 0, at, 0))
-    for b in range(new.shape[1]):
-        stack = lax.dynamic_update_slice(
-            stack, new[:, b:b + 1], (layer, b, 0, at[b], 0))
-    return stack
-
-
-def _write_ring(stack, new, layer, pos_b, real_b):
-    """Write the REAL ones of ``new`` [B, S_new, H_kv, D] into the ring
-    ``stack`` [L, B, H_kv, ring, D]: token ``t`` of row ``b`` into slot
-    ``(pos_b[b] + t) % ring``, wrapping. One token a row is one
-    ``dynamic_update_slice`` a row, real or not: a token that is not
-    real lands on the slot of position ``pos - ring``, which neither the
-    row's next query (at ``pos``) nor a later one sees, and the next real
-    token overwrites it. A wider call (a prefill chunk: few rows) rewrites
-    each row's ring whole, a slot taking the real token that maps to it
-    (of a call wider than the ring, the last such) and keeping its key
-    where none does: a pad is never written, for it would lie over a key
-    that the row's next queries still see."""
-    ring, S = stack.shape[3], new.shape[1]
-    if S == 1:
-        return _write_heads_major(stack, new, layer, pos_b % ring)
-    # the window of the call that a ring can hold: all of it, padded to
-    # the ring's length, or its last `ring` real tokens
-    slots = jnp.arange(ring)
-    for b in range(new.shape[0]):
-        if S <= ring:
-            start = 0
-            mine = jnp.pad(new[b], ((0, ring - S), (0, 0), (0, 0)))
-        else:
-            start = jnp.clip(real_b[b] - ring, 0, S - ring)
-            mine = lax.dynamic_slice_in_dim(new[b], start, ring, axis=0)
-        # slot j takes token `t`: rolled, not gathered
-        shift = (pos_b[b] + start) % ring
-        t = start + (slots - shift) % ring
-        old = lax.dynamic_slice(
-            stack, (layer, b, 0, 0, 0), (1, 1, *stack.shape[2:]))
-        mine = jnp.swapaxes(jnp.roll(mine, shift, axis=0), 0, 1)
-        stack = lax.dynamic_update_slice(
-            stack, jnp.where((t >= real_b[b])[None, None, None, :, None],
-                             old, mine[None, None]),
-            (layer, b, 0, 0, 0))
-    return stack
-
-
-def _ring_attend(q, k, v, k_stack, v_stack, layer, pos_b, real_b, window,
-                 n_rep, dt):
-    """A WINDOWED layer's attention and the write of its new rows into
-    the layer's rings: query ``i`` (absolute position) sees key ``j`` iff
-    ``0 <= i - j < window``. One new token a row (a decode step) is
-    written first and attends over the ring, which then holds exactly
-    the ``window`` keys it may see. A wider call's first query still
-    needs the ``window - 1`` keys before it while its last keys would
-    overwrite them, so it attends over the ring AS IT STOOD beside its
-    own new keys, and writes after. Either way the mask is made of each
-    slot's absolute position (:func:`_ring_positions`)."""
-    S = q.shape[1]
-    ring = k_stack.shape[3]
-    q_pos = pos_b[:, None] + jnp.arange(S)[None]            # [B, S]
-
-    def seen(k_pos):
-        back = q_pos[:, :, None] - k_pos[:, None, :]
-        return ((k_pos >= 0)[:, None, :] & (back >= 0)
-                & (back < window))[:, None, None]
-
-    def rows(stack):
-        return lax.dynamic_index_in_dim(stack, layer, keepdims=False)
-
-    def write(k_stack, v_stack):
-        with jax.named_scope("kv_write"):
-            return (_write_ring(k_stack, k.astype(dt), layer, pos_b, real_b),
-                    _write_ring(v_stack, v.astype(dt), layer, pos_b, real_b))
-
-    if S == 1:
-        k_stack, v_stack = write(k_stack, v_stack)
-        o = _heads_attend(q, rows(k_stack), rows(v_stack),
-                          seen(_ring_positions(pos_b, ring)), n_rep, dt)
-        return o, k_stack, v_stack
-    mask = jnp.concatenate(
-        [seen(_ring_positions(pos_b - 1, ring)), seen(q_pos)], axis=-1)
-    o = _heads_attend(
-        q, jnp.concatenate(
-            [rows(k_stack), jnp.swapaxes(k.astype(dt), 1, 2)], axis=2),
-        jnp.concatenate(
-            [rows(v_stack), jnp.swapaxes(v.astype(dt), 1, 2)], axis=2),
-        mask, n_rep, dt)
-    return (o, *write(k_stack, v_stack))
 
 
 def weights_at_rest(params: Params, cfg: TransformerConfig) -> Params:
@@ -417,7 +181,7 @@ def forward_cached(
     per-row positions — the continuous-batching serving engine).
 
     A block-diffusion model (``cfg.generation``) attends block-causally
-    (:func:`_layer_attend`), so a call's rows see each other inside a
+    (``cache.layer_attend``), so a call's rows see each other inside a
     block. Its DENOISING pass is this call with ``pos`` put back by the
     caller: the block's rows are written (the call's queries read them)
     and the next pass, and last the storing pass from the final tokens,
@@ -431,22 +195,51 @@ def forward_cached(
     takes in the real tokens alone; one that keeps rows alone does not
     read ``real`` at all.
     """
+    return tfm.family(cfg).forward_cached(params, tokens, cache, cfg, real)
+
+
+def _through_blocks(params, tokens, cache, cfg, attends: dict, held: dict):
+    """Both of this file's cached forwards: the embedding, the runs
+    through training's block (``attends[kind]`` a run's hook, ``held[kind]``
+    the ``(k, v)`` stacks its kind's layers carry) and the head: ``(logits,
+    held, each run's expert layers' loads or None)``."""
     c = cfg
-    if c.mixers:
-        from dlrover_tpu.models import hybrid
-
-        return hybrid.forward(params, tokens, c, cache, real=real)
-    if c.new_kinds:
-        # one definition of those kinds' block, cached or not
-        from dlrover_tpu.models import latent
-
-        return latent.forward(params, tokens, c, cache)
     if c.int8_matmuls:
-        # the cached products are plain whatever training ran
-        # (ROADMAP D12)
+        # the cached products are plain whatever training ran (D12)
         c = dataclasses.replace(c, int8_matmuls=False)
-    if c.layer_kinds:
-        return _forward_runs(params, tokens, cache, c, real)
+    B, S = tokens.shape
+    pos = cache["pos"]
+    # the held experts' stacks are closed over the block and indexed in
+    # place by its tile loop; everything else is read a layer at a time
+    experts, layers = tfm.split_experts(params["layers"], c)
+    positions = tfm.token_positions(pos, B, S)
+
+    def layer_of(run):
+        block = tfm.make_layer_fn(
+            c, attend=attends[run.kind], positions=positions,
+            experts=experts, kind=tfm.layer_kind(c, run.first))
+
+        def layer(x, rows, w, i):
+            # a layer's rows lie at its index among the layers of its kind
+            x, aux, (*rows, _) = block(
+                x, w, (*rows, i + (run.first_of_kind - run.first)), i)
+            return x, tuple(rows), (aux if c.held_experts else None)
+
+        return layers, layer
+
+    x, held, loads = tfm.scan_runs(
+        tfm.stack_runs(c), tfm.embed_tokens(params, tokens, c, pos=pos),
+        held, layer_of)
+    with jax.named_scope("lm_head"):
+        logits = tfm.lm_logits(params, tfm.final_norm(params, x, c), c)
+    return logits, held, loads
+
+
+def forward_rows(params, tokens, cache, cfg, real=None):
+    """:func:`forward_cached` for layers all of one kind
+    (:func:`init_row_cache`), through the kernel (a decode call on a TPU)
+    or the einsum. ``real`` is not read: the tree is rows alone."""
+    c = cfg
     dt = jnp.dtype(c.dtype)
     B, S_new = tokens.shape
     pos = cache["pos"]
@@ -458,7 +251,7 @@ def forward_cached(
     block = c.block_length if c.generation == "block_diffusion" else 0
 
     max_len = cache["k"].shape[2]
-    # one behind the last key each query sees (`_layer_attend`'s masks)
+    # one behind the last key each query sees (`layer_attend`'s masks)
     q_pos = (jnp.broadcast_to(pos, (B,)).astype(jnp.int32)[:, None]
              + jnp.arange(S_new, dtype=jnp.int32)[None])
     limits = (q_pos // block + 1) * block if block else q_pos + 1
@@ -486,41 +279,22 @@ def forward_cached(
     def attend(q, k, v, state):
         k_stack, v_stack, l = state
         with jax.named_scope("kv_write"):
-            k_stack = _write_rows(k_stack, rows_of(k), l, pos)
-            v_stack = _write_rows(v_stack, rows_of(v), l, pos)
+            k_stack = write_rows(k_stack, rows_of(k), l, pos)
+            v_stack = write_rows(v_stack, rows_of(v), l, pos)
         if walk is not None:
             with jax.named_scope("kv_read"):
                 o = cached_attention.cached_attention(
                     q, k_stack, v_stack, l, walk, n_rep=n_rep)
         else:
-            o = _layer_attend(q, heads_of(k_stack, l), heads_of(v_stack, l),
+            o = layer_attend(q, heads_of(k_stack, l), heads_of(v_stack, l),
                               pos, n_rep, dt, window=window, block=block)
         return o, (k_stack, v_stack, l)
 
-    # the held experts' stacks are closed over the block and indexed in
-    # place by its tile loop; everything else is scanned in
-    experts, scanned = tfm.split_experts(params["layers"], c)
-    run_layer = tfm.make_layer_fn(
-        c, attend=attend,
-        positions=tfm.token_positions(pos, B, S_new), experts=experts)
 
-    def layer(carry, inputs):
-        x, k_stack, v_stack = carry
-        w, l = inputs
-        x, aux, (k_stack, v_stack, _) = run_layer(
-            x, w, (k_stack, v_stack, l), l)
-        return (x, k_stack, v_stack), (aux if c.held_experts else None)
-
-    # the stack rides the CARRY: a scanned input or output of the
-    # per-layer shape would be sliced out and copied back whole, per
-    # layer, for the sake of S_new new rows
-    (x, k_new, v_new), loads = lax.scan(
-        layer, (tfm.embed_tokens(params, tokens, c, pos=pos),
-                cache["k"], cache["v"]),
-        (scanned, jnp.arange(c.n_layers, dtype=jnp.int32)),
-    )
-    with jax.named_scope("lm_head"):
-        logits = tfm.lm_logits(params, tfm.final_norm(params, x, c), c)
+    logits, held, loads = _through_blocks(
+        params, tokens, cache, c, {"full": attend},
+        {"full": (cache["k"], cache["v"])})
+    k_new, v_new = held["full"]
     new = {"k": k_new, "v": v_new, "pos": pos + S_new}
     if "counters" not in cache:     # a tree made by hand counts nothing
         return logits, new
@@ -532,22 +306,19 @@ def forward_cached(
         + jnp.sum(read).astype(jnp.int32),
         "context_tokens": old["context_tokens"] + jnp.sum(reach)}
     if c.held_experts:
-        from dlrover_tpu.ops.moe import count_loads
-
-        new["counters"].update(count_loads(old, loads))
+        new["counters"].update(count_loads(old, loads[0]))
     return logits, new
 
 
-def _forward_runs(params, tokens, cache, cfg, real):
+def forward_rings(params, tokens, cache, cfg, real=None):
     """:func:`forward_cached` for a stack whose layers are of several
-    kinds (``cfg.layer_windows`` / ``cfg.layer_rope``): one scan a run of
-    equal layers (``transformer.layer_runs``), the full layers' rows and
-    the windowed layers' rings each riding the carry of their own runs.
+    kinds (:func:`init_ring_cache`): the full layers' rows and the
+    windowed layers' rings each ride the carry of their own runs.
     A full layer attends over its row as ever (a wide call only as far
-    as its last query reaches, ``latent.key_reaches``'s lengths; a decode
+    as its last query reaches, ``cache.key_reaches``'s lengths; a decode
     step reads what its mask leaves), a windowed one
-    through :func:`_ring_attend`. ``real``: the rings take in the real
-    tokens alone (:func:`_write_ring`), and the counters count them."""
+    through ``cache.ring_attend``. ``real``: the rings take in the real
+    tokens alone (``cache.write_ring``), and the counters count them."""
     c = cfg
     dt = jnp.dtype(c.dtype)
     B, S = tokens.shape
@@ -557,8 +328,6 @@ def _forward_runs(params, tokens, cache, cfg, real):
         jnp.broadcast_to(jnp.asarray(real).astype(jnp.int32), (B,)), 0, S)
     n_rep = c.n_heads // c.n_kv_heads
     window = max(c.layer_windows, default=0)
-    from dlrover_tpu.models.latent import key_reaches
-
     keys = cache["k"].shape[3]
     reaches = [keys] if S == 1 else key_reaches(S, keys)
     reach = jnp.sum(jnp.asarray(reaches) < jnp.max(pos_b) + S)
@@ -568,12 +337,12 @@ def _forward_runs(params, tokens, cache, cfg, real):
         k_stack, v_stack, l = state
         with jax.named_scope("attn_full"):
             with jax.named_scope("kv_write"):
-                k_stack = _write_heads_major(k_stack, k.astype(dt), l, pos)
-                v_stack = _write_heads_major(v_stack, v.astype(dt), l, pos)
+                k_stack = write_heads_major(k_stack, k.astype(dt), l, pos)
+                v_stack = write_heads_major(v_stack, v.astype(dt), l, pos)
             k_rows = lax.dynamic_index_in_dim(k_stack, l, keepdims=False)
             v_rows = lax.dynamic_index_in_dim(v_stack, l, keepdims=False)
             o = lax.switch(reach, [
-                lambda q, k_rows, v_rows, n=n: _heads_attend(
+                lambda q, k_rows, v_rows, n=n: attend_heads_major(
                     q, k_rows[:, :, :n], v_rows[:, :, :n],
                     (q_pos[:, :, None] >= jnp.arange(n)[None, None]
                      )[:, None, None], n_rep, dt)
@@ -583,45 +352,20 @@ def _forward_runs(params, tokens, cache, cfg, real):
     def attend_window(q, k, v, state):
         k_stack, v_stack, l = state
         with jax.named_scope("attn_window"):
-            o, k_stack, v_stack = _ring_attend(
+            o, k_stack, v_stack = ring_attend(
                 q, k, v, k_stack, v_stack, l, pos_b, real_b, window,
                 n_rep, dt)
         return o, (k_stack, v_stack, l)
 
-    experts, layers = tfm.split_experts(params["layers"], c)
-    positions = tfm.token_positions(pos, B, S)
     rings = cache.get("state", {})
-    held = {False: (cache["k"], cache["v"]),
-            True: (rings.get("k_win"), rings.get("v_win"))}
-    x = tfm.embed_tokens(params, tokens, c, pos=pos)
-    loads = []
-    for win, rope, first, first_of_kind, n in tfm.layer_runs(c):
-        run_layer = tfm.make_layer_fn(
-            c, attend=attend_window if win else attend_full,
-            positions=positions, experts=experts, kind=(win, rope))
-
-        def layer(carry, i, run_layer=run_layer,
-                  offset=first_of_kind - first):
-            x, k_stack, v_stack = carry
-            w = jax.tree.map(
-                lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
-                layers)
-            x, aux, (k_stack, v_stack, _) = run_layer(
-                x, w, (k_stack, v_stack, i + offset), i)
-            return (x, k_stack, v_stack), (aux if c.held_experts else None)
-
-        # rows and rings ride the CARRY (module docstring)
-        (x, *held[win > 0]), aux = lax.scan(
-            layer, (x, *held[win > 0]),
-            jnp.arange(first, first + n, dtype=jnp.int32))
-        loads.append(aux)
-    with jax.named_scope("lm_head"):
-        logits = tfm.lm_logits(params, tfm.final_norm(params, x, c), c)
-    new = {"k": held[False][0], "v": held[False][1], "pos": pos + S}
+    logits, held, loads = _through_blocks(
+        params, tokens, cache, c,
+        {"full": attend_full, "window": attend_window},
+        {"full": (cache["k"], cache["v"]),
+         "window": (rings.get("k_win"), rings.get("v_win"))})
+    new = {"k": held["full"][0], "v": held["full"][1], "pos": pos + S}
     old, counters = cache.get("counters", {}), {}
     if c.held_experts:
-        from dlrover_tpu.ops.moe import count_loads
-
         counters = count_loads(old, jnp.concatenate(loads))
     if not window:
         return logits, {**new, **({"counters": counters} if counters else {})}
@@ -633,7 +377,7 @@ def _forward_runs(params, tokens, cache, cfg, real):
         counters[name] = old[name] + jnp.sum(
             jnp.where(live, what, 0).astype(jnp.int32))
     return logits, {**new, "counters": counters, "state": {
-        "k_win": held[True][0], "v_win": held[True][1]}}
+        "k_win": held["window"][0], "v_win": held["window"][1]}}
 
 
 @jax.named_scope("sample")

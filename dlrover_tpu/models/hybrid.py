@@ -45,11 +45,11 @@ it to each query's selection. The model's ``counters`` say how many keys
 each scored and how many the selection chose.
 
 **The second family** (``transformer.SINGLE_MIXERS``: nemotron_h), layers of
-ONE sublayer each, through the same ``runs`` / ``init_cache`` / ``forward``:
+ONE sublayer each, through the same ``labels`` / ``init_cache`` /
+``forward_cached``:
 
-  rows    ``k``, ``v`` ``[L_attention, B, max_len, G, D]`` (``models/
-          decode.py``'s layout and its ``_layer_attend``: no rotary
-          embedding reaches it)
+  rows    ``k``, ``v`` ``[L_attention, B, max_len, G, D]`` (read through
+          ``cache.layer_attend``: no rotary embedding reaches it)
   state   ``ssm`` ``[L_mamba2, B, H, P, N]`` float32 and ``conv``
           ``[L_mamba2, B, K - 1, C]``: the convolution's WINDOW, the last
           ``K - 1`` inputs of its ``C = H P + 2 G N`` channels
@@ -85,7 +85,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from dlrover_tpu.models import transformer as tfm
-from dlrover_tpu.models.decode import _layer_attend, _write_rows
+from dlrover_tpu.models.cache import layer_attend, write_rows
 from dlrover_tpu.ops import moe
 
 Params = Any
@@ -95,25 +95,16 @@ EVERY_KIND = (*KINDS, *tfm.SINGLE_MIXERS)
 # bytes of float32 scores a wide call's attention holds at once
 SCORE_BYTES = 300e6
 # a wide call reads a row up to one of this many lengths (a compiled
-# branch each): the least that holds the call's last query
+# branch each): the least that holds the call's last query. Quarters of
+# the row, block-aligned: not `cache.key_reaches`' rule (ROADMAP D19)
 KEY_REACHES = 4
 
 
-def runs(cfg) -> list[tuple[str, int, int]]:
-    """The stack as ``(mixer, first layer OF ITS KIND, layers)`` runs of
-    equal mixers: each is one scan over its kind's stacked weights."""
-    out, seen = [], {k: 0 for k in EVERY_KIND}
-    for kind in cfg.mixer_types:
-        if out and out[-1][0] == kind:
-            out[-1][2] += 1
-        else:
-            out.append([kind, seen[kind], 1])
-        seen[kind] += 1
-    return [tuple(r) for r in out]
-
-
-def n_of(cfg, kind: str) -> int:
-    return sum(1 for k in cfg.mixer_types if k == kind)
+def labels(cfg) -> list[tuple]:
+    """A layer's mixer names its cache and its weights' subtree
+    (``transformer.stack_runs``: each run one scan over its kind's
+    stacked weights, from its first layer OF ITS KIND on)."""
+    return [(kind, f"{kind}_layers") for kind in cfg.mixer_types]
 
 
 def kinds_of(cfg) -> tuple:
@@ -162,13 +153,13 @@ def param_shapes(cfg) -> dict:
     each kind the stack holds (``sparse_layers`` and ``lightning_layers``,
     or the single-sublayer kinds'), each stacked over the layers of its
     kind in their order in the stack."""
-    c = cfg
+    c, n_of = cfg, cfg.mixer_types.count
     tree = {"embed": (c.vocab_size, c.d_model), "ln_f": (c.d_model,),
             "lm_head": (c.d_model, c.vocab_size)}
     if _single(c):
         for kind in kinds_of(c):
             tree[f"{kind}_layers"] = {
-                name: (n_of(c, kind), *shape)
+                name: (n_of(kind), *shape)
                 for name, shape in _single_layer_shapes(c)[kind].items()}
         return tree
     e, h, d, f = c.d_model, c.n_heads, c.head_dim, c.d_ff
@@ -181,18 +172,18 @@ def param_shapes(cfg) -> dict:
         if kind == "lightning":
             layer["ln_o"] = (h * d,)
         tree[f"{kind}_layers"] = {
-            name: (n_of(c, kind), *shape) for name, shape in layer.items()}
+            name: (n_of(kind), *shape) for name, shape in layer.items()}
     return tree
 
 
 def init_cache(cfg, batch: int, max_len: int) -> dict:
     """The cache tree: rows, ``state``, the position and the counters."""
-    c = cfg
+    c, n_of = cfg, cfg.mixer_types.count
     if _single(c):
         dt = jnp.dtype(c.dtype)
-        rows = (n_of(c, "attention"), batch, max_len, c.n_kv_heads,
+        rows = (n_of("attention"), batch, max_len, c.n_kv_heads,
                 c.head_dim)
-        layers = n_of(c, "mamba2")
+        layers = n_of("mamba2")
         return {
             "k": jnp.zeros(rows, dt), "v": jnp.zeros(rows, dt),
             "pos": jnp.zeros((), jnp.int32),
@@ -202,7 +193,7 @@ def init_cache(cfg, batch: int, max_len: int) -> dict:
                 "conv": jnp.zeros((layers, batch, c.ssm_conv - 1,
                                    _ssm_sizes(c)[2]), dt)},
             "counters": {
-                **moe.held_counters(n_of(c, "latent_experts"),
+                **moe.held_counters(n_of("latent_experts"),
                                     tfm.routed_config(c).n_held),
                 "ssm_row_steps": jnp.zeros((), jnp.int32),
                 "context_tokens": jnp.zeros((), jnp.int32),
@@ -214,7 +205,7 @@ def init_cache(cfg, batch: int, max_len: int) -> dict:
             f"max_len {max_len} is not a multiple of the sparse block "
             f"{c.sparse_block}: the selection reads a row as whole blocks")
     dt = jnp.dtype(c.dtype)
-    rows = (n_of(c, "sparse") * c.sparse_kv_heads, batch, max_len,
+    rows = (n_of("sparse") * c.sparse_kv_heads, batch, max_len,
             c.head_dim)
     comp = rows[:2] + (max_len // c.sparse_stride, c.head_dim)
     return {
@@ -222,7 +213,7 @@ def init_cache(cfg, batch: int, max_len: int) -> dict:
         "kc": jnp.zeros(comp, dt),
         "pos": jnp.zeros((), jnp.int32),
         "state": {"s": jnp.zeros(
-            (n_of(c, "lightning"), batch, c.n_heads, c.head_dim, c.head_dim),
+            (n_of("lightning"), batch, c.n_heads, c.head_dim, c.head_dim),
             jnp.float32)},
         "counters": {
             "sparse_keys_selected": jnp.zeros((), jnp.int32),
@@ -393,16 +384,16 @@ def _mamba2_attend(xbc, dt_raw, w, state, *, cfg, real_b):
                              steps + jnp.sum(real_b), layer)
 
 
-def _heads_attend(q, k, v, state, *, cfg, pos):
-    """The attention kind's ``attend``: the call's rows into ``k``/``v``
-    (``models/decode.py``'s layout), then its grouped-head attention over
-    the layer's rows."""
+def _attention_attend(q, k, v, state, *, cfg, pos):
+    """The "attention" kind's ``attend``: the call's rows into ``k``/``v``
+    (positions before heads, ``[L, B, T, G, D]``), then
+    ``cache.layer_attend`` over the layer's rows."""
     k_stack, v_stack, layer = state
     dt = q.dtype
     with jax.named_scope("kv_write"):
-        k_stack = _write_rows(k_stack, k.astype(dt), layer, pos)
-        v_stack = _write_rows(v_stack, v.astype(dt), layer, pos)
-    o = _layer_attend(
+        k_stack = write_rows(k_stack, k.astype(dt), layer, pos)
+        v_stack = write_rows(v_stack, v.astype(dt), layer, pos)
+    o = layer_attend(
         q, lax.dynamic_index_in_dim(k_stack, layer, keepdims=False),
         lax.dynamic_index_in_dim(v_stack, layer, keepdims=False),
         pos, cfg.n_heads // cfg.n_kv_heads, dt)
@@ -446,7 +437,7 @@ def _compress(kc_stack, k_stack, layer, pos_b, real_b, width: int, cfg):
     old = kc_stack[heads, rows_b, j[None]]
     new = jnp.where(mine[None, ..., None], new, old)
     for g in range(G):
-        kc_stack = _write_rows(kc_stack, new[g], layer * G + g, first)
+        kc_stack = write_rows(kc_stack, new[g], layer * G + g, first)
     return kc_stack
 
 
@@ -646,9 +637,9 @@ def _sparse_attend(q, k, v, state, *, cfg, pos, pos_b, real_b):
     G = cfg.sparse_kv_heads
     with jax.named_scope("kv_write"):
         for g in range(G):
-            k_stack = _write_rows(k_stack, k[:, :, g].astype(dt),
+            k_stack = write_rows(k_stack, k[:, :, g].astype(dt),
                                   layer * G + g, pos)
-            v_stack = _write_rows(v_stack, v[:, :, g].astype(dt),
+            v_stack = write_rows(v_stack, v[:, :, g].astype(dt),
                                   layer * G + g, pos)
     with jax.named_scope("sparse_compress"):
         kc_stack = _compress(kc_stack, k_stack, layer, pos_b, real_b, S, cfg)
@@ -666,8 +657,8 @@ def _sparse_attend(q, k, v, state, *, cfg, pos, pos_b, real_b):
 # --------------------------------------------------------------- forward
 
 
-def forward(params: Params, tokens: jax.Array, cfg, cache: dict,
-            real=None, return_hidden: bool = False):
+def forward_cached(params: Params, tokens: jax.Array, cache: dict, cfg,
+                   real=None, return_hidden: bool = False):
     """``tokens [B, S]`` from ``cache['pos']`` on (a scalar: rows in
     lockstep; ``[B]``: rows at positions of their own) -> ``(float32
     logits [B, S, V], cache)``. ``real`` (a scalar or ``[B]``; None: all):
@@ -701,38 +692,30 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict,
                           real_b=real_b),
         "lightning": partial(_lightning_attend, real_b=real_b),
         "mamba2": partial(_mamba2_attend, cfg=c, real_b=real_b),
-        "attention": partial(_heads_attend, cfg=c, pos=pos)}
-    for kind, first, n in runs(c):
-        stack = params[f"{kind}_layers"]
-        experts = None
-        if kind == "latent_experts":
-            # closed over the block and indexed in place by its tile loop
-            # (`make_layer_fn`); everything else is sliced a layer
-            dt = jnp.dtype(c.dtype)
-            experts = {k: tfm._leaf(stack, k, dt)
-                       for k in tfm.EXPERT_STACKS if k in stack}
-            stack = {k: v for k, v in stack.items() if k not in experts}
-        run_layer = tfm.make_layer_fn(
-            c, attend=attends.get(kind), positions=positions, mixer=kind,
-            experts=experts,
+        "attention": partial(_attention_attend, cfg=c, pos=pos)}
+
+    def layer_of(run):
+        # a kind's held experts are closed over the block and indexed in
+        # place by its tile loop (`make_layer_fn`); everything else is
+        # read a layer at a time
+        experts, weights = tfm.split_experts(params[run.key], c)
+        block = tfm.make_layer_fn(
+            c, attend=attends.get(run.kind), positions=positions,
+            mixer=run.kind, experts=experts,
             mask=jnp.arange(S)[None] < real_b[:, None] if experts else None)
 
-        def layer(carry, i, stack=stack, run_layer=run_layer, kind=kind):
-            x, mine = carry
-            w = jax.tree.map(
-                lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
-                stack)
-            if kind == "latent_experts":
-                x, loads, _ = run_layer(x, w, None, i)
-                return (x, (lax.dynamic_update_index_in_dim(
-                    mine[0], loads, i, 0),)), None
-            x, _, (*mine, _) = run_layer(x, w, (*mine, i), i)
-            return (x, tuple(mine)), None
+        def layer(x, mine, w, i):
+            if run.kind == "latent_experts":
+                x, loads, _ = block(x, w, None, i)
+                return x, (lax.dynamic_update_index_in_dim(
+                    mine[0], loads, i, 0),), None
+            x, _, (*mine, _) = block(x, w, (*mine, i), i)
+            return x, tuple(mine), None
 
-        # the rows and the state ride the CARRY (models/decode.py)
-        (x, held[kind]), _ = lax.scan(
-            layer, (x, held[kind]),
-            jnp.arange(first, first + n, dtype=jnp.int32))
+        return weights, layer
+
+    # the rows and the state ride the CARRY (models/decode.py)
+    x, held, _ = tfm.scan_runs(tfm.stack_runs(c), x, held, layer_of)
     with jax.named_scope("lm_head"):
         x = tfm.final_norm(params, x, c)
         out = x if return_hidden else tfm.lm_logits(params, x, c)
@@ -774,10 +757,9 @@ def forward_uncached(params: Params, tokens: jax.Array, cfg,
                      return_hidden: bool = False):
     """``forward_with_aux``'s answer for these kinds: the cached forward
     from an empty cache just long enough (the same function, and the one
-    definition of it); no balancing loss, so the aux term is zero."""
+    definition of it)."""
     B, S = tokens.shape
     blk = 1 if _single(cfg) else cfg.sparse_block
-    out, _ = forward(params, tokens, cfg,
-                     init_cache(cfg, B, -(-S // blk) * blk),
-                     return_hidden=return_hidden)
-    return out, jnp.zeros((), jnp.float32)
+    return forward_cached(params, tokens,
+                          init_cache(cfg, B, -(-S // blk) * blk), cfg,
+                          return_hidden=return_hidden)[0]

@@ -3,12 +3,12 @@ norms, a sigmoid-routed expert layer beside a shared expert, a stack
 whose first layers are dense (the DeepSeek-V3 / openPangu-Ultra-MoE
 family). The forward pass only: scoring and serving.
 
-ONE definition of the block, :func:`forward`: without a cache it is the
-uncached forward (``transformer.forward_with_aux`` hands over to it),
-with one it is ``decode.forward_cached`` (prefill chunk, decode step,
-verify block). The two differ in one thing, which rows the queries
-attend over: the call's own latent rows, or the cache's after the call's
-rows were written into it.
+ONE definition of the block, :func:`forward_cached`: without a cache it
+is the uncached forward (:func:`forward_uncached`, ``forward_with_aux``'s
+through ``transformer.family``), with one it is ``decode.forward_cached``
+(prefill chunk, decode step, verify block). The two differ in one thing,
+which rows the queries attend over: the call's own latent rows, or the
+cache's after the call's rows were written into it.
 
 RMSNorm ``N`` (eps ``cfg.norm_eps``); ``h`` is a layer's normed input:
 
@@ -25,14 +25,15 @@ RMSNorm ``N`` (eps ``cfg.norm_eps``); ``h`` is a layer's normed input:
 
 **The cache is ``c_kv`` and ``k_rope``**: one stack ``latent [L, B,
 max_len, kv_lora_rank + qk_rope_head_dim]`` (576 numbers a token a layer
-at the published sizes), carried through both scans, written in place
-(``decode._write_rows``). A call of at most :data:`ABSORB_UPTO` new
-tokens a row reads it as it lies: ``W_kvb`` is absorbed into the
+at the published sizes), carried through both runs' scans
+(``transformer.scan_runs``), written in place (``cache.write_rows``). A
+call of at most :data:`ABSORB_UPTO` new tokens a row reads it as it
+lies: ``W_kvb`` is absorbed into the
 query (``q~_h = q_nope_h W_kvb,k,h^T``, ``score_h = [q~_h | q_rope_h] .
 [c_kv | k_rope]``) and into the output (``o_h = (sum p c_kv) W_kvb,v,h``):
 the same function. A wider call (a prefill chunk) expands keys and
 values, a group of heads at a time, over the row only as far as its
-last query reaches (:func:`key_reaches`: the first of a few lengths that
+last query reaches (``cache.key_reaches``: the first of a few lengths that
 holds it, chosen on the device from the call's positions; the keys past
 it were masked to exactly 0.0 before). The cache's ``counters`` say how
 far such a call read (``keys_read``).
@@ -54,11 +55,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dlrover_tpu.models.decode import _write_rows
+from dlrover_tpu.models import transformer as tfm
+from dlrover_tpu.models.cache import reach_of, write_rows
 from dlrover_tpu.ops import moe
 
 Params = Any
-EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 # heads whose keys and values the expanded path holds at once
 HEAD_GROUP = 16
 # a cached call of at most this many new tokens a row (a decode step, a
@@ -69,15 +70,10 @@ ABSORB_UPTO = 64
 KINDS = ("latent", "sandwich", "sigmoid_experts")
 
 
-def routed_config(cfg) -> moe.RoutedConfig:
-    return moe.RoutedConfig(
-        n_experts=cfg.n_routed_experts, top_k=cfg.moe_top_k,
-        scaling=cfg.routed_scaling_factor, norm_topk=cfg.norm_topk_prob,
-        first=cfg.expert_first, held=cfg.experts_held)
-
-
-def segments(cfg) -> list[tuple[str, bool, int]]:
-    """The stack as ``(params key, expert layer?, layers)`` runs."""
+def labels(cfg) -> list[tuple]:
+    """One cache, the latent stack, under every layer; the first
+    ``first_k_dense`` layers' weights under ``dense_layers``, the expert
+    layers' under ``layers``: each a whole run (``transformer.stack_runs``)."""
     kinds = (cfg.attn_kind, cfg.norm_kind, cfg.ffn_kind)
     if kinds != KINDS:
         # 'pre' norms or a plain 'swiglu' under latent attention: no
@@ -85,10 +81,13 @@ def segments(cfg) -> list[tuple[str, bool, int]]:
         raise NotImplementedError(
             f"attn_kind / norm_kind / ffn_kind {kinds}: models/latent.py "
             f"runs {KINDS} together, and `variant` names the rest")
-    dense = min(cfg.first_k_dense, cfg.n_layers)
-    runs = [("dense_layers", False, dense),
-            ("layers", True, cfg.n_layers - dense)]
-    return [r for r in runs if r[2] > 0]
+    return [("latent", "dense_layers" if l < cfg.first_k_dense else "layers")
+            for l in range(cfg.n_layers)]
+
+
+def segments(cfg) -> list[tuple[str, bool, int]]:
+    """The stack as ``(params key, expert layer?, layers)`` runs."""
+    return [(r.key, r.key == "layers", r.n) for r in tfm.stack_runs(cfg)]
 
 
 def param_shapes(cfg) -> dict:
@@ -104,7 +103,7 @@ def param_shapes(cfg) -> dict:
         "w_o": (h, c.v_head_dim, e), "ln_post_attn": (e,),
         "ln_pre_mlp": (e,), "ln_post_mlp": (e,),
     }
-    held, fe = routed_config(c).n_held, c.moe_d_ff
+    held, fe = tfm.routed_config(c).n_held, c.moe_d_ff
     fs = c.n_shared_experts * c.moe_d_ff
     ffn = {
         False: {"w_gate": (e, c.d_ff), "w_up": (e, c.d_ff),
@@ -122,18 +121,10 @@ def param_shapes(cfg) -> dict:
     return tree
 
 
-def init_params(cfg, key: jax.Array) -> Params:
-    """Seeded weights in ``cfg.param_dtype``: matrices normal /
-    sqrt(fan_in) (the contracted dim), norm scales one."""
-    from dlrover_tpu.models.transformer import init_from_shapes
-
-    return init_from_shapes(param_shapes(cfg), key, cfg.param_dtype)
-
-
 def init_cache(cfg, batch: int, max_len: int) -> dict:
     """The cache tree: the latent stack, the position, and the counters
     every cached call adds to: ``keys_read`` (the length of the row a
-    call's expanded attention read, :func:`key_reaches`; an absorbed
+    call's expanded attention read, ``cache.key_reaches``; an absorbed
     call adds none) beside the expert layers' (``ops/moe.held_counters``)."""
     c = cfg
     cache = {
@@ -146,19 +137,16 @@ def init_cache(cfg, batch: int, max_len: int) -> dict:
     n_expert = sum(n for _, experts, n in segments(c) if experts)
     if n_expert:
         cache["counters"].update(
-            moe.held_counters(n_expert, routed_config(c).n_held))
+            moe.held_counters(n_expert, tfm.routed_config(c).n_held))
     return cache
-
-
-def _rms(x, scale, eps):
-    x32 = x.astype(jnp.float32)
-    inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-    return (x32 * inv).astype(x.dtype) * scale.astype(x.dtype)
 
 
 def _rope(x, positions, theta):
     """Rotary embedding of ``x [B, S, H, D]`` at ``positions [B, S]``,
-    pairing components ``(2i, 2i + 1)``; angles in float32."""
+    pairing components ``(2i, 2i + 1)``; angles in float32. NOT
+    ``transformer``'s: this one rotates in float32 and rounds once, that
+    one rotates in the input's dtype, so swapping them moves the logits
+    (ROADMAP D22)."""
     d = x.shape[-1]
     freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[:, :, None, None].astype(jnp.float32) * freqs
@@ -177,31 +165,11 @@ def _masked_softmax(scores, q_pos, dt):
     return jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1).astype(dt)
 
 
-def key_reaches(tokens: int, keys: int) -> list[int]:
-    """The lengths an expanded call of ``tokens`` new tokens a row may
-    read a row of ``keys`` to: its own width doubled up to the row's
-    length, which ends the list. One length where the row is the call
-    (the uncached forward)."""
-    reach = []
-    while tokens < keys:
-        reach.append(tokens)
-        tokens *= 2
-    return reach + [keys]
-
-
-def reach_of(q_pos, tokens: int, keys: int):
-    """``(lengths, which)``: :func:`key_reaches` and the index of the
-    first that holds the last query of ``q_pos [B, S]`` (the keys a
-    query sees end at its own position)."""
-    reach = key_reaches(tokens, keys)
-    return reach, jnp.sum(jnp.asarray(reach) < jnp.max(q_pos) + 1)
-
-
 def attend(q_nope, q_rope, rows, w_kvb, q_pos, cfg, absorbed: bool):
     """``q_nope [B, S, H, nope]``, ``q_rope [B, S, H, rope]`` over the
     latent ``rows [B, K, rank + rope]`` -> ``[B, S, H, v]``. The
     expanded path reads ``rows[:, :keys]``, ``keys`` from
-    :func:`reach_of`."""
+    ``cache.reach_of``."""
     c = cfg
     dt = q_nope.dtype
     rank, nope = c.kv_lora_rank, c.qk_nope_head_dim
@@ -247,24 +215,23 @@ def attend(q_nope, q_rope, rows, w_kvb, q_pos, cfg, absorbed: bool):
     return lax.switch(which, [partial(expanded, keys) for keys in reach])
 
 
-def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
-            return_hidden: bool = False):
+def forward_cached(params: Params, tokens: jax.Array, cache: dict | None,
+                   cfg, real=None, return_hidden: bool = False):
     """``tokens [B, S]`` -> ``(float32 logits [B, S, V], cache)``.
 
     ``cache`` None: the uncached forward, every row from position 0.
     Else the call's tokens start at ``cache['pos']`` (a scalar: rows in
     lockstep; ``[B]``: rows at positions of their own), their latent
     rows are written into the stack and the queries attend over it.
+    ``real`` is not read: the tree is rows alone.
     """
     c = cfg
     dt = jnp.dtype(c.dtype)
-    eps = c.norm_eps
+    rms = partial(tfm.rms_norm, eps=c.norm_eps)
     B, S = tokens.shape
-    rcfg = routed_config(c)
+    rcfg = tfm.routed_config(c)
     pos = cache["pos"] if cache is not None else jnp.zeros((), jnp.int32)
-    steps = jnp.arange(S, dtype=jnp.int32)
-    positions = (pos[:, None] + steps[None] if jnp.ndim(pos)
-                 else jnp.broadcast_to(pos + steps, (B, S)))
+    positions = tfm.token_positions(pos, B, S)
     absorbed = cache is not None and S <= ABSORB_UPTO
     rank = c.kv_lora_rank
     counters = None if cache is None else cache["counters"]
@@ -276,10 +243,10 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
     def block(x, stack, w, experts, layer, global_layer):
         """One layer on ``x [B, S, E]``; ``stack`` is the cache's latent
         stack or None. Returns (x, stack, loads of this layer or None)."""
-        h = _rms(x, w["ln_in"], eps)
+        h = rms(x, w["ln_in"])
         with jax.named_scope("mla_q"):
-            c_q = _rms(jnp.einsum("bse,er->bsr", h, w["w_qa"].astype(dt)),
-                       w["ln_q"], eps)
+            c_q = rms(jnp.einsum("bse,er->bsr", h, w["w_qa"].astype(dt)),
+                      w["ln_q"])
             q = jnp.einsum("bsr,rhd->bshd", c_q, w["w_qb"].astype(dt))
             q_nope = q[..., :c.qk_nope_head_dim]
             q_rope = _rope(q[..., c.qk_nope_head_dim:], positions,
@@ -287,20 +254,20 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
         with jax.named_scope("latent_write"):
             kva = jnp.einsum("bse,er->bsr", h, w["w_kva"].astype(dt))
             rows = jnp.concatenate([
-                _rms(kva[..., :rank], w["ln_kv"], eps),
+                rms(kva[..., :rank], w["ln_kv"]),
                 _rope(kva[..., None, rank:], positions,
                       c.rope_theta)[:, :, 0]], -1)
             if stack is not None:
-                stack = _write_rows(stack, rows, global_layer, pos)
+                stack = write_rows(stack, rows, global_layer, pos)
                 rows = lax.dynamic_index_in_dim(stack, global_layer,
                                                 keepdims=False)
         with jax.named_scope("mla_attend"):
             o = attend(q_nope, q_rope, rows, w["w_kvb"].astype(dt),
                        positions, c, absorbed)
             o = jnp.einsum("bshv,hve->bse", o, w["w_o"].astype(dt))
-        x = x + _rms(o, w["ln_post_attn"], eps)
+        x = x + rms(o, w["ln_post_attn"])
 
-        h = _rms(x, w["ln_pre_mlp"], eps)
+        h = rms(x, w["ln_pre_mlp"])
         loads = None
         if experts is None:
             with jax.named_scope("mlp"):
@@ -318,44 +285,36 @@ def forward(params: Params, tokens: jax.Array, cfg, cache: dict | None,
                     h, w["ws_gate"].astype(dt), w["ws_up"].astype(dt),
                     w["ws_down"].astype(dt))
             ff = shared + routed.reshape(B, S, -1).astype(dt)
-        x = x + _rms(ff, w["ln_post_mlp"], eps)
+        x = x + rms(ff, w["ln_post_mlp"])
         return x, stack, loads
 
-    x = params["embed"].astype(dt)[tokens]
-    stack = cache["latent"] if cache is not None else None
-    first = 0
-    for key, is_expert, n in segments(c):
-        seg = params[key]
+    def layer_of(run):
         # the routed experts' stacks are closed over and indexed in
-        # place by the tile loop; everything else is scanned in
-        experts = ({k: seg[k].astype(dt) for k in EXPERT_STACKS}
-                   if is_expert else None)
-        scanned = {k: v for k, v in seg.items() if k not in EXPERT_STACKS}
+        # place by the tile loop; everything else is read a layer at a
+        # time. A run is a whole subtree: its first layer has index 0
+        # there and `first_of_kind` in the latent stack
+        experts, weights = tfm.split_experts(params[run.key], c)
+        return weights, lambda x, stack, w, i: block(
+            x, stack, w, experts, i, run.first_of_kind + i)
 
-        def layer(carry, inputs, experts=experts, first=first):
-            x, stack = carry
-            w, i = inputs
-            x, stack, mine = block(x, stack, w, experts, i, first + i)
-            return (x, stack), mine
-
-        (x, stack), loads = lax.scan(
-            layer, (x, stack), (scanned, jnp.arange(n, dtype=jnp.int32)))
-        if is_expert and counters is not None:
-            counters = {**counters, **moe.count_loads(counters, loads)}
-        first += n
+    x, held, loads = tfm.scan_runs(
+        tfm.stack_runs(c), tfm.embed_tokens(params, tokens, c),
+        {"latent": None if cache is None else cache["latent"]}, layer_of)
+    for mine in loads:
+        if mine is not None and counters is not None:
+            counters = {**counters, **moe.count_loads(counters, mine)}
     with jax.named_scope("lm_head"):
-        x = _rms(x, params["ln_f"], eps)
-        out = x if return_hidden else jnp.einsum(
-            "bse,ev->bsv", x, params["lm_head"].astype(dt)
-        ).astype(jnp.float32)
+        x = tfm.final_norm(params, x, c)
+        out = x if return_hidden else tfm.lm_logits(params, x, c)
     if cache is None:
         return out, None
-    return out, {"latent": stack, "pos": pos + S, "counters": counters}
+    return out, {"latent": held["latent"], "pos": pos + S,
+                 "counters": counters}
 
 
 def forward_uncached(params: Params, tokens: jax.Array, cfg,
                      return_hidden: bool = False):
-    """``forward_with_aux``'s answer for these kinds: no balancing loss
-    (the router is served, not trained), so the aux term is zero."""
-    out, _ = forward(params, tokens, cfg, None, return_hidden)
-    return out, jnp.zeros((), jnp.float32)
+    """``forward_with_aux``'s answer for these kinds (no balancing loss:
+    the router is served, not trained)."""
+    return forward_cached(params, tokens, None, cfg,
+                          return_hidden=return_hidden)[0]

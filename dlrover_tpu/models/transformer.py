@@ -21,9 +21,10 @@ Design choices for the MXU/XLA:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 from functools import partial
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -225,7 +226,7 @@ class TransformerConfig:
     moe_latent: int = 0
     moe_shared_d_ff: int = 0
     # --- per-layer kinds of THIS file's block (served only; the stack is
-    # then run as scanned runs of equal layers, `layer_runs`), one entry a
+    # then run as scanned runs of equal layers, `stack_runs`), one entry a
     # layer beside `n_layers`: `layer_windows[l]` > 0 makes layer l's
     # attention WINDOWED (query i sees key j iff 0 <= i - j < window) and
     # its cache a RING of that many rows (models/decode.py), 0 leaves it
@@ -335,7 +336,7 @@ class TransformerConfig:
 
     @property
     def layer_kinds(self) -> bool:
-        """True where the layers are not all of one kind (`layer_runs`)."""
+        """True where the layers are not all of one kind (`stack_runs`)."""
         return bool(self.layer_windows or self.layer_rope)
 
     @property
@@ -345,36 +346,10 @@ class TransformerConfig:
 
     @property
     def param_count(self) -> int:
-        c = self
-        if c.new_kinds:
-            # the parameters HELD (the share), counted from the shapes
-            from dlrover_tpu.models.latent import (
-                param_shapes as latent_shapes,
-            )
-
-            return sum(math.prod(s) for s in jax.tree.leaves(
-                latent_shapes(c), is_leaf=lambda s: isinstance(s, tuple)))
-        if not c.default_kinds:
-            # counted from the shapes, as the kinds above are
-            return sum(math.prod(s) for s in jax.tree.leaves(
-                param_shapes(c), is_leaf=lambda s: isinstance(s, tuple)))
-        embed = c.vocab_size * c.d_model
-        attn = c.d_model * c.head_dim * (c.n_heads * 2 + c.n_kv_heads * 2)
-        if c.moe_experts:
-            ffn = (c.d_model * c.moe_experts
-                   + 2 * c.moe_experts * c.d_model * c.d_ff)
-            norms = 2 * c.d_model
-        elif c.variant == "llama":
-            ffn = 3 * c.d_model * c.d_ff
-            norms = 2 * c.d_model
-        else:
-            ffn = 2 * c.d_model * c.d_ff + c.d_ff + c.d_model
-            norms = 4 * c.d_model
-        per_layer = attn + ffn + norms
-        pos = 0 if c.variant == "llama" else c.max_seq_len * c.d_model
-        lm_head = c.d_model * c.vocab_size  # untied
-        final_norm = c.d_model * (1 if c.variant == "llama" else 2)
-        return embed + pos + c.n_layers * per_layer + final_norm + lm_head
+        """The parameters HELD (a deployment's share), from the shapes."""
+        return sum(math.prod(s) for s in jax.tree.leaves(
+            family(self).param_shapes(self),
+            is_leaf=lambda s: isinstance(s, tuple)))
 
     def train_flops_per_token(self, seq: int) -> float:
         """Model FLOPs of one token's forward and backward pass in a
@@ -384,16 +359,11 @@ class TransformerConfig:
         half of attention when the model is causal, the backward twice
         the forward, recomputed operations NOT counted."""
         c = self
-        if c.new_kinds:
-            raise NotImplementedError(
-                "train_flops_per_token: the latent / sandwich / "
-                "sigmoid_experts kinds are served, not trained "
-                "(benchmark/counts/mla_moe.py counts their forward)")
         if not c.default_kinds:
             raise NotImplementedError(
                 f"train_flops_per_token: attn_kind {c.attn_kind!r} / "
-                f"ffn_kind {c.ffn_kind!r} are served, not trained "
-                "(forward_flops_per_token counts their forward)")
+                f"norm_kind {c.norm_kind!r} / ffn_kind {c.ffn_kind!r} are "
+                "served, not trained (forward_flops_per_token)")
         attn = c.d_model * c.head_dim * (c.n_heads * 2 + c.n_kv_heads * 2)
         if c.moe_experts:
             ffn = (c.d_model * c.moe_experts
@@ -405,7 +375,6 @@ class TransformerConfig:
         scores = c.n_layers * 2 * 2 * c.n_heads * c.head_dim * keys
         return 3.0 * (2.0 * matmul + scores)
 
-
     def forward_flops_per_token(self, keys: float) -> float:
         """Model FLOPs of one token's forward pass with ``keys`` keys in
         its sight, from the shapes (2 per multiply-add; a token passes
@@ -413,11 +382,11 @@ class TransformerConfig:
         kinds count where training's kinds count
         :meth:`train_flops_per_token`."""
         c = self
-        if c.new_kinds or c.mixers:
+        counted_by = family(c).counted_by
+        if counted_by:
             raise NotImplementedError(
-                "forward_flops_per_token: benchmark/counts/mla_moe.py "
-                "counts the latent kinds' forward and benchmark/counts/"
-                "sala.py the mixers'")
+                f"forward_flops_per_token: {counted_by} counts this "
+                "family's forward")
         layer = param_shapes(c)["layers"]
         matmul = 0
         for name, shape in layer.items():
@@ -654,42 +623,106 @@ EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 
 
 def routed_config(cfg: TransformerConfig):
-    """``ops/moe.RoutedConfig`` of the 'softmax_experts' layer (SwiGLU
-    experts, no scaling) or of a 'latent_experts' one (squared-ReLU
-    experts at ``moe_latent``, scaled)."""
+    """``ops/moe.RoutedConfig`` of the 'softmax_experts' layer (SwiGLU or
+    ReGLU experts, no scaling), of a 'sigmoid_experts' one (SwiGLU,
+    scaled) or of a 'latent_experts' one (squared-ReLU experts at
+    ``moe_latent``, scaled)."""
     from dlrover_tpu.ops.moe import RoutedConfig
 
     return RoutedConfig(
         n_experts=cfg.n_routed_experts, top_k=cfg.moe_top_k,
         norm_topk=cfg.norm_topk_prob, first=cfg.expert_first,
         held=cfg.experts_held,
-        **({"scaling": cfg.routed_scaling_factor, "form": "relu2"}
-           if cfg.moe_latent else {"form": cfg.expert_form}))
+        scaling=1.0 if cfg.held_experts else cfg.routed_scaling_factor,
+        form="relu2" if cfg.moe_latent else cfg.expert_form)
 
 
-def layer_runs(cfg: TransformerConfig) -> list[tuple[int, bool, int, int, int]]:
-    """The stack as runs of equal layers, ``(window, rope, first layer,
-    first layer OF ITS KIND, layers)``: each is one scan. The kind is
-    what decides a layer's cache, windowed (a ring) or full (rows), so
-    the fourth entry is where the run's rows start in its kind's stack.
-    One run of everything where `layer_windows` / `layer_rope` are
-    empty."""
+class Family(NamedTuple):
+    """What the rest of ``models/`` asks of an architecture
+    (:func:`family`); DESIGN.md §23.7 says how to add one."""
+    # cfg -> one label a layer, ``(kind, params key, *what else tells two
+    # layers apart)``: what :func:`stack_runs` cuts into runs
+    labels: Callable
+    param_shapes: Callable      # cfg -> the parameter tree as shapes
+    init_cache: Callable        # (cfg, batch, max_len) -> the cache tree
+    # (params, tokens, cache, cfg, real) -> (logits, cache)
+    forward_cached: Callable
+    # (params, tokens, cfg, return_hidden) -> logits or hidden, of a
+    # family that is served only; None: `forward_with_aux`'s own path
+    forward: Callable | None
+    counted_by: str = ""    # the file that counts its forward's FLOPs
+
+
+def family(cfg: TransformerConfig) -> Family:
+    """THE choice of family: the one place that reads a config's kinds to
+    decide whose code runs it, and the one place this file imports the
+    modules above it."""
     c = cfg
-    windows = c.layer_windows or (0,) * c.n_layers
-    ropes = c.layer_rope or (True,) * c.n_layers
-    out, seen = [], {True: 0, False: 0}
-    for l, (w, r) in enumerate(zip(windows, ropes)):
-        if out and out[-1][:2] == [w, bool(r)]:
-            out[-1][4] += 1
-        else:
-            out.append([w, bool(r), l, seen[w > 0], 1])
-        seen[w > 0] += 1
-    return [tuple(r) for r in out]
+    if c.new_kinds:
+        from dlrover_tpu.models import latent
+
+        return Family(latent.labels, latent.param_shapes, latent.init_cache,
+                      latent.forward_cached, latent.forward_uncached,
+                      "benchmark/counts/mla_moe.py")
+    if c.mixers:
+        from dlrover_tpu.models import hybrid
+
+        return Family(hybrid.labels, hybrid.param_shapes, hybrid.init_cache,
+                      hybrid.forward_cached, hybrid.forward_uncached,
+                      "benchmark/counts/sala.py, ssm_moe.py")
+    from dlrover_tpu.models import decode
+
+    if c.layer_kinds:
+        return Family(_labels, _block_shapes, decode.init_ring_cache,
+                      decode.forward_rings, _forward_served)
+    served = c.held_experts or c.generation == "block_diffusion"
+    return Family(_labels, _block_shapes, decode.init_row_cache,
+                  decode.forward_rows, _forward_served if served else None)
+
+
+class Run(NamedTuple):
+    """A run of equal layers of a stack: one scan."""
+    # whose cache its layers share: a mixer's name, "window" (rings) or
+    # "full" (rows), "latent"
+    kind: str
+    key: str            # ``params[key]``: the stacked weights they index
+    first: int          # its first layer
+    # ... among the layers of its kind: where its rows start in that
+    # kind's carried stacks
+    first_of_kind: int
+    n: int              # how many layers
+
+
+def stack_runs(cfg: TransformerConfig) -> list[Run]:
+    """Every family's stack as runs of equal layers: a layer joins the
+    run before it when its whole label (``Family.labels``) is the same."""
+    runs, seen, first = [], {}, 0
+    for (kind, key, *_), group in itertools.groupby(family(cfg).labels(cfg)):
+        n = len(list(group))
+        runs.append(Run(kind, key, first, seen.get(kind, 0), n))
+        seen[kind] = seen.get(kind, 0) + n
+        first += n
+    return runs
+
+
+def layer_kind(cfg: TransformerConfig, layer: int) -> tuple[int, bool]:
+    """`make_layer_fn`'s ``kind`` of one layer: ``(window, rope)``."""
+    return (cfg.layer_windows[layer] if cfg.layer_windows else 0,
+            bool(cfg.layer_rope[layer]) if cfg.layer_rope else True)
+
+
+def _labels(cfg: TransformerConfig) -> list[tuple]:
+    """This file's block: a ring or rows a layer, the kind beside."""
+    return [("window" if w else "full", "layers", w, r) for w, r in (
+        layer_kind(cfg, l) for l in range(cfg.n_layers))]
 
 
 def _check_kinds(cfg: TransformerConfig) -> None:
     """This file's block runs these kinds and names what it does not."""
     c = cfg
+    if (c.attn_kind, c.norm_kind, c.ffn_kind) == (
+            "latent", "sandwich", "sigmoid_experts"):
+        return          # models/latent.py's block, whole
     if (c.attn_kind not in ("heads", "heads_qk_norm", "mixers")
             or c.norm_kind != "pre"
             or c.ffn_kind not in ("", "softmax_experts")
@@ -716,14 +749,15 @@ def _check_kinds(cfg: TransformerConfig) -> None:
 
 
 def param_shapes(cfg: TransformerConfig) -> dict:
-    """The parameter tree of this file's block as shapes (a tuple a
-    leaf): what :func:`init_params` makes and the counts count."""
-    c = cfg
-    _check_kinds(c)
-    if c.mixers:
-        from dlrover_tpu.models.hybrid import param_shapes as mixer_shapes
+    """The parameter tree as shapes (a tuple a leaf): what
+    :func:`init_params` makes and the counts count."""
+    _check_kinds(cfg)
+    return family(cfg).param_shapes(cfg)
 
-        return mixer_shapes(c)
+
+def _block_shapes(cfg: TransformerConfig) -> dict:
+    """:func:`param_shapes` of this file's block."""
+    c = cfg
     e, hd, n = c.d_model, c.head_dim, c.n_layers
     layers = {
         "wq": (e, c.n_heads, hd), "wk": (e, c.n_kv_heads, hd),
@@ -780,8 +814,7 @@ def init_from_shapes(shapes: dict, key: jax.Array, dtype) -> Params:
     dict's leaves are stacked along a leading layer dim), in ``dtype``:
     matrices normal / sqrt(fan_in) (the contracted dims: an expert
     stack's second, an output projection's first two, else the first),
-    norm scales (``ln*``) one. The served kinds' init, here and in
-    ``models/latent.py``."""
+    norm scales (``ln*``) one. The served kinds' init."""
     dt = jnp.dtype(dtype)
     flat, treedef = jax.tree_util.tree_flatten_with_path(
         shapes, is_leaf=lambda s: isinstance(s, tuple))
@@ -811,13 +844,9 @@ def init_from_shapes(shapes: dict, key: jax.Array, dtype) -> Params:
 def init_params(cfg: TransformerConfig, key: jax.Array) -> Params:
     """Initialize an fp32 parameter pytree (layer-stacked)."""
     c = cfg
-    if c.new_kinds:
-        from dlrover_tpu.models.latent import init_params as init_latent
-
-        return init_latent(c, key)
-    _check_kinds(c)
-    if not c.default_kinds:
+    if not c.default_kinds:     # the served kinds: seeded from their shapes
         return init_from_shapes(param_shapes(c), key, c.param_dtype)
+    _check_kinds(c)
     k_embed, k_layers, k_out, k_pos = jax.random.split(key, 4)
     hd = c.head_dim
 
@@ -887,16 +916,12 @@ def logical_axes(cfg: TransformerConfig) -> Params:
     model dim — FSDP shards it), heads/kv_heads (TP), mlp (TP).
     """
     c = cfg
-    if c.new_kinds:
-        raise NotImplementedError(
-            "logical_axes: the latent / sandwich / sigmoid_experts kinds "
-            "run on one device (models/latent.py); no rule table names "
-            "their weights yet")
     if not c.default_kinds:
         raise NotImplementedError(
-            f"logical_axes: attn_kind {c.attn_kind!r} / ffn_kind "
-            f"{c.ffn_kind!r} are served on one device; no rule table "
-            "names ln_q, ln_k or the held experts' stacks yet")
+            f"logical_axes: attn_kind {c.attn_kind!r} / norm_kind "
+            f"{c.norm_kind!r} / ffn_kind {c.ffn_kind!r} are served on one "
+            "device; no rule table names their weights (ln_q, ln_k, the "
+            "held experts' stacks, the latent projections) yet")
     layers = {
         "wq": ("layers", "embed", "heads", None),
         "wk": ("layers", "embed", "kv_heads", None),
@@ -937,12 +962,16 @@ def logical_axes(cfg: TransformerConfig) -> Params:
 # ---------------------------------------------------------------- forward
 
 
+def rms_norm(x, scale, eps: float):
+    x32 = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return (x32 * inv).astype(x.dtype) * scale.astype(x.dtype)
+
+
 def _norm(x, scale, bias, variant: str, eps: float = 1e-6):
-    if variant == "llama":  # RMSNorm; `eps` is cfg.norm_eps where the
-        # block's kinds set it (`_norm_eps`), else the 1e-6 it always was
-        x32 = x.astype(jnp.float32)
-        inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
-        return (x32 * inv).astype(x.dtype) * scale.astype(x.dtype)
+    if variant == "llama":  # `eps` is cfg.norm_eps where the block's
+        # kinds set it (`_norm_eps`), else the 1e-6 it always was
+        return rms_norm(x, scale, eps)
     x32 = x.astype(jnp.float32)
     mean = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.var(x32, axis=-1, keepdims=True)
@@ -991,37 +1020,34 @@ def dense_attention(q, k, v, *, causal: bool = True) -> jax.Array:
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def block_causal_attention(q, k, v, *, block: int,
-                           causal: bool = True) -> jax.Array:
-    """Attention of a block-diffusion model over a whole sequence:
-    positions in blocks of ``block``, a query sees every key up to the
-    END of its own block (``k < (q // block + 1) * block``). [B,S,H,D];
-    fp32 softmax. ``causal`` is accepted for the call's form and has to
-    be true."""
+def _seen_attention(q, k, v, seen, causal: bool = True) -> jax.Array:
+    """Attention over a whole sequence, [B,S,H,D], under a mask of
+    positions: query ``i`` sees key ``j`` iff ``seen(i [S, 1], j [1, K])``.
+    fp32 softmax. ``causal`` is accepted for the call's form and has to be
+    true."""
     assert causal
     scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    ends = (jnp.arange(q.shape[1]) // block + 1) * block
-    mask = jnp.arange(k.shape[1])[None, :] < ends[:, None]
+    mask = seen(jnp.arange(q.shape[1])[:, None], jnp.arange(k.shape[1])[None])
     probs = jax.nn.softmax(jnp.where(mask, logits, -1e30),
                            axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+def block_causal_attention(q, k, v, *, block: int,
+                           causal: bool = True) -> jax.Array:
+    """Attention of a block-diffusion model: positions in blocks of
+    ``block``, a query sees every key up to the END of its own block."""
+    return _seen_attention(
+        q, k, v, lambda i, j: j < (i // block + 1) * block, causal)
+
+
 def windowed_attention(q, k, v, *, window: int,
                        causal: bool = True) -> jax.Array:
-    """Causal attention of a WINDOWED layer over a whole sequence: query
-    ``i`` sees key ``j`` iff ``0 <= i - j < window``. [B,S,H,D]; fp32
-    softmax. ``causal`` is accepted for the call's form and has to be
-    true."""
-    assert causal
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    back = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None, :]
-    probs = jax.nn.softmax(
-        jnp.where((back >= 0) & (back < window), logits, -1e30),
-        axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    """Causal attention of a WINDOWED layer: query ``i`` sees key ``j``
+    iff ``0 <= i - j < window``."""
+    return _seen_attention(
+        q, k, v, lambda i, j: (i - j >= 0) & (i - j < window), causal)
 
 
 def prefix_lm_attention(q, k, v, prefix_len: jax.Array, *,
@@ -1156,7 +1182,7 @@ def make_layer_fn(
 
     ``cfg.layer_windows`` / ``cfg.layer_rope`` (served only): the layers
     are not all of one kind, so the caller runs the stack as
-    :func:`layer_runs` and builds one block a run, ``kind=(window,
+    :func:`stack_runs` and builds one block a run, ``kind=(window,
     rope)``: ``rope`` False leaves the rotary embedding out; ``window``
     > 0 is the mask of the block's own attention (a cached caller's
     ``attend`` owns its mask, and its ring). ``cfg.router_input``
@@ -1174,7 +1200,7 @@ def make_layer_fn(
         raise NotImplementedError(
             "layer_windows / layer_rope (layers of several kinds) are the "
             "forward pass on one device (forward, forward_cached): the "
-            "caller runs the stack as `layer_runs` and says which kind "
+            "caller runs the stack as `stack_runs` and says which kind "
             "each block is; there is neither a kernel attention, a token "
             "mask, a sharding rule, an int8 path nor a gradient for it "
             "(training, parallel/pipeline.py, parallel/mpmd.py)")
@@ -1408,16 +1434,49 @@ def make_layer_fn(
 
 
 def split_experts(layers: dict, cfg: TransformerConfig):
-    """``(experts, scanned)`` of a layer stack: the routed experts'
-    stacks in ``cfg.dtype`` (nothing at all where they rest there), which
-    a caller closes over its block (`make_layer_fn`), and the leaves its
-    layer loop scans in. ``(None, layers)`` for a model without held
-    experts."""
-    if not cfg.held_experts:
-        return None, layers
+    """``(experts, indexed)`` of a layer stack: the routed experts'
+    stacks it holds, in ``cfg.dtype`` (nothing at all where they rest
+    there), which a caller closes over its block (`make_layer_fn`), and
+    the leaves its layer loop indexes a layer at a time. ``(None,
+    layers)`` for a stack without held experts."""
     dt = jnp.dtype(cfg.dtype)
-    return ({k: _leaf(layers, k, dt) for k in EXPERT_STACKS},
-            {k: v for k, v in layers.items() if k not in EXPERT_STACKS})
+    experts = {k: _leaf(layers, k, dt) for k in EXPERT_STACKS if k in layers}
+    return experts or None, {k: v for k, v in layers.items()
+                             if k not in experts}
+
+
+def scan_runs(runs: list[Run], x: jax.Array, held: dict,
+              layer_of: Callable) -> tuple[jax.Array, dict, list]:
+    """THE loop of a served stack, whatever the family: one ``lax.scan``
+    a run (:func:`stack_runs`). ``layer_of(run)``, asked before the run's
+    scan, gives ``(weights, layer)``: the stacked leaves its layers read
+    a layer at a time (``params[run.key]`` less what a block closes over:
+    `split_experts`) and ``layer(x, carried, w, i) -> (x, carried, out)``,
+    ``w`` being ``weights`` at ``i``, the layer's index there. ``carried``
+    is ``held[run.kind]``: what the layers of its kind keep (rows, rings,
+    state; None without a cache). It rides the CARRY beside the
+    activations, and the weights are indexed inside the body: a scanned
+    input or output of a layer's shape would be sliced out and copied
+    back whole, per layer (models/decode.py). Returns ``(x, held, each
+    run's stacked outs)``."""
+    at, outs = {}, []
+    for run in runs:
+        start = at.get(run.key, 0)
+        at[run.key] = start + run.n
+        weights, layer = layer_of(run)
+
+        def body(carry, i):    # traced here, before the next run rebinds
+            w = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, i, keepdims=False),
+                weights)
+            x, carried, out = layer(*carry, w, i)
+            return (x, carried), out
+
+        (x, held[run.kind]), out = lax.scan(
+            body, (x, held.get(run.kind)),
+            jnp.arange(start, start + run.n, dtype=jnp.int32))
+        outs.append(out)
+    return x, held, outs
 
 
 def embed_tokens(
@@ -1489,6 +1548,27 @@ def token_ce(logits: jax.Array, targets: jax.Array) -> jax.Array:
     return nll.mean()
 
 
+def _forward_served(params, tokens, cfg, return_hidden=False,
+                    attention_fn=None, inputs_embeds=None):
+    """`forward_with_aux` for the served-only kinds of this file's block
+    (held experts, block diffusion, layers of several kinds): the runs
+    with no cache. ``attention_fn`` None: the block picks its own
+    (block-causal, windowed) and refuses a kernel by name."""
+    c = cfg
+    x = (inputs_embeds.astype(jnp.dtype(c.dtype))
+         if inputs_embeds is not None else embed_tokens(params, tokens, c))
+    experts, layers = split_experts(params["layers"], c)
+
+    def layer_of(run):
+        block = make_layer_fn(c, attention_fn=attention_fn, experts=experts,
+                              kind=layer_kind(c, run.first))
+        return layers, lambda x, _, w, i: (block(x, w, None, i)[0], None, None)
+
+    x, _, _ = scan_runs(stack_runs(c), x, {}, layer_of)
+    x = final_norm(params, x, c)
+    return x if return_hidden else lm_logits(params, x, c)
+
+
 def forward(
     params: Params,
     tokens: jax.Array,
@@ -1528,61 +1608,28 @@ def forward_with_aux(
     stack with every strategy unchanged.
     """
     c = cfg
-    if c.new_kinds:
-        from dlrover_tpu.models.latent import forward_uncached
-
-        if (attention_fn is not None or c.prefix_lm or c.remat_scan
-                or c.pipeline_stages > 1 or inputs_embeds is not None
-                or mask is not None):
-            raise NotImplementedError(
-                "the latent / sandwich / sigmoid_experts kinds take "
-                "tokens and nothing else (models/latent.py)")
-        return forward_uncached(params, tokens, c,
-                                return_hidden=return_hidden)
-    if c.mixers:
-        from dlrover_tpu.models.hybrid import forward_uncached
-
-        if (attention_fn is not None or c.prefix_lm or c.remat_scan
-                or c.pipeline_stages > 1 or inputs_embeds is not None
-                or mask is not None or constrain is not None):
-            raise NotImplementedError(
-                "attn_kind 'mixers' takes tokens and nothing else "
-                "(models/hybrid.py)")
-        return forward_uncached(params, tokens, c,
-                                return_hidden=return_hidden)
-    dt = jnp.dtype(c.dtype)
-    pin = constrain or (lambda x, a: x)
-    if c.held_experts or c.generation == "block_diffusion" or c.layer_kinds:
+    served = family(c).forward
+    if served is not None:
         if (c.prefix_lm or c.remat_scan or c.pipeline_stages > 1
                 or mask is not None or constrain is not None):
             raise NotImplementedError(
-                f"ffn_kind {c.ffn_kind!r} / generation {c.generation!r} / "
-                "layer_windows, layer_rope: "
+                f"attn_kind {c.attn_kind!r} / ffn_kind {c.ffn_kind!r} / "
+                f"generation {c.generation!r} / layer_windows, layer_rope: "
                 "the forward pass on one device, and nothing of prefix_lm, "
                 "remat_scan, pipeline stages (parallel/pipeline.py), a "
                 "token mask or a sharding rule")
-        x = (inputs_embeds.astype(dt) if inputs_embeds is not None
-             else embed_tokens(params, tokens, cfg))
-        experts, scanned = split_experts(params["layers"], c)
-        for window, rope, first, _, n in layer_runs(c):
-            # attention_fn None: the block picks its own (block-causal
-            # for a block-diffusion model, windowed for a windowed run)
-            # and refuses a kernel by name
-            layer = make_layer_fn(
-                cfg, attention_fn=attention_fn, experts=experts,
-                kind=(window, rope) if c.layer_kinds else None)
-
-            def served_body(x, inputs, layer=layer):
-                w, i = inputs
-                return layer(x, w, None, i)[0], None
-
-            x, _ = lax.scan(served_body, x, (
-                scanned if n == c.n_layers else jax.tree.map(
-                    lambda a: a[first:first + n], scanned),
-                jnp.arange(first, first + n, dtype=jnp.int32)))
-        x = final_norm(params, x, c)
-        return (x if return_hidden else lm_logits(params, x, c),
+        if served is _forward_served:
+            served = partial(served, attention_fn=attention_fn,
+                             inputs_embeds=inputs_embeds)
+        elif attention_fn is not None or inputs_embeds is not None:
+            raise NotImplementedError(
+                f"attn_kind {c.attn_kind!r} takes tokens and nothing else "
+                "(models/latent.py, models/hybrid.py)")
+        # served, not trained: no balancing loss, so the aux term is zero
+        return (served(params, tokens, c, return_hidden),
                 jnp.zeros((), jnp.float32))
+    dt = jnp.dtype(c.dtype)
+    pin = constrain or (lambda x, a: x)
     if c.prefix_lm:
         if attention_fn is not None and attention_fn is not dense_attention:
             raise NotImplementedError(

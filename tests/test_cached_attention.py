@@ -1,6 +1,6 @@
 """The cached decode attention kernel (``ops/cached_attention.py``) off the
 TPU, in interpret mode, against the einsum it takes the place of
-(``decode._layer_attend``, which stays the path off the TPU and the
+(``cache.layer_attend``, which stays the path off the TPU and the
 oracle here, as ``held_expert_loop`` is the grouped kernel's).
 
 What interpret mode shows: the walk over (row, key block) pairs, the
@@ -20,8 +20,8 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models import transformer as tfm
-from dlrover_tpu.models.decode import (
-    _layer_attend, forward_cached, init_cache)
+from dlrover_tpu.models.cache import layer_attend
+from dlrover_tpu.models.decode import forward_cached, init_cache
 from dlrover_tpu.ops import cached_attention as ca
 
 GPT2 = dict(G=16, n_rep=1, D=64)        # gpt2-medium: 16 heads of 64
@@ -72,7 +72,7 @@ def test_kernel_reads_each_row_as_far_as_it_reaches(case):
     limits = _limits(pos, S, block)
     assert ca.takes(q.shape, k.shape, n_rep, 2)
 
-    want = _layer_attend(
+    want = layer_attend(
         q, k[layer].reshape(B, max_len, G, D),
         v[layer].reshape(B, max_len, G, D), pos, n_rep, dt, block=block)
     if poison:

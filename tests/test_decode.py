@@ -12,9 +12,9 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models import transformer as tfm
+from dlrover_tpu.models.cache import write_rows
 from dlrover_tpu.models.decode import (
     PRODUCT_LEAVES,
-    _write_rows,
     forward_cached,
     generate,
     init_cache,
@@ -180,7 +180,7 @@ class TestStackIsCarriedAndWrittenInPlace:
             jax.random.PRNGKey(0), (L, B, max_len, H, D)))
         new = np.asarray(jax.random.normal(
             jax.random.PRNGKey(1), (B, s_new, H, D)))
-        got = jax.jit(_write_rows)(
+        got = jax.jit(write_rows)(
             stack, new, 1, jnp.asarray(start, jnp.int32))
         want = stack.copy()
         for b in range(B):
@@ -188,7 +188,7 @@ class TestStackIsCarriedAndWrittenInPlace:
             want[1, b, at:at + s_new] = new[b]
         np.testing.assert_array_equal(np.asarray(got), want)
         if len(set(start)) == 1:      # lockstep: the scalar form agrees
-            np.testing.assert_array_equal(np.asarray(jax.jit(_write_rows)(
+            np.testing.assert_array_equal(np.asarray(jax.jit(write_rows)(
                 stack, new, 1, jnp.asarray(start[0], jnp.int32))), want)
 
     @pytest.mark.parametrize("pos_kind", ["scalar", "vector"])

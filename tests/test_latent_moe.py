@@ -17,6 +17,7 @@ from benchmark.reference import pangu_ultra_moe as ref
 from dlrover_tpu.common.constants import EnvKey
 from dlrover_tpu.models import decode, latent
 from dlrover_tpu.models import transformer as tfm
+from dlrover_tpu.models.cache import key_reaches, reach_of
 from dlrover_tpu.ops import moe
 from dlrover_tpu.serving import InferenceEngine
 from dlrover_tpu.serving.engine import SamplingParams
@@ -148,7 +149,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         rcfg = moe.RoutedConfig(n_experts=16, top_k=4, scaling=2.5,
                                 first=first, held=4)
         idx, gate = moe.sigmoid_topk_route(h, w["w_router"], rcfg)
-        held = {k: w[k][first:first + 4] for k in latent.EXPERT_STACKS}
+        held = {k: w[k][first:first + 4] for k in tfm.EXPERT_STACKS}
         part, loads = moe.held_expert_ffn(
             h, idx, gate, {k: v[None] for k, v in held.items()}, 0, rcfg)
         mine = ref.expert_layer(whole, h, {**w, **held},
@@ -230,19 +231,19 @@ def test_chunks_that_end_around_every_reach_agree_with_the_uncached_forward(
 @pytest.mark.parametrize("S,K", [(16, 80), (16, 64), (64, 1000), (128, 128),
                                  (512, 5120), (65, 66)])
 def test_the_chosen_reach_holds_the_last_query_at_every_position(S, K):
-    reach = latent.key_reaches(S, K)
+    reach = key_reaches(S, K)
     assert reach[0] == S and reach[-1] == K and reach == sorted(set(reach))
     assert all(b == 2 * a for a, b in zip(reach[:-2], reach[1:-1]))
     for pos in sorted({*range(0, K - S + 1, max(1, S // 4)), K - S}):
         q_pos = pos + np.arange(S)[None]
-        got, which = latent.reach_of(jnp.asarray(q_pos), S, K)
+        got, which = reach_of(jnp.asarray(q_pos), S, K)
         keys = got[int(which)]
         assert got == reach
         assert keys >= pos + S and keys == _first_reach(pos, S, K)
         # doubling: never more than twice what the call can see
         assert keys < 2 * (pos + S) or keys == S
     # rows at positions of their own: the farthest decides
-    _, which = latent.reach_of(jnp.asarray([[0, 1], [K - 2, K - 1]]), S, K)
+    _, which = reach_of(jnp.asarray([[0, 1], [K - 2, K - 1]]), S, K)
     assert reach[int(which)] == K
 
 

@@ -58,7 +58,8 @@ def test_the_file_builds_the_share_of_the_published_preset(model):
     assert cfg.mixer_types == tfm.single_mixers("MEM*EME") == (
         "mamba2", "latent_experts", "mamba2", "attention", "latent_experts",
         "mamba2", "latent_experts")
-    assert hybrid.runs(cfg) == [
+    assert [(r.kind, r.first_of_kind, r.n)
+            for r in tfm.stack_runs(cfg)] == [
         ("mamba2", 0, 1), ("latent_experts", 0, 1), ("mamba2", 1, 1),
         ("attention", 0, 1), ("latent_experts", 1, 1), ("mamba2", 2, 1),
         ("latent_experts", 2, 1)]

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from dlrover_tpu.models import decode, transformer as tfm
+from dlrover_tpu.models.cache import (
+    layer_attend, ring_attend, ring_positions, write_ring)
 from dlrover_tpu.ops import moe
 from dlrover_tpu.serving.engine import InferenceEngine, SamplingParams
 
@@ -62,13 +64,17 @@ def greedy(n):
 
 
 def test_the_stack_is_runs_of_equal_layers():
-    assert tfm.layer_runs(CFG) == [
-        (0, False, 0, 0, 1), (WINDOW, True, 1, 0, 3),
-        (0, False, 4, 1, 1), (WINDOW, True, 5, 3, 3)]
-    assert tfm.layer_runs(tfm.CONFIGS["tiny-sdar-moe"]) == [
-        (0, True, 0, 0, 3)]
+    runs = tfm.stack_runs(CFG)
+    assert runs == [
+        ("full", "layers", 0, 0, 1), ("window", "layers", 1, 0, 3),
+        ("full", "layers", 4, 1, 1), ("window", "layers", 5, 3, 3)]
+    assert [tfm.layer_kind(CFG, r.first) for r in runs] == [
+        (0, False), (WINDOW, True)] * 2
+    sdar = tfm.CONFIGS["tiny-sdar-moe"]
+    assert tfm.stack_runs(sdar) == [("full", "layers", 0, 0, 3)]
+    assert tfm.layer_kind(sdar, 0) == (0, True)
     full = tfm.CONFIGS["smallthinker-21b-a3b-instruct"]
-    assert len(tfm.layer_runs(full)) == 26 and (
+    assert len(tfm.stack_runs(full)) == 26 and (
         full.layer_windows[:4], full.layer_rope[:4]) == (
         (0, 4096, 4096, 4096), (False, True, True, True))
     with pytest.raises(ValueError, match="layer_windows"):
@@ -271,7 +277,7 @@ def test_a_decode_step_of_a_row_that_wrapped_beside_one_that_did_not(params):
 
 
 def test_the_ring_is_a_mask_over_a_full_length_row(params):
-    """(c) ``_ring_attend`` against ``_layer_attend(window=)`` over a
+    """(c) ``ring_attend`` against ``layer_attend(window=)`` over a
     full-length row at equal inputs: a decode step behind 21 keys and a
     chunk of 5 behind 13, rows at positions of their own."""
     G, D, H, L = CFG.n_kv_heads, CFG.head_dim, CFG.n_heads, 32
@@ -288,7 +294,7 @@ def test_the_ring_is_a_mask_over_a_full_length_row(params):
             for b in range(2):
                 ring = jnp.zeros((1, 1, G, WINDOW, D))
                 for p in range(pos[b]):
-                    ring = decode._write_ring(
+                    ring = write_ring(
                         ring, src[b:b + 1, p:p + 1], 0, jnp.asarray([p]),
                         jnp.ones((1,), jnp.int32))
                 rows.append(ring)
@@ -297,17 +303,17 @@ def test_the_ring_is_a_mask_over_a_full_length_row(params):
                            for b in range(2)])
         new_v = jnp.stack([jax.lax.dynamic_slice_in_dim(v_all[b], pos[b], S)
                            for b in range(2)])
-        got, _, _ = decode._ring_attend(
+        got, _, _ = ring_attend(
             q, new_k, new_v, rings[0], rings[1], 0, pos_b,
             jnp.full((2,), S, jnp.int32), WINDOW, H // G, jnp.float32)
-        want = decode._layer_attend(q, k_all, v_all, pos_b, H // G,
-                                    jnp.float32, window=WINDOW)
+        want = layer_attend(q, k_all, v_all, pos_b, H // G, jnp.float32,
+                            window=WINDOW)
         assert float(jnp.abs(got - want).max()) < 1e-5, S
 
 
 def test_ring_positions_are_the_latest_of_each_slot():
     last = jnp.asarray([-1, 0, 7, 8, 21])
-    got = np.asarray(decode._ring_positions(last, 8))
+    got = np.asarray(ring_positions(last, 8))
     assert (got[0] < 0).all()
     assert got[1].tolist() == [0] + [-8 + s for s in range(1, 8)]
     assert got[2].tolist() == list(range(8))
