@@ -81,7 +81,7 @@ def _kv_cache(cfg, shape: tuple, counted: tuple) -> dict:
     counters = {name: jnp.zeros((), jnp.int32) for name in counted}
     if c.held_experts:
         counters = {**counters, **held_counters(
-            c.n_layers, tfm.routed_config(c).n_held)}
+            c.n_layers - c.dense_layers, tfm.routed_config(c).n_held)}
     cache = {"k": jnp.zeros(shape, jnp.dtype(c.dtype)),
              "v": jnp.zeros(shape, jnp.dtype(c.dtype)),
              "pos": jnp.zeros((), jnp.int32)}
@@ -137,10 +137,12 @@ READ_COUNTERS = ("attn_keys_read", "context_tokens")
 # real token of one row in one call): how many there were, their
 # positions summed (`context_tokens`, as models/hybrid.py counts it: what
 # a full layer reads of a row), `min(position, window)` summed (what a
-# windowed layer reads of it) and how many lay past the window (the ring
-# had wrapped)
+# windowed layer reads of it), how many lay past the window (the ring
+# had wrapped), and how many no ring slot keeps because their call was
+# wider than the ring (`cache.write_ring` keeps a wide call's last
+# `window` real tokens: 0 wherever a chunk fits the ring)
 WINDOW_COUNTERS = ("row_steps", "context_tokens", "window_keys",
-                   "ring_wrapped_row_steps")
+                   "ring_wrapped_row_steps", "ring_chunk_tokens_dropped")
 
 
 def weights_at_rest(params: Params, cfg: TransformerConfig) -> Params:
@@ -202,37 +204,44 @@ def _through_blocks(params, tokens, cache, cfg, attends: dict, held: dict):
     """Both of this file's cached forwards: the embedding, the runs
     through training's block (``attends[kind]`` a run's hook, ``held[kind]``
     the ``(k, v)`` stacks its kind's layers carry) and the head: ``(logits,
-    held, each run's expert layers' loads or None)``."""
+    held, the expert layers' loads [expert layers, held] or None)``."""
     c = cfg
     if c.int8_matmuls:
         # the cached products are plain whatever training ran (D12)
         c = dataclasses.replace(c, int8_matmuls=False)
     B, S = tokens.shape
     pos = cache["pos"]
-    # the held experts' stacks are closed over the block and indexed in
-    # place by its tile loop; everything else is read a layer at a time
-    experts, layers = tfm.split_experts(params["layers"], c)
     positions = tfm.token_positions(pos, B, S)
+    runs = tfm.stack_runs(c)
+    # the held experts' stacks are closed over the block and indexed in
+    # place by its tile loop; everything else is read a layer at a time,
+    # out of the tree the run's layers lie in
+    split = {key: tfm.split_experts(params[key], c)
+             for key in {run.key for run in runs}}
 
     def layer_of(run):
+        experts, layers = split[run.key]
         block = tfm.make_layer_fn(
             c, attend=attends[run.kind], positions=positions,
-            experts=experts, kind=tfm.layer_kind(c, run.first))
+            experts=experts, kind=tfm.layer_kind(c, run.first),
+            dense=run.key == "dense_layers")
+        # `i` counts the layers of the run's tree; a layer's rows lie at
+        # its index among the layers of its kind
+        in_tree = sum(r.n for r in runs[:runs.index(run)] if r.key == run.key)
 
         def layer(x, rows, w, i):
-            # a layer's rows lie at its index among the layers of its kind
             x, aux, (*rows, _) = block(
-                x, w, (*rows, i + (run.first_of_kind - run.first)), i)
-            return x, tuple(rows), (aux if c.held_experts else None)
+                x, w, (*rows, i + (run.first_of_kind - in_tree)), i)
+            return x, tuple(rows), (aux if experts is not None else None)
 
         return layers, layer
 
     x, held, loads = tfm.scan_runs(
-        tfm.stack_runs(c), tfm.embed_tokens(params, tokens, c, pos=pos),
-        held, layer_of)
+        runs, tfm.embed_tokens(params, tokens, c, pos=pos), held, layer_of)
     with jax.named_scope("lm_head"):
         logits = tfm.lm_logits(params, tfm.final_norm(params, x, c), c)
-    return logits, held, loads
+    loads = [mine for mine in loads if mine is not None]
+    return logits, held, jnp.concatenate(loads) if loads else None
 
 
 def forward_rows(params, tokens, cache, cfg, real=None):
@@ -306,7 +315,7 @@ def forward_rows(params, tokens, cache, cfg, real=None):
         + jnp.sum(read).astype(jnp.int32),
         "context_tokens": old["context_tokens"] + jnp.sum(reach)}
     if c.held_experts:
-        new["counters"].update(count_loads(old, loads[0]))
+        new["counters"].update(count_loads(old, loads))
     return logits, new
 
 
@@ -366,14 +375,16 @@ def forward_rings(params, tokens, cache, cfg, real=None):
     new = {"k": held["full"][0], "v": held["full"][1], "pos": pos + S}
     old, counters = cache.get("counters", {}), {}
     if c.held_experts:
-        counters = count_loads(old, jnp.concatenate(loads))
+        counters = count_loads(old, loads)
     if not window:
         return logits, {**new, **({"counters": counters} if counters else {})}
     live = jnp.arange(S)[None] < real_b[:, None]
     for name, what in (
             ("row_steps", live), ("context_tokens", q_pos),
             ("window_keys", jnp.minimum(q_pos, window)),
-            ("ring_wrapped_row_steps", q_pos >= window)):
+            ("ring_wrapped_row_steps", q_pos >= window),
+            ("ring_chunk_tokens_dropped",
+             jnp.arange(S)[None] < real_b[:, None] - window)):
         counters[name] = old[name] + jnp.sum(
             jnp.where(live, what, 0).astype(jnp.int32))
     return logits, {**new, "counters": counters, "state": {

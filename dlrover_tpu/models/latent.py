@@ -21,7 +21,8 @@ RMSNorm ``N`` (eps ``cfg.norm_eps``); ``h`` is a layer's normed input:
              score_h = (q_nope_h . k_nope_h + q_rope_h . k_rope)
                        / sqrt(nope + rope); causal softmax in float32;
              out = concat_h(sum p v_h) W_o
-  experts    ops/moe.py: sigmoid_topk_route, held_expert_ffn, swiglu
+  experts    ops/moe.py: sigmoid_expert_half (the one this kind's layers
+             share with models/transformer.py's block), swiglu
 
 **The cache is ``c_kv`` and ``k_rope``**: one stack ``latent [L, B,
 max_len, kv_lora_rank + qk_rope_head_dim]`` (576 numbers a token a layer
@@ -70,10 +71,7 @@ ABSORB_UPTO = 64
 KINDS = ("latent", "sandwich", "sigmoid_experts")
 
 
-def labels(cfg) -> list[tuple]:
-    """One cache, the latent stack, under every layer; the first
-    ``first_k_dense`` layers' weights under ``dense_layers``, the expert
-    layers' under ``layers``: each a whole run (``transformer.stack_runs``)."""
+def _only_kinds(cfg) -> None:
     kinds = (cfg.attn_kind, cfg.norm_kind, cfg.ffn_kind)
     if kinds != KINDS:
         # 'pre' norms or a plain 'swiglu' under latent attention: no
@@ -81,12 +79,20 @@ def labels(cfg) -> list[tuple]:
         raise NotImplementedError(
             f"attn_kind / norm_kind / ffn_kind {kinds}: models/latent.py "
             f"runs {KINDS} together, and `variant` names the rest")
+
+
+def labels(cfg) -> list[tuple]:
+    """One cache, the latent stack, under every layer; the first
+    ``first_k_dense`` layers' weights under ``dense_layers``, the expert
+    layers' under ``layers``: each a whole run (``transformer.stack_runs``)."""
+    _only_kinds(cfg)
     return [("latent", "dense_layers" if l < cfg.first_k_dense else "layers")
             for l in range(cfg.n_layers)]
 
 
 def segments(cfg) -> list[tuple[str, bool, int]]:
     """The stack as ``(params key, expert layer?, layers)`` runs."""
+    _only_kinds(cfg)
     return [(r.key, r.key == "layers", r.n) for r in tfm.stack_runs(cfg)]
 
 
@@ -274,17 +280,8 @@ def forward_cached(params: Params, tokens: jax.Array, cache: dict | None,
                 ff = moe.swiglu(h, w["w_gate"].astype(dt),
                                 w["w_up"].astype(dt), w["w_down"].astype(dt))
         else:
-            ht = h.reshape(B * S, -1)
-            with jax.named_scope("moe_router"):
-                idx, gate = moe.sigmoid_topk_route(ht, w["w_router"], rcfg)
-            with jax.named_scope("moe_experts"):
-                routed, loads = moe.held_expert_ffn(
-                    ht, idx, gate, experts, layer, rcfg)
-            with jax.named_scope("moe_shared"):
-                shared = moe.swiglu(
-                    h, w["ws_gate"].astype(dt), w["ws_up"].astype(dt),
-                    w["ws_down"].astype(dt))
-            ff = shared + routed.reshape(B, S, -1).astype(dt)
+            ff, loads = moe.sigmoid_expert_half(h, w, experts, layer, rcfg,
+                                                dt)
         x = x + rms(ff, w["ln_post_mlp"])
         return x, stack, loads
 

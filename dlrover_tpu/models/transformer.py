@@ -127,7 +127,14 @@ class TransformerConfig:
     # ffn_kind "softmax_experts" (every layer `n_routed_experts` SwiGLU
     # experts of `moe_d_ff`, `moe_top_k` a token by a softmax router
     # renormalised over the chosen, no capacity and no drops:
-    # ops/moe.py `softmax_topk_route` + `held_expert_ffn`).
+    # ops/moe.py `softmax_topk_route` + `held_expert_ffn`). It also runs
+    # norm_kind "post" (NO norm on a sublayer's input: `ln1` / `ln2` norm
+    # the attention's and the feed-forward's OUTPUT before the residual
+    # add) and ffn_kind "sigmoid_experts" with its `first_k_dense` dense
+    # layers (their weights under `dense_layers`, the expert layers' under
+    # `layers`, with a score bias `b_router`): the expert half is
+    # ops/moe.py `sigmoid_expert_half`, which models/latent.py's block
+    # calls too.
     attn_kind: str = "heads"
     norm_kind: str = "pre"
     ffn_kind: str = ""
@@ -319,20 +326,22 @@ class TransformerConfig:
                     f"sparse_block: got {c}")
 
     @property
-    def new_kinds(self) -> bool:
-        """True where models/latent.py runs the block."""
-        return (self.attn_kind == "latent" or self.norm_kind != "pre"
-                or self.ffn_kind == "sigmoid_experts")
-
-    @property
     def mixers(self) -> bool:
         """True where the stack is a list of mixers (models/hybrid.py)."""
         return self.attn_kind == "mixers"
 
     @property
     def held_experts(self) -> bool:
-        """True where this file's block runs the no-drop expert layer."""
-        return self.ffn_kind == "softmax_experts"
+        """True where this file's block runs a no-drop expert layer."""
+        return self.attn_kind != "latent" and self.ffn_kind in (
+            "softmax_experts", "sigmoid_experts")
+
+    @property
+    def dense_layers(self) -> int:
+        """The leading layers of this file's block whose feed-forward half
+        is one SwiGLU of `d_ff` (their weights under `dense_layers`)."""
+        return (min(self.first_k_dense, self.n_layers)
+                if self.ffn_kind == "sigmoid_experts" else 0)
 
     @property
     def layer_kinds(self) -> bool:
@@ -613,6 +622,37 @@ CONFIGS = {
         expert_form="reglu", layer_windows=(0, 4096, 4096, 4096) * 13,
         layer_rope=(False, True, True, True) * 13,
         param_dtype="bfloat16"),
+    # the kinds of the entry below at a size for CPU tests: the dense layer
+    # and four expert layers, L L L G L, a window of 4, all 16 experts held
+    # (a test holds each eighth)
+    "tiny-k-exaone": TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=5, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=96, max_seq_len=256, rope_theta=10000.0,
+        rope_pairing="half", norm_eps=1e-5, attn_kind="heads_qk_norm",
+        norm_kind="post", ffn_kind="sigmoid_experts", first_k_dense=1,
+        n_routed_experts=16, moe_top_k=4, moe_d_ff=32, n_shared_experts=1,
+        routed_scaling_factor=2.5, norm_topk_prob=True,
+        layer_windows=(4, 4, 4, 0, 4),
+        layer_rope=(True, True, True, False, True), dtype="float32"),
+    # K-EXAONE-236B-A23B as published (config.json, model_type exaone_moe):
+    # layer_types LLLG x 12 (three windowed layers of 128 with a rotary
+    # embedding, then a full layer WITHOUT one), norms on the sublayers'
+    # OUTPUTS, q/k norms, layer 0 dense and 47 layers of 128 sigmoid-routed
+    # experts 8 a token (score + bias chosen, x 2.5) beside one shared
+    # expert. A deployment sets the share it holds with dataclasses.replace
+    # (n_layers, layer_windows, layer_rope, first_k_dense, experts_held,
+    # expert_first, vocab_size). Its multi-token-prediction module is not
+    # modelled.
+    "k-exaone-236b-a23b": TransformerConfig(
+        vocab_size=153600, d_model=6144, n_layers=48, n_heads=64,
+        n_kv_heads=8, head_dim=128, d_ff=18432, max_seq_len=262144,
+        rope_theta=1000000.0, rope_pairing="half", norm_eps=1e-5,
+        attn_kind="heads_qk_norm", norm_kind="post",
+        ffn_kind="sigmoid_experts", first_k_dense=1, n_routed_experts=128,
+        moe_top_k=8, moe_d_ff=2048, n_shared_experts=1,
+        routed_scaling_factor=2.5, norm_topk_prob=True,
+        layer_windows=(128, 128, 128, 0) * 12,
+        layer_rope=(True, True, True, False) * 12, param_dtype="bfloat16"),
 }
 
 
@@ -633,7 +673,8 @@ def routed_config(cfg: TransformerConfig):
         n_experts=cfg.n_routed_experts, top_k=cfg.moe_top_k,
         norm_topk=cfg.norm_topk_prob, first=cfg.expert_first,
         held=cfg.experts_held,
-        scaling=1.0 if cfg.held_experts else cfg.routed_scaling_factor,
+        scaling=(1.0 if cfg.ffn_kind == "softmax_experts"
+                 else cfg.routed_scaling_factor),
         form="relu2" if cfg.moe_latent else cfg.expert_form)
 
 
@@ -658,7 +699,7 @@ def family(cfg: TransformerConfig) -> Family:
     decide whose code runs it, and the one place this file imports the
     modules above it."""
     c = cfg
-    if c.new_kinds:
+    if c.attn_kind == "latent":
         from dlrover_tpu.models import latent
 
         return Family(latent.labels, latent.param_shapes, latent.init_cache,
@@ -672,12 +713,17 @@ def family(cfg: TransformerConfig) -> Family:
                       "benchmark/counts/sala.py, ssm_moe.py")
     from dlrover_tpu.models import decode
 
+    # two stacked trees are counted where the family's cell counts them
+    counted_by = ("benchmark/counts/swa_shared_moe.py"
+                  if c.ffn_kind == "sigmoid_experts" else "")
     if c.layer_kinds:
         return Family(_labels, _block_shapes, decode.init_ring_cache,
-                      decode.forward_rings, _forward_served)
-    served = c.held_experts or c.generation == "block_diffusion"
+                      decode.forward_rings, _forward_served, counted_by)
+    served = (c.held_experts or c.generation == "block_diffusion"
+              or c.norm_kind != "pre")
     return Family(_labels, _block_shapes, decode.init_row_cache,
-                  decode.forward_rows, _forward_served if served else None)
+                  decode.forward_rows, _forward_served if served else None,
+                  counted_by)
 
 
 class Run(NamedTuple):
@@ -712,9 +758,12 @@ def layer_kind(cfg: TransformerConfig, layer: int) -> tuple[int, bool]:
 
 
 def _labels(cfg: TransformerConfig) -> list[tuple]:
-    """This file's block: a ring or rows a layer, the kind beside."""
-    return [("window" if w else "full", "layers", w, r) for w, r in (
-        layer_kind(cfg, l) for l in range(cfg.n_layers))]
+    """This file's block: a ring or rows a layer, the tree its weights
+    lie in (a 'sigmoid_experts' stack's leading dense layers have one of
+    their own) and the kind beside."""
+    return [("window" if w else "full",
+             "dense_layers" if l < cfg.dense_layers else "layers", w, r)
+            for l in range(cfg.n_layers) for w, r in [layer_kind(cfg, l)]]
 
 
 def _check_kinds(cfg: TransformerConfig) -> None:
@@ -723,15 +772,20 @@ def _check_kinds(cfg: TransformerConfig) -> None:
     if (c.attn_kind, c.norm_kind, c.ffn_kind) == (
             "latent", "sandwich", "sigmoid_experts"):
         return          # models/latent.py's block, whole
+    sigmoid = c.ffn_kind == "sigmoid_experts"
     if (c.attn_kind not in ("heads", "heads_qk_norm", "mixers")
-            or c.norm_kind != "pre"
-            or c.ffn_kind not in ("", "softmax_experts")
+            or c.norm_kind not in ("pre", "post")
+            or c.ffn_kind not in ("", "softmax_experts", "sigmoid_experts")
             or (c.ffn_kind and c.moe_experts)
-            or (c.mixers and (c.ffn_kind or c.moe_experts))
+            or (c.mixers and (c.ffn_kind or c.moe_experts
+                              or c.norm_kind != "pre"))
             or (not c.default_kinds and c.variant != "llama")
             or c.rope_pairing not in ("interleaved", "half")
             or ((c.router_input != "ffn" or c.expert_form != "swiglu")
-                and not c.held_experts)
+                and c.ffn_kind != "softmax_experts")
+            or (sigmoid and (min(c.n_routed_experts, c.moe_top_k, c.moe_d_ff,
+                                 c.n_shared_experts) < 1
+                             or not 0 <= c.first_k_dense <= c.n_layers))
             or (c.layer_kinds and (c.mixers or c.moe_experts
                                    or c.generation != "autoregressive"))):
         raise NotImplementedError(
@@ -739,12 +793,15 @@ def _check_kinds(cfg: TransformerConfig) -> None:
             f"{c.norm_kind!r}, {c.ffn_kind!r}) with variant {c.variant!r}"
             f", moe_experts {c.moe_experts}, rope_pairing "
             f"{c.rope_pairing!r}: models/transformer.py runs 'heads' or "
-            "'heads_qk_norm' attention under 'pre' norms with the "
-            "variant's FFN, `moe_experts` or 'softmax_experts' (llama "
-            "variant; `router_input` and `expert_form` are that kind's), "
-            "'mixers' (models/hybrid.py) with the llama FFN, "
-            "`layer_windows` / `layer_rope` for autoregressive 'heads' "
-            "kinds without `moe_experts`, and models/latent.py runs "
+            "'heads_qk_norm' attention under 'pre' or 'post' norms with the "
+            "variant's FFN, `moe_experts`, 'softmax_experts' (llama "
+            "variant; `router_input` and `expert_form` are that kind's) or "
+            "'sigmoid_experts' (llama variant; `n_routed_experts`, "
+            "`moe_top_k`, `moe_d_ff` and a shared expert set, "
+            "`first_k_dense` within the stack), 'mixers' (models/hybrid.py) "
+            "under 'pre' norms with the llama FFN, `layer_windows` / "
+            "`layer_rope` for autoregressive 'heads' kinds without "
+            "`moe_experts` or 'mixers', and models/latent.py runs "
             "('latent', 'sandwich', 'sigmoid_experts')")
 
 
@@ -766,25 +823,35 @@ def _block_shapes(cfg: TransformerConfig) -> dict:
     }
     if c.attn_kind == "heads_qk_norm":
         layers.update(ln_q=(hd,), ln_k=(hd,))
+    dense = {"w_gate": (e, c.d_ff), "w_down": (c.d_ff, e)}
+    if c.variant == "llama":
+        dense["w_up"] = (e, c.d_ff)
+    else:
+        dense.update(b_ff=(c.d_ff,), b_out=(e,), ln1_b=(e,), ln2_b=(e,))
+    attention = dict(layers)
     if c.held_experts:
         held, f = routed_config(c).n_held, c.moe_d_ff
         layers.update(w_router=(e, c.n_routed_experts),
                       we_gate=(held, e, f), we_up=(held, e, f),
                       we_down=(held, f, e))
+        if c.ffn_kind == "sigmoid_experts":
+            fs = c.n_shared_experts * f
+            layers.update(b_router=(c.n_routed_experts,), ws_gate=(e, fs),
+                          ws_up=(e, fs), ws_down=(fs, e))
     elif c.moe_experts:
         layers.update(w_router=(e, c.moe_experts),
                       w_in=(c.moe_experts, e, c.d_ff),
                       w_out=(c.moe_experts, c.d_ff, e))
     else:
-        layers.update(w_gate=(e, c.d_ff), w_down=(c.d_ff, e))
-        if c.variant == "llama":
-            layers["w_up"] = (e, c.d_ff)
-        else:
-            layers.update(b_ff=(c.d_ff,), b_out=(e,), ln1_b=(e,),
-                          ln2_b=(e,))
-    tree = {"embed": (c.vocab_size, e),
-            "layers": {k: (n, *v) for k, v in layers.items()},
-            "ln_f": (e,), "lm_head": (e, c.vocab_size)}
+        layers.update(dense)
+    tree = {"embed": (c.vocab_size, e)}
+    # a stacked tree for each of `_labels`' keys that holds a layer
+    for key, shapes, held in (
+            ("dense_layers", {**attention, **dense}, c.dense_layers),
+            ("layers", layers, n - c.dense_layers)):
+        if held:
+            tree[key] = {k: (held, *v) for k, v in shapes.items()}
+    tree.update(ln_f=(e,), lm_head=(e, c.vocab_size))
     if c.variant == "gpt2":
         tree.update(pos_embed=(c.max_seq_len, e), ln_f_b=(e,))
     return tree
@@ -1089,7 +1156,7 @@ AttentionFn = Callable[..., jax.Array]
 PRODUCT_LEAVES = frozenset({
     "embed", "pos_embed", "wq", "wk", "wv", "wo", "w_og", "w_gate", "w_up",
     "w_down", "b_ff", "b_out", "lm_head", *EXPERT_STACKS, "w_ssm_in",
-    "w_ssm_out", "w_lat_down", "w_lat_up", "ws_up", "ws_down"})
+    "w_ssm_out", "w_lat_down", "w_lat_up", "ws_gate", "ws_up", "ws_down"})
 
 
 def _leaf(tree, name: str, dt) -> jax.Array:
@@ -1125,6 +1192,7 @@ def make_layer_fn(
     experts: dict | None = None,
     mixer: str = "",
     kind: tuple | None = None,
+    dense: bool = False,
 ) -> Callable[..., tuple[jax.Array, jax.Array, Any]]:
     """One transformer block as a reusable ``(x, w, state=None,
     index=None) -> (x, aux, state)``: THE definition of what a dense
@@ -1226,22 +1294,36 @@ def make_layer_fn(
         from dlrover_tpu.ops import moe as _moe
 
         rcfg = routed_config(c)
+    post = c.norm_kind == "post"
+    if post and (constrain is not None or mask is not None
+                 or c.int8_matmuls):
+        raise NotImplementedError(
+            "norm_kind 'post' is the forward pass on one device (forward, "
+            "forward_cached): there is neither a token mask, a sharding "
+            "rule, an int8 path nor a gradient for it (training, parallel/"
+            "pipeline.py, parallel/mpmd.py)")
+    if dense and c.ffn_kind != "sigmoid_experts":
+        raise ValueError("a `dense` block is one of the `first_k_dense` "
+                         "layers of a 'sigmoid_experts' stack")
+    routed = c.held_experts and not dense
     if c.held_experts:
-        if experts is None or mask is not None or constrain is not None:
+        if ((experts is None) == routed or mask is not None
+                or constrain is not None):
             raise NotImplementedError(
-                "ffn_kind 'softmax_experts' is the forward pass on one "
+                f"ffn_kind {c.ffn_kind!r} is the forward pass on one "
                 "device (forward, forward_cached): the caller closes the "
-                "experts' stacks over the block, and there is neither a "
-                "token mask, a sharding rule nor a gradient for it "
-                "(training, parallel/pipeline.py, parallel/mpmd.py)")
+                "experts' stacks over the block (none over a `dense` one), "
+                "and there is neither a token mask, a sharding rule nor a "
+                "gradient for it (training, parallel/pipeline.py, "
+                "parallel/mpmd.py)")
         if c.int8_matmuls:
             raise NotImplementedError(
-                "int8_matmuls with ffn_kind 'softmax_experts': the held "
+                f"int8_matmuls with ffn_kind {c.ffn_kind!r}: the held "
                 "experts' grouped product has no int8 path")
         from dlrover_tpu.ops import moe as _moe
 
         rcfg = routed_config(c)
-    router_early = c.held_experts and c.router_input == "attention"
+    router_early = routed and c.router_input == "attention"
     if c.generation == "block_diffusion" and attend is None:
         if attention_fn is not None:
             raise NotImplementedError(
@@ -1360,7 +1442,8 @@ def make_layer_fn(
         at = (positions if positions is not None
               else token_positions(None, *x.shape[:2]))
         with jax.named_scope("attn"):
-            h = _norm(x, w["ln1"], w.get("ln1_b"), c.variant, eps)
+            h = x if post else _norm(x, w["ln1"], w.get("ln1_b"), c.variant,
+                                     eps)
             if router_early:
                 with jax.named_scope("moe_router"):
                     idx, gate = _moe.softmax_topk_route(
@@ -1388,6 +1471,9 @@ def make_layer_fn(
                         gate.astype(jnp.float32)).astype(dt)
             o = proj(o, _leaf(w, "wo", dt), "bshd,hde->bse", n_contract=2)
             o = checkpoint_name(o, "attn_out")  # inert without a names policy
+            if post:
+                with jax.named_scope("post_norm"):
+                    o = _norm(o, w["ln1"], None, "llama", eps)
             if res != 1.0:
                 o = o * res
             x = pin(x + o, ("batch", "sequence", "embed"))
@@ -1395,8 +1481,12 @@ def make_layer_fn(
             return x, aux, state
 
         with jax.named_scope("mlp"):
-            h = _norm(x, w["ln2"], w.get("ln2_b"), c.variant, eps)
-            if c.held_experts:
+            h = x if post else _norm(x, w["ln2"], w.get("ln2_b"), c.variant,
+                                     eps)
+            if routed and c.ffn_kind == "sigmoid_experts":
+                ff, aux = _moe.sigmoid_expert_half(h, w, experts, index,
+                                                   rcfg, dt)
+            elif routed:
                 ht = h.reshape(-1, h.shape[-1])
                 if not router_early:
                     with jax.named_scope("moe_router"):
@@ -1425,6 +1515,9 @@ def make_layer_fn(
                 hidden = checkpoint_name(hidden, "ffn_hidden")
                 ff = (proj(hidden, _leaf(w, "w_down", dt), "bsf,fe->bse")
                       + _leaf(w, "b_out", dt))
+            if post:
+                with jax.named_scope("post_norm"):
+                    ff = _norm(ff, w["ln2"], None, "llama", eps)
             if res != 1.0:
                 ff = ff * res
             x = pin(x + ff, ("batch", "sequence", "embed"))
@@ -1557,14 +1650,18 @@ def _forward_served(params, tokens, cfg, return_hidden=False,
     c = cfg
     x = (inputs_embeds.astype(jnp.dtype(c.dtype))
          if inputs_embeds is not None else embed_tokens(params, tokens, c))
-    experts, layers = split_experts(params["layers"], c)
+    runs = stack_runs(c)
+    split = {key: split_experts(params[key], c)
+             for key in {run.key for run in runs}}
 
     def layer_of(run):
+        experts, layers = split[run.key]
         block = make_layer_fn(c, attention_fn=attention_fn, experts=experts,
-                              kind=layer_kind(c, run.first))
+                              kind=layer_kind(c, run.first),
+                              dense=run.key == "dense_layers")
         return layers, lambda x, _, w, i: (block(x, w, None, i)[0], None, None)
 
-    x, _, _ = scan_runs(stack_runs(c), x, {}, layer_of)
+    x, _, _ = scan_runs(runs, x, {}, layer_of)
     x = final_norm(params, x, c)
     return x if return_hidden else lm_logits(params, x, c)
 
@@ -1613,8 +1710,9 @@ def forward_with_aux(
         if (c.prefix_lm or c.remat_scan or c.pipeline_stages > 1
                 or mask is not None or constrain is not None):
             raise NotImplementedError(
-                f"attn_kind {c.attn_kind!r} / ffn_kind {c.ffn_kind!r} / "
-                f"generation {c.generation!r} / layer_windows, layer_rope: "
+                f"attn_kind {c.attn_kind!r} / norm_kind {c.norm_kind!r} / "
+                f"ffn_kind {c.ffn_kind!r} / generation {c.generation!r} / "
+                "layer_windows, layer_rope: "
                 "the forward pass on one device, and nothing of prefix_lm, "
                 "remat_scan, pipeline stages (parallel/pipeline.py), a "
                 "token mask or a sharding rule")
@@ -1899,13 +1997,15 @@ def loss_fn(
     explicit loss mask is given, one is derived so only the generated
     span (positions >= prefix_len) is scored — GLM's objective shape.
     """
-    if cfg.held_experts or cfg.generation != "autoregressive":
+    if (cfg.held_experts or cfg.generation != "autoregressive"
+            or cfg.norm_kind == "post"):
         raise NotImplementedError(
             f"loss_fn: ffn_kind {cfg.ffn_kind!r} / generation "
-            f"{cfg.generation!r} are served, not trained: the held "
-            "experts' tile loop has a data-dependent trip count (no "
-            "gradient), and a block-diffusion model's objective is a "
-            "masked-token loss this file does not have")
+            f"{cfg.generation!r} / norm_kind {cfg.norm_kind!r} are served, "
+            "not trained: the held experts' tile loop has a data-dependent "
+            "trip count (no gradient), a block-diffusion model's objective "
+            "is a masked-token loss this file does not have, and no "
+            "training path was ever run under 'post' norms")
     tokens = batch["tokens"]
     in_mask = batch.get("mask")
     prefix_len = batch.get("prefix_len") if cfg.prefix_lm else None

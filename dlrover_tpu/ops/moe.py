@@ -263,6 +263,29 @@ def softmax_topk_route(h: jax.Array, w_router: jax.Array,
     return idx.astype(jnp.int32), top * cfg.scaling
 
 
+def sigmoid_expert_half(h: jax.Array, w: dict, experts: dict, layer,
+                        cfg: RoutedConfig, dt) -> tuple[jax.Array, jax.Array]:
+    """A 'sigmoid_experts' layer's feed-forward on its input ``h [B, S,
+    E]`` (normed or not: the block's norm kind says), the ONE definition
+    every block of that kind calls (``models/transformer.py``'s,
+    ``models/latent.py``'s): the held experts' part of the sigmoid-routed
+    sum (:func:`sigmoid_topk_route`, the choice by score + ``w['b_router']``
+    where the layer has one; :func:`held_expert_ffn` on ``experts`` at
+    ``layer``) beside the shared expert ``ws_*``, which every chip of a
+    deployment computes alike. Returns ``(ff [B, S, E] in dt, loads
+    [held])``."""
+    ht = h.reshape(-1, h.shape[-1])
+    with jax.named_scope("moe_router"):
+        idx, gate = sigmoid_topk_route(ht, w["w_router"], cfg,
+                                       bias=w.get("b_router"))
+    with jax.named_scope("moe_experts"):
+        routed, loads = held_expert_ffn(ht, idx, gate, experts, layer, cfg)
+    with jax.named_scope("moe_shared"):
+        shared = swiglu(h, w["ws_gate"].astype(dt), w["ws_up"].astype(dt),
+                        w["ws_down"].astype(dt))
+    return shared + routed.reshape(h.shape).astype(dt), loads
+
+
 def held_counters(n_layers: int, n_held: int) -> dict:
     """What a cache tree keeps of its expert layers, which every cached
     call adds to: ``loads [expert layers, held]`` (assignments each held

@@ -393,6 +393,67 @@ def test_windowed_ring_serving_programs_compile(one_chip, monkeypatch):
     assert chunk.memory_analysis().temp_size_in_bytes < 0.7e9
 
 
+def test_post_norm_shared_expert_serving_programs_compile(one_chip,
+                                                          monkeypatch):
+    """Decode block and prefill chunk of ``InferenceEngine`` for K-EXAONE's
+    held share at the cell's sizes (layers 0-4: the dense layer's tree
+    beside the expert layers', 16 of 128 experts, an eighth of the
+    vocabulary; 48 slots of 8192 positions, a ring of 128 under chunks of
+    512). What the CPU cannot show: the grouped kernel once for each run
+    of expert layers (three) and none for the dense layer's, the full
+    layer's rows and the four rings donated and updated in place at 48
+    unrolled row writes, and the chunk's wide form (640 keys a windowed
+    layer) beside the full layer's reach within a chip's memory."""
+    from dlrover_tpu.models import decode
+    from dlrover_tpu.serving import engine as serving
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    base = tfm.CONFIGS["k-exaone-236b-a23b"]
+    cfg = dataclasses.replace(
+        base, n_layers=5, layer_windows=base.layer_windows[:5],
+        layer_rope=base.layer_rope[:5], experts_held=16, vocab_size=19200,
+        dtype="bfloat16")
+    assert [r.key for r in tfm.stack_runs(cfg)] == [
+        "dense_layers", "layers", "layers", "layers"]
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip),
+        tfm.param_shapes(cfg), is_leaf=lambda s: isinstance(s, tuple))
+    eng = serving.InferenceEngine(params, cfg, slots=48, max_len=8192,
+                                  prefill_len=512, decode_block=8)
+    row_bytes = 2 * 8 * 128 * 2
+    assert eng.cache_bytes_per_token == row_bytes            # one full layer
+    assert eng.state_bytes_per_slot == 4 * 128 * row_bytes   # four rings
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: a if isinstance(a, jax.ShapeDtypeStruct)
+            else jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                      sharding=one_chip), tree)
+
+    step = eng._step_block.lower(
+        *on_chip(eng._block_sample_args()), n_steps=8
+    ).compile(compiler_options=serving._CANONICAL_NUMERICS)
+    rings = eng._cache["state"]
+    assert rings["k_win"].shape == (4, 48, 8, 128, 128)
+    assert eng._cache["k"].shape == (1, 48, 8, 8192, 128)
+    assert executable_stats(step)["pallas_calls"] == 3   # one an expert run
+    held = 2 * 2 * (rings["k_win"].size + eng._cache["k"].size)
+    m = step.memory_analysis()
+    assert m.alias_size_in_bytes >= held                # donated whole
+    # beside 7.42 GB of weights and 1.71 of cache: no copy of the full
+    # layer's stacks (0.8 GB each) nor of a layer's experts (1.2 GB)
+    assert m.temp_size_in_bytes < 0.9e9
+    assert _device_bytes(step) < HBM_BYTES
+    row = on_chip(jax.eval_shape(lambda: decode.init_cache(cfg, 1, 8192)))
+    chunk = eng._prefill_chunk.lower(
+        params, jax.ShapeDtypeStruct((1, 512), jnp.int32, sharding=one_chip),
+        row, jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    ).compile()
+    assert executable_stats(chunk)["pallas_calls"] == 3
+    assert chunk.memory_analysis().temp_size_in_bytes < 0.9e9
+
+
 # the plain per-head tree's two cells: the model's widths (a smaller
 # vocabulary, few experts: neither is in attention's way), the engine's
 # slots and row length, and the stacks they give
@@ -501,6 +562,8 @@ EXPERT_CALLS = {
     "openpangu.chunk": (512, 7680, 2048, 16, 256, 8, 16, "swiglu"),
     "smallthinker.decode": (24, 2560, 768, 64, 64, 6, 0, "reglu"),
     "smallthinker.chunk": (512, 2560, 768, 64, 64, 6, 0, "reglu"),
+    "k-exaone.decode": (48, 6144, 2048, 16, 128, 8, 0, "swiglu"),
+    "k-exaone.chunk": (512, 6144, 2048, 16, 128, 8, 0, "swiglu"),
 }
 
 
